@@ -471,8 +471,8 @@ class PipelinedGPT(PipelinedCommon):
     logits) instead of replicating the whole-vocab matmul.  Same KNOWN
     LIMITATION as PipelinedBert: amp O2/O3 compute inside the
     partial-manual region trips this jax build's XLA CPU backend;
-    ``tp_axis`` is tested fp32 (tools/tp_pp_bf16_check.py rechecks the
-    TPU backend at live windows).
+    ``tp_axis`` is tested fp32 (bf16 on the TPU backend is open:
+    ``CHANGES.md`` PR 21, ``ROADMAP.md`` Speed item 10).
 
     Dropout composes like PipelinedBert: ``deterministic=False`` +
     ``rngs={"dropout": key}``; each (microbatch, stage[, shard]) folds

@@ -27,8 +27,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
-from apex_tpu.ops.pallas_utils import LANES, on_tpu, pallas_auto_gate
+from apex_tpu.ops.pallas_utils import (LANES, on_tpu, pallas_auto_gate,
+                                       union_vma)
 
 Shape = Union[int, Sequence[int]]
 
@@ -106,8 +108,6 @@ def _row_block(n2p: int, itemsize: int = 4) -> int:
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def _ln_fwd_pallas(x2: jax.Array, eps: float, interpret: bool):
-    from jax.experimental import pallas as pl
-
     n1 = x2.shape[0]
     xp, n2 = _pad_cols(x2)
     rows = _row_block(xp.shape[1])
@@ -117,6 +117,7 @@ def _ln_fwd_pallas(x2: jax.Array, eps: float, interpret: bool):
     grid = (n1p // rows,)
     row_spec = pl.BlockSpec((rows, xp.shape[1]), lambda i: (i, 0))
     stat_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+    vma = union_vma(x2)
     y, mean, invvar = pl.pallas_call(
         functools.partial(_ln_fwd_kernel, n2=n2, eps=eps),
         grid=grid,
@@ -128,11 +129,12 @@ def _ln_fwd_pallas(x2: jax.Array, eps: float, interpret: bool):
             # O(eps_bf16) error that the dweight row-sum amplifies (the
             # reference keeps fp32 stats for the same reason,
             # layer_norm_cuda_kernel.cu accumulation dtype)
-            jax.ShapeDtypeStruct(xp.shape, jnp.float32),
-            jax.ShapeDtypeStruct((n1p, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n1p, LANES), jnp.float32),
+            jax.ShapeDtypeStruct(xp.shape, jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((n1p, LANES), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((n1p, LANES), jnp.float32, vma=vma),
         ],
         interpret=interpret,
+        name="_ln_fwd_kernel",
     )(xp)
     return y[:n1, :n2], mean[:n1, 0], invvar[:n1, 0]
 
@@ -140,8 +142,6 @@ def _ln_fwd_pallas(x2: jax.Array, eps: float, interpret: bool):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _ln_bwd_pallas(dy2: jax.Array, xhat2: jax.Array, invvar: jax.Array,
                    interpret: bool):
-    from jax.experimental import pallas as pl
-
     n1 = dy2.shape[0]
     dyp, n2 = _pad_cols(dy2)
     xhp, _ = _pad_cols(xhat2)
@@ -160,8 +160,10 @@ def _ln_bwd_pallas(dy2: jax.Array, xhat2: jax.Array, invvar: jax.Array,
         grid=grid,
         in_specs=[row_spec, row_spec, stat_spec],
         out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct(dyp.shape, dy2.dtype),
+        out_shape=jax.ShapeDtypeStruct(dyp.shape, dy2.dtype,
+                                       vma=union_vma(dy2, xhat2, invvar)),
         interpret=interpret,
+        name="_ln_bwd_kernel",
     )(dyp, xhp, iv)
     return dx[:n1, :n2]
 
@@ -182,10 +184,7 @@ def _match_vma(cotangent, primal):
     cotangents of replicated (invariant) inputs; a custom_vjp must do the
     same by hand or the vma check rejects the bwd output. No-op outside
     shard_map (both vma sets empty)."""
-    try:
-        extra = jax.typeof(cotangent).vma - jax.typeof(primal).vma
-    except AttributeError:
-        return cotangent
+    extra = jax.typeof(cotangent).vma - jax.typeof(primal).vma
     if extra:
         cotangent = jax.lax.psum(cotangent, tuple(sorted(extra)))
     return cotangent

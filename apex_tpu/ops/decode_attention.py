@@ -46,9 +46,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.kv_quant import dequantize_kv
-from apex_tpu.ops.pallas_utils import pallas_auto_gate, on_tpu, unpatched
+from apex_tpu.ops.pallas_utils import (on_tpu, pallas_auto_gate,
+                                       union_vma, unpatched)
 
 NEG_INF = -1e30
 
@@ -144,14 +147,6 @@ def _decode_kernel_q8(bias_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
                  o_ref, acc_ref, m_ref, l_ref, scale=scale, nk=nk)
 
 
-try:  # mirrors ops.flash_attention: Pallas is TPU-only machinery
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover - environment without pallas
-    _HAVE_PALLAS = False
-
-
 @functools.partial(jax.jit,
                    static_argnames=("scale", "bk", "interpret"))
 def _decode_pallas(q3, k3, v3, bias, ksc=None, vsc=None, *,
@@ -183,18 +178,20 @@ def _decode_pallas(q3, k3, v3, bias, ksc=None, vsc=None, *,
         in_specs = [bias_spec, s_spec, s_spec, q_spec, k_spec, k_spec]
         args = (bias[:, None, :], ksc[:, None, :], vsc[:, None, :],
                 q3, k3, v3)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=(bh, nk),
         in_specs=in_specs,
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, _QROWS, d), q3.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, _QROWS, d), q3.dtype,
+                                       vma=union_vma(*args)),
         scratch_shapes=[pltpu.VMEM((_QROWS, d), jnp.float32),
                         pltpu.VMEM((_QROWS, lanes), jnp.float32),
                         pltpu.VMEM((_QROWS, lanes), jnp.float32)],
         interpret=interpret,
+        # names the custom call in the compiled HLO and in traces
+        name=kernel.func.__name__,
     )(*args)
-    return out
 
 
 def _layout(x):
@@ -322,7 +319,7 @@ def cached_attention(q, k, v, *, kv_bias: Optional[jax.Array] = None,
     _check_scales(k, k_scale, v_scale, "cached_attention")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if not (_HAVE_PALLAS and pallas_auto_gate(use_pallas)):
+    if not pallas_auto_gate(use_pallas):
         return _reference(q, k, v, kv_bias, scale, k_scale, v_scale)
 
     if interpret is None:

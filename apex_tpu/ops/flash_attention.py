@@ -61,8 +61,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops.pallas_utils import on_tpu, pallas_auto_gate, unpatched
+from apex_tpu.ops.pallas_utils import (on_tpu, pallas_auto_gate,
+                                       union_vma, unpatched)
 
 NEG_INF = -1e30
 
@@ -337,14 +340,6 @@ def _bwd_dkv_kernel(mask_ref, seed_ref, q_ref, k_ref, v_ref, do_ref,
 # host-side drivers
 # ---------------------------------------------------------------------------
 
-try:  # pallas is optional at import time (pure-jnp path works without it)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
-
 def _layout(x):
     """(B, S, H, D) -> (B*H, S, D)."""
     b, s, h, d = x.shape
@@ -362,26 +357,6 @@ def _pad_seq(x, block):
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
     return x
-
-
-def _union_vma(*xs):
-    """Union of the operands' varying mesh axes (shard_map's vma typing) —
-    pallas_call out_shapes must declare it explicitly under the default
-    check_vma=True."""
-    vma = set()
-    for x in xs:
-        try:
-            vma |= set(jax.typeof(x).vma)
-        except AttributeError:
-            pass
-    return frozenset(vma)
-
-
-def _out_struct(shape, dtype, vma):
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # older jax without vma typing
-        return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def _specs(bq, bk, d, h):
@@ -410,7 +385,7 @@ def _fwd_pallas(q3, k3, v3, mask, seed, *, scale, causal, bq, bk, h,
     lanes = 128
     q_spec, k_spec, mask_spec, row_spec = _specs(bq, bk, d, h)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    vma = _union_vma(q3, k3, v3, mask)
+    vma = union_vma(q3, k3, v3, mask)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, dropout_rate=dropout_rate,
@@ -418,12 +393,13 @@ def _fwd_pallas(q3, k3, v3, mask, seed, *, scale, causal, bq, bk, h,
         grid=(bh, nq, nk),
         in_specs=[mask_spec, seed_spec, q_spec, k_spec, k_spec],
         out_specs=[q_spec, row_spec],
-        out_shape=[_out_struct((bh, sq, d), q3.dtype, vma),
-                   _out_struct((bh, 1, sq), jnp.float32, vma)],
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q3.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32, vma=vma)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
                         pltpu.VMEM((bq, lanes), jnp.float32),
                         pltpu.VMEM((bq, lanes), jnp.float32)],
         interpret=interpret,
+        name="_fwd_kernel",
     )(mask[:, None, :], seed, q3, k3, v3)
     return o, lse[:, 0, :]                           # (BH, Sq)
 
@@ -449,7 +425,7 @@ def _bwd_pallas(q3, k3, v3, do3, o3, lse, mask, seed, *, scale, causal,
     lse3 = lse[:, None, :]
     delta3 = delta[:, None, :]
 
-    vma = _union_vma(q3, k3, v3, do3, lse3, delta3, mask3)
+    vma = union_vma(q3, k3, v3, do3, lse3, delta3, mask3)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, dropout_rate=dropout_rate,
@@ -458,9 +434,10 @@ def _bwd_pallas(q3, k3, v3, do3, o3, lse, mask, seed, *, scale, causal,
         in_specs=[mask_spec, seed_spec, q_spec, k_spec, k_spec, q_spec,
                   row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=_out_struct((bh, sq, d), q3.dtype, vma),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="_bwd_dq_kernel",
     )(mask3, seed, q3, k3, v3, do3, lse3, delta3)
 
     dkv_kspec = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
@@ -475,11 +452,12 @@ def _bwd_pallas(q3, k3, v3, do3, o3, lse, mask, seed, *, scale, causal,
         in_specs=[dkv_mask, seed_spec, dkv_qspec, dkv_kspec, dkv_kspec,
                   dkv_qspec, dkv_row, dkv_row],
         out_specs=[dkv_kspec, dkv_kspec],
-        out_shape=[_out_struct((bh, sk, d), k3.dtype, vma),
-                   _out_struct((bh, sk, d), v3.dtype, vma)],
+        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k3.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, sk, d), v3.dtype, vma=vma)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="_bwd_dkv_kernel",
     )(mask3, seed, q3, k3, v3, do3, lse3, delta3)
     return dq, dk, dv
 
@@ -753,7 +731,7 @@ def flash_attention(q, k, v, *, kv_mask: Optional[jax.Array] = None,
         # short-sequence auto fallback: XLA attention wins below the
         # crossover (FLASH_AUTO_MIN_SEQ, BENCH_NOTES r5)
         use = False
-    if not use or not _HAS_PALLAS:
+    if not use:
         return _reference(q, k, v, kv_mask, causal, scale,
                           return_lse=return_lse,
                           dropout_rate=dropout_rate, seed=seed)

@@ -6,11 +6,18 @@ extension built by setup.py with graceful degradation when absent
 path). Same contract here: the shared library is compiled on first use
 with g++ (no pip involved), cached next to this file, and every entry
 point has a numpy fallback — ``available`` tells you which path is live.
+
+The cached library is keyed on a hash of the source: its file name is
+``_libapex_tpu_host.<hash>.so``, so a library built from another version
+of ``host_ops.cpp`` (``*.so`` is gitignored, and a copied checkout can
+carry one) is never loaded; it is rebuilt and the stale file removed.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -20,7 +27,7 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(os.path.dirname(_HERE), "csrc", "host_ops.cpp")
-_LIB_PATH = os.path.join(_HERE, "_libapex_tpu_host.so")
+_LIB_PREFIX = "_libapex_tpu_host"
 
 _lib: Optional[ctypes.CDLL] = None
 available = False
@@ -28,14 +35,22 @@ jpeg_available = False
 _ABI = 2
 
 
-def _build() -> bool:
+def _lib_path(src: str = _SRC, lib_dir: str = _HERE) -> str:
+    """Where the library built from ``src`` as it is now lives."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(lib_dir, f"{_LIB_PREFIX}.{digest}.so")
+
+
+def _build(src: str, lib_path: str) -> bool:
+    lib_dir = os.path.dirname(lib_path)
     try:
         # build into a temp file then atomic-rename so concurrent imports
         # never load a half-written .so
-        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
+        fd, tmp = tempfile.mkstemp(suffix=".so.tmp", dir=lib_dir)
         os.close(fd)
         base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-                _SRC, "-o", tmp]
+                src, "-o", tmp]
         # try with libjpeg (the batch decode path) first; fall back to a
         # decode-less build on systems without it
         r = subprocess.run(base + ["-DAPEX_HAVE_JPEG", "-ljpeg"],
@@ -45,10 +60,35 @@ def _build() -> bool:
         if r.returncode != 0:
             os.unlink(tmp)
             return False
-        os.replace(tmp, _LIB_PATH)
+        os.replace(tmp, lib_path)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return False
+
+
+def _open_or_build(src: str = _SRC,
+                   lib_dir: str = _HERE) -> Optional[ctypes.CDLL]:
+    """The library for ``src``'s current contents: the cached one when
+    its hash matches, else a fresh build (libraries of other hashes in
+    ``lib_dir`` are removed).  None when it cannot be built or does not
+    load here — callers then take the numpy paths."""
+    lib_path = _lib_path(src, lib_dir)
+    for stale in glob.glob(os.path.join(lib_dir, _LIB_PREFIX + "*.so")):
+        if stale != lib_path:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
+    if not os.path.exists(lib_path) and not _build(src, lib_path):
+        return None
+    try:
+        lib = ctypes.CDLL(lib_path)
+    except OSError:          # e.g. built on another architecture
+        return None
+    lib.apex_native_abi_version.restype = ctypes.c_int
+    if lib.apex_native_abi_version() != _ABI:
+        return None          # source and bindings disagree
+    return lib
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -59,33 +99,9 @@ def _load() -> Optional[ctypes.CDLL]:
         # build-matrix hook: force the python-only install path (the
         # reference's "no --cpp_ext" axis) without monkeypatching
         return None
-    if not os.path.exists(_LIB_PATH) and not _build():
+    lib = _open_or_build()
+    if lib is None:
         return None
-
-    def _open():
-        lib = ctypes.CDLL(_LIB_PATH)
-        lib.apex_native_abi_version.restype = ctypes.c_int
-        return lib
-
-    try:
-        lib = _open()
-        stale = lib.apex_native_abi_version() != _ABI
-    except OSError:
-        stale = True  # e.g. different arch
-    if stale:
-        # out-of-date cached .so (older ABI / other arch) — rebuild once
-        try:
-            os.unlink(_LIB_PATH)
-        except OSError:
-            pass
-        if not _build():
-            return None
-        try:
-            lib = _open()
-        except OSError:
-            return None
-        if lib.apex_native_abi_version() != _ABI:
-            return None
 
     u8p = ctypes.POINTER(ctypes.c_uint8)
     i64p = ctypes.POINTER(ctypes.c_int64)

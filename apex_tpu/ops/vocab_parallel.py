@@ -32,7 +32,8 @@ KNOWN LIMITATION (shared with ``PipelinedBert`` ``tp_axis``):
 half-precision compute inside a partial-manual shard_map region trips
 this jax build's XLA **CPU** backend ("Invalid binary instruction
 opcode copy"); fp32 hidden works everywhere, bf16 hidden needs the TPU
-backend (``tools/tp_pp_bf16_check.py`` revalidates at live windows).
+backend (not yet re-run on the current installation: ``CHANGES.md`` PR 21,
+``ROADMAP.md`` Speed item 10).
 """
 
 from __future__ import annotations
@@ -59,19 +60,11 @@ SHARD_CANDIDATES = 128
 
 
 def _shard_map(f, mesh, in_specs, out_specs, axis: str):
-    """Partial-manual ``shard_map`` across jax API generations: the
-    current API spells it ``axis_names={axis}``/``check_vma``; this
-    build's ``jax.experimental`` API spells the same thing
-    ``auto=<every other axis>``/``check_rep``.  Only ``axis`` is
-    manual either way — dp/sp sharding stays GSPMD-automatic."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names={axis},
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False,
-                     auto=frozenset(mesh.axis_names) - {axis})
+    """Partial-manual ``shard_map``: only ``axis`` is manual — dp/sp
+    sharding stays GSPMD-automatic."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={axis},
+                         check_vma=False)
 
 
 @functools.lru_cache(maxsize=32)

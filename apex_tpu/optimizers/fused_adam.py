@@ -36,11 +36,13 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.flatten import (FlatSpec, flatten, flatten_grouped,
                                   flatten_like, unflatten)
 from apex_tpu.ops.pallas_utils import (LANES, on_tpu, pad_to_tiles,
-                                       pallas_auto_gate, untile)
+                                       pallas_auto_gate, union_vma, untile)
 from apex_tpu.optimizers.param_groups import (group_hparams,
                                               resolve_group_ids)
 
@@ -114,9 +116,6 @@ def _adam_kernel(scalars_ref, p_ref, m_ref, v_ref, g_ref,
 def _adam_flat_pallas(p, m, v, g, scalars, *, eps_inside_sqrt: bool,
                       rows: int = 512, interpret: bool = False):
     """Run the fused kernel over tiled flat fp32 buffers."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     n = p.shape[0]
     pt, _ = pad_to_tiles(p, rows)
     mt, _ = pad_to_tiles(m, rows)
@@ -125,7 +124,8 @@ def _adam_flat_pallas(p, m, v, g, scalars, *, eps_inside_sqrt: bool,
     total_rows = pt.shape[0]
     grid = (total_rows // rows,)
     tile_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct(pt.shape, jnp.float32)
+    out_shape = jax.ShapeDtypeStruct(pt.shape, jnp.float32,
+                                     vma=union_vma(p, m, v, g, scalars))
     kernel = functools.partial(_adam_kernel, eps_inside_sqrt=eps_inside_sqrt)
     p2, m2, v2 = pl.pallas_call(
         kernel,
@@ -140,6 +140,7 @@ def _adam_flat_pallas(p, m, v, g, scalars, *, eps_inside_sqrt: bool,
         # fused_adam_cuda_kernel.cu): halves the HBM footprint of the step
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
+        name="_adam_kernel",
     )(scalars, pt, mt, vt, gt)
     return untile(p2, n), untile(m2, n), untile(v2, n)
 
@@ -496,9 +497,9 @@ class FusedAdam:
                     # elementwise update, so no collectives inside
                     from jax.sharding import PartitionSpec as P
                     sharded = P(ax)
-                    # check_vma=False: pallas_call outputs carry no vma
-                    # annotation; the update is shard-local elementwise,
-                    # so there is no replication invariant to check
+                    # check_vma=False: the update is shard-local
+                    # elementwise, so there is no replication invariant
+                    # to check
                     return jax.shard_map(
                         call, mesh=mesh,
                         in_specs=(sharded, sharded, sharded, sharded, P()),

@@ -1,11 +1,11 @@
-"""Grouped collective primitives portable across TPU and CPU backends.
+"""Grouped collective primitives for process-group code (SyncBN groups,
+grouped DDP).
 
-XLA TPU supports replica groups (``axis_index_groups``) natively; the CPU
-host-platform backend in this JAX version hangs compiling grouped psum
-under shard_map. These wrappers use native replica groups on TPU and an
-equivalent all_gather+mask formulation elsewhere, so process-group code
-(SyncBN groups, grouped DDP) tests on the virtual CPU mesh and runs native
-on hardware.
+One formulation on every backend, the one that traces inside a default
+(``check_vma=True``) ``shard_map``: ``lax.all_gather`` takes
+``axis_index_groups`` there, ``lax.psum`` does not (it raises
+``NotImplementedError`` at trace time), so the grouped sum is a grouped
+gather followed by a local sum.
 
 Group partitions must be equal-sized (guaranteed by
 ``create_process_group``).
@@ -13,13 +13,11 @@ Group partitions must be equal-sized (guaranteed by
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
-
-from apex_tpu.ops.pallas_utils import on_tpu
 
 
 def vary_like(x, *refs, extra_axes=()):
@@ -27,42 +25,19 @@ def vary_like(x, *refs, extra_axes=()):
     ``extra_axes``, e.g. a ring axis that ppermute will introduce) —
     needed so lax.cond/scan branches built from constants type-check
     under shard_map's vma tracking. No-op outside shard_map."""
-    import jax
-
-    try:
-        target = set(extra_axes)
-        for r in refs:
-            target |= set(jax.typeof(r).vma)
-        missing = tuple(sorted(target - set(jax.typeof(x).vma)))
-    except AttributeError:
-        return x
+    target = set(extra_axes)
+    for r in refs:
+        target |= set(jax.typeof(r).vma)
+    missing = tuple(sorted(target - set(jax.typeof(x).vma)))
     return lax.pcast(x, missing, to="varying") if missing else x
-
-
-def _group_maps(groups) -> Tuple[np.ndarray, np.ndarray]:
-    """(rank->group id, group id -> member ranks) as static arrays."""
-    n_ranks = sum(len(g) for g in groups)
-    rank_to_group = np.zeros((n_ranks,), np.int32)
-    members = np.asarray(groups, np.int32)
-    for gid, g in enumerate(groups):
-        for r in g:
-            rank_to_group[r] = gid
-    return rank_to_group, members
 
 
 def psum_g(x, axis_name: str, groups: Optional[Sequence[Sequence[int]]] = None):
     """psum over the axis, or within equal-sized groups of it."""
     if groups is None:
         return lax.psum(x, axis_name)
-    if on_tpu():
-        return lax.psum(x, axis_name, axis_index_groups=groups)
-    rank_to_group, _ = _group_maps(groups)
-    idx = lax.axis_index(axis_name)
-    my_gid = jnp.asarray(rank_to_group)[idx]
-    gathered = lax.all_gather(x, axis_name)           # (W, ...)
-    mask = (jnp.asarray(rank_to_group) == my_gid)
-    mask = mask.reshape((-1,) + (1,) * (gathered.ndim - 1))
-    return jnp.sum(jnp.where(mask, gathered, 0), axis=0)
+    return jnp.sum(lax.all_gather(x, axis_name, axis_index_groups=groups),
+                   axis=0)
 
 
 def pmean_g(x, axis_name: str, groups=None):
@@ -75,27 +50,5 @@ def all_gather_g(x, axis_name: str, groups=None, *, axis: int = 0,
                  tiled: bool = False):
     """all_gather over the axis or within groups; group results stack the
     group's members in group order."""
-    if groups is None:
-        return lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
-    if on_tpu():
-        return lax.all_gather(x, axis_name, axis=axis, tiled=tiled,
-                              axis_index_groups=groups)
-    rank_to_group, members = _group_maps(groups)
-    # normalize negative axes against the *output* rank (tiled keeps the
-    # input rank; untiled inserts a new axis) so the slice arithmetic below
-    # can't wrap around
-    axis = axis % (jnp.ndim(x) if tiled else jnp.ndim(x) + 1)
-    idx = lax.axis_index(axis_name)
-    my_gid = jnp.asarray(rank_to_group)[idx]
-    my_members = jnp.asarray(members)[my_gid]         # (G,) dynamic row
-    # gather untiled (one entry per rank on a new axis), select the group's
-    # members, then collapse the rank axis into `axis` if tiled output was
-    # requested — taking raw rank indices out of a tiled (concatenated)
-    # gather would pick shard rows, not rank blocks.
-    gathered = lax.all_gather(x, axis_name, axis=axis, tiled=False)
-    picked = jnp.take(gathered, my_members, axis=axis)  # (..., G, d, ...)
-    if not tiled:
-        return picked
-    shape = list(picked.shape)
-    shape[axis:axis + 2] = [shape[axis] * shape[axis + 1]]
-    return picked.reshape(shape)
+    return lax.all_gather(x, axis_name, axis=axis, tiled=tiled,
+                          axis_index_groups=groups)

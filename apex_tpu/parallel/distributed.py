@@ -182,11 +182,8 @@ class DistributedDataParallel:
         # checking is on; under check_rep/check_vma=False EVERY value has
         # an empty vma set and "not in vma" would wrongly skip the psum.
         # Probe with axis_index, which is varying by construction.
-        try:
-            probe = lax.axis_index(pg.axis_name)
-            vma_tracked = pg.axis_name in jax.typeof(probe).vma
-        except AttributeError:
-            vma_tracked = False
+        probe = lax.axis_index(pg.axis_name)
+        vma_tracked = pg.axis_name in jax.typeof(probe).vma
 
         def one(g):
             orig_dtype = g.dtype

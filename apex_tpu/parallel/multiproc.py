@@ -17,15 +17,28 @@ reference's ecosystem uses (WORLD_SIZE/RANK, reference
 Running as a module (``python -m apex_tpu.parallel.multiproc script.py``)
 spawns NUM_PROCESSES local copies with PROCESS_ID set, logging non-zero
 ranks to ``PROC_i.log`` — matching the reference launcher's behavior
-(``GPU_i.log``) for local multi-process CPU experiments.
+(``GPU_i.log``) for local multi-process CPU experiments.  The copies
+share one environment, so on a host with TPU chips each would try to
+open every chip, and a chip belongs to one process: there the launcher
+refuses to start more than one.  One process drives all the chips of a
+host (``jax.devices()``, a ``Mesh`` over them).
 """
 
 from __future__ import annotations
 
+import glob
 import os
 import subprocess
 import sys
 from typing import Optional
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips on this host, counted from its device nodes without
+    touching JAX: a launcher that had opened the chips would itself
+    keep them from its children."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 def initialize_distributed(coordinator_address: Optional[str] = None,
@@ -70,6 +83,19 @@ def main(argv=None):
     world = int(os.environ.get("NUM_PROCESSES",
                                os.environ.get("WORLD_SIZE", "1")))
     addr = os.environ.get("COORDINATOR_ADDRESS", "localhost:12355")
+    chips = (_local_tpu_chips()
+             if world > 1 and os.environ.get("JAX_PLATFORMS") != "cpu"
+             else 0)
+    if chips:
+        print(f"apex_tpu.parallel.multiproc: refusing to start {world} "
+              f"local processes on a host with {chips} TPU "
+              "chip(s): they would share one environment, each would "
+              "try to open every chip, and a chip belongs to one "
+              "process.  This launcher is for multi-host bootstrap (one "
+              "process per host, started by the pod runtime) and for "
+              "local CPU runs (JAX_PLATFORMS=cpu); on one host, one "
+              "process drives all the chips.", file=sys.stderr)
+        return 2
     procs = []
     for rank in range(world):
         env = dict(os.environ, PROCESS_ID=str(rank), NUM_PROCESSES=str(world),

@@ -70,6 +70,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.observability import NULL_PROGRAM_ACCOUNTING, NULL_TRACER
 from apex_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+from apex_tpu.ops.pallas_utils import on_tpu
 from apex_tpu.ops.sampling import finite_rows, greedy_argmax, sample_tokens
 from apex_tpu.ops.vocab_parallel import (
     vocab_parallel_sample,
@@ -222,6 +223,21 @@ class DecodeEngine:
                     f"tp_axis {tp_axis!r} is not an axis of the mesh "
                     f"(axes: {tuple(mesh.shape)})")
             self.tp = int(mesh.shape[tp_axis])
+            if mesh.size > 1 and on_tpu():
+                # the decode programs lower through GSPMD, which
+                # cannot partition the Mosaic kernels in them
+                # (cached_attention, FusedLayerNorm, flash prefill):
+                # refuse here rather than fail in the first launch or
+                # serve from the jnp references unannounced
+                raise NotImplementedError(
+                    f"InferenceServer/DecodeEngine(mesh=...) over "
+                    f"{mesh.size} TPU devices: GSPMD cannot partition "
+                    "the Pallas kernels of the decode path (\"Mosaic "
+                    "kernels cannot be automatically partitioned\"), "
+                    "and they are not yet wrapped in a shard_map over "
+                    "the mesh.  Open work: CHANGES.md PR 21, ROADMAP.md "
+                    "Speed item 10.  One process can instead serve one "
+                    "unsharded replica on each chip.")
             if cfg.num_attention_heads % self.tp:
                 raise ValueError(
                     f"num_attention_heads={cfg.num_attention_heads} "
@@ -1078,6 +1094,18 @@ class DecodeEngine:
                 self._decode_jit._cache_size()
                 + self._decode_sampled_jit._cache_size()
                 + self._decode_stoch_jit._cache_size())
+
+    def decode_hlo(self) -> str:
+        """Compiled HLO text of the greedy decode program at this
+        engine's shapes: lowered and compiled (a persistent-cache hit
+        once the program has run), never executed.  ``chip_smoke.py``
+        looks for the ``_decode_kernel`` Mosaic call in it."""
+        b = self.max_batch_size
+        args = self._decode_args(
+            np.zeros((b,), np.int32), np.zeros((b,), np.int32),
+            np.zeros((b, self.blocks_per_seq), np.int32))
+        return self._decode_sampled_jit.lower(
+            self.params, self.cache, *args).compile().as_text()
 
     def verify_compiles(self) -> int:
         """Verify-program traces (logits + sampled + stochastic

@@ -73,7 +73,7 @@ in-flight request).  A bounded waiting queue (``max_waiting``) rejects
 at submission with :class:`QueueFullError`; expired deadlines and
 non-finite logits are detected by ``serving.api`` and routed through
 the same :meth:`Scheduler.fail` (reasons ``timeout`` / ``nonfinite``).
-``docs/resilience.md`` has the full failure taxonomy.
+``docs/resilience.md`` has the full failure catalogue.
 
 Overload control (:mod:`serving.overload`, on by default through
 ``InferenceServer``): requests carry a priority class and a

@@ -23,7 +23,9 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from apex_tpu import amp, models, optimizers
+from apex_tpu.ops.pallas_utils import on_tpu
 from apex_tpu.utils import AverageMeter, maybe_print
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def parse_args():
@@ -112,6 +114,7 @@ def synthetic_mlm_batch(rng, args, cfg):
 
 def main():
     args = parse_args()
+    enable_compile_cache()
     cfg = get_config(args.config)
     cfg = dataclasses.replace(cfg, remat=args.remat,
                               moe_experts=args.moe,
@@ -120,6 +123,19 @@ def main():
 
     devices = jax.devices()
     n_dev = len(devices)
+    if n_dev > 1 and on_tpu():
+        # the step below is a GSPMD-partitioned jit, which cannot carry
+        # the Mosaic kernels in it (FusedLayerNorm, flash attention):
+        # refuse rather than fail at the first step's lowering or train
+        # through the jnp references unannounced
+        raise SystemExit(
+            f"examples/bert: {n_dev} TPU devices - GSPMD cannot "
+            "partition the Pallas kernels of this step (\"Mosaic "
+            "kernels cannot be automatically partitioned\"), and the "
+            "step is not yet a shard_map region as in examples/gpt "
+            "(its masked-LM loss normalizes over the global batch).  "
+            "Open work: CHANGES.md PR 21, ROADMAP.md Speed item 10.  "
+            "Runs on one chip and on CPU meshes.")
     sp = args.ring_attention
     pp = args.pp
     if pp and sp:

@@ -30,6 +30,7 @@ import optax
 
 from apex_tpu import amp, models
 from apex_tpu.utils import AverageMeter, maybe_print
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def parse_args():
@@ -50,6 +51,7 @@ def parse_args():
 
 def main():
     args = parse_args()
+    enable_compile_cache()
 
     netG = models.Generator(z_dim=args.nz)
     netD = models.Discriminator()

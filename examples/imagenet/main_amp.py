@@ -39,6 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from apex_tpu import amp, models, parallel
 from apex_tpu.data import prefetch_to_device, put_global
 from apex_tpu.utils import AverageMeter, maybe_print
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 ARCHS = {
@@ -202,6 +203,7 @@ STD = np.array([0.229, 0.224, 0.225], np.float32) * 255.0
 
 def main():
     args = parse_args()
+    enable_compile_cache()
     if args.deterministic:
         jax.config.update("jax_default_matmul_precision", "highest")
 

@@ -25,6 +25,7 @@ import numpy as np
 
 from apex_tpu import models
 from apex_tpu.serving import InferenceServer
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def parse_args():
@@ -101,6 +102,7 @@ def build(args):
 
 def main():
     args = parse_args()
+    enable_compile_cache()
     cfg, params = build(args)
     attention_fn = None
     if args.flash:

@@ -30,6 +30,7 @@ shard_map = jax.shard_map
 
 from apex_tpu import amp, parallel
 from apex_tpu.models import MLP
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -47,6 +48,7 @@ def main():
                    "the DDP numeric knobs and --opt-level apply to "
                    "the default path only)")
     args = p.parse_args()
+    enable_compile_cache()
     if args.zero2 and (args.allreduce_always_fp32
                        or args.gradient_predivide_factor != 1.0):
         p.error("--zero2 bypasses ddp.reduce_gradients, so "

@@ -19,6 +19,7 @@ import numpy as np
 import optax
 
 from apex_tpu import amp
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 class MLP(nn.Module):
@@ -55,6 +56,7 @@ def main():
     parser.add_argument("--lr", type=float, default=0.05)
     parser.add_argument("--mnist-npz", default=None)
     args = parser.parse_args()
+    enable_compile_cache()
 
     if args.mnist_npz:
         with np.load(args.mnist_npz) as z:

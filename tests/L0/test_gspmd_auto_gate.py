@@ -1,6 +1,6 @@
 """Pallas kernels must step aside under GSPMD-automatic axes.
 
-Round-5 live-hardware finding (tools/tp_pp_bf16_check.py on v5e): inside
+Round-5 live-hardware finding (one v5e): inside
 a partial-manual ``shard_map`` region — pipelined Megatron TP, where the
 model axis stays automatic so XLA inserts the TP collectives — the SPMD
 partitioner rejects Mosaic custom calls outright::
@@ -65,9 +65,7 @@ def test_auto_gate_warns_once_on_tpu_downgrade(monkeypatch):
     import apex_tpu.ops.pallas_utils as pu
 
     monkeypatch.setattr(pu, "on_tpu", lambda: True)
-    monkeypatch.setattr(pu, "gspmd_auto_axes", lambda: True)
-    monkeypatch.setattr(pu, "_gspmd_auto_axis_names",
-                        lambda: ("model",))
+    monkeypatch.setattr(pu, "gspmd_auto_axes", lambda: ("model",))
     monkeypatch.setattr(pu, "_warned_auto_downgrade", False)
     with pytest.warns(RuntimeWarning, match=r"model"):
         assert pu.pallas_auto_gate() is False
@@ -85,7 +83,7 @@ def test_detector_outside_any_mesh():
     assert not gspmd_auto_axes()
     seen = []
     jax.jit(lambda x: (seen.append(gspmd_auto_axes()), x)[1])(jnp.ones(3))
-    assert seen == [False]
+    assert seen == [()]
 
 
 def test_detector_full_manual_vs_partial_manual():
@@ -93,11 +91,11 @@ def test_detector_full_manual_vs_partial_manual():
     seen = {}
 
     def full(x):
-        seen["full"] = gspmd_auto_axes()
+        seen["full"] = bool(gspmd_auto_axes())
         return x
 
     def partial(x):
-        seen["partial"] = gspmd_auto_axes()
+        seen["partial"] = bool(gspmd_auto_axes())
         return x
 
     with mesh:
@@ -109,6 +107,34 @@ def test_detector_full_manual_vs_partial_manual():
     # fully-manual regions keep the real kernels; partial-manual (an
     # Auto axis remains) must reroute
     assert seen == {"full": False, "partial": True}
+
+
+def test_detector_gspmd_jit_under_mesh_scopes():
+    """A plain ``jax.jit`` under a mesh scope of several devices is
+    sharded by GSPMD, and the Mosaic lowering refuses it ("cannot be
+    automatically partitioned") — in the legacy ``with mesh:`` scope,
+    which leaves the abstract mesh empty, as much as under
+    ``jax.set_mesh``.  A one-device mesh partitions nothing."""
+    mesh = _mesh()
+    seen = {}
+
+    def probe(tag):
+        def f(x):
+            seen[tag] = bool(gspmd_auto_axes())
+            return x
+        return jax.jit(f)
+
+    with mesh:
+        probe("legacy")(jnp.ones(8))
+    with jax.set_mesh(mesh):
+        probe("set_mesh")(jnp.ones(8))
+    one = Mesh(np.array(jax.devices()[:1]), ("data",))
+    with one:
+        probe("legacy_one")(jnp.ones(8))
+    with jax.set_mesh(one):
+        probe("set_mesh_one")(jnp.ones(8))
+    assert seen == {"legacy": True, "set_mesh": True,
+                    "legacy_one": False, "set_mesh_one": False}
 
 
 def _boobytrap(monkeypatch, module, kernel_name):

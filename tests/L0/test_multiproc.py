@@ -123,3 +123,18 @@ def test_launcher_spawns_world_size_processes(clean_env, tmp_path,
     assert (tmp_path / "rank_0.txt").read_text() == "2"
     assert (tmp_path / "rank_1.txt").read_text() == "2"
     assert "hello from 1" in (tmp_path / "PROC_1.log").read_text()
+
+
+def test_launcher_refuses_on_a_tpu_host(clean_env, tmp_path, monkeypatch,
+                                        capsys):
+    """Local copies share one environment, so on a TPU host each would
+    open every chip: the launcher starts none of them and says what it
+    is for.  JAX_PLATFORMS=cpu children need no chip and still run."""
+    monkeypatch.setattr(multiproc, "_local_tpu_chips", lambda: 4)
+    monkeypatch.setattr(
+        multiproc.subprocess, "Popen",
+        lambda *a, **k: pytest.fail("started a child on a TPU host"))
+    monkeypatch.setenv("NUM_PROCESSES", "4")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert multiproc.main([str(tmp_path / "never_run.py")]) == 2
+    assert "one process" in capsys.readouterr().err

@@ -4,7 +4,7 @@ Before this layer, ``Scheduler.admit`` / ``ensure_decode_capacity``
 raised ``MemoryError`` out of ``InferenceServer.generate``, killing
 every in-flight request; a non-finite logits row would silently poison
 sampling for the whole batch.  These tests pin the isolation contract
-(``docs/resilience.md`` failure taxonomy): under injected pool
+(``docs/resilience.md`` failure catalogue): under injected pool
 exhaustion, expired deadlines, a full queue, or poisoned logits, every
 HEALTHY request completes bit-identically to an undisturbed run and
 only the affected request carries the failure ``finish_reason``

@@ -11,6 +11,9 @@ per-dtype and printed).
 
 Usage: ``python tools/kernel_parity.py`` — prints one JSON line per
 kernel plus a final summary line; exit code 0 iff every kernel passes.
+Needs a TPU and exits non-zero without one.  ``chip_smoke.py`` calls
+:func:`run_checks` in-process; the checks take their sizes so that a
+CPU test can run the same control flow tiny, kernels in interpret mode.
 """
 
 import json
@@ -35,18 +38,15 @@ TOL = {
     jnp.bfloat16: 2e-2,  # + bf16 IO rounding; observed ~3-7e-3
 }
 
-RESULTS = []
 
-
-def record(kernel, dtype, ok, rel_err, max_err, note="", tol=None):
-    row = {"kernel": kernel, "dtype": str(jnp.dtype(dtype)),
+def row(kernel, dtype, ok, rel_err, max_err, note="", tol=None):
+    out = {"kernel": kernel, "dtype": str(jnp.dtype(dtype)),
            "pass": bool(ok), "rel_err": float(rel_err),
            "max_abs_err": float(max_err),
            "tol": TOL[dtype] if tol is None else tol}
     if note:
-        row["note"] = note
-    RESULTS.append(row)
-    print(json.dumps(row))
+        out["note"] = note
+    return out
 
 
 def _errs(a, b):
@@ -64,10 +64,10 @@ def _tree_errs(tree_a, tree_b):
     return max(e[0] for e in es), max(e[1] for e in es)
 
 
-def check_flash_attention(dtype):
+def check_flash_attention(dtype, shape=(2, 512, 4, 64)):
     from apex_tpu.ops.flash_attention import flash_attention
 
-    b, s, h, d = 2, 512, 4, 64
+    b, s, h, d = shape
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v = (jax.random.normal(kk, (b, s, h, d), dtype) for kk in ks[:3])
     kv_mask = jnp.where(
@@ -82,11 +82,12 @@ def check_flash_attention(dtype):
         ("flash_attention_dropout", dict(causal=True, dropout_rate=0.2,
                                          dropout_seed=11)),
     ]
+    rows = []
     for name, kw in variants:
         def loss(fn_use_pallas):
             def f(q, k, v):
                 o = flash_attention(q, k, v, use_pallas=fn_use_pallas,
-                                    interpret=False, **kw)
+                                    **kw)
                 return (o.astype(jnp.float32) ** 2).sum(), o
             return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
                                               has_aux=True))
@@ -96,13 +97,14 @@ def check_flash_attention(dtype):
         rel_o, max_o = _errs(o_p, o_r)
         rel_g, max_g = _tree_errs(g_p, g_r)
         rel, mx = max(rel_o, rel_g), max(max_o, max_g)
-        record(name, dtype, rel <= TOL[dtype], rel, mx)
+        rows.append(row(name, dtype, rel <= TOL[dtype], rel, mx))
+    return rows
 
 
-def check_fused_layer_norm(dtype):
+def check_fused_layer_norm(dtype, shape=(512, 1024)):
     from apex_tpu.normalization.fused_layer_norm import fused_layer_norm_affine
 
-    n1, n2 = 512, 1024
+    n1, n2 = shape
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     x = jax.random.normal(ks[0], (n1, n2), dtype)
     w = jax.random.normal(ks[1], (n2,), jnp.float32) * 0.1 + 1.0
@@ -121,7 +123,7 @@ def check_fused_layer_norm(dtype):
     rel_y, max_y = _errs(y_p, y_r)
     rel_g, max_g = _tree_errs(g_p, g_r)
     rel, mx = max(rel_y, rel_g), max(max_y, max_g)
-    record("fused_layer_norm", dtype, rel <= TOL[dtype], rel, mx)
+    return [row("fused_layer_norm", dtype, rel <= TOL[dtype], rel, mx)]
 
 
 def check_fused_adam(dtype):
@@ -148,7 +150,7 @@ def check_fused_adam(dtype):
     rel_m, max_m = _errs(s_p.m, s_r.m)
     rel, mx = max(rel_p, rel_m), max(max_p, max_m)
     # fused adam is pure elementwise VPU math: hold it to fp32 parity
-    record("fused_adam", dtype, rel <= 1e-5, rel, mx, tol=1e-5)
+    rows = [row("fused_adam", dtype, rel <= 1e-5, rel, mx, tol=1e-5)]
 
     # in-kernel skip-step (scalar-bool select through Mosaic's compiled
     # lowering — interpret mode can't validate it): skip=True must leave
@@ -160,8 +162,9 @@ def check_fused_adam(dtype):
     rel_p, max_p = _tree_errs(p2, params)
     rel_m, max_m = _errs(s2.m, state.m)
     ok = max_p == 0.0 and max_m == 0.0 and int(s2.step) == 0
-    record("fused_adam_skip", dtype, ok, max(rel_p, rel_m),
-           max(max_p, max_m), tol=0.0)
+    rows.append(row("fused_adam_skip", dtype, ok, max(rel_p, rel_m),
+                    max(max_p, max_m), tol=0.0))
+    return rows
 
 
 def check_s2d_stem(dtype):
@@ -224,26 +227,85 @@ def check_s2d_stem(dtype):
         maxes.append(m)
     tol = TOL[dtype]
     ok = max(rels) < tol
-    record("s2d_stem_grad", dtype, ok, max(rels), max(maxes))
+    return [row("s2d_stem_grad", dtype, ok, max(rels), max(maxes))]
+
+
+def check_cached_attention(shape=(8, 1024, 12, 64)):
+    """The serving decode kernel against its jnp oracle, bf16 queries:
+    one row for a bf16 pool and one for an int8 pool with its per-slot
+    per-head scales (the in-kernel dequantizing front).  ``shape`` is
+    the gathered context (B, T, H, D); every batch row masks a
+    different tail, as the engine's per-request context lengths do."""
+    from apex_tpu.ops.decode_attention import _reference, cached_attention
+    from apex_tpu.ops.kv_quant import quantize_kv
+
+    b, t, h, d = shape
+    dtype = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (b, 1, h, d), dtype)
+    k = jax.random.normal(ks[1], (b, t, h, d), dtype)
+    v = jax.random.normal(ks[2], (b, t, h, d), dtype)
+    lengths = jnp.linspace(t // 8, t, b).astype(jnp.int32)
+    bias = jnp.where(jnp.arange(t)[None, :] < lengths[:, None],
+                     0.0, -1e30).astype(jnp.float32)
+    (kq, ksc), (vq, vsc) = quantize_kv(k), quantize_kv(v)
+    scale = 1.0 / float(np.sqrt(d))
+    rows = []
+    for name, kk, vv, scales in (
+            ("cached_attention", k, v, {}),
+            ("cached_attention_int8", kq, vq,
+             {"k_scale": ksc, "v_scale": vsc})):
+        got = jax.jit(lambda q, k, v, bias, kw: cached_attention(
+            q, k, v, kv_bias=bias, use_pallas=True, **kw))(
+                q, kk, vv, bias, scales)
+        want = jax.jit(lambda q, k, v, bias, kw: _reference(
+            q, k, v, bias, scale, **kw))(q, kk, vv, bias, scales)
+        rel, mx = _errs(got, want)
+        rows.append(row(name, dtype, rel <= TOL[dtype], rel, mx))
+    return rows
+
+
+CHECKS = (check_flash_attention, check_fused_layer_norm, check_fused_adam,
+          check_s2d_stem)
+
+
+def run_checks(checks=CHECKS, dtypes=(jnp.float32, jnp.bfloat16),
+               sizes=None, decode_shape=(8, 1024, 12, 64)):
+    """Run each of ``checks`` for each dtype, then the two
+    ``cached_attention`` rows at ``decode_shape``; print and return the
+    rows.  ``sizes`` maps a check to its ``shape`` argument (default:
+    the check's own).  A check that raises becomes a failed row, so
+    that one kernel that does not compile does not hide the others."""
+    sizes = sizes or {}
+    jobs = [(fn, (dtype,), {"shape": sizes[fn]} if fn in sizes else {})
+            for dtype in dtypes for fn in checks]
+    jobs.append((check_cached_attention, (), {"shape": decode_shape}))
+    rows = []
+    for fn, args, kw in jobs:
+        try:
+            new = fn(*args, **kw)
+        except Exception as e:
+            new = [row(fn.__name__, args[0] if args else jnp.bfloat16,
+                       False, float("nan"), float("nan"),
+                       note=f"{type(e).__name__}: {e}")]
+        for r in new:
+            print(json.dumps(r), flush=True)
+        rows.extend(new)
+    return rows
 
 
 def main():
-    dev = jax.devices()[0]
+    from apex_tpu.ops.pallas_utils import require_tpu
+    from apex_tpu.utils.compile_cache import enable_compile_cache
+
+    dev = require_tpu()
     print(json.dumps({"platform": dev.platform,
                       "device": dev.device_kind,
-                      "note": ("COMPILED kernels" if dev.platform == "tpu"
-                               else "interpret-mode (no TPU visible)")}))
-    for dtype in (jnp.float32, jnp.bfloat16):
-        for fn in (check_flash_attention, check_fused_layer_norm,
-                   check_fused_adam, check_s2d_stem):
-            try:
-                fn(dtype)
-            except Exception as e:
-                record(fn.__name__, dtype, False, float("nan"),
-                       float("nan"), note=f"{type(e).__name__}: {e}")
-    n_pass = sum(r["pass"] for r in RESULTS)
-    summary = {"total": len(RESULTS), "passed": n_pass,
-               "all_pass": n_pass == len(RESULTS)}
+                      "compile_cache_dir": enable_compile_cache()}))
+    rows = run_checks()
+    n_pass = sum(r["pass"] for r in rows)
+    summary = {"total": len(rows), "passed": n_pass,
+               "all_pass": n_pass == len(rows)}
     print(json.dumps(summary))
     sys.exit(0 if summary["all_pass"] else 1)
 

@@ -2680,6 +2680,8 @@ def main():
     ap.add_argument("--repeats", type=int, default=3,
                     help="interference repeats (min of maxes)")
     args = ap.parse_args()
+    from apex_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         args.requests = 8
