@@ -1,0 +1,12 @@
+"""The plain float32 reference of ``gpt2-xl``: GPT-2's published
+equations (``benchmarks/reference/gpt2.py``) at the sizes of
+``gpt2-xl.json``.  Every token the timed server emitted for a sample of
+its finished greedy requests is judged by one full forward pass of
+these functions over the prompt and the served tokens: how far the
+served token's logit lies below the reference's best.
+``harness/compare.py`` holds the comparison and the configuration's
+``limits`` the limit."""
+
+from benchmarks.reference.gpt2 import (  # noqa: F401
+    logit_at, logits, mass_above, next_token_loss, param_table, stacked,
+    token_gaps, train_steps, unstacked_leaf_norms)
