@@ -10,9 +10,11 @@ Two pieces, both process-wide and dependency-free:
   onto a registry when constructed with ``registry=``.
 - :mod:`observability.tracing` — :class:`SpanTracer`, a bounded
   ring-buffer span tracer exporting Chrome trace-event JSON
-  (Perfetto-loadable).  Disabled by default (:data:`NULL_TRACER`,
-  zero overhead); ``APEX_TPU_TRACE=/path.json`` or
-  :func:`enable_tracing` turns it on.
+  (Perfetto-loadable).  The process default records only while a
+  ``jax.profiler`` session is active, when every span is also an
+  ``apex:<span>`` annotation on the profiler's clock, and costs
+  nothing otherwise; ``APEX_TPU_TRACE=/path.json`` or
+  :func:`enable_tracing` turns it on for good.
 - :mod:`observability.flightrecorder` — :class:`FlightRecorder`, a
   bounded ring of structured per-engine-step records (batch
   composition, admit/shed/preempt/evict decisions, memory occupancy,
@@ -43,8 +45,9 @@ Two pieces, both process-wide and dependency-free:
   ``stats()["programs"]`` table and the
   ``serving_program_*`` registry counters.
 
-What is instrumented out of the box: the serving step loop (admit /
-prefix-match / chunk-prefill / decode / evict / preempt spans,
+What is instrumented out of the box: the serving step loop (``step``
+over retire / apply / plan / chunk-prefill / draft / inputs / launch /
+account spans,
 per-request enqueue→admit→first-token→finish timelines feeding TTFT /
 queue-wait / decode-latency histograms in
 ``InferenceServer.stats()``), engine compile events, checkpoint

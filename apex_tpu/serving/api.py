@@ -181,6 +181,14 @@ DEFAULT_PREFILL_CHUNK = 256
 # on entry — servers without an ops endpoint never take a real lock
 _NO_LOCK = contextlib.nullcontext()
 
+_NO_RID: dict = {}
+
+
+def _rid(req) -> dict:
+    """``rid=`` for a per-request span where journeys are on (it rides
+    beside ``uid=``, which the ``request_*`` instants share)."""
+    return {"rid": req.journey.rid} if req.journey is not None else _NO_RID
+
 # default speculation depth (max drafted tokens per verify step).  The
 # verify program is spec_tokens + 1 columns wide; deeper speculation
 # multiplies the best-case tokens/step but also the wasted columns when
@@ -356,11 +364,12 @@ class InferenceServer:
         holding every counter/gauge/histogram this server feeds
         (default: a fresh private one).  Pass a shared registry to
         co-scrape serving and training metrics from one snapshot.
-      tracer: span tracer for the step-loop phases
-        (admit / prefix-match / chunk-prefill / decode / evict /
-        preempt) and per-request lifecycle instants; default is the
-        process tracer (``APEX_TPU_TRACE`` turns it on, else a
-        zero-overhead no-op — ``docs/observability.md``).
+      tracer: span tracer for the step-loop phases (``step`` over
+        retire / apply / plan / chunk_prefill / draft / inputs /
+        launch / account) and per-request lifecycle instants; default
+        is the process tracer, which records while a ``jax.profiler``
+        session is active or ``APEX_TPU_TRACE`` is set and is a no-op
+        otherwise — ``docs/observability.md``.
       slo_policy: per-priority-class SLO targets
         (:class:`observability.SLOPolicy`) behind the
         ``stats()["slo"]`` attainment/goodput block; the stock policy
@@ -957,12 +966,15 @@ class InferenceServer:
         default) starts a fresh journey keyed by the request ``uid``
         when journeys are enabled, and carries nothing when they are
         off."""
-        with (self._ops_lock or _NO_LOCK):
-            return self._submit(prompt, max_new_tokens, eos_id,
-                                priority=priority,
-                                deadline_iters=deadline_iters,
-                                deadline_s=deadline_s,
-                                sampling=sampling, journey=journey)
+        with (self._ops_lock or _NO_LOCK), \
+                self.tracer.span("submit") as span:
+            req = self._submit(prompt, max_new_tokens, eos_id,
+                               priority=priority,
+                               deadline_iters=deadline_iters,
+                               deadline_s=deadline_s,
+                               sampling=sampling, journey=journey)
+            span.set(uid=req.uid, **_rid(req))
+            return req
 
     def _submit(self, prompt, max_new_tokens, eos_id, *, priority,
                 deadline_iters, deadline_s, sampling=None,
@@ -1143,33 +1155,40 @@ class InferenceServer:
             wd.step_started()
         try:
             with (self._ops_lock or _NO_LOCK):
-                return self._step()
+                if self.disagg:
+                    return self._step_disagg()
+                # the span tree (docs/observability.md, "What is
+                # instrumented"): every statement of the step runs
+                # under exactly one child of ``step``, so an idle gap
+                # on the device can be put down to the phase that held
+                # the host
+                with self.tracer.span("step", iter=self._iter + 1):
+                    return self._step()
         finally:
             if wd.enabled:
                 wd.step_finished(self.scheduler.has_work)
 
     def _step(self) -> int:
-        """The :meth:`step` body (see its docstring)."""
-        if self.disagg:
-            return self._step_disagg()
+        """The :meth:`step` body of the monolithic (single-pool)
+        server, under its ``step`` span (see :meth:`step`)."""
         sched, engine, tr = self.scheduler, self.engine, self.tracer
         rec = self.recorder
         self._iter += 1
         produced, self._pending_produced = self._pending_produced, 0
         step_start = self.clock()
-        self._phase = None
+        self._phase = marks = None
         if rec.enabled:
             # pre-step marks for the flight record's per-step deltas
-            # (plain int binds — the disabled path skips even these)
-            preempt0 = sched.preemption_count
-            lk_grant0 = sched.lookahead_granted
-            lk_roll0 = sched.lookahead_rolled_back
-            evict0 = self.prefix.count("prefix_evicted_blocks")
-            oom0 = self.oom.total
-            drafted0 = self.spec.count("drafted_tokens")
-            accepted0 = self.spec.count("accepted_tokens")
-            off0 = self._offload_marks()
-            self._phase = self._new_phase()
+            # (plain int reads — the disabled path skips even these)
+            with tr.span("account"):
+                marks = (sched.preemption_count, sched.lookahead_granted,
+                         sched.lookahead_rolled_back,
+                         self.prefix.count("prefix_evicted_blocks"),
+                         self.oom.total,
+                         self.spec.count("drafted_tokens"),
+                         self.spec.count("accepted_tokens"),
+                         self._offload_marks())
+                self._phase = self._new_phase()
         # RETIRE: consume the previous iteration's launched step before
         # any host decision — deadlines, shedding, admission, and
         # drafts below then see exactly the state the synchronous loop
@@ -1178,72 +1197,78 @@ class InferenceServer:
         retired = self._flush_window()
         produced += retired
         plan_start = self.clock()
-        self._expire_deadlines()
+        with tr.span("plan"):
+            self._expire_deadlines()
 
-        # overload: record the pressure signal at its pre-shed peak,
-        # then shed best-effort waiting work while the policy says so
-        self.pressure_gauge.update(sched.pressure())
-        shed = sched.shed_overload()
-        if shed and tr.enabled:
-            for r in shed:
-                tr.instant("request_shed", uid=r.uid,
-                           priority=r.priority)
+            # overload: record the pressure signal at its pre-shed
+            # peak, then shed best-effort waiting work while the
+            # policy says so
+            self.pressure_gauge.update(sched.pressure())
+            shed = sched.shed_overload()
 
-        with tr.span("admit"):
-            admitted = sched.admit()
-        if admitted:
-            now = self.clock()
-            for req in admitted:
-                if req.admitted_at is None:
-                    req.admitted_at = now
-                if tr.enabled:
-                    tr.instant("request_admit", uid=req.uid,
-                               cached_tokens=req.cached_prefix_tokens)
-        # whole-context cache hits first duplicate their final shared
-        # block (copy-on-write) so the tail re-write stays private
-        cows = [r for r in sched._admit_order if r.pending_cow]
-        if cows:
-            try:
-                with tr.span("cow_copy", blocks=len(cows)):
-                    engine.copy_blocks([r.pending_cow for r in cows])
-            except MemoryError:
-                # transient HBM burst: nothing was accounted, the same
-                # copies re-launch next iteration bit-identically
-                self._note_oom("copy_blocks")
-            else:
-                for req in cows:
-                    sched.cow_done(req)
+            with tr.span("admit"):
+                admitted = sched.admit()
+            if admitted:
+                now = self.clock()
+                for req in admitted:
+                    if req.admitted_at is None:
+                        req.admitted_at = now
+                    if tr.enabled:
+                        tr.instant(
+                            "request_admit", uid=req.uid,
+                            cached_tokens=req.cached_prefix_tokens)
+            # whole-context cache hits first duplicate their final
+            # shared block (copy-on-write) so the tail re-write stays
+            # private
+            cows = [r for r in sched._admit_order if r.pending_cow]
+            if cows:
+                try:
+                    with tr.span("cow_copy", blocks=len(cows)):
+                        engine.copy_blocks(
+                            [r.pending_cow for r in cows])
+                except MemoryError:
+                    # transient HBM burst: nothing was accounted, the
+                    # same copies re-launch next iteration
+                    # bit-identically
+                    self._note_oom("copy_blocks")
+                else:
+                    for req in cows:
+                        sched.cow_done(req)
 
         chunks = 0
         pipelined = self.pipelining
         for req in [r for r in sched._admit_order if r.prefilling]:
-            tokens, start, is_last = sched.prefill_plan(req)
-            # the per-request stochastic params ride the fused twin
-            # only when this launch's token will actually be sampled
-            # (final chunk of a fresh prefill) — mid-prefill chunks
-            # and preemption re-prefills keep the greedy program
-            samp1 = (sched.prefill_sampling(req)
-                     if pipelined and is_last and req.prefill_sample
-                     else None)
-            # kwarg omitted when greedy so duck-typed engine wrappers
-            # predating the stochastic twins keep working
-            skw = {"sampling": samp1} if samp1 is not None else {}
-            try:
-                if (start == 0 and is_last
-                        and self.prefill_chunk is None):
-                    # no cached prefix, no chunking: the monolithic
-                    # bucketed prefill (the pre-chunking path,
-                    # bit-for-bit)
-                    with tr.span("prefill", uid=req.uid,
-                                 tokens=len(tokens)):
+            with tr.span("plan", uid=req.uid):
+                tokens, start, is_last = sched.prefill_plan(req)
+                # the per-request stochastic params ride the fused
+                # twin only when this launch's token will actually be
+                # sampled (final chunk of a fresh prefill) —
+                # mid-prefill chunks and preemption re-prefills keep
+                # the greedy program
+                samp1 = (sched.prefill_sampling(req)
+                         if pipelined and is_last and req.prefill_sample
+                         else None)
+                # kwarg omitted when greedy so duck-typed engine
+                # wrappers predating the stochastic twins keep working
+                skw = {"sampling": samp1} if samp1 is not None else {}
+                # no cached prefix, no chunking: the monolithic
+                # bucketed prefill (the pre-chunking path, bit-for-bit)
+                mono = (start == 0 and is_last
+                        and self.prefill_chunk is None)
+            # one span for the request's share of this step: the
+            # launch, its accounting and, on a final chunk, the read
+            # of its token
+            with tr.span("prefill" if mono else "chunk_prefill",
+                         uid=req.uid, tokens=len(tokens), start=start,
+                         **_rid(req)):
+                try:
+                    if mono:
                         out = (engine.prefill_sampled(
                             tokens, req.block_table,
                             **skw) if pipelined
                             else engine.prefill(tokens,
                                                 req.block_table))
-                else:
-                    with tr.span("chunk_prefill", uid=req.uid,
-                                 tokens=len(tokens), start=start):
+                    else:
                         out = (engine.chunk_prefill_sampled(
                             tokens, start, req.block_table,
                             pad_to=self.prefill_chunk,
@@ -1251,66 +1276,67 @@ class InferenceServer:
                             else engine.chunk_prefill(
                                 tokens, start, req.block_table,
                                 pad_to=self.prefill_chunk))
-                    chunks += 1
-            except MemoryError:
-                # chunk_done not called: this exact chunk replays
-                # next iteration, so generation stays bit-stable
-                self._note_oom("prefill")
-                continue
-            if self._phase is not None:
-                self._phase["prefill_launches"] += 1
-                self._phase["prefill_tokens"] += len(tokens)
-            done = sched.chunk_done(req, len(tokens))
-            if not done or not req.prefill_sample:
-                # mid-prefill, or resumed after preemption (the
-                # pending token continues instead of these logits)
-                continue
-            # prefill sampling stays synchronous either way — the
-            # sampled twin just shrinks the transfer to one id + one
-            # flag; only decode/verify dispatch ahead (a prefill's
-            # token gates whether the request joins THIS iteration's
-            # decode launch, so deferring it would change scheduling)
-            if pipelined:
-                ids, fin = out
-                if not bool(np.asarray(fin)[0]):
+                        chunks += 1
+                except MemoryError:
+                    # chunk_done not called: this exact chunk replays
+                    # next iteration, so generation stays bit-stable
+                    self._note_oom("prefill")
+                    continue
+                if self._phase is not None:
+                    self._phase["prefill_launches"] += 1
+                    self._phase["prefill_tokens"] += len(tokens)
+                done = sched.chunk_done(req, len(tokens))
+                if not done or not req.prefill_sample:
+                    # mid-prefill, or resumed after preemption (the
+                    # pending token continues instead of these logits)
+                    continue
+                # prefill sampling stays synchronous either way — the
+                # sampled twin just shrinks the transfer to one id +
+                # one flag; only decode/verify dispatch ahead (a
+                # prefill's token gates whether the request joins THIS
+                # iteration's decode launch, so deferring it would
+                # change scheduling)
+                with tr.span("prefill_read", uid=req.uid):
+                    # the host waits for the device here
+                    if pipelined:
+                        ids, fin = out
+                        finite = bool(np.asarray(fin)[0])
+                        if finite:
+                            tok = int(np.asarray(ids)[0])
+                    else:
+                        logits = np.asarray(out)
+                        finite = np.all(np.isfinite(logits))
+                        if finite:
+                            tok = self._sample_prefill_host(req, logits)
+                if not finite:
                     sched.fail(req, reasons.NONFINITE)
                     if self.breaker is not None:
                         self.breaker.record_failure()
                     continue
-                tok = int(np.asarray(ids)[0])
-            else:
-                logits = np.asarray(out)
-                if not np.all(np.isfinite(logits)):
-                    sched.fail(req, reasons.NONFINITE)
+                req.record_token(tok)
+                self._note_first_token(req)
+                produced += 1
+                if req.finished:
+                    sched.retire(req)
                     if self.breaker is not None:
-                        self.breaker.record_failure()
-                    continue
-                tok = self._sample_prefill_host(req, logits)
-            req.record_token(tok)
-            self._note_first_token(req)
-            produced += 1
-            if req.finished:
-                sched.retire(req)
-                if self.breaker is not None:
-                    self.breaker.record_success()
-        self.chunk_iters.update(chunks)
-        if chunks:
-            self.prefix.incr("prefill_chunks", chunks)
+                        self.breaker.record_success()
 
         if sched.running:
-            for req in list(sched.running.values()):
-                if req.running and not req.prefilling:
-                    # an earlier pass may have preempted it; a False
-                    # return means the request outgrew the pool with no
-                    # victim left — it fails alone instead of raising
-                    # into the batch
-                    if not sched.ensure_decode_capacity(req):
-                        sched.fail(req, reasons.CAPACITY)
-            running = [r for r in sched.running.values()
-                       if not r.prefilling]
+            with tr.span("plan"):
+                for req in list(sched.running.values()):
+                    if req.running and not req.prefilling:
+                        # an earlier pass may have preempted it; a
+                        # False return means the request outgrew the
+                        # pool with no victim left — it fails alone
+                        # instead of raising into the batch
+                        if not sched.ensure_decode_capacity(req):
+                            sched.fail(req, reasons.CAPACITY)
+                running = [r for r in sched.running.values()
+                           if not r.prefilling]
             if running:
-                drafts = (self._propose_drafts(running)
-                          if self.speculating else {})
+                with tr.span("draft"):
+                    drafts = (self._propose_drafts(running)
+                              if self.speculating else {})
                 if pipelined:
                     # LAUNCH: enqueue the device step and stash the
                     # un-materialized result handles; its tokens
@@ -1325,7 +1351,21 @@ class InferenceServer:
                 else:
                     produced += self._decode_step(running)
 
-        if pipelined:
+        # everything below runs while the device works on the launch
+        with tr.span("account"):
+            self._account_step(produced, retired, chunks, admitted,
+                               shed, plan_start, step_start, marks)
+        return produced
+
+    def _account_step(self, produced, retired, chunks, admitted, shed,
+                      plan_start, step_start, marks) -> None:
+        """The step's meters, gauges, finished-request stamps and
+        flight record (``marks``: the recorder's pre-step readings)."""
+        sched, engine, rec = self.scheduler, self.engine, self.recorder
+        self.chunk_iters.update(chunks)
+        if chunks:
+            self.prefix.incr("prefill_chunks", chunks)
+        if self.pipelining:
             self.plan_time.record(self.clock() - plan_start)
         self.tokens.update(produced)
         self.queue_depth.update(sched.num_waiting)
@@ -1344,6 +1384,8 @@ class InferenceServer:
             if self.prefix_cache is not None else 0)
         self.mem_frag.update(sched.frag_slots())
         if rec.enabled:
+            (preempt0, lk_grant0, lk_roll0, evict0, oom0, drafted0,
+             accepted0, off0) = marks
             fin = sched.finished
             new_fin = fin[self._rec_cursor:]
             finished_now = [
@@ -1418,7 +1460,6 @@ class InferenceServer:
                 self._last_breaker_state = state
                 if state == "open":
                     self._auto_postmortem("breaker_open")
-        return produced
 
     def _sample_prefill_host(self, req, logits) -> int:
         """Sample one request's prefill token from materialized
@@ -1487,7 +1528,8 @@ class InferenceServer:
         loop; also the custom-``sample_fn`` path).  Returns tokens
         produced."""
         engine, tr = self.engine, self.tracer
-        tokens, positions, tables = self._decode_inputs(running)
+        with tr.span("inputs", program="decode"):
+            tokens, positions, tables = self._decode_inputs(running)
         try:
             with tr.span("decode", batch=len(running)):
                 logits = np.asarray(
@@ -1497,26 +1539,27 @@ class InferenceServer:
             # identical decode re-runs next iteration
             self._note_oom("decode")
             return 0
-        self.spec.incr("decode_steps")
-        if self._phase is not None:
-            self._phase["decode_launches"] += 1
-            self._phase["decode_tokens"] += len(running)
-        finite = np.all(np.isfinite(logits), axis=-1)
-        samp = (self.scheduler.sampling_inputs(running)
-                if self.sample_fn is greedy_sample else None)
-        if samp is None:
-            toks = self.sample_fn(logits)
-        else:
-            # the synchronous stochastic path: the SAME jitted
-            # sampler as the fused twin, fed the same counter keys
-            # (each slot's next sequence index), so sync and
-            # pipelined streams agree byte-for-byte
-            counters = np.zeros((logits.shape[0],), np.int32)
-            for req in running:
-                counters[req.slot] = req.num_cached + 1
-            toks = np.asarray(sample_tokens_host(
-                logits, *samp, counters)[0])
-        return self._apply_decode_results(running, toks, finite)
+        with tr.span("apply", program="decode"):
+            self.spec.incr("decode_steps")
+            if self._phase is not None:
+                self._phase["decode_launches"] += 1
+                self._phase["decode_tokens"] += len(running)
+            finite = np.all(np.isfinite(logits), axis=-1)
+            samp = (self.scheduler.sampling_inputs(running)
+                    if self.sample_fn is greedy_sample else None)
+            if samp is None:
+                toks = self.sample_fn(logits)
+            else:
+                # the synchronous stochastic path: the SAME jitted
+                # sampler as the fused twin, fed the same counter keys
+                # (each slot's next sequence index), so sync and
+                # pipelined streams agree byte-for-byte
+                counters = np.zeros((logits.shape[0],), np.int32)
+                for req in running:
+                    counters[req.slot] = req.num_cached + 1
+                toks = np.asarray(sample_tokens_host(
+                    logits, *samp, counters)[0])
+            return self._apply_decode_results(running, toks, finite)
 
     def _launch_decode(self, running) -> bool:
         """The pipelined decode launch: enqueue the fused sampled
@@ -1526,12 +1569,13 @@ class InferenceServer:
         and retried bit-identically, exactly like the synchronous
         path)."""
         sched, engine, tr = self.scheduler, self.engine, self.tracer
-        tokens, positions, tables = self._decode_inputs(running)
-        samp = sched.sampling_inputs(running)
-        # the kwarg is omitted on all-greedy launches so duck-typed
-        # engine wrappers (chaos injection, tests) predating the
-        # stochastic twins keep working unchanged
-        kw = {"sampling": samp} if samp is not None else {}
+        with tr.span("inputs", program="decode"):
+            tokens, positions, tables = self._decode_inputs(running)
+            samp = sched.sampling_inputs(running)
+            # the kwarg is omitted on all-greedy launches so duck-typed
+            # engine wrappers (chaos injection, tests) predating the
+            # stochastic twins keep working unchanged
+            kw = {"sampling": samp} if samp is not None else {}
         try:
             with tr.span("launch", program="decode",
                          batch=len(running)):
@@ -1540,14 +1584,15 @@ class InferenceServer:
         except MemoryError:
             self._note_oom("decode")
             return False
-        self.spec.incr("decode_steps")
-        if self._phase is not None:
-            self._phase["decode_launches"] += 1
-            self._phase["decode_tokens"] += len(running)
-        self._inflight = _InflightStep(
-            "decode", list(running), ids, fin, self.clock())
-        sched.hold_inflight(running)
-        self.pipe.incr("launches")
+        with tr.span("account"):
+            self.spec.incr("decode_steps")
+            if self._phase is not None:
+                self._phase["decode_launches"] += 1
+                self._phase["decode_tokens"] += len(running)
+            self._inflight = _InflightStep(
+                "decode", list(running), ids, fin, self.clock())
+            sched.hold_inflight(running)
+            self.pipe.incr("launches")
         return True
 
     def _apply_decode_results(self, running, toks, finite,
@@ -1654,8 +1699,9 @@ class InferenceServer:
         rolled back (``Scheduler.rollback_lookahead``).  Returns
         tokens produced."""
         sched, engine, tr = self.scheduler, self.engine, self.tracer
-        tokens, lengths, positions, tables = self._verify_inputs(
-            running, drafts)
+        with tr.span("inputs", program="verify"):
+            tokens, lengths, positions, tables = self._verify_inputs(
+                running, drafts)
         try:
             with tr.span("verify", batch=len(running),
                          drafted=sum(len(v) for v in drafts.values())):
@@ -1672,31 +1718,32 @@ class InferenceServer:
                 if req.running:
                     sched.rollback_lookahead(req)
             return 0
-        self.spec.incr("verify_steps")
-        if self._phase is not None:
-            self._phase["verify_launches"] += 1
-            self._phase["verify_columns"] += (
-                len(running) + sum(len(d) for d in drafts.values()))
-        finite = np.all(np.isfinite(logits), axis=-1)      # (B, K)
-        samp = (self.scheduler.sampling_inputs(running)
-                if self.sample_fn is greedy_sample else None)
-        if samp is None:
-            row_toks = self.sample_fn(logits)              # (B, K)
-        else:
-            # every verify column sampled with its own positional
-            # counter key — acceptance below compares drafts to these
-            # samples, which IS rejection sampling (the Gumbel-max
-            # coupling, ops.sample_tokens) and keeps the stream
-            # identical to plain decode
-            b, kw = logits.shape[:2]
-            counters = (positions[:, None].astype(np.int32) + 1
-                        + np.arange(kw, dtype=np.int32)[None, :])
-            samp2 = tuple(np.broadcast_to(a[:, None], (b, kw))
-                          for a in samp)
-            row_toks = np.asarray(sample_tokens_host(
-                logits, *samp2, counters)[0])
-        return self._apply_verify_results(running, drafts, lengths,
-                                          row_toks, finite)
+        with tr.span("apply", program="verify"):
+            self.spec.incr("verify_steps")
+            if self._phase is not None:
+                self._phase["verify_launches"] += 1
+                self._phase["verify_columns"] += (
+                    len(running) + sum(len(d) for d in drafts.values()))
+            finite = np.all(np.isfinite(logits), axis=-1)      # (B, K)
+            samp = (self.scheduler.sampling_inputs(running)
+                    if self.sample_fn is greedy_sample else None)
+            if samp is None:
+                row_toks = self.sample_fn(logits)              # (B, K)
+            else:
+                # every verify column sampled with its own positional
+                # counter key — acceptance below compares drafts to
+                # these samples, which IS rejection sampling (the
+                # Gumbel-max coupling, ops.sample_tokens) and keeps
+                # the stream identical to plain decode
+                b, kw = logits.shape[:2]
+                counters = (positions[:, None].astype(np.int32) + 1
+                            + np.arange(kw, dtype=np.int32)[None, :])
+                samp2 = tuple(np.broadcast_to(a[:, None], (b, kw))
+                              for a in samp)
+                row_toks = np.asarray(sample_tokens_host(
+                    logits, *samp2, counters)[0])
+            return self._apply_verify_results(running, drafts, lengths,
+                                              row_toks, finite)
 
     def _launch_verify(self, running, drafts) -> bool:
         """The pipelined verify launch: enqueue the fused sampled
@@ -1707,10 +1754,11 @@ class InferenceServer:
         rolled back and the identical verify (drafts are deterministic
         functions of request history) retries next iteration."""
         sched, engine, tr = self.scheduler, self.engine, self.tracer
-        tokens, lengths, positions, tables = self._verify_inputs(
-            running, drafts)
-        samp = sched.sampling_inputs(running)
-        kw = {"sampling": samp} if samp is not None else {}
+        with tr.span("inputs", program="verify"):
+            tokens, lengths, positions, tables = self._verify_inputs(
+                running, drafts)
+            samp = sched.sampling_inputs(running)
+            kw = {"sampling": samp} if samp is not None else {}
         try:
             with tr.span("launch", program="verify",
                          batch=len(running),
@@ -1724,18 +1772,19 @@ class InferenceServer:
                 if req.running:
                     sched.rollback_lookahead(req)
             return False
-        self.spec.incr("verify_steps")
-        if self._phase is not None:
-            self._phase["verify_launches"] += 1
-            # columns fed = each slot's pending token + its drafts
-            # (host ints — lengths mirrors exactly this)
-            self._phase["verify_columns"] += (
-                len(running) + sum(len(d) for d in drafts.values()))
-        self._inflight = _InflightStep(
-            "verify", list(running), ids, fin, self.clock(),
-            drafts=drafts, lengths=lengths)
-        sched.hold_inflight(running)
-        self.pipe.incr("launches")
+        with tr.span("account"):
+            self.spec.incr("verify_steps")
+            if self._phase is not None:
+                self._phase["verify_launches"] += 1
+                # columns fed = each slot's pending token + its drafts
+                # (host ints — lengths mirrors exactly this)
+                self._phase["verify_columns"] += (
+                    len(running) + sum(len(d) for d in drafts.values()))
+            self._inflight = _InflightStep(
+                "verify", list(running), ids, fin, self.clock(),
+                drafts=drafts, lengths=lengths)
+            sched.hold_inflight(running)
+            self.pipe.incr("launches")
         return True
 
     def _apply_verify_results(self, running, drafts, lengths,
@@ -1835,17 +1884,19 @@ class InferenceServer:
                               batch=len(inf.running)):
             toks = np.asarray(inf.ids)
             finite = np.asarray(inf.finite)
-        self.retire_wait.record(self.clock() - t0)
-        # the device step is fully consumed: its K/V writes landed, so
-        # the window's block pin lifts before any request state moves
-        self.scheduler.release_inflight()
-        self.pipe.incr("retired_behind")
-        if inf.kind == "decode":
-            return self._apply_decode_results(
-                inf.running, toks, finite, now=inf.launched_at)
-        return self._apply_verify_results(
-            inf.running, inf.drafts, inf.lengths, toks, finite,
-            now=inf.launched_at)
+        with self.tracer.span("apply", program=inf.kind):
+            self.retire_wait.record(self.clock() - t0)
+            # the device step is fully consumed: its K/V writes landed,
+            # so the window's block pin lifts before any request state
+            # moves
+            self.scheduler.release_inflight()
+            self.pipe.incr("retired_behind")
+            if inf.kind == "decode":
+                return self._apply_decode_results(
+                    inf.running, toks, finite, now=inf.launched_at)
+            return self._apply_verify_results(
+                inf.running, inf.drafts, inf.lengths, toks, finite,
+                now=inf.launched_at)
 
     # -- disaggregated prefill/decode pools (docs/serving.md) --------------
 
@@ -1861,7 +1912,7 @@ class InferenceServer:
         step; greedy output is bit-exact vs the monolithic loop by
         construction (same programs, same per-request context, the
         copy is byte-preserving)."""
-        sched, tr = self.scheduler, self.tracer
+        sched = self.scheduler
         psched = self.prefill_scheduler
         rec = self.recorder
         self._iter += 1
@@ -1887,10 +1938,6 @@ class InferenceServer:
         self._expire_deadlines()
         self.pressure_gauge.update(self.pressure())
         shed = psched.shed_overload()
-        if shed and tr.enabled:
-            for r in shed:
-                tr.instant("request_shed", uid=r.uid,
-                           priority=r.priority)
         # HAND-OFF: prefills that finished in an earlier step
         # materialize their first token and move pools (the copy and
         # this step's decode of the moved request share the decode
@@ -2310,9 +2357,6 @@ class InferenceServer:
             sched.admit_handoff(req, blocks)
             self.handoffs.incr("ingested")
             self.handoffs.incr("blocks", n)
-            if self.tracer.enabled:
-                self.tracer.instant("handoff_ingest", uid=req.uid,
-                                    blocks=n)
             return req
 
     def _offload_ingest(self, meta: dict, payload: dict) -> dict:
@@ -2716,10 +2760,6 @@ class InferenceServer:
                         + list(sched.waiting)):
                 if req.uid == uid and not req.finished:
                     sched.fail(req, reasons.CANCELLED)
-                    if self.tracer.enabled:
-                        self.tracer.instant("request_cancel",
-                                            uid=uid,
-                                            tokens=len(req.generated))
                     cancelled = True
                     break
             if cancelled:

@@ -1,0 +1,4 @@
+"""Share of the device's idle time under ``step`` or ``submit`` themselves or under no span of the program."""
+from benchmarks.harness import spans
+
+read = spans.reader("idle_unattributed_pct.chat", spans.idle_unattributed_pct)

@@ -640,3 +640,250 @@ def test_checkpoint_spans_recorded(tmp_path):
     assert snap["checkpoint_save_s"]["count"] == 1
     assert snap["checkpoint_restore_s"]["count"] == 1
     assert snap['checkpoint{event="checkpoints_written"}']["value"] == 1
+
+
+# -- the process default follows the profiler ------------------------------
+
+
+@pytest.fixture
+def default_tracer():
+    """A fresh process default (no ``APEX_TPU_TRACE``), put back after."""
+    from apex_tpu.observability import tracing
+    prev = tracing.set_tracer(None)
+    saved = os.environ.pop(tracing.TRACE_ENV, None)
+    try:
+        yield tracing.get_tracer()
+    finally:
+        tracing.set_tracer(prev)
+        if saved is not None:
+            os.environ[tracing.TRACE_ENV] = saved
+
+
+class _Profiler:
+    """A ``jax.profiler`` session on the CPU backend, Python tracer off
+    as the benchmark has it; ``data()`` reads the ``.xplane.pb``."""
+
+    def __init__(self, tmp_path):
+        self.dir = str(tmp_path / "xprof")
+
+    def __enter__(self):
+        import jax
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+        jax.profiler.stop_trace()
+
+    def host_events(self):
+        import glob
+
+        import jax
+        pb, = glob.glob(os.path.join(self.dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(pb)
+        return [(e.name, dict(e.stats)) for p in data.planes
+                if p.name == "/host:CPU" for l in p.lines for e in l.events]
+
+
+def _tiny_server(**kw):
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu import models
+    from apex_tpu.serving import InferenceServer
+    cfg = models.GPTConfig(
+        vocab_size=61, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=2, intermediate_size=64,
+        max_position_embeddings=128, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0)
+    params = models.GPTLMHeadModel(cfg).init(
+        jax.random.PRNGKey(1), jnp.ones((1, 8), jnp.int32))["params"]
+    return InferenceServer(cfg, params, max_batch_size=4, max_context=128,
+                           cache_dtype=jnp.float32, **kw)
+
+
+def _wave(server, new=10):
+    """Three distinct prompts and a periodic one (its n-gram drafts
+    bring the verify program in); returns the generated tokens."""
+    rng = random.Random(5)
+    prompts = [[rng.randrange(61) for _ in range(20)] for _ in range(3)]
+    reqs = [server.submit(p, new) for p in prompts + [[1, 2, 3, 4] * 6]]
+    while server.has_work:
+        server.step()
+    return [list(r.generated) for r in reqs]
+
+
+def test_default_tracer_is_off_and_allocates_nothing_without_a_session(
+        default_tracer):
+    tr = default_tracer
+    assert tr is not NULL_TRACER and isinstance(tr, SpanTracer)
+    assert tr.enabled is False
+    # off, a span is the shared no-op; begin/end stay paired
+    assert tr.span("step", iter=1) is NULL_TRACER.span("x")
+    assert tr.begin("step") == 0
+    tr.end()
+    tr.instant("warm")
+    tracemalloc.start()
+    base = tracemalloc.get_traced_memory()[0]
+    for _ in range(10_000):
+        with tr.span("launch", program="decode") as s:
+            s.set(uid=3)
+            tr.instant("tok")
+    cur, peak = tracemalloc.get_traced_memory()
+    tracemalloc.stop()
+    assert cur - base < 2048, "the default tracer retained memory"
+    assert peak - base < 8192, "the default tracer allocated per event"
+    assert tr.events == () and tr.spans() == [] and tr.dropped == 0
+
+
+def test_default_server_step_records_nothing_without_a_session(
+        default_tracer):
+    server = _tiny_server()
+    assert server.tracer is default_tracer
+    assert all(_wave(server))
+    assert default_tracer.events == ()
+    assert server.stats()["trace_dropped_events"] == 0
+    server.close()
+
+
+def test_profiler_session_arms_the_ring_and_children_tile_each_step(
+        default_tracer, tmp_path):
+    from apex_tpu.observability.tracing import PROFILER_PREFIX
+    tr = default_tracer
+    server = _tiny_server()
+    _wave(server)                       # compile outside the session
+    assert tr.events == ()
+    with _Profiler(tmp_path) as prof:
+        assert tr.enabled is True
+        _wave(server)
+    assert tr.enabled is False
+    n_events = len(tr.events)
+    _wave(server)                       # the session is over: nothing more
+    assert len(tr.events) == n_events
+    server.close()
+
+    spans = tr.spans()
+    by_id = {s.span_id: s for s in spans}
+    steps = [s for s in spans if s.name == "step"]
+    assert len(steps) >= 4
+    assert [s.args["iter"] for s in steps] == sorted(
+        s.args["iter"] for s in steps)
+    seen, worst = set(), []
+    for st in steps:
+        kids = sorted((s for s in spans if s.parent_id == st.span_id),
+                      key=lambda s: s.start)
+        seen |= {k.name for k in kids}
+        assert {k.name for k in kids} <= {
+            "retire", "apply", "plan", "chunk_prefill", "prefill", "draft",
+            "inputs", "launch", "account"}
+        edges = [st.start] + [t for k in kids for t in (k.start, k.end)] \
+            + [st.end]
+        gaps = [b - a for a, b in zip(edges[::2], edges[1::2])]
+        assert min(gaps) >= 0, "children overlap"
+        worst.append(max(gaps) / (st.end - st.start))
+    # no uncovered stretch over 5% of a step (the median step: a busy
+    # machine may take the thread away between two spans of one)
+    assert sorted(worst)[len(worst) // 2] <= 0.05, worst
+    assert {"retire", "apply", "plan", "chunk_prefill", "draft", "inputs",
+            "launch", "account"} <= seen
+    # per-request spans share ``uid`` with the request_* instants
+    uids = {ev[6]["uid"] for ev in tr.events if ev[1] == "request_enqueue"}
+    assert {s.args["uid"] for s in spans if s.name == "submit"} == uids
+    assert {s.args["uid"] for s in spans
+            if s.name == "chunk_prefill"} == uids
+    for s in spans:
+        if s.name in ("admit", "cow_copy"):
+            assert by_id[s.parent_id].name == "plan"
+        if s.name == "prefill_read":
+            assert by_id[s.parent_id].name == "chunk_prefill"
+        if s.name in ("retire", "apply"):
+            assert by_id[s.parent_id].name in ("step", "submit")
+
+    # the same names, under the prefix, on the profiler's host plane
+    host = prof.host_events()
+    names = [n for n, _ in host if n.startswith(PROFILER_PREFIX)]
+    for name in {s.name for s in spans}:
+        assert names.count(PROFILER_PREFIX + name) == sum(
+            1 for s in spans if s.name == name), name
+    launch = next(st for n, st in host if n == PROFILER_PREFIX + "launch")
+    assert launch["program"] in ("decode", "verify") and launch["batch"] > 0
+    submit = next(st for n, st in host if n == PROFILER_PREFIX + "submit")
+    assert submit["uid"] in uids
+
+
+def test_session_that_starts_or_stops_inside_a_span_leaves_the_stack_balanced(
+        default_tracer, tmp_path):
+    import jax
+    tr = default_tracer
+    with tr.span("outer"):              # decided off at entry
+        with _Profiler(tmp_path / "a"):
+            with tr.span("inner", k=1):
+                tr.instant("mark")
+    assert tr._stack() == []
+    assert [(s.name, s.parent_id) for s in tr.spans()] == [("inner", 0)]
+    tr.clear()
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "b"), profiler_options=opts)
+    try:
+        with tr.span("outer"):          # decided on at entry
+            with tr.span("kept"):
+                pass
+            jax.profiler.stop_trace()
+            with tr.span("dropped"):
+                tr.instant("dropped_too")
+    finally:
+        if tr.enabled:
+            jax.profiler.stop_trace()
+    assert tr._stack() == []
+    assert [s.name for s in tr.spans()] == ["outer", "kept"]
+    assert [ev[0] for ev in tr.events] == ["B", "B", "E", "E"]
+    # begin/end pair up the same way
+    assert tr.begin("off") == 0
+    jax.profiler.start_trace(str(tmp_path / "c"), profiler_options=opts)
+    try:
+        sid = tr.begin("on")
+        assert sid > 0
+    finally:
+        jax.profiler.stop_trace()
+    tr.end()
+    tr.end()
+    assert tr._stack() == []
+    assert [s.name for s in tr.spans()] == ["outer", "kept", "on"]
+
+
+def test_greedy_output_is_the_same_with_the_tracer_on_and_off(tmp_path):
+    off = _tiny_server(tracer=NULL_TRACER)
+    want = _wave(off, new=24)
+    off.close()
+    on_tr = SpanTracer()
+    on = _tiny_server(tracer=on_tr)
+    with _Profiler(tmp_path):
+        got = _wave(on, new=24)
+    on.close()
+    assert got == want
+    assert any(s.name == "step" for s in on_tr.spans())
+    # an explicit tracer records with or without a session
+    assert on_tr.enabled is True
+
+
+def test_spans_are_handed_out_on_the_clocks_own_seconds():
+    clk = FakeClock(tick=1.0)
+    clk.now = 100.0
+    tr = SpanTracer(clock=clk)          # t0 = 100
+    with tr.span("step", iter=7) as s:              # B at 101
+        with tr.span("launch", program="decode"):   # B 102, E 103
+            pass
+        s.set(uid=9)
+    open_sid = tr.begin("still_open")   # never closed: not handed out
+    got = tr.spans()
+    assert [(x.name, x.start, x.end) for x in got] == [
+        ("step", 101.0, 104.0), ("launch", 102.0, 103.0)]
+    assert got[0].args == {"iter": 7, "uid": 9}
+    assert got[1].parent_id == got[0].span_id and got[0].parent_id == 0
+    assert open_sid not in {x.span_id for x in got}
+    assert NULL_TRACER.spans() == []
