@@ -122,37 +122,35 @@ class GPTSelfAttention(nn.Module):
     @nn.compact
     def __call__(self, x, attn_bias, deterministic: bool = True,
                  cache_view=None, return_kv: bool = False,
-                 kv_quant: bool = False):
-        """``cache_view``: serving mode — ``(k_ctx, v_ctx, ctx_bias)``
-        with k/v_ctx (B, T, H, D) gathered cache context and ctx_bias
-        (B, T) additive (0 keep / NEG_INF for unwritten slots).  With x
-        a single new token (B, 1, h) — decode — attention runs over
-        [context; self] via ``ops.cached_attention``; with x a prefill
-        CHUNK (B, C, h) it runs over [context; chunk] via
-        ``ops.chunk_cached_attention`` (all cached positions precede
-        the chunk, causal within it).  ``attention_fn`` (a causal
-        full-sequence kernel) is deliberately bypassed on both.
-        ``return_kv``: also return this call's freshly projected
-        ``(k, v)`` so the serving engine can append them to the cache.
-        Both default off — the training path is byte-identical to
-        before.
+                 kv_quant: bool = False, layer: int = 0):
+        """``cache_view``: serving mode — the launch's view of the KV
+        pool (``serving.kv_cache.CacheView``), ``layer`` this block's
+        index in it.  The view's ``attend`` takes the queries and the
+        freshly projected K/V, returns the context the fed rows attend
+        (their cached past through the block table plus themselves,
+        causally) and the view with this layer's rows written; how the
+        pool is laid out and whether it is read in place or gathered is
+        the view's business.  ``attention_fn`` (a causal full-sequence
+        kernel) is deliberately bypassed there.  ``return_kv``: also
+        return the view after the write, or without a view this call's
+        freshly projected ``(k, v)`` for the engine to write (the
+        monolithic prefill).  Both default off — the training path is
+        byte-identical to before.
 
         ``kv_quant``: int8-quantized-pool serving (``docs/serving.md``,
         "Quantized KV cache").  The freshly projected K/V quantize AT
         THE SOURCE (:func:`ops.kv_quant.quantize_kv`, per token per
         head) and attention everywhere operates on the QUANTIZED grid
-        — the cache context arrives int8 with its scale sidecar
-        (``cache_view`` is then the 5-tuple ``(k_ctx, v_ctx, ctx_bias,
-        k_scale_ctx, v_scale_ctx)``), the token's own / within-chunk
-        K/V concatenate as int8 with their fresh scales, and the
-        no-cache causal forward attends the dequantized values.  That
-        uniformity is the bit-stability argument: a (query, key)
-        pair's score is identical whether the key is fresh this call,
-        fresh earlier in the same chunk, or read back from the pool —
-        so chunking boundaries, preemption re-prefill, COW, and
-        speculation cannot move a logit.  ``return_kv`` then returns
-        ``((k_q, k_scale), (v_q, v_scale))`` — byte-for-byte what
-        attention just used, ready to scatter."""
+        — the view hands the int8 context with its scale sidecar to
+        ops that widen at read, the fed rows' own K/V join it as int8
+        with their fresh scales, and the no-cache causal forward
+        attends the dequantized values.  That uniformity is the
+        bit-stability argument: a (query, key) pair's score is
+        identical whether the key is fresh this call, fresh earlier in
+        the same chunk, or read back from the pool — so chunking
+        boundaries, preemption re-prefill, COW, and speculation cannot
+        move a logit.  The fresh K/V are then ``((k_q, k_scale),
+        (v_q, v_scale))`` — byte-for-byte what attention uses."""
         cfg = self.cfg
         h, nh = cfg.hidden_size, cfg.num_attention_heads
         init = _init(cfg)
@@ -169,45 +167,7 @@ class GPTSelfAttention(nn.Module):
             (k_q, k_s), (v_q, v_s) = quantize_kv(k), quantize_kv(v)
             kv_out = ((k_q, k_s), (v_q, v_s))
         if cache_view is not None:
-            from apex_tpu.ops.decode_attention import (
-                cached_attention,
-                chunk_cached_attention,
-            )
-
-            if kv_quant:
-                # int8 end to end: quantized context + the chunk's own
-                # quantized K/V concatenate with their scale rows; the
-                # attention ops widen at read (in-kernel on the Pallas
-                # path), so no dequantized context ever materializes
-                k_ctx, v_ctx, ctx_bias, ks_ctx, vs_ctx = cache_view
-                k_full = jnp.concatenate([k_ctx, k_q], axis=1)
-                v_full = jnp.concatenate([v_ctx, v_q], axis=1)
-                ks_full = jnp.concatenate([ks_ctx, k_s], axis=1)
-                vs_full = jnp.concatenate([vs_ctx, v_s], axis=1)
-            else:
-                k_ctx, v_ctx, ctx_bias = cache_view
-                # the new token(s) attend the gathered past plus
-                # themselves
-                k_full = jnp.concatenate(
-                    [k_ctx.astype(k.dtype), k], axis=1)
-                v_full = jnp.concatenate(
-                    [v_ctx.astype(v.dtype), v], axis=1)
-                ks_full = vs_full = None
-            if x.shape[1] == 1:
-                # decode: the self slot is always live (bias 0)
-                bias = jnp.concatenate(
-                    [ctx_bias, jnp.zeros((x.shape[0], 1), jnp.float32)],
-                    axis=1)
-                ctx = cached_attention(q, k_full, v_full, kv_bias=bias,
-                                       k_scale=ks_full,
-                                       v_scale=vs_full)
-            else:
-                # chunked prefill: context masked by ctx_bias, causal
-                # within the chunk
-                ctx = chunk_cached_attention(q, k_full, v_full,
-                                             ctx_bias,
-                                             k_scale=ks_full,
-                                             v_scale=vs_full)
+            ctx, kv_out = cache_view.attend(layer, q, kv_out)
         else:
             dropout_fn = None
             if cfg.attention_probs_dropout_prob > 0 and not deterministic:
@@ -245,7 +205,7 @@ class GPTSelfAttention(nn.Module):
 class GPTBlock(nn.Module):
     """Pre-LN: x + Attn(LN(x)); x + MLP(LN(x)).
 
-    ``cache_view``/``return_kv`` thread straight through to
+    ``cache_view``/``layer``/``return_kv`` thread straight through to
     :class:`GPTSelfAttention` (serving decode/prefill); the training
     call sites never pass them."""
 
@@ -255,7 +215,7 @@ class GPTBlock(nn.Module):
     @nn.compact
     def __call__(self, x, attn_bias, deterministic: bool = True,
                  cache_view=None, return_kv: bool = False,
-                 kv_quant: bool = False):
+                 kv_quant: bool = False, layer: int = 0):
         cfg = self.cfg
         init = _init(cfg)
         drop = nn.Dropout(cfg.hidden_dropout_prob,
@@ -267,7 +227,8 @@ class GPTBlock(nn.Module):
                                                deterministic,
                                                cache_view=cache_view,
                                                return_kv=return_kv,
-                                               kv_quant=kv_quant)
+                                               kv_quant=kv_quant,
+                                               layer=layer)
         kv = None
         if return_kv:
             h, kv = h
@@ -302,19 +263,20 @@ class GPTLMHeadModel(nn.Module):
 
     - ``positions``: explicit (B, S) position-embedding indices
       (decode feeds one token per sequence at its own depth);
-    - ``cache_views``: serving mode — ``(k_ctx, v_ctx, ctx_bias)`` with
-      k/v_ctx (L, B, T, H, D) per-layer gathered KV-cache context and
-      ctx_bias (B, T); each block attends [its context; self] (decode,
-      S == 1) or [its context; chunk] causally (chunked prefill,
-      S > 1);
-    - ``return_kv``: also return the per-layer freshly projected
-      ``(k, v)`` list so the engine can write them into the cache
-      (prefill uses this with ``cache_views=None`` — the normal causal
-      forward, optionally through the flash ``attention_fn``);
-    - ``kv_quant``: int8-quantized-pool serving — ``cache_views``
-      grows per-layer fp32 scale sidecars (a 5-tuple), fresh K/V
-      quantize at projection and attention runs on the quantized grid
-      everywhere, and ``return_kv`` yields per-layer
+    - ``cache_views``: serving mode — the launch's
+      ``serving.kv_cache.CacheView`` of the KV pool, threaded through
+      the blocks: each attends its cached context plus the fed rows
+      through it (decode, S == 1; verify and chunked prefill, S > 1,
+      causal among the rows) and hands on the view with its rows
+      written.  Passed with ``return_kv=True``, which then returns the
+      view after the last block in place of the K/V list;
+    - ``return_kv``: without a view, also return the per-layer freshly
+      projected ``(k, v)`` list so the engine can write them into the
+      cache (the monolithic prefill: the normal causal forward,
+      optionally through the flash ``attention_fn``);
+    - ``kv_quant``: int8-quantized-pool serving — fresh K/V quantize at
+      projection and attention runs on the quantized grid everywhere,
+      and ``return_kv`` without a view yields per-layer
       ``((k_q, k_scale), (v_q, v_scale))`` (``docs/serving.md``,
       "Quantized KV cache").
     """
@@ -342,27 +304,17 @@ class GPTLMHeadModel(nn.Module):
             # (return_kv) never remats: there is no backward to save
             # memory for, and the kv pytree output confuses the policy.
             block = nn.remat(GPTBlock, static_argnums=(3,))
-        kvs = []
+        kvs, view = [], cache_views
         for i in range(cfg.num_hidden_layers):
-            cv = None
-            if cache_views is not None:
-                if kv_quant:
-                    # quantized serving: (k, v, bias, k_scale,
-                    # v_scale) with int8 payloads and the per-layer
-                    # scale sidecar riding along
-                    k_ctx, v_ctx, ctx_bias, ks_ctx, vs_ctx = \
-                        cache_views
-                    cv = (k_ctx[i], v_ctx[i], ctx_bias,
-                          ks_ctx[i], vs_ctx[i])
-                else:
-                    k_ctx, v_ctx, ctx_bias = cache_views
-                    cv = (k_ctx[i], v_ctx[i], ctx_bias)
             if return_kv:
                 x, kv = block(cfg, self.attention_fn,
                               name=f"block_{i}")(
-                    x, bias, deterministic, cache_view=cv,
-                    return_kv=True, kv_quant=kv_quant)
-                kvs.append(kv)
+                    x, bias, deterministic, cache_view=view,
+                    return_kv=True, kv_quant=kv_quant, layer=i)
+                if view is None:
+                    kvs.append(kv)
+                else:
+                    view = kv
             else:
                 x = block(cfg, self.attention_fn, name=f"block_{i}")(
                     x, bias, deterministic)
@@ -377,7 +329,8 @@ class GPTLMHeadModel(nn.Module):
         # weight-tied head: logits = x @ wte^T
         logits = wte.attend(x)
         if return_kv:
-            return logits.astype(jnp.float32), kvs
+            return logits.astype(jnp.float32), (kvs if view is None
+                                                else view)
         return logits.astype(jnp.float32)
 
 

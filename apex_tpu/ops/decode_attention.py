@@ -50,7 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.kv_quant import dequantize_kv
-from apex_tpu.ops.pallas_utils import (on_tpu, pallas_auto_gate,
+from apex_tpu.ops.pallas_utils import (LANES, on_tpu, pallas_auto_gate,
                                        union_vma, unpatched)
 
 NEG_INF = -1e30
@@ -346,3 +346,207 @@ def cached_attention(q, k, v, *, kv_bias: Optional[jax.Array] = None,
                          interpret=bool(interpret))
     # row 0 of the sublane-broadcast block is the real query
     return out[:, :1].reshape(b, h, 1, d).swapaxes(1, 2)
+
+
+# -- attention in place: the pool read through the block table ---------------
+
+# pages one grid step streams: 8 pages of 16 slots are 128 keys, one
+# full lane row of scores and one native MXU tile of K/V per head
+_PAGES = 8
+
+
+def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
+                  scale, block_size, rows, heads, pages):
+    """One (slot, row tile, ``pages``-page window) step of the
+    streaming softmax over the pool itself.  ``rest`` is the window's
+    page blocks (``(block_size, heads * 2 * D)`` each: one row a token,
+    ``K_h`` beside ``V_h`` for every head), the output block and the
+    ``(acc, m, l)`` scratch.  The query rows of a head are ``2 * D``
+    wide with zeros over the ``V`` half, so ``q . [K_h | V_h]`` is
+    ``q . K_h`` and no lane is sliced; the product of the probabilities
+    with the same tile carries ``p . V_h`` in its upper ``D`` lanes,
+    which the caller takes."""
+    del layer_ref, tables_ref          # the index maps read them
+    page_refs = rest[:pages]
+    o_ref, acc_ref, m_ref, l_ref = rest[pages:]
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    span = pages * block_size           # keys in one window
+    tile, d2 = q_ref.shape[1], q_ref.shape[2]
+    first = starts_ref[b] + i * tile    # position of the tile's row 0
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    # row r sits at position first + r and sees every key at or before
+    # itself (the rows were written before this call); windows past the
+    # tile's last row hold nothing any of its rows may see
+    @pl.when(j * span < first + jnp.minimum(tile, rows - i * tile))
+    def _window():
+        key = j * span + lax.broadcasted_iota(jnp.int32, (tile, span), 1)
+        row = first + lax.broadcasted_iota(jnp.int32, (tile, span), 0)
+        seen = key <= row
+
+        def head(h, carry):
+            lanes = pl.ds(pl.multiple_of(h * d2, d2), d2)
+            kv = jnp.concatenate([r[:, lanes] for r in page_refs], axis=0)
+            s = lax.dot_general(q_ref[h], kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            s = jnp.where(seen, s * scale, NEG_INF)      # (tile, span)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1,
+                                                 keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr[:, :1] + lax.dot_general(
+                p.astype(kv.dtype), kv, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+            return carry
+
+        # one traced body, unrolled when lowered: the compiled kernel
+        # is the Python loop's (163.6 us a layer either way with eight
+        # slots live at GPT-2 XL, 391 rolled up; my chip run, PR 25)
+        # and the host traces a twenty-fifth of it
+        lax.fori_loop(0, heads, head, 0, unroll=True)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _writeout():
+        # key 0 is at or before every row, so no row is fully masked
+        o_ref[...] = (acc_ref[...] / l_ref[...][:, :, :1]).astype(
+            o_ref.dtype)
+
+
+# query rows one grid step holds: more rows a step reuse each K/V tile
+# the MXU has latched for more work, and cost VMEM
+_ROW_TILE = 128
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "rows", "scale", "name", "interpret"))
+def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
+                  scale, name, interpret):
+    """q4: (B, H, Rp, 2D) queries, zero over each head's upper D lanes,
+    Rp a whole number of row tiles; pages: the pool leaf
+    (L, num_slots, H * 2D); layer (1,), tables (B * blocks_per_seq,),
+    starts (B,) int32 are prefetched scalars."""
+    b, h, rp, d2 = q4.shape
+    nb = tables.shape[0] // b
+    width = pages.shape[2]
+    window = min(_PAGES, nb)
+    tile = min(rp, _ROW_TILE)
+
+    def page_spec(k):
+        def index(bi, ii, ji, layer_ref, tables_ref, starts_ref):
+            # the window's k-th page, held at the tile's last live
+            # page beyond it: a block index that does not change is
+            # not fetched again
+            last = (starts_ref[bi]
+                    + jnp.minimum((ii + 1) * tile, rows) - 1) // block_size
+            blk = jnp.minimum(jnp.minimum(ji * window + k, last), nb - 1)
+            return layer_ref[0], tables_ref[bi * nb + blk], 0
+        return pl.BlockSpec((None, block_size, width), index)
+
+    q_spec = pl.BlockSpec((None, h, tile, d2),
+                          lambda bi, ii, ji, *_: (bi, 0, ii, 0))
+    kernel = functools.partial(_paged_kernel, scale=scale,
+                               block_size=block_size, rows=rows, heads=h,
+                               pages=window)
+    itemsize = jnp.dtype(q4.dtype).itemsize
+    vmem = (h * tile * (d2 + 2 * LANES) * 4         # acc, m, l
+            + 2 * 2 * h * tile * d2 * itemsize      # q and out, twice
+            + 2 * window * block_size * width * itemsize)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, rp // tile, _cdiv(nb, window)),
+            in_specs=[q_spec] + [page_spec(k) for k in range(window)],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((h, tile, d2), jnp.float32),
+                            pltpu.VMEM((h, tile, LANES), jnp.float32),
+                            pltpu.VMEM((h, tile, LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype,
+                                       vma=union_vma(q4, pages)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # the default scoped limit is 16 MiB; a chunk's row tiles
+            # need about that, so ask for what is used and half again
+            vmem_limit_bytes=max(16 * 2 ** 20, vmem * 3 // 2)),
+        interpret=interpret,
+        name=name,
+    )(layer, tables, starts, q4, *([pages] * window))
+
+
+def paged_attention_fits(head_dim: int, block_size: int, dtype) -> bool:
+    """Whether :func:`paged_attention` can take a pool of this
+    geometry: a head's ``K | V`` pair has to fill whole 128-lane tiles
+    and a page whole sublane tiles of its dtype, or the kernel's slices
+    would not be aligned."""
+    packing = max(1, 4 // jnp.dtype(dtype).itemsize)
+    return (2 * head_dim) % LANES == 0 and block_size % (8 * packing) == 0
+
+
+def paged_attention(q, pages, layer, block_tables, starts, *,
+                    block_size: int, scale: Optional[float] = None,
+                    interpret: Optional[bool] = None):
+    """Attention of freshly written rows over a paged KV pool, read in
+    place through the block table.
+
+    Args:
+      q: (B, R, H, D) — R query rows a sequence: 1 (decode), the
+        verify width, or a prefill chunk.  Row ``i`` of sequence ``b``
+        sits at position
+        ``starts[b] + i`` and attends every key at or before itself, so
+        its own K/V must ALREADY be in the pool.
+      pages: (L, num_slots, H * 2 * D) — the pool leaf as
+        ``serving.kv_cache`` lays it out: one row a token slot, every
+        head's ``K_h`` beside its ``V_h``.
+      layer: int32 scalar, the layer whose pages to read.
+      block_tables: (B, blocks_per_seq) int32 physical block ids;
+        unallocated entries are 0 (the garbage block) and lie beyond
+        every valid row's position.
+      starts: (B,) int32 position of each sequence's first row (its
+        cached context length).
+      block_size: token slots a page.
+      scale: logit scale, default 1/sqrt(D).
+      interpret: Pallas interpret mode (defaults to not-on-TPU).
+
+    Only the pages up to each sequence's last row are streamed; nothing
+    of ``max_context`` size is built.  fp32 scores, softmax state and
+    accumulation; the probabilities meet V in the pool's dtype, as the
+    jnp oracle's do.  Returns (B, R, H, D) in q.dtype.  The Pallas call
+    is named ``_decode_kernel`` for one row, ``_verify_kernel`` for up
+    to a sublane tile of them and ``_chunk_kernel`` beyond, so a trace
+    tells the programs apart.  Inference only."""
+    b, r, h, d = q.shape
+    if pages.ndim != 3 or pages.shape[2] != h * 2 * d:
+        raise ValueError(
+            f"pages must be (L, num_slots, H*2*D) = (.., .., {h * 2 * d}) "
+            f"for q={q.shape}; got {pages.shape}")
+    if not paged_attention_fits(d, block_size, pages.dtype):
+        raise ValueError(
+            f"paged_attention cannot tile head_dim={d}, "
+            f"block_size={block_size}, dtype={pages.dtype}")
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    if interpret is None:
+        interpret = not on_tpu()
+    rp = _cdiv(r, _QROWS) * _QROWS
+    if rp > _ROW_TILE:
+        rp = _cdiv(r, _ROW_TILE) * _ROW_TILE
+    q4 = jnp.pad(jnp.swapaxes(q, 1, 2).astype(pages.dtype),
+                 ((0, 0), (0, 0), (0, rp - r), (0, d)))
+    out = _paged_pallas(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        block_tables.astype(jnp.int32).reshape(-1),
+        starts.astype(jnp.int32), q4, pages,
+        block_size=int(block_size), rows=int(r), scale=float(scale),
+        name=("_decode_kernel" if r == 1 else
+              "_verify_kernel" if r <= _QROWS else "_chunk_kernel"),
+        interpret=bool(interpret))
+    return jnp.swapaxes(out[:, :, :r, d:], 1, 2).astype(q.dtype)

@@ -3026,11 +3026,16 @@ class InferenceServer:
         """The ``stats()["programs"]`` block: the per-compiled-program
         table (call count, host wall time, compile count/time,
         steady-state per-call ms per program/shape key) plus the
-        totals — empty ``by_program`` when accounting is off."""
+        totals — empty ``by_program`` when accounting is off — and
+        ``attention``, which way each serving program family was
+        built to attend: ``"table"`` (the pool read in place through
+        the block table) or ``"gathered"``, fixed when the engine
+        built its programs."""
         table = self.programs.table()
         return {
             "enabled": self.programs.enabled,
             "by_program": table,
+            "attention": dict(self.engine.attention_paths),
             "total_wall_ms": round(
                 sum(r["wall_ms"] for r in table.values()), 3),
             "total_compile_ms": round(

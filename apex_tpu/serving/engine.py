@@ -11,26 +11,25 @@ never recompiles in steady state:
   ``len(prefill_buckets)``, not by the distribution of prompt lengths.
 - **chunk prefill** (one request, one fixed-width chunk at a carried
   KV position): the chunked-prefill and prefix-cached-tail workhorse —
-  the chunk attends the request's ALREADY-CACHED context through its
-  block table (gather + ``ops.chunk_cached_attention``) plus itself
-  causally, and its K/V scatter at block-offset slots.  A fixed chunk
-  size means ONE trace however long prompts get.
+  each layer writes the chunk's K/V at its block-offset slots and the
+  chunk attends the request's already-cached context through its block
+  table plus itself causally.  A fixed chunk size means ONE trace
+  however long prompts get.
 - **decode** (the whole running batch, always ``max_batch_size``
-  wide): gather every slot's context through its block table, run the
-  model on one token per slot at its own position
-  (``ops.cached_attention`` inside), scatter the new K/V, return
-  next-token logits.  Compiled exactly once.
+  wide): the model runs on one token per slot at its own position;
+  each layer writes the token's K/V into the pool and attends through
+  the block table.  Returns next-token logits.  Compiled exactly once.
 - **verify** (the whole batch, ``max_batch_size`` x a fixed token
   width): the speculative-decoding scoring step — every slot feeds its
-  pending token plus its drafted guesses at carried positions, attends
-  its cached context through its block table plus itself causally
-  (``ops.chunk_cached_attention``, the same program shape as chunk
-  prefill but batched and returning EVERY row's logits), and scatters
-  all fed tokens' K/V.  Greedy acceptance happens on the host
-  (``serving.api``); rejected suffix positions hold garbage K/V that
-  sits beyond the accepted length — masked by the context bias and
-  overwritten before the request ever advances past it.  One trace per
-  verify width, so a fixed speculation depth compiles exactly once.
+  pending token plus its drafted guesses at carried positions; each
+  layer writes all fed tokens' K/V and they attend their cached
+  context plus themselves causally (the same body as chunk prefill,
+  batched and returning EVERY row's logits).  Greedy acceptance happens
+  on the host (``serving.api``); rejected suffix positions hold garbage
+  K/V that sits beyond the accepted length — never at or before a
+  valid row's position, and overwritten before the request ever
+  advances past it.  One trace per verify width, so a fixed
+  speculation depth compiles exactly once.
 - **block copy** (fixed-width (src, dst) id batch): whole-block
   duplication inside the pool — the device half of the prefix cache's
   copy-on-write.  Compiled exactly once.
@@ -52,9 +51,36 @@ Empty slots ride along as no-ops by construction: position 0 masks
 the whole context, the zeroed block table routes the KV write into
 the reserved garbage block, and the caller ignores their logits.
 
-The cache pytree is donated through both steps — on TPU the pool is
-the HBM hog and must be updated in place, not double-buffered.  (XLA
-on CPU ignores donation; the warning is filtered.)
+How a program attends is fixed when the engine is built
+(``DecodeEngine.attention_paths``, reported under
+``stats()["programs"]["attention"]``), from what can be seen then:
+
+- ``"table"`` — *attend through the table*: an unquantized pool on one
+  TPU device whose geometry the kernel tiles (``2 * head_dim`` a
+  multiple of 128 lanes, ``block_size`` of whole sublane tiles).
+  Decode, verify and chunk prefill write a layer's rows, then
+  ``ops.decode_attention.paged_attention`` takes the pool itself with
+  the block tables and lengths as prefetched scalars and streams only
+  the pages up to each slot's last row: no ``(B, max_context)`` copy of
+  keys and values, no bias row, no concatenation, no pad.  (The chunk
+  program took the kernel by measurement: 13.0 ms a launch at GPT-2 XL
+  against 20.0 ms gathering its one request's context; my chip run,
+  PR 25.)
+- ``"gathered"``: each layer's context is gathered block by block into
+  the ops of the gathered form (``ops.cached_attention`` /
+  ``ops.chunk_cached_attention``, the parity oracle of the kernel).
+  An int8 pool (its kernel front works on gathered input), a mesh (a
+  GSPMD-sharded jit cannot hold a Mosaic kernel) and the CPU backend
+  take it for every program.  Results are the same either way.
+
+The cache pytree is donated through every step, and the donation
+holds: the pool's layout (``serving.kv_cache``) lets every write, block
+copy and read compile to an update or a slice of the donated buffer, so
+no program holds a second copy of the pool or any pool-sized temporary
+(``memory_info()["decode_temp_bytes"]``;
+``tests/L0/test_serving_programs_compiled.py`` reads the compiled
+programs at GPT-2 XL's size).  (XLA on CPU ignores donation; the
+warning is filtered.)
 """
 
 from __future__ import annotations
@@ -70,7 +96,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.observability import NULL_PROGRAM_ACCOUNTING, NULL_TRACER
 from apex_tpu.models.gpt import GPTConfig, GPTLMHeadModel
-from apex_tpu.ops.pallas_utils import on_tpu
+from apex_tpu.ops.decode_attention import paged_attention_fits
+from apex_tpu.ops.pallas_utils import on_tpu, pallas_auto_gate
 from apex_tpu.ops.sampling import finite_rows, greedy_argmax, sample_tokens
 from apex_tpu.ops.vocab_parallel import (
     vocab_parallel_sample,
@@ -78,17 +105,17 @@ from apex_tpu.ops.vocab_parallel import (
 )
 from apex_tpu.serving.kv_cache import (
     BlockAllocator,
+    CacheView,
     KVCacheConfig,
-    context_bias,
     copy_blocks,
     copy_blocks_across,
-    gather_context,
-    gather_scales,
     init_kv_cache,
+    pool_specs,
+    read_blocks,
     resolve_kv_quant,
     slot_index,
+    write_blocks,
     write_prefill,
-    write_tokens,
 )
 
 # CPU backends can't honor donation; the fallback copy is exactly the
@@ -155,7 +182,8 @@ class DecodeEngine:
         historical full-width pool, byte-identical programs).
       attention_fn: optional fused attention for the PREFILL pass
         (``make_flash_attention(causal=True)`` on TPU); decode always
-        takes the ``ops.cached_attention`` path.
+        attends the pool, through the block table or gathered
+        (``attention_paths``).
       prefill_buckets: ascending prompt-length buckets; None =
         :func:`default_prefill_buckets`.
       tracer: optional :class:`apex_tpu.observability.SpanTracer`;
@@ -253,12 +281,11 @@ class DecodeEngine:
             params = shard_params(params, mesh, tp_rules)
             self._tp_rules = tp_rules
             self._repl = NamedSharding(mesh, P())
-            self._pool_shard = NamedSharding(
-                mesh, P(None, None, tp_axis, None))
-            # scale sidecar (L, num_slots, H): heads are its LAST
-            # dim, so it shards alongside the heads it dequantizes
-            self._scale_shard = NamedSharding(
-                mesh, P(None, None, tp_axis))
+            # the pool and its scale sidecar shard their heads, each
+            # leaf as kv_cache lays it out
+            specs = pool_specs(tp_axis)
+            self._pool_shard = NamedSharding(mesh, specs["kv"])
+            self._scale_shard = NamedSharding(mesh, specs["k_scale"])
         self.params = params
         self.max_batch_size = int(max_batch_size)
         self.max_context = int(max_context
@@ -281,6 +308,20 @@ class DecodeEngine:
             dtype=cache_dtype,
             quantize=self.kv_quant)
         self.allocator = BlockAllocator(self.cache_cfg)
+        # which way each serving program attends, decided once from
+        # what can be seen here: an unquantized pool on one TPU device
+        # whose geometry the kernel tiles is read in place through the
+        # block table; an int8 pool, a mesh (GSPMD cannot hold a
+        # Mosaic kernel) and the CPU gather each layer's context into
+        # the ops of the gathered form.
+        in_place = (not self.quantized and mesh is None
+                    and pallas_auto_gate()
+                    and paged_attention_fits(
+                        self.cache_cfg.head_dim, self.block_size,
+                        self.cache_cfg.storage_dtype()))
+        self.attention_paths = dict.fromkeys(
+            ("decode", "verify", "chunk_prefill"),
+            "table" if in_place else "gathered")
         self.cache = init_kv_cache(self.cache_cfg,
                                    sharding=self._pool_shard,
                                    scale_sharding=self._scale_shard)
@@ -307,7 +348,7 @@ class DecodeEngine:
 
         cache_sh = None
         if self.mesh is not None:
-            cache_sh = {"k": self._pool_shard, "v": self._pool_shard}
+            cache_sh = {"kv": self._pool_shard}
             if self.quantized:
                 cache_sh["k_scale"] = self._scale_shard
                 cache_sh["v_scale"] = self._scale_shard
@@ -331,6 +372,8 @@ class DecodeEngine:
         self._xfer_jit = _jit(self._xfer_impl, xfer_donate, cache_sh)
         self._import_jit = _jit(self._import_impl, xfer_donate,
                                 cache_sh)
+        self._export_jit = jax.jit(self._export_impl)
+        self._decode_exe = None   # _decode_compiled's, made when asked
         # the fused on-device-sampling twins (docs/serving.md,
         # "Pipelined serve loop"): same bodies + argmax/finite-guard,
         # so a greedy server transfers token ids, never logits.
@@ -379,20 +422,18 @@ class DecodeEngine:
 
     # -- compiled bodies --------------------------------------------------
 
-    def _cache_views(self, cache, tables, bias):
-        """The model's ``cache_views`` struct for one gathered
-        context: (k, v, bias) plain, plus the per-layer scale sidecar
-        legs under quantization (int8 payload + fp32 scales — the
-        attention ops widen at read)."""
-        k_ctx, v_ctx = gather_context(cache, tables, self.block_size)
-        if not self.quantized:
-            return (k_ctx, v_ctx, bias)
-        ks_ctx, vs_ctx = gather_scales(cache, tables, self.block_size)
-        return (k_ctx, v_ctx, bias, ks_ctx, vs_ctx)
+    def _view(self, program, cache, tables, start, slots):
+        """The model's view of the pool for one launch of ``program``
+        (``kv_cache.CacheView``)."""
+        return CacheView(
+            cache, tables, start.astype(jnp.int32), slots,
+            block_size=self.block_size,
+            num_heads=self.cache_cfg.num_heads,
+            table=self.attention_paths[program] == "table")
 
     def _stack_kvs(self, kvs):
-        """Stack the model's per-layer fresh K/V into the scatter
-        layout ``write_prefill``/``write_tokens`` expect: plain
+        """Stack the monolithic prefill's per-layer fresh K/V into the
+        layout ``write_prefill`` expects: plain
         (k, v) arrays, or the quantized
         ``((k_q, k_scale), (v_q, v_scale))`` quadruple."""
         if self.quantized:
@@ -429,30 +470,13 @@ class DecodeEngine:
         zero-padded chunk tokens; start (1,) absolute position of
         ``ids[0]`` (== tokens already materialized through ``table``);
         length (1,) valid tokens in the chunk; table (1,
-        blocks_per_seq).  Gathers the request's full cached context,
-        runs the chunk through the model's chunked ``cache_views``
-        path (context masked to slots < start, causal within the
-        chunk), scatters the chunk's K/V at its block-offset slots,
-        and returns (cache, last-valid-token logits (1, V)) — the
-        logits only matter on the final chunk."""
-        cb = ids.shape[1]
-        off = jnp.arange(cb, dtype=jnp.int32)[None, :]
-        pos = start[:, None].astype(jnp.int32) + off       # (1, Cb)
-        t_ctx = self.blocks_per_seq * self.block_size
-        bias = context_bias(start, t_ctx)                  # slots < start
-        views = self._cache_views(cache, table, bias)
-        # padded tail positions can run past the embedding table; clamp
-        # them (their logits and K/V writes are discarded/garbage-sunk)
-        pos_emb = jnp.minimum(pos, self.cfg.max_position_embeddings - 1)
-        logits, kvs = self.model.apply(
-            {"params": params}, ids, positions=pos_emb,
-            deterministic=True, cache_views=views,
-            return_kv=True, kv_quant=self.quantized)
-        kv_new = self._stack_kvs(kvs)                      # (L, 1, Cb, H, D)
-        valid = off < length[:, None]
-        slots = jnp.where(valid,
-                          slot_index(table, pos, self.block_size), 0)
-        cache = write_prefill(cache, kv_new, slots)
+        blocks_per_seq).  Each layer attends the request's cached
+        context (slots < start) plus the chunk causally and writes the
+        chunk's K/V at its block-offset slots (:meth:`_fed_rows`).
+        Returns (cache, last-valid-token logits (1, V)) — the logits
+        only matter on the final chunk."""
+        logits, cache = self._fed_rows("chunk_prefill", params, cache,
+                                       ids, start, length, table)
         last = jnp.take_along_axis(
             logits, (length[:, None, None] - 1).astype(jnp.int32),
             axis=1)[:, 0]                                  # (1, V)
@@ -466,32 +490,37 @@ class DecodeEngine:
         valid tokens per slot (0 = idle slot); tables (B,
         blocks_per_seq).
 
-        Each slot's K tokens attend its full cached context (masked to
-        slots < start) plus themselves causally — the batched
-        generalization of ``_chunk_impl`` — and their K/V scatter at
-        block-offset slots (invalid columns sink into the garbage
-        block).  Returns (cache, logits (B, K, V)): EVERY row's
+        Each slot's K tokens attend its cached context (slots < start)
+        plus themselves causally — the batched generalization of
+        ``_chunk_impl`` — and their K/V are written at block-offset
+        slots (invalid columns sink into the garbage block).  Returns
+        (cache, logits (B, K, V)): EVERY row's
         logits, because greedy acceptance needs the model's argmax at
         each drafted position, not just the last."""
-        kw = ids.shape[1]
-        off = jnp.arange(kw, dtype=jnp.int32)[None, :]
+        logits, cache = self._fed_rows("verify", params, cache, ids,
+                                       start, length, tables)
+        return cache, logits                               # (B, K, V)
+
+    def _fed_rows(self, program, params, cache, ids, start, length,
+                  tables):
+        """The body verify and chunk prefill share: ids (B, K) fed at
+        positions ``start + 0..K-1``, the first ``length`` of each row
+        valid.  Every layer attends through the launch's view of the
+        pool and writes its K/V there (invalid columns sink into the
+        garbage block).  Returns (logits (B, K, V), cache)."""
+        off = jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :]
         pos = start[:, None].astype(jnp.int32) + off       # (B, K)
-        t_ctx = self.blocks_per_seq * self.block_size
-        bias = context_bias(start, t_ctx)                  # slots < start
-        views = self._cache_views(cache, tables, bias)
+        slots = jnp.where(off < length[:, None],
+                          slot_index(tables, pos, self.block_size), 0)
         # padded columns can run past the embedding table; clamp (their
         # logits are ignored and their K/V writes garbage-sunk)
         pos_emb = jnp.minimum(pos, self.cfg.max_position_embeddings - 1)
-        logits, kvs = self.model.apply(
+        logits, view = self.model.apply(
             {"params": params}, ids, positions=pos_emb,
-            deterministic=True, cache_views=views,
+            deterministic=True,
+            cache_views=self._view(program, cache, tables, start, slots),
             return_kv=True, kv_quant=self.quantized)
-        kv_new = self._stack_kvs(kvs)                      # (L, B, K, H, D)
-        valid = off < length[:, None]
-        slots = jnp.where(valid,
-                          slot_index(tables, pos, self.block_size), 0)
-        cache = write_prefill(cache, kv_new, slots)
-        return cache, logits                               # (B, K, V)
+        return logits, view.cache
 
     def _copy_impl(self, cache, src, dst):
         """(_COPY_WIDTH,) src/dst block ids, (0, 0)-padded — the COW
@@ -506,31 +535,31 @@ class DecodeEngine:
         return copy_blocks_across(dst_cache, src_cache, src, dst,
                                   self.block_size)
 
-    def _import_impl(self, cache, slots, leaves):
-        """Scatter a host-shipped block payload into the pool:
-        ``slots`` (W * block_size,) flat slot indices (padding rows
-        point at the garbage block), ``leaves`` a dict matching the
-        cache's leaf names with per-slot rows along axis 1."""
-        return {name: arr.at[:, slots].set(leaves[name])
-                for name, arr in cache.items()}
+    def _import_impl(self, cache, block_ids, leaves):
+        """Write a host-shipped block payload into the pool:
+        ``block_ids`` (W,) physical blocks (padding ids are the garbage
+        block), ``leaves`` a dict matching the cache's leaf names with
+        the blocks' per-slot rows along axis 1."""
+        return write_blocks(cache, block_ids, leaves, self.block_size)
+
+    def _export_impl(self, cache, block_ids):
+        """Every leaf's rows of ``block_ids`` (n,), slots along axis
+        1."""
+        return read_blocks(cache, block_ids, self.block_size)
 
     def _decode_impl(self, params, cache, tokens, positions, tables):
         """tokens (B,) current input token per slot; positions (B,)
         its position (== cached context length); tables (B,
         blocks_per_seq).  Returns (cache, logits (B, V))."""
-        t_ctx = self.blocks_per_seq * self.block_size
-        bias = context_bias(positions, t_ctx)
-        views = self._cache_views(cache, tables, bias)
-        logits, kvs = self.model.apply(
+        slots = slot_index(tables, positions, self.block_size)
+        logits, view = self.model.apply(
             {"params": params}, tokens[:, None],
             positions=positions[:, None].astype(jnp.int32),
             deterministic=True,
-            cache_views=views, return_kv=True,
-            kv_quant=self.quantized)
-        kv_new = self._stack_kvs(kvs)                 # (L, B, 1, H, D)
-        slots = slot_index(tables, positions, self.block_size)
-        cache = write_tokens(cache, kv_new, slots)
-        return cache, logits[:, 0]                    # (B, V)
+            cache_views=self._view("decode", cache, tables, positions,
+                                   slots[:, None]),
+            return_kv=True, kv_quant=self.quantized)
+        return view.cache, logits[:, 0]               # (B, V)
 
     # -- fused on-device-sampling bodies ----------------------------------
     # Each composes its logits twin with greedy argmax + the finite-row
@@ -876,14 +905,6 @@ class DecodeEngine:
             self._account(self._xfer_jit, mark, "handoff_copy",
                           key=self._qkey())
 
-    def _block_slots(self, block_ids, pad_to: int) -> np.ndarray:
-        """Flat pool slots of ``block_ids``' token rows, padded with
-        the garbage block's slots to ``pad_to`` blocks."""
-        bs = self.block_size
-        ids = np.zeros((pad_to,), np.int64)
-        ids[:len(block_ids)] = block_ids
-        return (ids[:, None] * bs + np.arange(bs)[None, :]).reshape(-1)
-
     def export_blocks(self, block_ids, *,
                       per_block_crc: bool = False) -> dict:
         """Materialize ``block_ids``' contents as a host payload — the
@@ -902,9 +923,13 @@ class DecodeEngine:
         whole-leaf crc already covers a one-shot transfer."""
         import zlib
 
-        slots = self._block_slots(block_ids, len(block_ids))
-        leaves = {name: np.ascontiguousarray(np.asarray(arr[:, slots]))
-                  for name, arr in self.cache.items()}
+        if len(block_ids):
+            leaves = self._export_jit(
+                self.cache, *self._put(np.asarray(block_ids, np.int32)))
+        else:
+            leaves = {name: arr[:, :0] for name, arr in self.cache.items()}
+        leaves = {name: np.ascontiguousarray(np.asarray(arr))
+                  for name, arr in leaves.items()}
         bs = self.block_size
         payload = {
             "num_blocks": len(block_ids),
@@ -963,14 +988,15 @@ class DecodeEngine:
             # zeros and overwrite block 0's slots with zero bytes
             return
         w = self.blocks_per_seq
-        slots = self._block_slots(block_ids, w).astype(np.int32)
+        ids = np.zeros((w,), np.int32)          # padding: garbage block
+        ids[:len(block_ids)] = block_ids
         padded = {}
         for name, arr in leaves.items():
             full = np.zeros((arr.shape[0], w * self.block_size)
                             + arr.shape[2:], arr.dtype)
             full[:, :arr.shape[1]] = arr
             padded[name] = full
-        args = self._put(slots, padded)
+        args = self._put(ids, padded)
         mark = self._mark(self._import_jit)
         self.cache = self._import_jit(self.cache, *args)
         self._account(self._import_jit, mark, "import_blocks",
@@ -1095,17 +1121,24 @@ class DecodeEngine:
                 + self._decode_sampled_jit._cache_size()
                 + self._decode_stoch_jit._cache_size())
 
+    def _decode_compiled(self):
+        """The greedy decode program at this engine's shapes, lowered
+        and compiled once (a persistent-cache hit once the program has
+        run), never executed."""
+        if self._decode_exe is None:
+            b = self.max_batch_size
+            args = self._decode_args(
+                np.zeros((b,), np.int32), np.zeros((b,), np.int32),
+                np.zeros((b, self.blocks_per_seq), np.int32))
+            self._decode_exe = self._decode_sampled_jit.lower(
+                self.params, self.cache, *args).compile()
+        return self._decode_exe
+
     def decode_hlo(self) -> str:
-        """Compiled HLO text of the greedy decode program at this
-        engine's shapes: lowered and compiled (a persistent-cache hit
-        once the program has run), never executed.  ``chip_smoke.py``
-        looks for the ``_decode_kernel`` Mosaic call in it."""
-        b = self.max_batch_size
-        args = self._decode_args(
-            np.zeros((b,), np.int32), np.zeros((b,), np.int32),
-            np.zeros((b, self.blocks_per_seq), np.int32))
-        return self._decode_sampled_jit.lower(
-            self.params, self.cache, *args).compile().as_text()
+        """Compiled HLO text of the greedy decode program.
+        ``chip_smoke.py`` looks for the ``_decode_kernel`` Mosaic call
+        in it."""
+        return self._decode_compiled().as_text()
 
     def verify_compiles(self) -> int:
         """Verify-program traces (logits + sampled + stochastic
@@ -1147,9 +1180,19 @@ class DecodeEngine:
         sidecar — summed over ALL live cache leaves' shard shapes, so
         ``pool_bytes_per_device`` is what the int8 pool plus its fp32
         scales actually pin on each chip, and ``bytes_per_block`` is
-        the true per-block HBM price headroom math divides by."""
+        the true per-block HBM price headroom math divides by.
+
+        ``decode_temp_bytes`` is what the compiler reserves for the
+        decode program's temporaries beside the pool (0 where the pool
+        is updated in place and nothing of its size is copied): read
+        from the compiled program once, after a decode launch has
+        built it; ``None`` before."""
         cfg = self.cache_cfg
-        k = self.cache["k"]
+        temp = None
+        if self._decode_exe is not None or self.compile_counts()[1]:
+            analysis = self._decode_compiled().memory_analysis()
+            temp = (int(analysis.temp_size_in_bytes)
+                    if analysis is not None else None)
         per_device = sum(
             int(np.prod(arr.sharding.shard_shape(arr.shape)))
             * jnp.dtype(arr.dtype).itemsize
@@ -1161,7 +1204,8 @@ class DecodeEngine:
             "pool_bytes": cfg.bytes(),
             "pool_bytes_per_device": per_device,
             "bytes_per_block": cfg.bytes_per_block,
-            "cache_dtype": str(jnp.dtype(k.dtype)),
+            "decode_temp_bytes": temp,
+            "cache_dtype": str(cfg.storage_dtype()),
             "quantize": cfg.quantize,
             "compute_dtype": str(cfg.resolved_dtype()),
         }

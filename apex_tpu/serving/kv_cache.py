@@ -2,33 +2,69 @@
 
 vLLM's PagedAttention insight, re-derived for jit-stability on TPU:
 the cache is ONE preallocated fixed-shape pool of ``num_blocks``
-physical blocks of ``block_size`` token slots each, per layer —
+physical blocks of ``block_size`` token slots each, and every request
+owns an ordered *block table* mapping its logical token positions to
+physical blocks.  Fixed shapes mean the jitted prefill/decode steps
+never recompile as requests come and go; block granularity means a
+request's memory grows in ``block_size`` quanta with zero copying, and
+a finished request's blocks return to the free list immediately (no
+compaction, no fragmentation beyond the last partial block).
 
-    k, v: (num_layers, num_blocks * block_size, num_heads, head_dim)
+The pool's layout, and why.  One leaf holds keys and values:
 
-— and every request owns an ordered *block table* mapping its logical
-token positions to physical blocks.  Fixed shapes mean the jitted
-prefill/decode steps never recompile as requests come and go; block
-granularity means a request's memory grows in ``block_size`` quanta
-with zero copying, and a finished request's blocks return to the free
-list immediately (no compaction, no fragmentation beyond the last
-partial block).
+    kv: (num_layers, num_blocks * block_size, num_heads * 2 * head_dim)
+
+one row a token slot, and in it every head's ``K_h`` beside its
+``V_h`` (``pack_rows``).  This module is the one place that spells the
+layout out; everything else goes through its functions.  It follows
+from how XLA:TPU lays arrays out in HBM.  The minor dimension fills the
+128 lanes of a tile.  A leaf whose minor dimension is ``head_dim`` 64
+(the former ``(L, slots, H, D)``) is narrower than the lanes, so the
+compiler puts the SLOT dimension there instead
+(``bf16[48,8208,25,64]{1,3,2,0:T(8,128)(2,1)}`` at GPT-2 XL's size,
+compiled for a described v5e), and every gather or scatter along slots
+first copies the whole leaf to a slot-major layout and back: a 1.26 GB
+leaf moved five times in every launch (ledger, PR 24).  A minor
+dimension that is a multiple of 128 gets the plain row-major layout,
+and ``2 * head_dim`` = 128 is one head's ``K | V`` pair exactly.  Then
+
+- a token's row is contiguous and a block is ``block_size`` rows:
+  writing rows at ``[layer, slots]`` (``write_layer``) and copying
+  whole blocks (``copy_blocks``, ``read_blocks``, ``write_blocks``)
+  update the donated buffer in place, with no temporary (the compiled
+  decode program at 8 slots of 1,024: 0.23 GiB of temporaries, where
+  it had 9.5; ``tests/L0/test_serving_programs_compiled.py``);
+- a Pallas kernel can index the leaf by block through the table with
+  no relayout: one page of one layer is ``block_size x (H * 2D)``, a
+  whole number of (16, 128) tiles, and a head's ``K | V`` one lane
+  tile of it (``ops.decode_attention.paged_attention``);
+- the lane dimension is heads-major, so tensor parallelism splits it
+  into whole heads (``pool_specs``).
+
+Token slots are axis 1 of every leaf (the scale sidecar's too), which
+is what the hand-off and offload payloads slice by.  The forms that
+index ACROSS layers at once (``arr.at[:, slots]``) are the ones to
+avoid: they make the compiler transpose the leaf to bring the slots
+outermost, a pool-sized copy each way.
 
 Split of responsibilities:
 
-- device side (this module's pure functions): fixed-shape gather of a
-  request batch's context (``gather_context``), scatter of freshly
-  projected K/V into flat slots (``write_tokens`` / ``write_prefill``)
-  — all jit-traceable, cache pytree in/out;
+- device side (this module's pure functions): writes of freshly
+  projected K/V at flat slots (``write_layer``; ``write_tokens`` /
+  ``write_prefill`` over all layers), the fixed-shape gather of a
+  request batch's context (``gather_layer``; ``gather_context`` for
+  the oracle), whole-block copies, and :class:`CacheView`, what the
+  model sees of the pool — all jit-traceable, cache pytree in/out;
 - host side (:class:`BlockAllocator`): the free list.  Allocation is
   control flow, not math — it stays in Python where it is O(blocks)
   trivial, exactly like the schedulers it serves.
 
 Physical block 0 is RESERVED as the garbage sink: unallocated
 block-table entries and padded prefill positions all point at it, so
-every scatter/gather stays in-bounds with no data-dependent branching
-— reads from it are masked by the context bias (built from lengths),
-writes to it land on data nothing will ever read.
+every write and read stays in-bounds with no data-dependent branching
+— reads from it are masked (by the context bias built from lengths, or
+by position inside the kernel), writes to it land on data nothing will
+ever read.
 
 Dtype policy: the cache is typically the HBM hog (2 * L * T * H * D
 per token), so it defaults to the amp "half" dtype — the active
@@ -57,10 +93,13 @@ kv-quant decision table).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 # the quantization numeric contract lives with the kernels that widen
 # it back (ops); re-exported here because the cache is what stores it
@@ -211,46 +250,70 @@ class KVCacheConfig:
         return self.num_blocks * self.bytes_per_block
 
 
+def pool_specs(axis):
+    """``PartitionSpec`` of every pool leaf under tensor parallelism
+    over mesh axis ``axis``: the payload's lane dimension is heads-major
+    (``H`` groups of ``2 * D``), so splitting it ``tp`` ways hands each
+    device whole heads; the scale sidecar's heads are its last
+    dimension."""
+    from jax.sharding import PartitionSpec as P
+    return {"kv": P(None, None, axis), "k_scale": P(None, None, axis),
+            "v_scale": P(None, None, axis)}
+
+
 def init_kv_cache(cfg: KVCacheConfig, sharding=None,
                   scale_sharding=None):
-    """Allocate the zeroed pool: ``{"k","v"}`` each
-    (L, num_slots, H, D) in the storage dtype, plus — under
-    ``quantize="int8"`` — the fp32 scale sidecar ``{"k_scale",
-    "v_scale"}`` each (L, num_slots, H).
+    """Allocate the zeroed pool: ``{"kv"}`` of shape
+    (L, num_slots, H * 2 * D) in the storage dtype (the layout the
+    module docstring derives), plus — under ``quantize="int8"`` — the
+    fp32 scale sidecar ``{"k_scale", "v_scale"}`` each
+    (L, num_slots, H).  Token slots are axis 1 of every leaf.
 
-    ``sharding``: optional ``jax.sharding.Sharding`` for the pool
-    leaves — tensor-parallel serving passes the head-sharded pool
-    placement (``P(None, None, model, None)``) so every device
-    materializes ONLY its ``H/tp`` heads of every block; the zeros are
-    created sharded (jit ``out_shardings``), never allocated whole and
-    scattered.  ``scale_sharding`` is the sidecar's placement
-    (``P(None, None, model)`` — heads are its LAST dim), so scales
-    live on the same shard as the heads they dequantize."""
-    shape = (cfg.num_layers, cfg.num_slots, cfg.num_heads, cfg.head_dim)
+    ``sharding``: optional ``jax.sharding.Sharding`` for the payload —
+    tensor-parallel serving passes the head-sharded placement
+    (:func:`pool_specs`) so every device materializes ONLY its ``H/tp``
+    heads of every block; the zeros are created sharded (jit
+    ``out_shardings``), never allocated whole and scattered.
+    ``scale_sharding`` is the sidecar's placement, so scales live on
+    the same shard as the heads they dequantize."""
+    shape = (cfg.num_layers, cfg.num_slots,
+             cfg.num_heads * 2 * cfg.head_dim)
     dt = cfg.storage_dtype()
 
     def build():
-        cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+        cache = {"kv": jnp.zeros(shape, dt)}
         if cfg.quantized:
-            sshape = shape[:-1]
+            sshape = shape[:2] + (cfg.num_heads,)
             cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
             cache["v_scale"] = jnp.zeros(sshape, jnp.float32)
         return cache
 
     if sharding is None:
         return build()
-    outs = {"k": sharding, "v": sharding}
+    outs = {"kv": sharding}
     if cfg.quantized:
         outs["k_scale"] = scale_sharding
         outs["v_scale"] = scale_sharding
     return jax.jit(build, out_shardings=outs)()
 
 
-
-
 # ---------------------------------------------------------------------------
 # device-side pure functions (jit-traceable, cache pytree in -> out)
 # ---------------------------------------------------------------------------
+
+def pack_rows(k, v):
+    """(..., H, D) keys and values -> (..., H * 2 * D) pool rows: every
+    head's ``K_h`` beside its ``V_h``."""
+    return jnp.concatenate([k, v], axis=-1).reshape(
+        *k.shape[:-2], k.shape[-2] * 2 * k.shape[-1])
+
+
+def unpack_rows(rows, num_heads: int):
+    """The inverse of :func:`pack_rows`: (..., H * 2 * D) -> ``(k, v)``
+    each (..., H, D)."""
+    r = rows.reshape(*rows.shape[:-1], num_heads, 2, -1)
+    return r[..., 0, :], r[..., 1, :]
+
 
 def slot_index(block_tables, positions, block_size: int):
     """Flat pool slot of logical ``positions`` — (B,) one per
@@ -269,92 +332,137 @@ def slot_index(block_tables, positions, block_size: int):
     return phys * block_size + off
 
 
-def write_tokens(cache, kvs, slots):
-    """Scatter one new token per sequence into the pool.
+def write_layer(cache, layer, kv, slots):
+    """Write one layer's fresh rows into the (donated) pool, in place.
 
-    kvs: (L, B, 1, H, D) stacked per-layer (k, v) pairs — i.e. a tuple
-    ``(k_new, v_new)`` of that shape; slots: (B,) flat slot indices.
-    Under quantization kvs is ``((k_q, k_scale), (v_q, v_scale))``
-    with the payloads (L, B, 1, H, D) int8 and the scales
-    (L, B, 1, H) fp32 — ALREADY quantized by the model's projection
-    path, so the pool receives byte-for-byte the values attention just
-    used."""
-    k_new, v_new = kvs
+    kv: ``(k, v)`` each (B, S, H, D); slots: (B, S) flat slot indices
+    (padded positions pointed at the garbage block by the caller).
+    Under quantization kv is ``((k_q, k_scale), (v_q, v_scale))`` with
+    int8 payloads and (B, S, H) fp32 scales — ALREADY quantized by the
+    model's projection path, so the pool receives byte-for-byte the
+    values attention uses.
+
+    The form matters on the chip: a scatter of whole rows at
+    ``[layer, slots]`` compiles to an update of the donated buffer with
+    no temporary, where one over every layer at once (``[:, slots]``)
+    makes XLA:TPU transpose the whole leaf there and back."""
+    k, v = kv
+    flat = slots.reshape(-1)
+    out = dict(cache)
     if "k_scale" in cache:
-        (kq, ks), (vq, vs) = k_new, v_new
-        return {"k": cache["k"].at[:, slots].set(kq[:, :, 0]),
-                "v": cache["v"].at[:, slots].set(vq[:, :, 0]),
-                "k_scale": cache["k_scale"].at[:, slots].set(ks[:, :, 0]),
-                "v_scale": cache["v_scale"].at[:, slots].set(vs[:, :, 0])}
-    k_new = k_new[:, :, 0].astype(cache["k"].dtype)   # (L, B, H, D)
-    v_new = v_new[:, :, 0].astype(cache["v"].dtype)
-    return {"k": cache["k"].at[:, slots].set(k_new),
-            "v": cache["v"].at[:, slots].set(v_new)}
+        (k, ks), (v, vs) = k, v
+        out["k_scale"] = cache["k_scale"].at[layer, flat].set(
+            ks.reshape(-1, ks.shape[-1]))
+        out["v_scale"] = cache["v_scale"].at[layer, flat].set(
+            vs.reshape(-1, vs.shape[-1]))
+    rows = pack_rows(k, v).astype(cache["kv"].dtype)
+    out["kv"] = cache["kv"].at[layer, flat].set(
+        rows.reshape(-1, rows.shape[-1]))
+    return out
+
+
+def _per_layer(kvs, layer):
+    """Layer ``layer`` of a stacked (L, ...) fresh-K/V struct, plain or
+    quantized."""
+    return jax.tree.map(lambda x: x[layer], kvs)
+
+
+def write_tokens(cache, kvs, slots):
+    """Write one new token per sequence into every layer of the pool.
+
+    kvs: ``(k_new, v_new)`` each (L, B, 1, H, D) stacked per layer;
+    slots: (B,) flat slot indices.  Under quantization kvs is
+    ``((k_q, k_scale), (v_q, v_scale))`` with the payloads
+    (L, B, 1, H, D) int8 and the scales (L, B, 1, H) fp32."""
+    return write_prefill(cache, kvs, slots[:, None])
 
 
 def write_prefill(cache, kvs, slots):
-    """Scatter a whole prompt's K/V into the pool.
+    """Write a whole prompt's K/V into every layer of the pool.
 
     kvs: tuple of (L, B, S, H, D); slots: (B, S) flat slot indices with
     padded positions pointed at the garbage block by the caller.
     Under quantization kvs is ``((k_q, k_scale), (v_q, v_scale))``
     exactly as in :func:`write_tokens` (payloads (L, B, S, H, D),
-    scales (L, B, S, H))."""
-    k_new, v_new = kvs
+    scales (L, B, S, H)).  One :func:`write_layer` a layer: each is an
+    in-place update."""
+    for layer in range(cache["kv"].shape[0]):
+        cache = write_layer(cache, layer, _per_layer(kvs, layer), slots)
+    return cache
+
+
+def _by_block(arr, block_size: int):
+    """(L, num_slots, ...) -> (L, num_blocks, block_size, ...): a
+    reshape of leading dimensions, free in the pool's layout."""
+    return arr.reshape(arr.shape[0], -1, block_size, *arr.shape[2:])
+
+
+def gather_layer(cache, layer, block_tables, block_size: int,
+                 num_heads: int):
+    """One layer's logical context of each sequence, gathered from the
+    pool block by block: ``(k_ctx, v_ctx)`` of shape (B, T, H, D) with
+    T = max_blocks * block_size, plus ``(k_scale, v_scale)`` (B, T, H)
+    under quantization.  Position j IS logical token j because tables
+    are ordered; unallocated entries read the garbage block and the
+    caller's context bias masks them."""
+    b, mb = block_tables.shape
+    rows = _by_block(cache["kv"], block_size)[layer][block_tables]
+    out = unpack_rows(rows.reshape(b, mb * block_size, -1), num_heads)
     if "k_scale" in cache:
-        (kq, ks), (vq, vs) = k_new, v_new
-        L = kq.shape[0]
-        flat = slots.reshape(-1)                      # (B*S,)
-        out = {"k": cache["k"].at[:, flat].set(
-                   kq.reshape(L, -1, *kq.shape[3:])),
-               "v": cache["v"].at[:, flat].set(
-                   vq.reshape(L, -1, *vq.shape[3:]))}
-        out["k_scale"] = cache["k_scale"].at[:, flat].set(
-            ks.reshape(L, -1, *ks.shape[3:]))
-        out["v_scale"] = cache["v_scale"].at[:, flat].set(
-            vs.reshape(L, -1, *vs.shape[3:]))
-        return out
-    L = k_new.shape[0]
-    flat = slots.reshape(-1)                          # (B*S,)
-    k2 = k_new.reshape(L, -1, *k_new.shape[3:]).astype(cache["k"].dtype)
-    v2 = v_new.reshape(L, -1, *v_new.shape[3:]).astype(cache["v"].dtype)
-    return {"k": cache["k"].at[:, flat].set(k2),
-            "v": cache["v"].at[:, flat].set(v2)}
+        out += tuple(
+            _by_block(cache[n], block_size)[layer][block_tables].reshape(
+                b, mb * block_size, num_heads)
+            for n in ("k_scale", "v_scale"))
+    return out
 
 
-def gather_context(cache, block_tables, block_size: int, out_dtype=None):
-    """Gather each sequence's logical context from the pool.
+def gather_context(cache, block_tables, block_size: int, num_heads: int,
+                   out_dtype=None):
+    """Gather each sequence's logical context from the pool, every
+    layer at once — the oracle's form (the serving programs read one
+    layer at a time, :func:`gather_layer`, or in place).
 
     block_tables: (B, max_blocks) int32 (0 = unallocated -> garbage
     block; masked by the caller's ctx bias).  Returns ``(k_ctx,
     v_ctx)`` of shape (L, B, max_blocks * block_size, H, D): gathered
     position j IS logical token j because tables are ordered."""
     b, mb = block_tables.shape
-    bs = block_size
-    slots = (block_tables[:, :, None] * bs
-             + jnp.arange(bs, dtype=block_tables.dtype)[None, None, :]
-             ).reshape(b, mb * bs)                    # (B, T)
-    k = cache["k"][:, slots]                          # (L, B, T, H, D)
-    v = cache["v"][:, slots]
+    rows = _by_block(cache["kv"], block_size)[:, block_tables]
+    k, v = unpack_rows(rows.reshape(rows.shape[0], b, mb * block_size,
+                                    -1), num_heads)
     if out_dtype is not None:
         k = k.astype(out_dtype)
         v = v.astype(out_dtype)
     return k, v
 
 
-def gather_scales(cache, block_tables, block_size: int):
-    """The scale-sidecar leg of :func:`gather_context`: gather each
-    sequence's per-slot dequantization scales with the SAME slot map
-    the payload gather uses.  Returns ``(k_scale, v_scale)`` of shape
-    (L, B, max_blocks * block_size, H) fp32 — position j is logical
-    token j's scales, garbage slots carry garbage scales that the
-    context bias masks exactly like the payload they scale."""
-    b, mb = block_tables.shape
-    bs = block_size
-    slots = (block_tables[:, :, None] * bs
-             + jnp.arange(bs, dtype=block_tables.dtype)[None, None, :]
-             ).reshape(b, mb * bs)                    # (B, T)
-    return cache["k_scale"][:, slots], cache["v_scale"][:, slots]
+def pool_dtype(cache):
+    """The dtype the pool's K/V payload is stored in."""
+    return cache["kv"].dtype
+
+
+def scale_sidecar(cache):
+    """The quantized pool's ``(k_scale, v_scale)`` leaves, each
+    (L, num_slots, H) fp32."""
+    return cache["k_scale"], cache["v_scale"]
+
+
+def block_slots(block_ids, block_size: int):
+    """Flat slots of physical blocks ``block_ids``' token rows, block
+    after block (host side, numpy)."""
+    ids = np.asarray(block_ids, np.int64).reshape(-1, 1)
+    return (ids * block_size + np.arange(block_size)[None, :]).reshape(-1)
+
+
+def read_slots(cache, slots, num_heads: int):
+    """What the pool holds at flat ``slots`` (n,), by name: ``k`` and
+    ``v`` (L, n, H, D) in the storage dtype, and ``k_scale`` /
+    ``v_scale`` (L, n, H) under quantization.  For tests and
+    postmortems; no serving program calls it."""
+    k, v = unpack_rows(cache["kv"][:, slots], num_heads)
+    out = {"k": k, "v": v}
+    out.update({n: cache[n][:, slots] for n in cache if n != "kv"})
+    return out
 
 
 def context_bias(lengths, max_context: int):
@@ -366,15 +474,44 @@ def context_bias(lengths, max_context: int):
                      0.0, NEG_INF).astype(jnp.float32)
 
 
+def read_blocks(cache, block_ids, block_size: int):
+    """Every leaf's rows of physical blocks ``block_ids`` (n,), in
+    order: ``{name: (L, n * block_size, ...)}`` — the export half of
+    the hand-off and offload payloads.  Whole blocks are sliced, so
+    nothing of the pool's size is touched."""
+    return {name: jnp.concatenate(
+        [lax.dynamic_slice_in_dim(arr, block_ids[i] * block_size,
+                                  block_size, 1)
+         for i in range(block_ids.shape[0])], axis=1)
+        for name, arr in cache.items()}
+
+
+def write_blocks(cache, block_ids, leaves, block_size: int):
+    """Write ``leaves`` (``{name: (L, n * block_size, ...)}``, as
+    :func:`read_blocks` gives them) into physical blocks ``block_ids``
+    (n,) of the (donated) pool, one in-place block update each.
+    Padding ids point at the garbage block."""
+    def put(i, arr, rows):
+        return lax.dynamic_update_slice_in_dim(
+            arr, lax.dynamic_slice_in_dim(rows, i * block_size,
+                                          block_size, 1),
+            block_ids[i] * block_size, 1)
+
+    return {name: lax.fori_loop(
+        0, block_ids.shape[0],
+        functools.partial(put, rows=leaves[name].astype(arr.dtype)), arr)
+        for name, arr in cache.items()}
+
+
 def copy_blocks_across(dst_cache, src_cache, src, dst, block_size: int):
     """Whole-block copy ``src[i] (in src_cache) -> dst[i] (in
     dst_cache)`` BETWEEN two pools of identical geometry — the device
     half of the disaggregated prefill/decode hand-off
     (``docs/serving.md``, "Disaggregated prefill/decode"): a finished
     prefill's blocks move from the prefill pool into the decode pool
-    as one fixed-shape gather+scatter, so the two pools' programs
-    share no array and their compute never serializes through a common
-    pool version.
+    as fixed-shape block reads and in-place block updates, so the two
+    pools' programs share no array and their compute never serializes
+    through a common pool version.
 
     src, dst: (M,) int32 physical block ids, (0, 0)-padded exactly
     like :func:`copy_blocks` (garbage block -> garbage block is a
@@ -382,18 +519,17 @@ def copy_blocks_across(dst_cache, src_cache, src, dst, block_size: int):
     under quantization the scale sidecar rows move with their int8
     payload, so a handed-off block dequantizes bit-identically on the
     decode side."""
-    off = jnp.arange(block_size, dtype=src.dtype)[None, :]
-    s = (src[:, None] * block_size + off).reshape(-1)
-    d = (dst[:, None] * block_size + off).reshape(-1)
-    return {name: arr.at[:, d].set(src_cache[name][:, s])
-            for name, arr in dst_cache.items()}
+    return write_blocks(dst_cache, dst,
+                        read_blocks(src_cache, src, block_size),
+                        block_size)
 
 
 def copy_blocks(cache, src, dst, block_size: int):
     """Whole-block copy ``src[i] -> dst[i]`` inside the pool — the
     device half of copy-on-write duplication (a request that must
     write into a block shared through the prefix cache first clones it
-    into a private block).
+    into a private block).  Every source is read before any
+    destination is written.
 
     src, dst: (M,) int32 physical block ids.  Unused pairs pad with
     (0, 0): copying the garbage block onto itself is a no-op by
@@ -402,11 +538,109 @@ def copy_blocks(cache, src, dst, block_size: int):
     Copies EVERY cache leaf — under quantization the scale sidecar
     legs duplicate with their payload in the same program, so a COW
     clone dequantizes bit-identically to its source block."""
-    off = jnp.arange(block_size, dtype=src.dtype)[None, :]
-    s = (src[:, None] * block_size + off).reshape(-1)
-    d = (dst[:, None] * block_size + off).reshape(-1)
-    return {name: arr.at[:, d].set(arr[:, s])
-            for name, arr in cache.items()}
+    return copy_blocks_across(cache, cache, src, dst, block_size)
+
+
+# ---------------------------------------------------------------------------
+# what the model sees of the pool
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("block_size",))
+def _attend_in_place(pool, layer, q, kv, tables, start, slots, *,
+                     block_size):
+    """One layer of the table path: write the fed rows, then attend
+    the pool in place.  A jitted function with ``layer`` an array, so
+    that a program of many layers traces and lowers it once and calls
+    it for each.  (Traced layer by layer with a Python ``layer``, the
+    wrapper's index arrays were made on the device while tracing and
+    read back while lowering: 30 s more set-up at GPT-2 XL's 48 layers;
+    my chip runs, PR 25.)"""
+    from apex_tpu.ops.decode_attention import paged_attention
+
+    pool = write_layer({"kv": pool}, layer, kv, slots)["kv"]
+    return paged_attention(q, pool, layer, tables, start,
+                           block_size=block_size), pool
+
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=("cache", "tables", "start", "slots"),
+    meta_fields=("block_size", "num_heads", "table"))
+@dataclasses.dataclass(frozen=True)
+class CacheView:
+    """The pool as one serving launch sees it, threaded through the
+    model's layers: each layer's attention calls :meth:`attend` with its
+    queries and fresh K/V and gets back its context and the view with
+    the pool updated.  The model never learns the pool's layout.
+
+    ``tables`` (B, blocks_per_seq) and ``start`` (B,) — each
+    sequence's cached length, the position of its first fed row —
+    address the reads; ``slots`` (B, S) are the flat slots the S fed
+    rows are written to (invalid ones pointed at the garbage block).
+    ``table`` picks the attention path, fixed when the engine builds
+    its programs (``DecodeEngine.attention_paths``):
+
+    - ``True``, *attend through the table*: the layer's rows are
+      written first, then :func:`ops.decode_attention.paged_attention`
+      reads the pool in place, only the pages up to each sequence's
+      last row.  No gathered copy, no bias row, no concatenation.
+    - ``False``, *gathered*: the layer's context is gathered block by
+      block (:func:`gather_layer`), the fresh K/V concatenated behind
+      it, and the jnp-or-kernel ops of the gathered form
+      (``ops.cached_attention`` for one row,
+      ``ops.chunk_cached_attention`` for more) attend it; then the rows
+      are written.  The int8 pool, a mesh and the CPU take this
+      path."""
+
+    cache: dict
+    tables: jax.Array
+    start: jax.Array
+    slots: jax.Array
+    block_size: int
+    num_heads: int
+    table: bool
+
+    def attend(self, layer: int, q, kv):
+        """``q`` (B, S, H, D) and the layer's fresh ``kv`` — ``(k, v)``
+        or, quantized, ``((k_q, k_scale), (v_q, v_scale))`` — to
+        ``(context (B, S, H, D), the view after the write)``."""
+        from apex_tpu.ops.decode_attention import (
+            cached_attention,
+            chunk_cached_attention,
+        )
+
+        if self.table:
+            ctx, pool = _attend_in_place(
+                self.cache["kv"], np.int32(layer), q, kv, self.tables,
+                self.start, self.slots, block_size=self.block_size)
+            return ctx, dataclasses.replace(self, cache={"kv": pool})
+        ctx_kv = gather_layer(self.cache, layer, self.tables,
+                              self.block_size, self.num_heads)
+        k, v = kv
+        ks = vs = None
+        if "k_scale" in self.cache:
+            # int8 end to end: quantized context + the fed rows' own
+            # quantized K/V concatenate with their scale rows; the
+            # attention ops widen at read
+            (k, ks), (v, vs) = k, v
+            ks = jnp.concatenate([ctx_kv[2], ks], axis=1)
+            vs = jnp.concatenate([ctx_kv[3], vs], axis=1)
+        k_full = jnp.concatenate([ctx_kv[0].astype(k.dtype), k], axis=1)
+        v_full = jnp.concatenate([ctx_kv[1].astype(v.dtype), v], axis=1)
+        bias = context_bias(self.start, ctx_kv[0].shape[1])
+        if q.shape[1] == 1:
+            # decode: the self slot is always live (bias 0)
+            bias = jnp.concatenate(
+                [bias, jnp.zeros((q.shape[0], 1), jnp.float32)], axis=1)
+            ctx = cached_attention(q, k_full, v_full, kv_bias=bias,
+                                   k_scale=ks, v_scale=vs)
+        else:
+            # context masked to slots < start, causal within the rows
+            ctx = chunk_cached_attention(q, k_full, v_full, bias,
+                                         k_scale=ks, v_scale=vs)
+        return ctx, dataclasses.replace(
+            self, cache=write_layer(self.cache, layer, kv, self.slots))
 
 
 # ---------------------------------------------------------------------------

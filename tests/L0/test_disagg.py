@@ -24,6 +24,7 @@ import pytest
 
 from apex_tpu import models
 from apex_tpu.serving import InferenceServer, RouterFleet
+from apex_tpu.serving.kv_cache import block_slots, read_slots
 
 pytestmark = pytest.mark.serving
 
@@ -278,12 +279,14 @@ def test_export_import_blocks_roundtrip_and_torn_detection(tiny):
     payload = eng.export_blocks(blocks)
     dst = eng.allocator.alloc(3)
     eng.import_blocks(dst, payload)
-    s_src = eng._block_slots(blocks, 3)
-    s_dst = eng._block_slots(dst, 3)
-    for name in eng.cache:
-        a = np.asarray(eng.cache[name][:, s_src])
-        b = np.asarray(eng.cache[name][:, s_dst])
-        assert (a == b).all(), name
+    heads = cfg.num_attention_heads
+    src_rows = read_slots(eng.cache, block_slots(blocks, eng.block_size),
+                          heads)
+    dst_rows = read_slots(eng.cache, block_slots(dst, eng.block_size),
+                          heads)
+    for name in src_rows:
+        assert (np.asarray(src_rows[name])
+                == np.asarray(dst_rows[name])).all(), name
     torn = {**payload,
             "leaves": {k: v.copy() for k, v in
                        payload["leaves"].items()}}

@@ -19,6 +19,7 @@ from apex_tpu.serving.kv_cache import (
     context_bias,
     gather_context,
     init_kv_cache,
+    pool_dtype,
     resolve_cache_dtype,
     slot_index,
     write_prefill,
@@ -101,7 +102,7 @@ def test_config_validation_and_sizing():
 def test_cache_dtype_defaults_to_bf16_and_explicit_wins():
     assert resolve_cache_dtype(None) == jnp.bfloat16
     assert resolve_cache_dtype(jnp.float32) == jnp.float32
-    assert init_kv_cache(_cfg(dtype=None))["k"].dtype == jnp.bfloat16
+    assert pool_dtype(init_kv_cache(_cfg(dtype=None))) == jnp.bfloat16
 
 
 def test_cache_dtype_follows_amp_policy():
@@ -147,7 +148,8 @@ def test_write_prefill_then_gather_roundtrip():
     slots = slot_index(tables, jnp.arange(n, dtype=jnp.int32)[None, :],
                        cfg.block_size)
     cache = write_prefill(cache, (k, v), slots)
-    k_ctx, v_ctx = gather_context(cache, tables, cfg.block_size)
+    k_ctx, v_ctx = gather_context(cache, tables, cfg.block_size,
+                                  cfg.num_heads)
     np.testing.assert_allclose(np.asarray(k_ctx[:, :, :n]),
                                np.asarray(k))
     np.testing.assert_allclose(np.asarray(v_ctx[:, :, :n]),
@@ -177,7 +179,8 @@ def test_block_reuse_no_cross_talk():
                          jnp.arange(5, dtype=jnp.int32)[None, :],
                          cfg.block_size)
     cache = write_prefill(cache, (kb, vb), slots_b)
-    k_ctx, _ = gather_context(cache, tables_b, cfg.block_size)
+    k_ctx, _ = gather_context(cache, tables_b, cfg.block_size,
+                              cfg.num_heads)
     np.testing.assert_allclose(np.asarray(k_ctx[:, :, :5]),
                                np.asarray(kb))
     bias = context_bias(jnp.array([5]), 8)
@@ -195,7 +198,8 @@ def test_write_tokens_single_step_and_garbage_block_sink():
     slots = slot_index(tables, jnp.array([2, 0], jnp.int32),
                        cfg.block_size)
     cache = write_tokens(cache, (k, v), slots)
-    k_ctx, _ = gather_context(cache, tables, cfg.block_size)
+    k_ctx, _ = gather_context(cache, tables, cfg.block_size,
+                                  cfg.num_heads)
     np.testing.assert_allclose(np.asarray(k_ctx[:, 0, 2]),
                                np.asarray(k[:, 0, 0]))
     np.testing.assert_allclose(np.asarray(k_ctx[:, 1, 0]),
@@ -207,7 +211,8 @@ def test_write_tokens_single_step_and_garbage_block_sink():
     cache = write_tokens(cache, (kd, vd),
                          slot_index(dead, jnp.array([0], jnp.int32),
                                     cfg.block_size))
-    k_ctx2, _ = gather_context(cache, tables, cfg.block_size)
+    k_ctx2, _ = gather_context(cache, tables, cfg.block_size,
+                                  cfg.num_heads)
     np.testing.assert_allclose(np.asarray(k_ctx2[:, 0, 2]),
                                np.asarray(k[:, 0, 0]))  # untouched
 
@@ -218,9 +223,10 @@ def test_write_casts_to_cache_dtype_and_gather_casts_out():
     k, v = _fill(cfg, 5, 1, 1)                    # fp32 in
     cache = write_tokens(cache, (k, v),
                          jnp.array([4], jnp.int32))
-    assert cache["k"].dtype == jnp.bfloat16
+    assert pool_dtype(cache) == jnp.bfloat16
     k_ctx, _ = gather_context(cache, jnp.asarray([[1]], jnp.int32),
-                              cfg.block_size, out_dtype=jnp.float32)
+                              cfg.block_size, cfg.num_heads,
+                              out_dtype=jnp.float32)
     assert k_ctx.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(k_ctx[:, 0, 0]),
                                np.asarray(k[:, 0, 0]),

@@ -39,7 +39,7 @@ from apex_tpu.ops.decode_attention import cached_attention, \
 from apex_tpu.ops.kv_quant import INT8_QMAX, dequantize_kv, quantize_kv
 from apex_tpu.serving import InferenceServer, KVCacheConfig
 from apex_tpu.serving.kv_cache import resolve_cache_dtype, \
-    resolve_kv_quant
+    resolve_kv_quant, scale_sidecar
 
 pytestmark = pytest.mark.serving
 
@@ -436,7 +436,7 @@ def test_quant_tp_parity(tiny, tp):
     assert mi["pool_bytes_per_device"] * tp == mi["pool_bytes"]
     # the sidecar is genuinely head-sharded: each device holds H/tp
     # heads' scale rows
-    ksc = srv.engine.cache["k_scale"]
+    ksc, _ = scale_sidecar(srv.engine.cache)
     shard = ksc.sharding.shard_shape(ksc.shape)
     assert shard[-1] == cfg.num_attention_heads // tp
 

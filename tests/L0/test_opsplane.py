@@ -507,6 +507,7 @@ def test_program_accounting_opt_out(tiny):
     server.generate([[1, 2, 3]], max_new_tokens=3)
     st = server.stats()["programs"]
     assert st == {"enabled": False, "by_program": {},
+                  "attention": server.engine.attention_paths,
                   "total_wall_ms": 0.0, "total_compile_ms": 0.0}
     assert not any("serving_program" in k
                    for k in server.registry.snapshot())
@@ -521,8 +522,12 @@ def test_stats_programs_watchdog_ops_blocks_pinned(tiny):
     server.generate([[1, 2, 3]], max_new_tokens=4)
     st = server.stats()
     prog = st["programs"]
-    assert set(prog) == {"enabled", "by_program", "total_wall_ms",
-                         "total_compile_ms"}
+    assert set(prog) == {"enabled", "by_program", "attention",
+                         "total_wall_ms", "total_compile_ms"}
+    # the CPU backend gathers; the chip reads through the table
+    assert prog["attention"] == {"decode": "gathered",
+                                 "verify": "gathered",
+                                 "chunk_prefill": "gathered"}
     assert prog["enabled"] is True
     for key, row in prog["by_program"].items():
         assert set(row) == {"calls", "compiles", "wall_ms",

@@ -22,6 +22,7 @@ import pytest
 from apex_tpu import models
 from apex_tpu.serving import InferenceServer
 from apex_tpu.serving.engine import default_prefill_buckets, pick_bucket
+from apex_tpu.serving.kv_cache import pool_dtype
 
 pytestmark = pytest.mark.serving
 
@@ -153,7 +154,7 @@ def test_default_cache_dtype_is_half_and_still_generates(tiny):
     cfg, params, _ = tiny
     server = InferenceServer(cfg, params, max_batch_size=2,
                              max_context=64, block_size=8)
-    assert server.engine.cache["k"].dtype == jnp.bfloat16
+    assert pool_dtype(server.engine.cache) == jnp.bfloat16
     out = server.generate([[1, 2, 3]], max_new_tokens=8)[0]
     assert len(out) == 8
     assert all(0 <= t < VOCAB for t in out)

@@ -1,0 +1,234 @@
+"""The engine's serving programs compiled at GPT-2 XL's size for a
+described v5e by the real Mosaic and XLA:TPU compilers, with no chip:
+what the pool's layout and "attend through the table" promise is read
+off the compiled text (``docs/serving.md``, "The pool's layout").
+
+- nothing but the donated pool and its in-place updates is as large as
+  the pool: no relayout copy, no transpose;
+- decode and verify build nothing of ``slots x max_context`` K/V size;
+- decode holds the ``_decode_kernel`` Mosaic call, verify the
+  ``_verify_kernel`` one, chunk prefill the ``_chunk_kernel`` one;
+- the decode program's temporaries stay under 2 GiB at 8 slots, and it
+  compiles within the chip's 16 GiB at 32.
+
+All in this one file, inside fixtures, as the ``on-chip-measurement``
+guide prescribes: only the worker that runs this file loads libtpu.
+The CPU backend's decode program is held to the same reading of its
+text at a small size."""
+
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from apex_tpu import models
+from apex_tpu.serving.engine import DecodeEngine
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+pytestmark = pytest.mark.serving
+
+GIB = 2 ** 30
+XL = dict(vocab_size=50257, hidden_size=1600, num_hidden_layers=48,
+          num_attention_heads=25, intermediate_size=6400,
+          max_position_embeddings=1024, hidden_dropout_prob=0.0,
+          attention_probs_dropout_prob=0.0)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_on_tpu(monkeypatch):
+    """The kernels' gates and the engine's choice of attention path ask
+    ``on_tpu()``; answer as the chip would."""
+    from apex_tpu.ops import pallas_utils
+    import apex_tpu.normalization.fused_layer_norm  # noqa: F401
+    import apex_tpu.ops.decode_attention  # noqa: F401
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    for mod in ("apex_tpu.ops.decode_attention",
+                "apex_tpu.normalization.fused_layer_norm",
+                "apex_tpu.serving.engine"):
+        monkeypatch.setattr(sys.modules[mod], "on_tpu", lambda: True)
+    # a compile for a described chip cannot be read back from the cache
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \(?(\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def instructions(text):
+    """``(name, element count, opcode)`` of every instruction of a
+    compiled module's text whose result is one array."""
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            dims = [int(d) for d in m.group(3).split(",") if d]
+            yield m.group(1), int(np.prod(dims)), m.group(4)
+
+
+# what may be as large as the pool: the donated parameter, a view of
+# it, and an update of it in place (alone or as a fusion's root)
+IN_PLACE = {"parameter", "bitcast", "get-tuple-element", "scatter",
+            "dynamic-update-slice", "fusion", "while", "conditional",
+            "call"}
+
+
+def pool_sized_strays(text, pool_elements):
+    return [(name, op) for name, n, op in instructions(text)
+            if n == pool_elements and op not in IN_PLACE]
+
+
+def context_sized(text, engine):
+    """Instructions holding every slot's ``max_context`` keys or
+    values (or both, packed), one layer's or all layers'."""
+    cfg = engine.cache_cfg
+    one = (engine.max_batch_size * engine.blocks_per_seq
+           * engine.block_size * cfg.num_heads * cfg.head_dim)
+    sizes = {one * f * l for f in (1, 2) for l in (1, cfg.num_layers)}
+    return [(name, op) for name, n, op in instructions(text)
+            if n in sizes]
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding), tree)
+
+
+def _xl_engine(slots, sharding, monkeypatch):
+    """A GPT-2 XL engine that holds shapes only: the parameters and the
+    pool are described, never allocated."""
+    cfg = models.GPTConfig(**XL)
+    model = models.GPTLMHeadModel(cfg)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=sharding), params)
+    import apex_tpu.serving.engine as engine_mod
+    real = engine_mod.init_kv_cache
+    monkeypatch.setattr(
+        engine_mod, "init_kv_cache",
+        lambda cfg, **kw: jax.eval_shape(lambda: real(cfg)))
+    engine = DecodeEngine(cfg, params, max_batch_size=slots,
+                          max_context=1024)
+    engine.cache = _shapes(engine.cache, sharding)
+    return engine
+
+
+def _ints(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+def _compile(jit_fn, engine, *args):
+    """The logits twins: they donate the pool on every backend (the
+    sampled twins do not where the process runs on the CPU)."""
+    return jit_fn.lower(engine.params, engine.cache, *args).compile()
+
+
+def test_xl_decode_in_place_at_8_slots(one_chip, as_on_tpu, monkeypatch):
+    e = _xl_engine(8, one_chip, monkeypatch)
+    assert e.attention_paths["decode"] == "table"
+    b, nb = 8, e.blocks_per_seq
+    exe = _compile(e._decode_jit, e, _ints(one_chip, b),
+                   _ints(one_chip, b), _ints(one_chip, b, nb))
+    text = exe.as_text()
+    pool = int(np.prod(e.cache["kv"].shape))
+    assert not pool_sized_strays(text, pool)
+    assert not context_sized(text, e)
+    assert "_decode_kernel" in text
+    assert exe.memory_analysis().temp_size_in_bytes < 2 * GIB
+
+
+def test_xl_verify_in_place_at_8_slots(one_chip, as_on_tpu, monkeypatch):
+    e = _xl_engine(8, one_chip, monkeypatch)
+    assert e.attention_paths["verify"] == "table"
+    b, nb = 8, e.blocks_per_seq
+    exe = _compile(e._verify_jit, e, _ints(one_chip, b, 5),
+                   _ints(one_chip, b), _ints(one_chip, b),
+                   _ints(one_chip, b, nb))
+    text = exe.as_text()
+    assert not pool_sized_strays(text, int(np.prod(e.cache["kv"].shape)))
+    assert not context_sized(text, e)
+    assert "_verify_kernel" in text and "_decode_kernel" not in text
+    assert exe.memory_analysis().temp_size_in_bytes < 2 * GIB
+
+
+def test_xl_chunk_in_place_at_8_slots(one_chip, as_on_tpu, monkeypatch):
+    e = _xl_engine(8, one_chip, monkeypatch)
+    assert e.attention_paths["chunk_prefill"] == "table"
+    nb = e.blocks_per_seq
+    exe = _compile(e._chunk_jit, e, _ints(one_chip, 1, 256),
+                   _ints(one_chip, 1), _ints(one_chip, 1),
+                   _ints(one_chip, 1, nb))
+    text = exe.as_text()
+    assert not pool_sized_strays(text, int(np.prod(e.cache["kv"].shape)))
+    assert not context_sized(text, e)
+    assert "_chunk_kernel" in text
+    assert exe.memory_analysis().temp_size_in_bytes < 2 * GIB
+
+
+def test_xl_decode_fits_the_chip_at_32_slots(one_chip, as_on_tpu, monkeypatch):
+    e = _xl_engine(32, one_chip, monkeypatch)
+    b, nb = 32, e.blocks_per_seq
+    exe = _compile(e._decode_jit, e, _ints(one_chip, b),
+                   _ints(one_chip, b), _ints(one_chip, b, nb))
+    m = exe.memory_analysis()
+    assert not pool_sized_strays(exe.as_text(),
+                                 int(np.prod(e.cache["kv"].shape)))
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert total < 16 * GIB
+    assert m.temp_size_in_bytes < 2 * GIB
+
+
+def test_cpu_decode_writes_in_place_at_a_small_size():
+    """The gathered path the CPU runs: nothing pool-sized but the pool
+    and its updates, and no all-slot context either (each layer gathers
+    its own)."""
+    cfg = models.GPTConfig(vocab_size=97, hidden_size=64,
+                           num_hidden_layers=3, num_attention_heads=2,
+                           intermediate_size=128,
+                           max_position_embeddings=128,
+                           hidden_dropout_prob=0.0,
+                           attention_probs_dropout_prob=0.0)
+    params = models.GPTLMHeadModel(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    e = DecodeEngine(cfg, params, max_batch_size=4, max_context=128,
+                     num_blocks=64, cache_dtype=jnp.float32)
+    assert e.attention_paths == {"decode": "gathered",
+                                 "verify": "gathered",
+                                 "chunk_prefill": "gathered"}
+    text = e.decode_hlo()
+    pool = int(np.prod(e.cache["kv"].shape))
+    assert not [s for s in pool_sized_strays(text, pool)
+                if s[1] != "copy"], "a transpose of the pool"
+    # all layers' contexts at once, as the old program gathered them
+    every_layer = (cfg.num_hidden_layers * 4 * 128 * 2 * 32)
+    assert not [n for n, c, _ in instructions(text)
+                if c in (every_layer, 2 * every_layer)]
+    assert e.memory_info()["decode_temp_bytes"] is not None
