@@ -5,8 +5,15 @@ its finished greedy requests is judged by one full forward pass of
 these functions over the prompt and the served tokens: how far the
 served token's logit lies below the reference's best.
 ``harness/compare.py`` holds the comparison and the configuration's
-``limits`` the limit."""
+``limits`` the limit.  The counts of that work (operations, bytes,
+parameters) that the per-layer readers divide by come from the same
+place, ``reference/gpt2_counts.py``."""
 
 from benchmarks.reference.gpt2 import (  # noqa: F401
-    logit_at, logits, mass_above, next_token_loss, param_table, stacked,
-    token_gaps, train_steps, unstacked_leaf_norms)
+    logit_at, logits, longest_row, mass_above, next_token_loss, param_table,
+    stacked, token_gaps, train_steps, unstacked_leaf_norms, vocab,
+    weight_std)
+from benchmarks.reference.gpt2_counts import (  # noqa: F401
+    adam_bytes, attention_flops_causal, decode_attention_bytes,
+    flash_train_flops_bytes, forward_flops_at, matmul_params, total_params,
+    train_flops_per_sequence)

@@ -1,7 +1,8 @@
 """What decides ``correct``: the timed path against the plain reference.
 
 Every number compared is printed beside its limit.  The limits live in
-the configuration's file (``limits``), each set from two readings taken
+the configuration's file (``limits``, by the runner's kind or by a
+traffic mix's name: ``limits_for``), each set from two readings taken
 on the chip: the largest that sound runs of the program gave, and the
 smallest that the control (the reference in the next lower precision)
 or a planted fault gave.  ``PERF.md`` records both for each.
@@ -10,6 +11,16 @@ or a planted fault gave.  ``PERF.md`` records both for each.
 import math
 
 import numpy as np
+
+
+def limits_for(cell, kind):
+    """The limits a cell's numbers are held to: those its configuration
+    states for the cell's traffic mix by its name, where the readings at
+    that mix's sizes called for limits of its own, else those of the
+    runner's ``kind`` (``"train"``, ``"serve"``)."""
+    limits = cell.config["limits"]
+    return limits.get(cell.traffic_name, limits[kind])
+
 
 # stands for "no number at all" in a line that may hold no NaN
 NO_NUMBER = 1e30

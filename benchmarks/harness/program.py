@@ -1,8 +1,10 @@
 """The only place the benchmark touches the system under test: its
 entries (``chip_smoke.build_trainer``, ``InferenceServer``), its
-configuration class, its compile-cache rule and the layout of its
-optimizer state.  Everything it hands back is the program's own object;
-the yardstick (traffic, reference, reduction, comparison) is elsewhere.
+compile-cache rule and the layout of its optimizer state.  How its
+configuration object is made from a configuration's keys is the
+configuration's own ``"program"`` file (``Cell.model_config``).
+Everything it hands back is the program's own object; the yardstick
+(traffic, reference, reduction, comparison) is elsewhere.
 """
 
 import sys
@@ -19,20 +21,6 @@ def import_program():
     from apex_tpu import models, serving
     from apex_tpu.utils.compile_cache import enable_compile_cache
     return chip_smoke, models, serving, enable_compile_cache
-
-
-def gpt_config(models, sizes):
-    """The program's ``GPTConfig`` at a GPT-2 ``config.json``'s sizes."""
-    return models.GPTConfig(
-        vocab_size=sizes["vocab_size"], hidden_size=sizes["n_embd"],
-        num_hidden_layers=sizes["n_layer"],
-        num_attention_heads=sizes["n_head"],
-        intermediate_size=sizes["n_inner"] or 4 * sizes["n_embd"],
-        max_position_embeddings=sizes["n_positions"],
-        hidden_dropout_prob=sizes["resid_pdrop"],
-        attention_probs_dropout_prob=sizes["attn_pdrop"],
-        layer_norm_eps=sizes["layer_norm_epsilon"],
-        initializer_range=sizes["initializer_range"])
 
 
 def devices_for(cell, require_chip=True):

@@ -4,14 +4,16 @@ a new metric of a new kind brings its own arithmetic in its own file.
 
 ``ctx`` is what a traced run hands every reader: the reduced trace
 (``ctx["trace"]``), the benchmark's spans and the server's counters of
-the window, the cell's configuration and traffic.  A reader that finds
-nothing to read returns None and the metric stays out of the line.
+the window, the cell's configuration and traffic, and its reference
+(``ctx["ref"]``), which counts the operations and bytes of the work:
+the readers know no model's sizes.  A reader that finds nothing to read
+returns None and the metric stays out of the line.
 """
 
 import numpy as np
 
-from benchmarks.harness import flops
 from benchmarks.harness.peaks import peaks_for
+
 
 def read_all(cell, ctx, values, device):
     """What a traced run adds: every per-layer metric of the cell whose
@@ -51,8 +53,8 @@ def train_step_mfu(ctx):
     n, lo, hi = _whole_steps(ctx)
     if not n:
         return None
-    per_token = flops.train_flops_per_sequence(ctx["sizes"], ctx["seq"]) \
-        / ctx["seq"]
+    per_token = ctx["ref"].train_flops_per_sequence(
+        ctx["sizes"], ctx["seq"]) / ctx["seq"]
     rate = n * ctx["tokens_per_step"] / (hi - lo)
     peak = peaks_for(ctx["device_kind"])["flops_bf16"] * ctx["chips"]
     return 100.0 * rate * per_token / peak
@@ -74,13 +76,14 @@ def kernel_roofline(ctx, kernels, flops_bytes):
 
 
 def flash_roofline_train(ctx):
-    return kernel_roofline(ctx, FLASH_KERNELS, flops.flash_train_flops_bytes(
-        ctx["sizes"], ctx["batch_per_chip"], ctx["seq"]))
+    return kernel_roofline(
+        ctx, FLASH_KERNELS, ctx["ref"].flash_train_flops_bytes(
+            ctx["sizes"], ctx["batch_per_chip"], ctx["seq"]))
 
 
 def adam_roofline_train(ctx):
     return kernel_roofline(ctx, ("_adam_kernel",),
-                           (0, flops.adam_bytes(ctx["sizes"])))
+                           (0, ctx["ref"].adam_bytes(ctx["sizes"])))
 
 
 def collective_exposed_ms(ctx):
@@ -139,7 +142,7 @@ def serve_step_mfu(ctx):
     sub = ctx["run"]["sub"]
     before, after = sub["open"]["cached"], sub["close"]["cached"]
     before = before + [0] * (len(after) - len(before))
-    ops = sum(flops.forward_flops_at(ctx["sizes"], a, b)
+    ops = sum(ctx["ref"].forward_flops_at(ctx["sizes"], a, b)
               for a, b in zip(before, after))
     if ops <= 0:
         return None
@@ -155,6 +158,6 @@ def decode_attn_roofline(ctx):
     live = sum(s[4] for s in _sub_steps(ctx) if s[3] == "decode")
     if not n or not live:
         return None
-    least = flops.decode_attention_bytes(ctx["sizes"], live) \
+    least = ctx["ref"].decode_attention_bytes(ctx["sizes"], live) \
         / peaks_for(ctx["device_kind"])["hbm_bytes_per_s"]
     return 100.0 * least / took

@@ -162,6 +162,10 @@ def drive(server, serving, plan, mix, seconds, traced):
                 "accepted": spec.count("accepted_tokens"),
                 "families": _families(server), "at": clock(),
                 "waiting": sched.num_waiting,
+                "prefix_hit_tokens": server.prefix.count(
+                    "prefix_hit_tokens"),
+                "prefix_miss_tokens": server.prefix.count(
+                    "prefix_miss_tokens"),
                 "cached": [s.req.num_cached for s in sent]}
 
     while True:
@@ -283,13 +287,13 @@ def end_to_end(run, seconds, latencies_until=None):
     return out
 
 
-def build_server(models, serving, sizes, mix, params):
+def build_server(cell, models, serving, params):
     """The server as users run it: the slots and context the mix names,
     every other argument at its default (prefix cache, chunked prefill,
     speculation, the pipelined loop, overload control, streaming)."""
-    srv = mix["server"]
+    srv = cell.traffic["server"]
     return serving.InferenceServer(
-        program.gpt_config(models, sizes), params,
+        cell.model_config(models), params,
         max_batch_size=srv["max_batch_size"],
         max_context=srv["max_context"])
 
@@ -309,11 +313,12 @@ class Session:
         marks = [("start", t_start), ("imports", clock())]
 
         params = self.fresh_params()
-        server = build_server(models, serving, sizes, mix, params)
+        server = build_server(cell, models, serving, params)
         marks.append(("server", clock()))
         n = traffic.planned_count(mix, seconds, DRAIN_LIMIT_S)
-        plan = traffic.plan_requests(mix, seed, n, sizes["vocab_size"])
-        warm_up(server, serving, mix, sizes["vocab_size"], seed)
+        vocab = self.ref.vocab(sizes)
+        plan = traffic.plan_requests(mix, seed, n, vocab)
+        warm_up(server, serving, mix, vocab, seed)
         marks.append(("warm_up", clock()))
         warm = _families(server)
         _log(f"serve: warm programs {warm} planned {n} requests")
@@ -332,15 +337,20 @@ class Session:
                     for k, v in m["close"]["families"].items()}
         compiled = {k: v for k, v in compiled.items() if v}
         st = server.stats()
+
+        def grew(counter):
+            return m["close"][counter] - m["open"][counter]
+
         _log(f"serve: window {seconds}s attempted {e2e['attempted']} "
              f"failed {e2e['failed']} drain {run_['drain_s']:.2f}s setup "
              f"{self.setup_s:.2f}s = " + ", ".join(
                  f"{y[0]} {y[1] - x[1]:.2f}"
                  for x, y in zip(marks, marks[1:])) + f"; {e2e}")
         _log(f"serve: compiled after the warm-up: {compiled or 'nothing'}; "
-             f"speculation drafted "
-             f"{m['close']['drafted'] - m['open']['drafted']} accepted "
-             f"{m['close']['accepted'] - m['open']['accepted']}; "
+             f"speculation drafted {grew('drafted')} accepted "
+             f"{grew('accepted')}; prompt tokens found in the prefix "
+             f"cache {grew('prefix_hit_tokens')} and not "
+             f"{grew('prefix_miss_tokens')}; "
              f"preemptions {st['preemptions']} failed "
              f"{st['requests_failed']} oom {st['oom_events']}; waiting at "
              f"open {m['open']['waiting']} at close "
@@ -371,7 +381,8 @@ class Session:
             (list(s.planned.prompt), list(s.req.generated))
             for s in sample if s.planned.greedy is g] for g in (True, False))
         self.ctx = {
-            "cell": cell, "sizes": sizes, "mix": mix, "chips": cell.chips,
+            "cell": cell, "sizes": sizes, "mix": mix, "ref": self.ref,
+            "chips": cell.chips,
             "device_kind": devices[0].device_kind, "run": run_, "e2e": e2e,
             "num_blocks": server.engine.cache_cfg.num_blocks,
             "queue_waits": [s.req.admitted_at - s.req.submitted_at
@@ -389,7 +400,7 @@ class Session:
         import jax.numpy as jnp
         return weights.make_params(
             self.table, self.seed, jnp.bfloat16,
-            self.sizes["initializer_range"],
+            self.ref.weight_std(self.sizes),
             jax.sharding.SingleDeviceSharding(self.devices[0]))
 
     def reference_gaps(self, precision="float32", tokens_of=None):
@@ -430,7 +441,7 @@ def run(cell, seed, seconds, traced, t_start, require_chip=True):
          f"{len(gaps)} served tokens, and {len(s.sampled_rows)} sampled "
          f"ones, {len(mass)} tokens, took {clock() - t0:.2f}s")
     compared, notes = compare.compare_served(
-        gaps, s.sizes["limits"]["serve"], mass,
+        gaps, compare.limits_for(cell, "serve"), mass,
         (s.mix.get("sampling") or {}).get("top_p"))
 
     breakdown = None
@@ -493,7 +504,8 @@ def served_gaps(ref, params, rows, sizes, rows_per_block,
     gaps_fn = jax.jit(lambda p, ids: ref.token_gaps(p, ids, None, sizes,
                                                     precision))
     out = []
-    for block, ids in _id_blocks(rows, rows_per_block, sizes["n_positions"]):
+    for block, ids in _id_blocks(rows, rows_per_block,
+                                 ref.longest_row(sizes)):
         _, gap, _ = gaps_fn(stacked, ids)
         if tokens_of is not None:
             low = jax.jit(lambda p, ids: ref.token_gaps(
@@ -516,6 +528,7 @@ def served_mass_above(ref, params, rows, sizes, rows_per_block,
     fn = jax.jit(lambda p, ids: ref.mass_above(p, ids, sizes, temperature,
                                                precision))
     out = []
-    for block, ids in _id_blocks(rows, rows_per_block, sizes["n_positions"]):
+    for block, ids in _id_blocks(rows, rows_per_block,
+                                 ref.longest_row(sizes)):
         out += _served(block, fn(stacked, ids))
     return np.concatenate(out)

@@ -5,7 +5,14 @@ files of its own and an entry in ``BENCHMARK.json``; nothing here knows
 any of them by name.
 
     configs/<config>.json      the sizes as run, the source, what was
-                               assumed, and which reference is beside it
+                               assumed, and the two files beside it:
+      "reference": <path>      the plain reference with its counts: the
+                               equations, ``param_table``, ``vocab``,
+                               ``longest_row``, ``weight_std``, and the
+                               operations and bytes the readers divide by
+      "program": <path>        ``model_config(models, sizes)``: the
+                               program's own configuration object from
+                               the configuration's own keys
     workloads/<traffic>.json   the generator's kind and its parameters
     metrics/<metric>.py        ``read(ctx)``: the number, or None
 """
@@ -48,6 +55,13 @@ class Cell:
 
     def reference(self):
         return load_module(os.path.join(self.root, self.config["reference"]))
+
+    def model_config(self, models):
+        """The program's own configuration object, as the
+        configuration's ``"program"`` file makes it."""
+        return load_module(os.path.join(
+            self.root, self.config["program"])).model_config(
+                models, self.config)
 
     def reader(self, metric):
         return load_module(os.path.join(

@@ -222,13 +222,19 @@ class Trace:
 
     def whole_launches(self, prefix):
         """The launches of programs named ``prefix``... that lie wholly
-        inside the window: ``(count, first start, last end)``."""
+        inside the window: ``(count, first start, last end)``.  An
+        event shorter than half the median launch is no whole launch
+        but a stub the profiler leaves of one (1.5 ms beside ten steps
+        of 283 in one traced training run of two, ``PERF.md`` section
+        6, PR 26), and is not counted."""
         mods = self.planes[self.device]["modules"].inside(
             self.lo, self.hi).pick(
                 lambda n: program_of(n).startswith(prefix))
         if not mods.names:
             return 0, None, None
-        return len(mods.names), float(mods.start.min()), float(mods.end.max())
+        whole = mods.durations >= 0.5 * np.median(mods.durations)
+        return (int(whole.sum()), float(mods.start[whole].min()),
+                float(mods.end[whole].max()))
 
     def program_durations(self, prefix):
         """Device durations of the launches of programs whose name

@@ -47,9 +47,8 @@ class Trainer:
         else:
             mesh = None
             self.repl = self.split = SingleDeviceSharding(devices[0])
-        cfg = program.gpt_config(models, self.sizes)
         _, self.optimizer, self.step, _ = chip_smoke.build_trainer(
-            cfg, mesh, lr=self.hyper["lr"])
+            cell.model_config(models), mesh, lr=self.hyper["lr"])
         self.table = self.ref.param_table(self.sizes)
         self._delta = jax.jit(lambda a, b: {
             "/".join(k.key for k in path): jnp.sqrt(jnp.sum(jnp.square(x)))
@@ -59,11 +58,11 @@ class Trainer:
     def fresh_params(self, seed, sharding):
         import jax.numpy as jnp
         return weights.make_params(self.table, seed, jnp.float32,
-                                   self.sizes["initializer_range"], sharding)
+                                   self.ref.weight_std(self.sizes), sharding)
 
     def feed(self, seed):
         return traffic.packed_batches(self.mix, seed, self.batch, self.seq,
-                                      self.sizes["vocab_size"])
+                                      self.ref.vocab(self.sizes))
 
     def put(self, feed):
         """The next batch, built on the host and waited for on the
@@ -185,14 +184,15 @@ def run(cell, seed, seconds, traced, t_start, require_chip=True):
     t0 = clock()
     theirs = trainer.reference(seed, first_batches)
     _log(f"train: reference took {clock() - t0:.2f}s")
-    compared, notes = compare.compare_training(mine, theirs,
-                                               sizes["limits"]["train"])
+    compared, notes = compare.compare_training(
+        mine, theirs, compare.limits_for(cell, "train"))
 
     breakdown = None
     if traced:
         t0 = clock()
         trace = sub.reduce()
         ctx = {"trace": trace, "cell": cell, "sizes": sizes, "mix": mix,
+               "ref": trainer.ref,
                "chips": cell.chips, "device_kind": devices[0].device_kind,
                "tokens_per_step": batch * seq, "batch_per_chip":
                mix["batch_per_chip"], "seq": seq,
@@ -213,7 +213,7 @@ def reference_readings(ref, table, sizes, hyper, seed, batches, rows,
     import jax
     import jax.numpy as jnp
     p0 = ref.stacked(weights.make_params(
-        table, seed, jnp.float32, sizes["initializer_range"]), sizes)
+        table, seed, jnp.float32, ref.weight_std(sizes)), sizes)
     if keep_rows is not None:
         batches = [b[:keep_rows] for b in batches]
         rows = min(rows, keep_rows)

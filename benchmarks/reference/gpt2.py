@@ -34,9 +34,24 @@ HIGHEST = jax.lax.Precision.HIGHEST
 PRECISIONS = ("float32", "bfloat16", "fp8")
 
 
+def vocab(sizes):
+    """How many ids there are: the traffic draws from ``range(vocab)``."""
+    return sizes["vocab_size"]
+
+
+def longest_row(sizes):
+    """The longest row of ids ``logits`` takes: the learned positions."""
+    return sizes["n_positions"]
+
+
+def weight_std(sizes):
+    """The standard deviation of ``param_table``'s ``normal`` leaves."""
+    return sizes["initializer_range"]
+
+
 def param_table(sizes):
     """``{path: (shape, kind)}`` for every parameter, kind one of
-    ``normal`` (N(0, initializer_range)), ``zeros``, ``ones``."""
+    ``normal`` (N(0, ``weight_std``)), ``zeros``, ``ones``."""
     h, nh = sizes["n_embd"], sizes["n_head"]
     hd, inner = h // nh, sizes["n_inner"] or 4 * sizes["n_embd"]
     table = {"wte/embedding": ((sizes["vocab_size"], h), "normal"),

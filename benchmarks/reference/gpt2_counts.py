@@ -1,8 +1,13 @@
-"""Operations and bytes that the work needs, from the shapes alone.
+"""Operations and bytes that GPT-2's work needs, from the shapes alone:
+the counts of the reference beside this file (``gpt2.py``).
 
 These count what the algorithm has to do, whatever implements it: no
 recomputation, no padding, no copies the program happens to make.  A
 multiply-add is two operations.  ``sizes`` is a configuration's file.
+A configuration's ``<name>.reference.py`` exports them with its
+equations, and the readers (``harness/readers.py``) reach them through
+the cell's reference: another family brings its own counts under the
+same names and the metric files stay one line each.
 """
 
 

@@ -19,11 +19,11 @@ from benchmarks.harness import line, spec  # noqa: E402
 def main(paths):
     bad = 0
     for path in paths:
-        cell, _seed, mode = os.path.basename(path)[:-len(".out")].rsplit(
-            ".", 2)
         with open(path) as f:
             lines = f.read().strip().splitlines()
         try:
+            cell, _seed, mode = os.path.basename(path)[:-len(".out")].rsplit(
+                ".", 2)
             obj = json.loads(lines[-1])
             line.check_line(obj, spec.load_cell(cell), mode == "t1")
             print(f"{path}: ok, correct={obj['correct']}")

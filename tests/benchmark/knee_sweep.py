@@ -30,17 +30,17 @@ def main():
     enable_compile_cache()
     import jax
     import jax.numpy as jnp
-    sizes, seed = cell.config, 4242
+    sizes, seed, ref = cell.config, 4242, cell.reference()
+    vocab = ref.vocab(sizes)
     params = weights.make_params(
-        cell.reference().param_table(sizes), seed, jnp.bfloat16,
-        sizes["initializer_range"],
+        ref.param_table(sizes), seed, jnp.bfloat16, ref.weight_std(sizes),
         jax.sharding.SingleDeviceSharding(devices[0]))
-    server = serve.build_server(models, serving, sizes, cell.traffic, params)
-    serve.warm_up(server, serving, cell.traffic, sizes["vocab_size"], seed)
+    server = serve.build_server(cell, models, serving, params)
+    serve.warm_up(server, serving, cell.traffic, vocab, seed)
     for rate in [float(r) for r in rates.split(",")]:
         mix = dict(cell.traffic, rate_per_s=rate)
         n = traffic.planned_count(mix, seconds, serve.DRAIN_LIMIT_S)
-        plan = traffic.plan_requests(mix, seed, n, sizes["vocab_size"])
+        plan = traffic.plan_requests(mix, seed, n, vocab)
         run = serve.drive(server, serving, plan, mix, seconds, False)
         e2e = serve.end_to_end(run, seconds)
         m = run["marks"]

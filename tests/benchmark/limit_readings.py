@@ -66,7 +66,7 @@ def train(cell, seeds, n_control, out, with_program=True):
     for i, seed in enumerate(seeds):
         t0 = time.perf_counter()
         feed = traffic.packed_batches(mix, seed, rows, mix["seq"],
-                                      sizes["vocab_size"])
+                                      ref.vocab(sizes))
         if with_program:
             params, opt_state, loss, batches, mine = trainer.first_steps(
                 seed, feed)

@@ -113,10 +113,9 @@ def test_layer_norm_kernels(one_chip, as_on_tpu, rows, hidden):
 
 def test_adam_kernel_at_355m_parameters(one_chip, as_on_tpu):
     from apex_tpu import optimizers
-    from benchmarks.harness import flops, spec
-    sizes = spec._json(os.path.join(spec.ROOT, "benchmarks", "configs",
-                                    "gpt2-medium.json"))
-    n = flops.total_params(sizes)
+    from benchmarks.harness import spec
+    cell = spec.load_cell("gpt2m-train-1chip")
+    n = cell.reference().total_params(cell.config)
     assert 354_000_000 < n < 356_000_000
     opt = optimizers.FusedAdam(lr=3e-4)
     p = {"w": jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)}

@@ -1,11 +1,14 @@
 """The operation and byte functions against numbers worked by hand from
-the published sizes of both configurations."""
+the published sizes of both configurations.  They live beside the
+reference they count (``benchmarks/reference/gpt2_counts.py``) and the
+readers reach them through a cell's reference."""
 
 import os
 
 import pytest
 
-from benchmarks.harness import flops, spec
+from benchmarks.harness import spec
+from benchmarks.reference import gpt2_counts as flops
 
 
 def sizes(name):
@@ -79,3 +82,20 @@ def test_flash_and_medium_per_token_by_hand():
     assert nbytes == 12 * 24 * 8 * 1024 * 1024 * 2
     per_token = flops.train_flops_per_sequence(s, 1024) / 1024
     assert 2.27e9 < per_token < 2.28e9        # 6 * 353M + attention
+
+
+@pytest.mark.parametrize("cell", ["gpt2m-train-1chip", "gpt2m-train-ddp4",
+                                  "gpt2xl-chat-open", "gpt2xl-doc-backlog"])
+def test_a_cells_reference_carries_the_counts_the_readers_divide_by(cell):
+    c = spec.load_cell(cell)
+    ref, s = c.reference(), c.config
+    for name in ("matmul_params", "total_params", "attention_flops_causal",
+                 "forward_flops_at", "train_flops_per_sequence",
+                 "flash_train_flops_bytes", "adam_bytes",
+                 "decode_attention_bytes"):
+        assert callable(getattr(ref, name)), name
+    assert ref.total_params(s) == flops.total_params(s)
+    assert ref.forward_flops_at(s, 3, 9) == flops.forward_flops_at(s, 3, 9)
+    # and what the runners ask of it in place of a model's key names
+    assert ref.vocab(s) == 50257 and ref.longest_row(s) == 1024
+    assert ref.weight_std(s) == 0.02

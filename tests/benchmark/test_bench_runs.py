@@ -1,8 +1,10 @@
 """Whole runs on the CPU at a tiny size, through the command line's
 ``main`` with only the look for a chip skipped.  The cells are added to
-a copy of the benchmark as NEW files and entries: a configuration, three
-traffic mixes, a metric.  Then the same runs with the timed path broken
-underneath, each of which has to come out as not correct."""
+a copy of the benchmark as NEW files and entries: two configurations
+(one in GPT-2's key names, one in another dialect with its own program
+and reference files), four traffic mixes, a metric.  Then the same runs
+with the timed path broken underneath, each of which has to come out as
+not correct."""
 
 import json
 import os
@@ -42,8 +44,13 @@ def drive(root, capsys, cell, seed, seconds="1"):
 
 @pytest.mark.parametrize("cell,metrics", [
     ("tiny-train", {"train_tokens_per_s", "setup_s"}),
-    ("tiny-chat", {"itl_p95_ms", "setup_s"}),
+    ("tiny-chat", {"itl_p50_ms", "setup_s"}),
     ("tiny-backlog", {"serve_tokens_per_s", "setup_s"}),
+    # the same three under a configuration that holds none of GPT-2's
+    # key names and brings its own program and reference files
+    ("hf-train", {"train_tokens_per_s", "setup_s"}),
+    ("hf-chat", {"itl_p50_ms", "setup_s"}),
+    ("hf-backlog", {"serve_tokens_per_s", "setup_s"}),
 ])
 def test_new_cells_run_with_no_edit_to_a_file_that_was_there(
         added, capsys, cell, metrics):
@@ -59,7 +66,7 @@ def test_new_cells_run_with_no_edit_to_a_file_that_was_there(
     assert len(tail) == len(last["compared"]) + 1
     assert err.strip().splitlines()[-1].startswith("compared notes")
     # sampled requests are read beside the greedy ones that are compared
-    assert ("'sampled_over_top_p'" in err) == (cell == "tiny-chat")
+    assert ("'sampled_over_top_p'" in err) == cell.endswith("-chat")
     line.check_line(last, spec.load_cell(cell, root), False)
     assert tiny_root.unchanged(before) is None
 
@@ -81,11 +88,66 @@ def test_the_added_metric_is_found_by_its_name(added):
     n, lo, hi = t.whole_launches("jit_step")
     assert got == pytest.approx(n / (hi - lo)) and got > 0
     # and every metric the cells list has its reader's file
-    for name in ("gpt2m-train-1chip", "gpt2xl-chat-open",
-                 "gpt2xl-doc-backlog"):
+    for name in ("gpt2m-train-1chip", "gpt2m-train-ddp4",
+                 "gpt2xl-chat-open", "gpt2xl-doc-backlog"):
         c = spec.load_cell(name)
         for m in c.per_layer:
             assert callable(c.reader(m["name"])), m["name"]
+
+
+def test_the_harness_reads_no_models_key_names(added):
+    """The runners make the program's configuration through the
+    configuration's ``program`` file and ask its reference for the
+    vocabulary, the longest row and the weights' deviation; the readers
+    take the counts from it.  So no file of the harness names a key of
+    GPT-2's ``config.json`` or the program's configuration class, and
+    the other dialect's file holds none of those keys."""
+    names = ("n_embd", "n_layer", "n_head", "n_inner", "n_positions",
+             "initializer_range", "layer_norm_epsilon", "pdrop",
+             "GPTConfig")
+    files = [os.path.join(ROOT, "benchmarks", "run.py")] + [
+        os.path.join(d, f)
+        for d, _, fs in os.walk(os.path.join(ROOT, "benchmarks", "harness"))
+        for f in fs if f.endswith(".py")]
+    assert len(files) > 10
+    for path in files:
+        with open(path) as fh:
+            text = fh.read()
+        assert not [n for n in names if n in text], path
+    assert not os.path.exists(os.path.join(ROOT, "benchmarks", "harness",
+                                           "flops.py"))
+    hf = spec.load_cell("hf-train", added[0]).config
+    assert not set(hf) & set(tiny_root.TINY_SIZES) - {
+        "name", "source", "vocab_size", "reduced", "reference", "program",
+        "optimizer", "limits"}
+    assert not [k for k in hf if "pdrop" in k]
+
+
+def test_requests_that_share_a_document_hit_the_servers_prefix_cache(
+        added):
+    """The tiny shared mix asks each document two or three times, three
+    places apart: the server's own counter of prompt tokens found in
+    its prefix cache rises through the window, and with a mix that
+    shares nothing it stays at nought."""
+    import time
+
+    import jax
+    from benchmarks.harness import serve
+    hits = {}
+    for name in ("tiny-shared", "tiny-backlog"):
+        cell = spec.load_cell(name, added[0])
+        s = serve.Session(cell, 11, 1.0, False, jax.devices()[:1],
+                          time.perf_counter())
+        m = s.run["marks"]
+        hits[name] = (m["close"]["prefix_hit_tokens"]
+                      - m["open"]["prefix_hit_tokens"])
+        assert s.e2e["failed"] == 0 and s.e2e["attempted"] > 10
+        if name == "tiny-shared":
+            gaps = s.reference_gaps()
+            assert len(gaps) and float(gaps.max()) < 0.004
+    assert hits["tiny-backlog"] == 0
+    # a document of 40 to 80 ids shares two to five blocks of 16
+    assert hits["tiny-shared"] >= 32 * 5
 
 
 # -- the timed path broken underneath ----------------------------------------

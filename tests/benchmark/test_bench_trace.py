@@ -91,6 +91,22 @@ def test_kernels_programs_and_collectives():
     assert trace.op_label("%copy-start.10") == "%copy-start.10"
 
 
+def test_the_stub_of_a_launch_in_flight_is_no_whole_launch():
+    """Ten steps of 283 ms and, before them, the 1.5 ms stub the
+    profiler leaves of the step that was running when it started: ten
+    whole launches, or every share that divides by their count reads a
+    tenth too high (``step_mfu.train`` 36.7 for 33.4)."""
+    step = 0.283
+    mods = line(("jit_step(7)", 0.0100, 0.0115), *[
+        ("jit_step(7)", 0.0115 + i * step, 0.0115 + (i + 1) * step)
+        for i in range(10)])
+    t = hand_made(line(("%f = x", 0.0, 3.0)), mods, hi=3.06)
+    n, lo, hi = t.whole_launches("jit_step")
+    assert n == 10 and lo == pytest.approx(0.0115)
+    assert n / (hi - lo) == pytest.approx(1 / step)
+    assert len(t.program_durations("jit_step")) == 11    # the median's
+
+
 def test_a_trace_with_no_device_plane_or_no_window_is_an_error():
     class Plane:
         def __init__(self, name, lines):
@@ -112,7 +128,8 @@ def load(tag):
 
 
 def test_the_chip_fixtures_are_there():
-    assert len(FIXTURES) >= 3, FIXTURES
+    assert len(FIXTURES) >= 4, FIXTURES
+    assert any("4chip" in tag for tag in FIXTURES), FIXTURES
 
 
 @pytest.mark.parametrize("tag", FIXTURES)
@@ -128,6 +145,16 @@ def test_recorded_trace_gives_the_numbers_written_beside_it(tag):
     for k, (seconds, n) in want["kernels"].items():
         s, c = t.kernel_seconds(k)
         assert c == n and s == pytest.approx(seconds, rel=1e-9, abs=1e-12)
+    # what of the collectives no other operation hid, on the busiest
+    # chip: None where the trace holds no collective (one chip)
+    exposed = t.collective_exposed_s()
+    if want["collective_exposed_s"] is None:
+        assert exposed is None and len(t.planes) == 1
+    else:
+        assert exposed == pytest.approx(want["collective_exposed_s"],
+                                        rel=1e-9)
+        assert 0 < exposed < t.busy_s
+        assert t.busy_s == max(t.busy_on(d) for d in t.planes)
 
 
 @pytest.mark.parametrize("tag", FIXTURES)

@@ -1,7 +1,13 @@
 """A copy of the benchmark with tiny cells added AS NEW FILES ONLY: what
 a later PR may do.  The CPU tests run these cells through the real
 command line's ``main``; ``record_fixture.py`` runs them on the chip to
-record the small trace the reducer is checked on."""
+record the small trace the reducer is checked on.
+
+Two configurations come in: ``gpt2-tiny`` in GPT-2's own key names,
+which takes the GPT-2 files that are there, and ``hf-tiny``, whose file
+holds none of those names and which brings its own ``program`` and
+``reference`` files: the harness reads no model's keys, so a
+configuration of another key dialect is files alone."""
 
 import json
 import os
@@ -17,6 +23,7 @@ TINY_SIZES = {
     "n_embd": 64, "n_head": 2, "n_inner": None, "n_layer": 2,
     "n_positions": 256, "vocab_size": 512, "reduced": [],
     "reference": "benchmarks/configs/gpt2-tiny.reference.py",
+    "program": "benchmarks/configs/gpt2.program.py",
     "optimizer": {"name": "adam", "lr": 0.0003, "betas": [0.9, 0.999],
                   "eps": 1e-08, "weight_decay": 0.0},
     # set as the cells' own are, from readings at this size on the CPU:
@@ -68,6 +75,96 @@ NEW_METRIC = ('"""A metric a later PR brings: steps per second."""\n\n\n'
               '    return n / (hi - lo) if n else None\n')
 
 
+# the same tiny decoder in another key dialect: none of GPT-2's names
+HF_SIZES = {
+    "name": "hf-tiny", "source": "a test's own sizes",
+    "hidden_size": 64, "num_attention_heads": 2, "num_hidden_layers": 2,
+    "intermediate_size": 256, "max_position_embeddings": 256,
+    "vocab_size": 512, "norm_eps": 1e-05, "init_std": 0.02, "reduced": [],
+    "reference": "benchmarks/configs/hf-tiny.reference.py",
+    "program": "benchmarks/configs/hf-tiny.program.py",
+    "optimizer": TINY_SIZES["optimizer"], "limits": TINY_SIZES["limits"],
+}
+
+HF_PROGRAM = '''"""``hf-tiny`` onto the program's only decoder."""
+
+
+def model_config(models, sizes):
+    return models.GPTConfig(
+        vocab_size=sizes["vocab_size"], hidden_size=sizes["hidden_size"],
+        num_hidden_layers=sizes["num_hidden_layers"],
+        num_attention_heads=sizes["num_attention_heads"],
+        intermediate_size=sizes["intermediate_size"],
+        max_position_embeddings=sizes["max_position_embeddings"],
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
+        layer_norm_eps=sizes["norm_eps"],
+        initializer_range=sizes["init_std"])
+'''
+
+HF_REFERENCE = '''"""``hf-tiny``'s reference and counts: the published
+GPT-2 equations, with this configuration's keys translated for them."""
+
+from benchmarks.reference import gpt2, gpt2_counts
+
+
+def _keys(sizes):
+    return {"vocab_size": sizes["vocab_size"],
+            "n_embd": sizes["hidden_size"],
+            "n_head": sizes["num_attention_heads"],
+            "n_layer": sizes["num_hidden_layers"],
+            "n_inner": sizes["intermediate_size"],
+            "n_positions": sizes["max_position_embeddings"],
+            "layer_norm_epsilon": sizes["norm_eps"],
+            "initializer_range": sizes["init_std"]}
+
+
+def _translated(fn):
+    def call(*args, **kwargs):
+        return fn(*[_keys(a) if isinstance(a, dict) and "hidden_size" in a
+                    else a for a in args], **kwargs)
+    return call
+
+
+for _module, _names in (
+        (gpt2, ("logit_at", "logits", "longest_row", "mass_above",
+                "next_token_loss", "param_table", "stacked", "token_gaps",
+                "train_steps", "unstacked_leaf_norms", "vocab",
+                "weight_std")),
+        (gpt2_counts, ("adam_bytes", "attention_flops_causal",
+                       "decode_attention_bytes", "flash_train_flops_bytes",
+                       "forward_flops_at", "matmul_params", "total_params",
+                       "train_flops_per_sequence"))):
+    for _name in _names:
+        globals()[_name] = _translated(getattr(_module, _name))
+'''
+
+# requests that share a prefix: each document asked 2 or 3 times
+SHARED_TRAFFIC = {
+    "tiny-shared": {
+        "runner": "serve", "loop": "closed", "clients": 6,
+        "prompt": {"dist": "uniform", "min": 40, "max": 80},
+        "shared": {"asks": {"dist": "uniform", "min": 2, "max": 3},
+                   "suffix": {"dist": "uniform", "min": 4, "max": 12},
+                   "apart": 3},
+        "output": {"dist": "uniform", "min": 4, "max": 8},
+        "max_total": 128, "greedy_share": 1.0, "shape_seed": 9,
+        "stratum": 6, "fill_s": 0.5, "planned_requests": 706,
+        "server": {"max_batch_size": 4, "max_context": 128},
+        "check": {"requests": 4, "rows_per_block": 2},
+        "trace": {"ends_with_window": True, "seconds": 0.5}},
+}
+
+# (cell, configuration, traffic mix, the accepted cell whose metrics it
+# reports)
+CELLS = [("tiny-train", "gpt2-tiny", "tiny-docs", "train"),
+         ("tiny-chat", "gpt2-tiny", "tiny-chat", "chat"),
+         ("tiny-backlog", "gpt2-tiny", "tiny-backlog", "backlog"),
+         ("tiny-shared", "gpt2-tiny", "tiny-shared", "backlog"),
+         ("hf-train", "hf-tiny", "tiny-docs", "train"),
+         ("hf-chat", "hf-tiny", "tiny-chat", "chat"),
+         ("hf-backlog", "hf-tiny", "tiny-backlog", "backlog")]
+
+
 SMALL_SIZES = dict(TINY_SIZES, n_embd=256, n_head=4, n_positions=1024,
                    vocab_size=2048)
 
@@ -87,8 +184,8 @@ def small_traffic(trace_seconds):
 
 def make(tmp, chips=1, sizes=TINY_SIZES, mixes=TINY_TRAFFIC):
     """Copy ``BENCHMARK.json`` and ``benchmarks/`` into ``tmp``, then
-    add a configuration, three traffic mixes, a metric and three cells,
-    touching no file that was there except to append entries."""
+    add two configurations, four traffic mixes, a metric and seven
+    cells, touching no file that was there except to append entries."""
     tmp = str(tmp)
     before = {}
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp)
@@ -100,38 +197,43 @@ def make(tmp, chips=1, sizes=TINY_SIZES, mixes=TINY_TRAFFIC):
             with open(os.path.join(d, f), "rb") as fh:
                 before[os.path.join(d, f)] = fh.read()
     b = os.path.join(tmp, "benchmarks")
-    with open(os.path.join(b, "configs", "gpt2-tiny.json"), "w") as f:
-        json.dump(sizes, f)
+
+    def write(path, text):
+        with open(os.path.join(b, path), "x") as f:    # never one there
+            f.write(text)
+
+    write("configs/gpt2-tiny.json", json.dumps(sizes))
     shutil.copy(os.path.join(b, "configs", "gpt2-medium.reference.py"),
                 os.path.join(b, "configs", "gpt2-tiny.reference.py"))
-    for name, mix in mixes.items():
-        with open(os.path.join(b, "workloads", name + ".json"), "w") as f:
-            json.dump(mix, f)
-    with open(os.path.join(b, "metrics", "steps_per_s.tiny.py"), "w") as f:
-        f.write(NEW_METRIC)
+    write("configs/hf-tiny.json", json.dumps(HF_SIZES))
+    write("configs/hf-tiny.program.py", HF_PROGRAM)
+    write("configs/hf-tiny.reference.py", HF_REFERENCE)
+    for name, mix in dict(SHARED_TRAFFIC, **mixes).items():
+        write(f"workloads/{name}.json", json.dumps(mix))
+    write("metrics/steps_per_s.tiny.py", NEW_METRIC)
     with open(os.path.join(tmp, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    bench["configs"].append({"name": "gpt2-tiny", "source": "a test",
-                             "file": "benchmarks/configs/gpt2-tiny.json",
-                             "reduced": [], "why": "a test"})
-    cells = {"tiny-train": "tiny-docs", "tiny-chat": "tiny-chat",
-             "tiny-backlog": "tiny-backlog"}
-    for cell, mix in cells.items():
-        bench["workloads"].append({"name": cell, "config": "gpt2-tiny",
+    for name in ("gpt2-tiny", "hf-tiny"):
+        bench["configs"].append({
+            "name": name, "source": "a test", "reduced": [],
+            "file": f"benchmarks/configs/{name}.json", "why": "a test"})
+    for cell, config, mix, _ in CELLS:
+        bench["workloads"].append({"name": cell, "config": config,
                                    "traffic": mix, "chips": chips,
                                    "why": "a test"})
     for m in bench["end_to_end"] + bench["per_layer"]:
         kind = m["name"].rsplit(".", 1)[-1]
         if m["name"] == "train_tokens_per_s" or kind in ("train", "ddp4"):
-            add = ["tiny-train"] if kind != "ddp4" or chips > 1 else []
-        elif m["name"] in ("ttft_p95_ms", "itl_p95_ms") or kind == "chat":
-            add = ["tiny-chat"]
+            of = "train" if kind != "ddp4" or chips > 1 else None
+        elif m["name"] in ("ttft_p95_ms", "itl_p50_ms") or kind == "chat":
+            of = "chat"
         elif m["name"] == "serve_tokens_per_s" or kind == "backlog":
-            add = ["tiny-backlog"]
+            of = "backlog"
         else:
-            add = []
+            of = None
         if "workloads" in m:
-            m["workloads"] = m["workloads"] + add
+            m["workloads"] = m["workloads"] + [
+                cell for cell, _, _, like in CELLS if like == of]
     bench["per_layer"].append({
         "name": "steps_per_s.tiny", "unit": "steps/s", "better": "higher",
         "source": "device_trace", "layer": "trainer step",
