@@ -29,6 +29,8 @@ from apex_tpu.models.gpt import (
     lm_loss,
 )
 from apex_tpu.models.moe import EP_RULES, MoEMlp, ep_rules
+from apex_tpu.models.family import CacheRow
+from apex_tpu.models.deepseek import DeepseekV3Config, DeepseekV3LMHeadModel
 from apex_tpu.models.bert import (
     BertConfig,
     BertEncoder,
@@ -40,6 +42,9 @@ from apex_tpu.models.bert import (
 
 __all__ = [
     "BasicBlock",
+    "CacheRow",
+    "DeepseekV3Config",
+    "DeepseekV3LMHeadModel",
     "EP_RULES",
     "GPTConfig",
     "GPTLMHeadModel",

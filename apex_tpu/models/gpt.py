@@ -37,6 +37,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.models.family import CacheRow
 from apex_tpu.models.pipelined_common import PipelinedCommon
 from apex_tpu.normalization import FusedLayerNorm
 
@@ -57,6 +58,17 @@ class GPTConfig:
     initializer_range: float = 0.02
     # rematerialize each block in backward: the long-sequence lever
     remat: bool = False
+
+    # -- what the serving engine asks a family (models/family.py) ---------
+
+    def build_model(self, attention_fn=None, kv_quant: bool = False):
+        return GPTLMHeadModel(self, attention_fn=attention_fn,
+                              kv_quant=kv_quant)
+
+    def cache_row(self) -> CacheRow:
+        """Every head's ``K_h | V_h`` pair a token and layer."""
+        return CacheRow.kv(self.num_attention_heads,
+                           self.hidden_size // self.num_attention_heads)
 
 
 def gpt_small() -> "GPTConfig":
@@ -118,11 +130,12 @@ def causal_dot_product_attention(q, k, v, bias=None, dropout_fn=None):
 class GPTSelfAttention(nn.Module):
     cfg: GPTConfig
     attention_fn: Optional[Callable] = None
+    kv_quant: bool = False
 
     @nn.compact
     def __call__(self, x, attn_bias, deterministic: bool = True,
                  cache_view=None, return_kv: bool = False,
-                 kv_quant: bool = False, layer: int = 0):
+                 layer: int = 0):
         """``cache_view``: serving mode — the launch's view of the KV
         pool (``serving.kv_cache.CacheView``), ``layer`` this block's
         index in it.  The view's ``attend`` takes the queries and the
@@ -137,7 +150,8 @@ class GPTSelfAttention(nn.Module):
         monolithic prefill).  Both default off — the training path is
         byte-identical to before.
 
-        ``kv_quant``: int8-quantized-pool serving (``docs/serving.md``,
+        ``kv_quant`` (a field, set where the model is built:
+        ``GPTConfig.build_model``): int8-quantized-pool serving (``docs/serving.md``,
         "Quantized KV cache").  The freshly projected K/V quantize AT
         THE SOURCE (:func:`ops.kv_quant.quantize_kv`, per token per
         head) and attention everywhere operates on the QUANTIZED grid
@@ -151,7 +165,7 @@ class GPTSelfAttention(nn.Module):
         boundaries, preemption re-prefill, COW, and speculation cannot
         move a logit.  The fresh K/V are then ``((k_q, k_scale),
         (v_q, v_scale))`` — byte-for-byte what attention uses."""
-        cfg = self.cfg
+        cfg, kv_quant = self.cfg, self.kv_quant
         h, nh = cfg.hidden_size, cfg.num_attention_heads
         init = _init(cfg)
 
@@ -211,23 +225,23 @@ class GPTBlock(nn.Module):
 
     cfg: GPTConfig
     attention_fn: Optional[Callable] = None
+    kv_quant: bool = False
 
     @nn.compact
     def __call__(self, x, attn_bias, deterministic: bool = True,
                  cache_view=None, return_kv: bool = False,
-                 kv_quant: bool = False, layer: int = 0):
+                 layer: int = 0):
         cfg = self.cfg
         init = _init(cfg)
         drop = nn.Dropout(cfg.hidden_dropout_prob,
                           deterministic=deterministic)
         h = FusedLayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
                            name="attn_ln")(x)
-        h = GPTSelfAttention(cfg, self.attention_fn,
+        h = GPTSelfAttention(cfg, self.attention_fn, self.kv_quant,
                              name="attention")(h, attn_bias,
                                                deterministic,
                                                cache_view=cache_view,
                                                return_kv=return_kv,
-                                               kv_quant=kv_quant,
                                                layer=layer)
         kv = None
         if return_kv:
@@ -274,7 +288,7 @@ class GPTLMHeadModel(nn.Module):
       projected ``(k, v)`` list so the engine can write them into the
       cache (the monolithic prefill: the normal causal forward,
       optionally through the flash ``attention_fn``);
-    - ``kv_quant``: int8-quantized-pool serving — fresh K/V quantize at
+    - ``kv_quant`` (a field): int8-quantized-pool serving — fresh K/V quantize at
       projection and attention runs on the quantized grid everywhere,
       and ``return_kv`` without a view yields per-layer
       ``((k_q, k_scale), (v_q, v_scale))`` (``docs/serving.md``,
@@ -283,14 +297,14 @@ class GPTLMHeadModel(nn.Module):
 
     cfg: GPTConfig
     attention_fn: Optional[Callable] = None
+    kv_quant: bool = False
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None,
                  deterministic: bool = True,
                  return_hidden: bool = False,
                  positions=None, cache_views=None,
-                 return_kv: bool = False,
-                 kv_quant: bool = False):
+                 return_kv: bool = False):
         cfg = self.cfg
         x, wte = _embed_block(cfg, input_ids, deterministic, positions)
         bias = None
@@ -307,10 +321,10 @@ class GPTLMHeadModel(nn.Module):
         kvs, view = [], cache_views
         for i in range(cfg.num_hidden_layers):
             if return_kv:
-                x, kv = block(cfg, self.attention_fn,
+                x, kv = block(cfg, self.attention_fn, self.kv_quant,
                               name=f"block_{i}")(
                     x, bias, deterministic, cache_view=view,
-                    return_kv=True, kv_quant=kv_quant, layer=i)
+                    return_kv=True, layer=i)
                 if view is None:
                     kvs.append(kv)
                 else:

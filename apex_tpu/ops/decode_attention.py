@@ -355,24 +355,46 @@ def cached_attention(q, k, v, *, kv_bias: Optional[jax.Array] = None,
 _PAGES = 8
 
 
+def _query_row(m, shared):
+    """The query row that row ``m`` of a tile belongs to, where the
+    rows of ``shared`` heads lie side by side."""
+    if shared == 1:
+        return m
+    if shared & (shared - 1) == 0:
+        return lax.shift_right_logical(m, shared.bit_length() - 1)
+    return m // shared
+
+
 def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
-                  scale, block_size, rows, heads, pages):
+                  scale, block_size, rows, groups, shared, value, pages):
     """One (slot, row tile, ``pages``-page window) step of the
     streaming softmax over the pool itself.  ``rest`` is the window's
-    page blocks (``(block_size, heads * 2 * D)`` each: one row a token,
-    ``K_h`` beside ``V_h`` for every head), the output block and the
-    ``(acc, m, l)`` scratch.  The query rows of a head are ``2 * D``
-    wide with zeros over the ``V`` half, so ``q . [K_h | V_h]`` is
-    ``q . K_h`` and no lane is sliced; the product of the probabilities
-    with the same tile carries ``p . V_h`` in its upper ``D`` lanes,
-    which the caller takes."""
+    page blocks (``(block_size, groups * width)`` each: one row a
+    token, ``groups`` groups of ``width`` values side by side), the
+    output block and the ``(acc, m, l)`` scratch.
+
+    A query row is as wide as a group, with zeros over the lanes that
+    are not key, so ``q . group`` is ``q . K`` and no lane is sliced.
+    ``value`` names the lanes of a group that are its value: the whole
+    group for a head's ``K_h | V_h`` pair (the product of the
+    probabilities with the same tile then carries ``p . V_h`` in its
+    upper lanes, which the caller takes), or a whole number of leading
+    lane tiles for a latent row.  ``shared`` query heads read one
+    group; their rows lie side by side in the tile (row ``m`` is query
+    row ``m // shared``), so a sequence's heads meet a page as one
+    matrix."""
     del layer_ref, tables_ref          # the index maps read them
     page_refs = rest[:pages]
     o_ref, acc_ref, m_ref, l_ref = rest[pages:]
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     span = pages * block_size           # keys in one window
-    tile, d2 = q_ref.shape[1], q_ref.shape[2]
-    first = starts_ref[b] + i * tile    # position of the tile's row 0
+    tile, width = q_ref.shape[1], q_ref.shape[2]
+
+    query_row = functools.partial(_query_row, shared=shared)
+    start = starts_ref[b]
+    # the tile's last live row: beyond it the tile holds padding
+    last = start + query_row(
+        i * tile + jnp.minimum(tile, rows * shared - i * tile) - 1)
 
     @pl.when(j == 0)
     def _init():
@@ -380,39 +402,41 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # row r sits at position first + r and sees every key at or before
-    # itself (the rows were written before this call); windows past the
-    # tile's last row hold nothing any of its rows may see
-    @pl.when(j * span < first + jnp.minimum(tile, rows - i * tile))
+    # a row sees every key at or before its own position (the rows were
+    # written before this call); windows past the tile's last row hold
+    # nothing any of its rows may see
+    @pl.when(j * span <= last)
     def _window():
         key = j * span + lax.broadcasted_iota(jnp.int32, (tile, span), 1)
-        row = first + lax.broadcasted_iota(jnp.int32, (tile, span), 0)
+        row = start + query_row(
+            i * tile + lax.broadcasted_iota(jnp.int32, (tile, span), 0))
         seen = key <= row
 
-        def head(h, carry):
-            lanes = pl.ds(pl.multiple_of(h * d2, d2), d2)
+        def group(g, carry):
+            lanes = pl.ds(pl.multiple_of(g * width, width), width)
             kv = jnp.concatenate([r[:, lanes] for r in page_refs], axis=0)
-            s = lax.dot_general(q_ref[h], kv, (((1,), (1,)), ((), ())),
+            s = lax.dot_general(q_ref[g], kv, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
             s = jnp.where(seen, s * scale, NEG_INF)      # (tile, span)
-            m_prev = m_ref[h]
+            m_prev = m_ref[g]
             m_new = jnp.maximum(m_prev,
                                 jnp.max(s, axis=1, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new[:, :1])
-            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1,
+            l_ref[g] = l_ref[g] * corr + jnp.sum(p, axis=1,
                                                  keepdims=True)
-            acc_ref[h] = acc_ref[h] * corr[:, :1] + lax.dot_general(
-                p.astype(kv.dtype), kv, (((1,), (0,)), ((), ())),
+            v = kv if value == (0, width) else kv[:, value[0]:value[1]]
+            acc_ref[g] = acc_ref[g] * corr[:, :1] + lax.dot_general(
+                p.astype(kv.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
+            m_ref[g] = m_new
             return carry
 
         # one traced body, unrolled when lowered: the compiled kernel
         # is the Python loop's (163.6 us a layer either way with eight
         # slots live at GPT-2 XL, 391 rolled up; my chip run, PR 25)
         # and the host traces a twenty-fifth of it
-        lax.fori_loop(0, heads, head, 0, unroll=True)
+        lax.fori_loop(0, groups, group, 0, unroll=True)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _writeout():
@@ -424,53 +448,71 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
 # query rows one grid step holds: more rows a step reuse each K/V tile
 # the MXU has latched for more work, and cost VMEM
 _ROW_TILE = 128
+# ... and where heads share a row: 32 positions of 32 heads a tile and
+# 512 keys a step.  The accumulator (row tile x value, float32) is
+# rescaled once a step whatever the step's keys, so more keys a step
+# cost less of it.  A chunk of 256 at 8,192 cached rows, one layer at
+# the long-document cell's shapes: 2.94 ms at 8 pages and a tile of 512,
+# 1.81 at 32 pages, 1.37 at 32 pages and a tile of 1,024 (58% of the
+# MXU's peak on the absorbed product); 8 slots decoding at 12,000: 0.97,
+# 0.71, 0.71 (my chip run, PR 27).
+_SHARED_ROW_TILE = 1024
+_SHARED_PAGES = 32
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_size", "rows", "scale", "name", "interpret"))
+    "block_size", "rows", "shared", "value", "scale", "name", "interpret",
+    "window", "tile"))
 def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
-                  scale, name, interpret):
-    """q4: (B, H, Rp, 2D) queries, zero over each head's upper D lanes,
-    Rp a whole number of row tiles; pages: the pool leaf
-    (L, num_slots, H * 2D); layer (1,), tables (B * blocks_per_seq,),
-    starts (B,) int32 are prefetched scalars."""
-    b, h, rp, d2 = q4.shape
+                  shared, value, scale, name, interpret, window=_PAGES,
+                  tile=None):
+    """q4: (B, G, Mp, W) queries, one row of a group's width a tile
+    row, zero over the lanes that are not key, Mp a whole number of row
+    tiles (``shared`` heads' rows of one position side by side); pages:
+    the pool leaf (L, num_slots, G * W); layer (1,), tables
+    (B * blocks_per_seq,), starts (B,) int32 are prefetched scalars."""
+    b, g, mp, gw = q4.shape
     nb = tables.shape[0] // b
     width = pages.shape[2]
-    window = min(_PAGES, nb)
-    tile = min(rp, _ROW_TILE)
+    window = min(window, nb)
+    tile = min(mp, tile or _ROW_TILE)
+    vw = value[1] - value[0]
 
     def page_spec(k):
         def index(bi, ii, ji, layer_ref, tables_ref, starts_ref):
             # the window's k-th page, held at the tile's last live
             # page beyond it: a block index that does not change is
             # not fetched again
-            last = (starts_ref[bi]
-                    + jnp.minimum((ii + 1) * tile, rows) - 1) // block_size
+            last = (starts_ref[bi] + _query_row(jnp.minimum(
+                (ii + 1) * tile, rows * shared) - 1, shared)
+                    ) // block_size
             blk = jnp.minimum(jnp.minimum(ji * window + k, last), nb - 1)
             return layer_ref[0], tables_ref[bi * nb + blk], 0
         return pl.BlockSpec((None, block_size, width), index)
 
-    q_spec = pl.BlockSpec((None, h, tile, d2),
-                          lambda bi, ii, ji, *_: (bi, 0, ii, 0))
+    def rows_spec(lanes):
+        return pl.BlockSpec((None, g, tile, lanes),
+                            lambda bi, ii, ji, *_: (bi, 0, ii, 0))
+
     kernel = functools.partial(_paged_kernel, scale=scale,
-                               block_size=block_size, rows=rows, heads=h,
-                               pages=window)
+                               block_size=block_size, rows=rows, groups=g,
+                               shared=shared, value=value, pages=window)
     itemsize = jnp.dtype(q4.dtype).itemsize
-    vmem = (h * tile * (d2 + 2 * LANES) * 4         # acc, m, l
-            + 2 * 2 * h * tile * d2 * itemsize      # q and out, twice
+    vmem = (g * tile * (vw + 2 * LANES) * 4         # acc, m, l
+            + 2 * g * tile * (gw + vw) * itemsize   # q and out, twice
             + 2 * window * block_size * width * itemsize)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, rp // tile, _cdiv(nb, window)),
-            in_specs=[q_spec] + [page_spec(k) for k in range(window)],
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((h, tile, d2), jnp.float32),
-                            pltpu.VMEM((h, tile, LANES), jnp.float32),
-                            pltpu.VMEM((h, tile, LANES), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype,
+            grid=(b, mp // tile, _cdiv(nb, window)),
+            in_specs=[rows_spec(gw)] + [page_spec(k)
+                                        for k in range(window)],
+            out_specs=rows_spec(vw),
+            scratch_shapes=[pltpu.VMEM((g, tile, vw), jnp.float32),
+                            pltpu.VMEM((g, tile, LANES), jnp.float32),
+                            pltpu.VMEM((g, tile, LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, g, mp, vw), q4.dtype,
                                        vma=union_vma(q4, pages)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -484,28 +526,41 @@ def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
 
 def paged_attention_fits(head_dim: int, block_size: int, dtype) -> bool:
     """Whether :func:`paged_attention` can take a pool of this
-    geometry: a head's ``K | V`` pair has to fill whole 128-lane tiles
-    and a page whole sublane tiles of its dtype, or the kernel's slices
-    would not be aligned."""
+    geometry: a group of ``2 * head_dim`` values (a head's ``K | V``
+    pair, or a latent row) has to fill whole 128-lane tiles and a page
+    whole sublane tiles of its dtype, or the kernel's slices would not
+    be aligned."""
     packing = max(1, 4 // jnp.dtype(dtype).itemsize)
     return (2 * head_dim) % LANES == 0 and block_size % (8 * packing) == 0
 
 
+def _kernel_name(rows: int, prefix: str = "") -> str:
+    """One row, up to a sublane tile of them, more: a trace tells the
+    decode, verify and chunk programs' kernels apart."""
+    return prefix + ("_decode_kernel" if rows == 1 else
+                     "_verify_kernel" if rows <= _QROWS
+                     else "_chunk_kernel")
+
+
 def paged_attention(q, pages, layer, block_tables, starts, *,
                     block_size: int, scale: Optional[float] = None,
+                    latent_value: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Attention of freshly written rows over a paged KV pool, read in
     place through the block table.
 
     Args:
-      q: (B, R, H, D) — R query rows a sequence: 1 (decode), the
-        verify width, or a prefill chunk.  Row ``i`` of sequence ``b``
-        sits at position
+      q: R query rows a sequence: 1 (decode), the verify width, or a
+        prefill chunk.  Row ``i`` of sequence ``b`` sits at position
         ``starts[b] + i`` and attends every key at or before itself, so
-        its own K/V must ALREADY be in the pool.
-      pages: (L, num_slots, H * 2 * D) — the pool leaf as
-        ``serving.kv_cache`` lays it out: one row a token slot, every
-        head's ``K_h`` beside its ``V_h``.
+        its own row must ALREADY be in the pool.  (B, R, H, D) for a
+        pool of ``K | V`` pairs; with ``latent_value`` (B, R, H, W),
+        each head's absorbed query ``q_lat | q_pe`` as wide as the
+        pool's row, zeros over its padding.
+      pages: the pool leaf as ``serving.kv_cache`` lays it out, one row
+        a token slot: (L, num_slots, H * 2 * D), every head's ``K_h``
+        beside its ``V_h``; or (L, num_slots, W), one latent row
+        ``c | k_pe | padding`` that all H heads read.
       layer: int32 scalar, the layer whose pages to read.
       block_tables: (B, blocks_per_seq) int32 physical block ids;
         unallocated entries are 0 (the garbage block) and lie beyond
@@ -513,17 +568,53 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
       starts: (B,) int32 position of each sequence's first row (its
         cached context length).
       block_size: token slots a page.
-      scale: logit scale, default 1/sqrt(D).
+      scale: logit scale, default 1/sqrt(D); a latent pool's caller
+        gives it (the width of the expanded query, not of the row).
+      latent_value: for a latent pool, how many of a row's leading
+        values are also the value (whole lane tiles).
       interpret: Pallas interpret mode (defaults to not-on-TPU).
 
     Only the pages up to each sequence's last row are streamed; nothing
     of ``max_context`` size is built.  fp32 scores, softmax state and
     accumulation; the probabilities meet V in the pool's dtype, as the
-    jnp oracle's do.  Returns (B, R, H, D) in q.dtype.  The Pallas call
-    is named ``_decode_kernel`` for one row, ``_verify_kernel`` for up
-    to a sublane tile of them and ``_chunk_kernel`` beyond, so a trace
-    tells the programs apart.  Inference only."""
+    jnp oracle's do.  Returns (B, R, H, D) in q.dtype, or
+    (B, R, H, latent_value).  The Pallas call is named
+    ``_decode_kernel`` for one row, ``_verify_kernel`` for up to a
+    sublane tile of them and ``_chunk_kernel`` beyond, with
+    ``_latent`` in front for a latent pool, so a trace tells the
+    programs apart.  Inference only."""
     b, r, h, d = q.shape
+    if interpret is None:
+        interpret = not on_tpu()
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1),
+               block_tables.astype(jnp.int32).reshape(-1),
+               starts.astype(jnp.int32))
+    if latent_value is not None:
+        if pages.ndim != 3 or pages.shape[2] != d or scale is None \
+                or latent_value % LANES or not 0 < latent_value <= d:
+            raise ValueError(
+                f"a latent pool is (L, num_slots, W) with queries "
+                f"(B, R, H, W), a scale and a value of whole lane tiles; "
+                f"got pages {pages.shape}, q {q.shape}, scale {scale}, "
+                f"latent_value {latent_value}")
+        if not paged_attention_fits(d // 2, block_size, pages.dtype):
+            raise ValueError(
+                f"paged_attention cannot tile a latent row of {d}, "
+                f"block_size={block_size}, dtype={pages.dtype}")
+        # the heads of one position side by side: row m is (m // H)
+        m = r * h
+        mp = _cdiv(m, 2 * _QROWS) * 2 * _QROWS
+        if mp > _SHARED_ROW_TILE:
+            mp = _cdiv(m, _SHARED_ROW_TILE) * _SHARED_ROW_TILE
+        q4 = jnp.pad(q.astype(pages.dtype).reshape(b, 1, m, d),
+                     ((0, 0), (0, 0), (0, mp - m), (0, 0)))
+        out = _paged_pallas(
+            *scalars, q4, pages, block_size=int(block_size), rows=int(r),
+            shared=int(h), value=(0, int(latent_value)),
+            scale=float(scale), name=_kernel_name(r, "_latent"),
+            interpret=bool(interpret), window=_SHARED_PAGES,
+            tile=_SHARED_ROW_TILE)
+        return out[:, 0, :m].reshape(b, r, h, latent_value).astype(q.dtype)
     if pages.ndim != 3 or pages.shape[2] != h * 2 * d:
         raise ValueError(
             f"pages must be (L, num_slots, H*2*D) = (.., .., {h * 2 * d}) "
@@ -534,19 +625,33 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
             f"block_size={block_size}, dtype={pages.dtype}")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = not on_tpu()
     rp = _cdiv(r, _QROWS) * _QROWS
     if rp > _ROW_TILE:
         rp = _cdiv(r, _ROW_TILE) * _ROW_TILE
     q4 = jnp.pad(jnp.swapaxes(q, 1, 2).astype(pages.dtype),
                  ((0, 0), (0, 0), (0, rp - r), (0, d)))
     out = _paged_pallas(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        block_tables.astype(jnp.int32).reshape(-1),
-        starts.astype(jnp.int32), q4, pages,
-        block_size=int(block_size), rows=int(r), scale=float(scale),
-        name=("_decode_kernel" if r == 1 else
-              "_verify_kernel" if r <= _QROWS else "_chunk_kernel"),
-        interpret=bool(interpret))
+        *scalars, q4, pages, block_size=int(block_size), rows=int(r),
+        shared=1, value=(0, 2 * d), scale=float(scale),
+        name=_kernel_name(r), interpret=bool(interpret))
     return jnp.swapaxes(out[:, :, :r, d:], 1, 2).astype(q.dtype)
+
+
+def latent_attention_reference(q, rows, positions, *, value: int,
+                               scale: float):
+    """The jnp form of latent attention over gathered rows, the parity
+    oracle of :func:`paged_attention`'s latent form and what the CPU
+    runs: ``q`` (B, R, H, W) absorbed queries, ``rows`` (B, T, W) each
+    sequence's logical context with the fed rows already in it (row j
+    is position j), ``positions`` (B, R) each query row's own position.
+    A row attends every key at or before itself.  fp32 scores and
+    softmax; returns (B, R, H, value) in q.dtype."""
+    s = _einsum("brhw,btw->bhrt", q, rows.astype(q.dtype)
+                ).astype(jnp.float32) * scale
+    key = jnp.arange(rows.shape[1], dtype=jnp.int32)
+    seen = key[None, None, :] <= positions[:, :, None]       # (B, R, T)
+    s = jnp.where(seen[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = _einsum("bhrt,btv->brhv", p.astype(q.dtype),
+                  rows[..., :value].astype(q.dtype))
+    return out.astype(q.dtype)

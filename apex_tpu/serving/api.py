@@ -121,6 +121,7 @@ import warnings
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
+import jax
 import numpy as np
 
 from apex_tpu.observability import (
@@ -796,6 +797,7 @@ class InferenceServer:
         self.pipelining = bool(enable_pipeline
                                and self.sample_fn is greedy_sample)
         self._inflight: Optional[_InflightStep] = None
+        self._verify_compiled = set()   # _compile_verify_beside's kinds
         self._pending_produced = 0   # retired outside step() (submit)
         self.pipe = CounterMeter(registry=self.registry,
                                  name="serving_pipeline", label="event")
@@ -1581,6 +1583,7 @@ class InferenceServer:
                          batch=len(running)):
                 ids, fin = engine.decode_sampled(tokens, positions,
                                                  tables, **kw)
+                self._compile_verify_beside(kw)
         except MemoryError:
             self._note_oom("decode")
             return False
@@ -1594,6 +1597,32 @@ class InferenceServer:
             sched.hold_inflight(running)
             self.pipe.incr("launches")
         return True
+
+    def _compile_verify_beside(self, kw) -> None:
+        """On an accelerator, the first decode launch of a kind
+        (greedy or stochastic: ``kw``) is followed by one launch of
+        the verify program of that kind with every row idle, so that
+        the verify program is compiled beside the decode program and
+        not when the first draft turns up: that first draft may come
+        minutes into serving (a model that does not repeat itself gives
+        the n-gram drafter nothing to match), and its compile, 15 to 50
+        s at the benchmark's sizes, would stall every stream.  The idle
+        rows write to the garbage block and count nothing.  The CPU
+        backend, where programs are tests' and compile lazily, skips
+        it, as it skips donation for the sampled programs."""
+        kind = "stoch" if kw else "sampled"
+        if kind in self._verify_compiled:
+            return
+        self._verify_compiled.add(kind)
+        if not self.speculating or jax.default_backend() == "cpu":
+            return
+        engine = self.engine
+        b, k = engine.max_batch_size, self.spec_tokens + 1
+        idle = np.zeros((b,), np.int32)
+        with self.tracer.span("compile_verify", kind=kind):
+            engine.verify_sampled(
+                np.zeros((b, k), np.int32), idle, idle,
+                np.zeros((b, engine.blocks_per_seq), np.int32), **kw)
 
     def _apply_decode_results(self, running, toks, finite,
                               now: Optional[float] = None) -> int:
@@ -2943,6 +2972,11 @@ class InferenceServer:
             # quantization both include the fp32 scale sidecar
             "pool_bytes_per_device": info["pool_bytes_per_device"],
             "bytes_per_block": info["bytes_per_block"],
+            # what a token keeps a layer, as its family says
+            # (models/family.py): "kv" every head's K|V pair, "latent"
+            # one compressed row for all heads, and its bytes as stored
+            "cache_kind": info["cache_kind"],
+            "row_bytes_per_token_layer": info["row_bytes_per_token_layer"],
             "cache_dtype": info["cache_dtype"],
             # quantized KV pool (docs/serving.md, "Quantized KV
             # cache"): storage mode + the compute dtype values widen
@@ -2951,6 +2985,30 @@ class InferenceServer:
             "compute_dtype": info["compute_dtype"],
         }
         return out
+
+    def _expert_stats(self) -> dict:
+        """The ``stats()["experts"]`` block: the rows each expert of
+        each expert layer was given since the start (or the last
+        ``reset_cache``), as the programs counted them on the device in
+        an array carried beside the pool.  It is read here and nowhere
+        else: a step copies nothing of it to the host.  ``{"enabled":
+        False}`` for a family without expert layers."""
+        engines = [e for e in (self.engine, self.prefill_engine)
+                   if e is not None and "routed" in e.cache]
+        if not engines:
+            return {"enabled": False}
+        routed = sum(np.asarray(e.cache["routed"], np.int64)
+                     for e in engines)
+        mean = routed.mean(axis=1)
+        return {
+            "enabled": True,
+            "layers": int(routed.shape[0]),
+            "experts_held": int(routed.shape[1]),
+            "rows_routed": int(routed.sum()),
+            "routed": routed.tolist(),
+            "max_over_mean": [round(float(m), 3) for m in
+                              routed.max(axis=1) / np.maximum(mean, 1e-9)],
+        }
 
     def _disagg_stats(self) -> dict:
         """The pinned ``stats()["disagg"]`` block: hand-off counters
@@ -3235,6 +3293,10 @@ class InferenceServer:
             # KV memory occupancy, high-watermarks, fragmentation
             # (docs/observability.md, "Memory accounting")
             "memory": self._memory_stats(),
+            # routed experts (docs/observability.md, "Expert load"):
+            # rows given to each expert of each expert layer, counted
+            # on the device and read only here
+            "experts": self._expert_stats(),
             # ring-buffer loss accounting: a saturated tracer or
             # recorder silently truncates history — surface it
             "trace_dropped_events": self.tracer.dropped,

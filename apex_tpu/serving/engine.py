@@ -4,9 +4,11 @@ Compiled programs, all fixed-shape so the continuous-batching loop
 never recompiles in steady state:
 
 - **prefill** (one request, prompt padded to a length *bucket*): the
-  ordinary causal GPT forward — optionally through the flash kernel
-  via ``attention_fn`` — with ``return_kv=True``; the per-layer K/V
-  are scattered into the request's blocks in the same program.  One
+  model's ordinary causal forward — for GPT optionally through the
+  flash kernel via ``attention_fn`` — with ``return_kv=True``; the
+  per-layer rows (a head's K and V, or a latent row: what the model's
+  family keeps, ``models/family.py``) are scattered into the request's
+  blocks in the same program.  One
   trace per bucket length, so the compile count is bounded by
   ``len(prefill_buckets)``, not by the distribution of prompt lengths.
 - **chunk prefill** (one request, one fixed-width chunk at a carried
@@ -56,8 +58,10 @@ How a program attends is fixed when the engine is built
 ``stats()["programs"]["attention"]``), from what can be seen then:
 
 - ``"table"`` — *attend through the table*: an unquantized pool on one
-  TPU device whose geometry the kernel tiles (``2 * head_dim`` a
-  multiple of 128 lanes, ``block_size`` of whole sublane tiles).
+  TPU device whose geometry the kernel tiles (a group of the row —
+  a head's ``K | V`` pair of ``2 * head_dim`` values, or a latent row —
+  a multiple of 128 lanes, a latent row's value too, ``block_size`` of
+  whole sublane tiles).
   Decode, verify and chunk prefill write a layer's rows, then
   ``ops.decode_attention.paged_attention`` takes the pool itself with
   the block tables and lengths as prefetched scalars and streams only
@@ -95,9 +99,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.observability import NULL_PROGRAM_ACCOUNTING, NULL_TRACER
-from apex_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from apex_tpu.ops.decode_attention import paged_attention_fits
-from apex_tpu.ops.pallas_utils import on_tpu, pallas_auto_gate
+from apex_tpu.ops.pallas_utils import LANES, on_tpu, pallas_auto_gate
 from apex_tpu.ops.sampling import finite_rows, greedy_argmax, sample_tokens
 from apex_tpu.ops.vocab_parallel import (
     vocab_parallel_sample,
@@ -110,12 +113,13 @@ from apex_tpu.serving.kv_cache import (
     copy_blocks,
     copy_blocks_across,
     init_kv_cache,
+    pool_leaves,
     pool_specs,
     read_blocks,
     resolve_kv_quant,
     slot_index,
     write_blocks,
-    write_prefill,
+    write_layer,
 )
 
 # CPU backends can't honor donation; the fallback copy is exactly the
@@ -161,7 +165,16 @@ class DecodeEngine:
     ``serving.scheduler``/``serving.api``.
 
     Args:
-      cfg: the GPT architecture (params must match).
+      cfg: the model's configuration object, of any family that tells
+        the engine what it needs (``models/family.py``): how to build
+        the model (``cfg.build_model``), what one token keeps in one
+        layer of the pool (``cfg.cache_row()``: every head's ``K | V``
+        pair for ``models.GPTConfig``, one latent row shared by all
+        heads for ``models.DeepseekV3Config``), and ``vocab_size``,
+        ``num_hidden_layers``, ``max_position_embeddings``.  The
+        family is told from the object; there is no switch.  What a
+        latent pool does not do yet (``kv_quant="int8"``, ``mesh``)
+        raises here with its reason.
       params: the model's ``{"params": ...}["params"]`` pytree (pass
         amp-cast params to serve in half).
       max_batch_size: decode batch width (running-request slots).
@@ -219,7 +232,7 @@ class DecodeEngine:
         (default ``"model"``).
     """
 
-    def __init__(self, cfg: GPTConfig, params, *,
+    def __init__(self, cfg, params, *,
                  max_batch_size: int = 8,
                  max_context: Optional[int] = None,
                  num_blocks: Optional[int] = None,
@@ -234,6 +247,7 @@ class DecodeEngine:
                  tp_rules=None,
                  tp_axis: str = "model"):
         self.cfg = cfg
+        self.row = row = cfg.cache_row()
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.programs = (programs if programs is not None
                          else NULL_PROGRAM_ACCOUNTING)
@@ -245,6 +259,17 @@ class DecodeEngine:
         self._repl = None         # replicated placement for launch args
         self._pool_shard = None   # the pool's head-sharded placement
         self._scale_shard = None  # the scale sidecar's (heads last)
+        if row.shared and (self.quantized or mesh is not None):
+            # both split or scale the row by heads, and a row that all
+            # heads share has none
+            raise NotImplementedError(
+                f"a {row.kind!r} pool keeps one row of {row.used} values "
+                f"for all {row.heads} query heads: "
+                + ("kv_quant='int8' stores one scale a head for a K|V "
+                   "pair" if self.quantized else
+                   "mesh=... shards the pool's row by whole heads")
+                + ", and this row has neither.  Open work: ROADMAP.md "
+                "Reach.")
         if mesh is not None:
             if tp_axis not in mesh.shape:
                 raise ValueError(
@@ -266,9 +291,9 @@ class DecodeEngine:
                     "the mesh.  Open work: CHANGES.md PR 21, ROADMAP.md "
                     "Speed item 10.  One process can instead serve one "
                     "unsharded replica on each chip.")
-            if cfg.num_attention_heads % self.tp:
+            if row.groups % self.tp:
                 raise ValueError(
-                    f"num_attention_heads={cfg.num_attention_heads} "
+                    f"num_attention_heads={row.groups} "
                     f"must divide the {tp_axis!r} axis ({self.tp}) — "
                     "the KV pool shards its heads dim, so every "
                     "device must hold a whole number of heads")
@@ -301,8 +326,8 @@ class DecodeEngine:
             num_blocks = self.max_batch_size * self.blocks_per_seq + 1
         self.cache_cfg = KVCacheConfig(
             num_layers=cfg.num_hidden_layers,
-            num_heads=cfg.num_attention_heads,
-            head_dim=cfg.hidden_size // cfg.num_attention_heads,
+            num_heads=row.groups,
+            head_dim=row.group_width // 2,
             num_blocks=int(num_blocks),
             block_size=self.block_size,
             dtype=cache_dtype,
@@ -318,14 +343,18 @@ class DecodeEngine:
                     and pallas_auto_gate()
                     and paged_attention_fits(
                         self.cache_cfg.head_dim, self.block_size,
-                        self.cache_cfg.storage_dtype()))
+                        self.cache_cfg.storage_dtype())
+                    # a shared row's value is sliced from it by lanes
+                    and not (row.shared and row.value[1] % LANES))
         self.attention_paths = dict.fromkeys(
             ("decode", "verify", "chunk_prefill"),
             "table" if in_place else "gathered")
-        self.cache = init_kv_cache(self.cache_cfg,
-                                   sharding=self._pool_shard,
-                                   scale_sharding=self._scale_shard)
-        self.model = GPTLMHeadModel(cfg, attention_fn=attention_fn)
+        self.model = cfg.build_model(attention_fn=attention_fn,
+                                     kv_quant=self.quantized)
+        # what the family's programs carry beside the pool
+        self._counters = dict(getattr(cfg, "serving_counters",
+                                      dict)())
+        self.cache = self._fresh_cache()
         if prefill_buckets is None:
             prefill_buckets = default_prefill_buckets(self.max_context)
         self.prefill_buckets = tuple(sorted(int(b)
@@ -347,12 +376,12 @@ class DecodeEngine:
                            out_shardings=outs)
 
         cache_sh = None
+        repl = self._repl
         if self.mesh is not None:
             cache_sh = {"kv": self._pool_shard}
             if self.quantized:
                 cache_sh["k_scale"] = self._scale_shard
                 cache_sh["v_scale"] = self._scale_shard
-        repl = self._repl
         self._prefill_jit = _jit(self._prefill_impl, (1,),
                                  (cache_sh, repl))
         self._decode_jit = _jit(self._decode_impl, (1,),
@@ -422,27 +451,21 @@ class DecodeEngine:
 
     # -- compiled bodies --------------------------------------------------
 
+    def _fresh_cache(self):
+        """The zeroed pool and, beside it, the family's counters."""
+        cache = init_kv_cache(self.cache_cfg, sharding=self._pool_shard,
+                              scale_sharding=self._scale_shard)
+        for name, shape in self._counters.items():
+            cache[name] = jnp.zeros(shape, jnp.int32)
+        return cache
+
     def _view(self, program, cache, tables, start, slots):
         """The model's view of the pool for one launch of ``program``
         (``kv_cache.CacheView``)."""
         return CacheView(
             cache, tables, start.astype(jnp.int32), slots,
-            block_size=self.block_size,
-            num_heads=self.cache_cfg.num_heads,
+            block_size=self.block_size, row=self.row,
             table=self.attention_paths[program] == "table")
-
-    def _stack_kvs(self, kvs):
-        """Stack the monolithic prefill's per-layer fresh K/V into the
-        layout ``write_prefill`` expects: plain
-        (k, v) arrays, or the quantized
-        ``((k_q, k_scale), (v_q, v_scale))`` quadruple."""
-        if self.quantized:
-            return ((jnp.stack([kv[0][0] for kv in kvs]),
-                     jnp.stack([kv[0][1] for kv in kvs])),
-                    (jnp.stack([kv[1][0] for kv in kvs]),
-                     jnp.stack([kv[1][1] for kv in kvs])))
-        return (jnp.stack([kv[0] for kv in kvs]),
-                jnp.stack([kv[1] for kv in kvs]))
 
     def _prefill_impl(self, params, cache, ids, length, table):
         """ids (1, Sb) zero-padded prompt; length (1,) true length;
@@ -453,13 +476,12 @@ class DecodeEngine:
         mask = (pos < length[:, None]).astype(jnp.int32)
         logits, kvs = self.model.apply(
             {"params": params}, ids, attention_mask=mask,
-            deterministic=True, return_kv=True,
-            kv_quant=self.quantized)
-        kv_new = self._stack_kvs(kvs)                 # (L, 1, Sb, H, D)
+            deterministic=True, return_kv=True)
         # padded positions scatter into the garbage block (slot 0)
         slots = jnp.where(mask > 0,
                           slot_index(table, pos, self.block_size), 0)
-        cache = write_prefill(cache, kv_new, slots)
+        for layer, kv in enumerate(kvs):     # each an in-place update
+            cache = write_layer(cache, layer, kv, slots)
         last = jnp.take_along_axis(
             logits, (length[:, None, None] - 1).astype(jnp.int32),
             axis=1)[:, 0]                             # (1, V)
@@ -519,7 +541,7 @@ class DecodeEngine:
             {"params": params}, ids, positions=pos_emb,
             deterministic=True,
             cache_views=self._view(program, cache, tables, start, slots),
-            return_kv=True, kv_quant=self.quantized)
+            return_kv=True)
         return logits, view.cache
 
     def _copy_impl(self, cache, src, dst):
@@ -558,7 +580,7 @@ class DecodeEngine:
             deterministic=True,
             cache_views=self._view("decode", cache, tables, positions,
                                    slots[:, None]),
-            return_kv=True, kv_quant=self.quantized)
+            return_kv=True)
         return view.cache, logits[:, 0]               # (B, V)
 
     # -- fused on-device-sampling bodies ----------------------------------
@@ -927,7 +949,8 @@ class DecodeEngine:
             leaves = self._export_jit(
                 self.cache, *self._put(np.asarray(block_ids, np.int32)))
         else:
-            leaves = {name: arr[:, :0] for name, arr in self.cache.items()}
+            leaves = {name: arr[:, :0] for name, arr in
+                      pool_leaves(self.cache).items()}
         leaves = {name: np.ascontiguousarray(np.asarray(arr))
                   for name, arr in leaves.items()}
         bs = self.block_size
@@ -963,10 +986,10 @@ class DecodeEngine:
                 f"{payload.get('block_size')} slots, importing "
                 f"{len(block_ids)} blocks of {self.block_size}")
         leaves = payload["leaves"]
-        if set(leaves) != set(self.cache):
+        if set(leaves) != set(pool_leaves(self.cache)):
             raise ValueError(
                 f"hand-off payload leaves {sorted(leaves)} != pool "
-                f"leaves {sorted(self.cache)} (quantization modes "
+                f"leaves {sorted(pool_leaves(self.cache))} (quantization modes "
                 f"must match across replicas)")
         for name, arr in leaves.items():
             got = zlib.crc32(np.ascontiguousarray(arr).tobytes())
@@ -1171,7 +1194,8 @@ class DecodeEngine:
     def memory_info(self) -> dict:
         """Static pool geometry for ``stats()["memory"]`` and
         postmortem manifests: usable blocks, tokens per block, the
-        pool's LOGICAL footprint (both K and V, all shards), and —
+        pool's LOGICAL footprint (whole rows, all shards), what a row is
+        (``cache_kind``, ``row_bytes_per_token_layer``), and —
         what per-chip HBM budgeting must use — the ACTUAL per-device
         bytes, read off the live arrays' shard shape and dtype (under
         tensor parallelism each device holds ``num_heads/tp`` heads of
@@ -1196,7 +1220,7 @@ class DecodeEngine:
         per_device = sum(
             int(np.prod(arr.sharding.shard_shape(arr.shape)))
             * jnp.dtype(arr.dtype).itemsize
-            for arr in self.cache.values())
+            for arr in pool_leaves(self.cache).values())
         return {
             "blocks_usable": cfg.num_blocks - 1,
             "block_size": cfg.block_size,
@@ -1204,6 +1228,8 @@ class DecodeEngine:
             "pool_bytes": cfg.bytes(),
             "pool_bytes_per_device": per_device,
             "bytes_per_block": cfg.bytes_per_block,
+            "cache_kind": self.row.kind,
+            "row_bytes_per_token_layer": cfg.row_bytes,
             "decode_temp_bytes": temp,
             "cache_dtype": str(cfg.storage_dtype()),
             "quantize": cfg.quantize,
@@ -1232,7 +1258,5 @@ class DecodeEngine:
     def reset_cache(self):
         """Zero the pool and refill the allocator in place (between
         workloads; schedulers holding the allocator stay wired)."""
-        self.cache = init_kv_cache(self.cache_cfg,
-                                   sharding=self._pool_shard,
-                                   scale_sharding=self._scale_shard)
+        self.cache = self._fresh_cache()
         self.allocator.reset()

@@ -41,6 +41,20 @@ and ``2 * head_dim`` = 128 is one head's ``K | V`` pair exactly.  Then
 - the lane dimension is heads-major, so tensor parallelism splits it
   into whole heads (``pool_specs``).
 
+What a row holds is the model family's to say
+(``models.family.CacheRow``, from ``cfg.cache_row()``).  The leaf is
+``(num_layers, num_slots, row width)`` for both kinds the pool can hold:
+
+- ``"kv"``: every head's ``K_h | V_h`` pair, as above;
+- ``"latent"``: ONE compressed row ``c | k_pe`` a token and layer
+  (latent attention: 512 + 64 values where 32 heads of 192 + 128 would
+  be 10,240), padded with zeros to whole lane tiles (640), which all
+  query heads read and whose first ``rank`` values are also the value.
+  The model hands :meth:`CacheView.attend` its absorbed queries.
+
+Writes, block copies, hand-off payloads, the allocator and the prefix
+cache's block hashing see rows of some width and nothing else.
+
 Token slots are axis 1 of every leaf (the scale sidecar's too), which
 is what the hand-off and offload payloads slice by.  The forms that
 index ACROSS layers at once (``arr.at[:, slots]``) are the ones to
@@ -101,6 +115,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from apex_tpu.models.family import CacheRow
 # the quantization numeric contract lives with the kernels that widen
 # it back (ops); re-exported here because the cache is what stores it
 from apex_tpu.ops.kv_quant import (  # noqa: F401  (re-export)
@@ -167,6 +182,13 @@ def resolve_cache_dtype(dtype=None):
 class KVCacheConfig:
     """Geometry of the block pool.
 
+    A token's row in one layer is ``num_heads`` groups of
+    ``2 * head_dim`` values (:attr:`row_width` in all): a head's ``K``
+    beside its ``V``, ``head_dim`` each, or ONE group for a latent row
+    that all query heads share (``num_heads`` 1, ``head_dim`` half the
+    stored row; ``DecodeEngine`` fills both from the model family's
+    ``CacheRow``).  All byte accounting goes by the row's width.
+
     ``num_blocks`` INCLUDES the reserved garbage block 0, so the
     usable capacity is ``(num_blocks - 1) * block_size`` tokens.
     ``dtype=None`` defers to :func:`resolve_cache_dtype`.
@@ -206,6 +228,18 @@ class KVCacheConfig:
         return self.num_blocks * self.block_size
 
     @property
+    def row_width(self) -> int:
+        """Stored values of one token in one layer: the pool leaf's
+        minor dimension."""
+        return self.num_heads * 2 * self.head_dim
+
+    @property
+    def row_bytes(self) -> int:
+        """HBM bytes of one token in one layer, scale sidecar
+        included."""
+        return self.bytes_per_block // (self.num_layers * self.block_size)
+
+    @property
     def usable_tokens(self) -> int:
         return (self.num_blocks - 1) * self.block_size
 
@@ -234,18 +268,17 @@ class KVCacheConfig:
 
     @property
     def bytes_per_block(self) -> int:
-        """TRUE HBM cost of one physical block — K + V payload plus
+        """TRUE HBM cost of one physical block — the rows' payload plus
         the scale sidecar under quantization.  The allocator's
         occupancy/fragmentation math and the fixed-pool-bytes bench
         arms price blocks with this, so quantized headroom claims are
         net of the sidecar."""
-        payload = (2 * self.num_layers * self.block_size
-                   * self.num_heads * self.head_dim
+        payload = (self.num_layers * self.block_size * self.row_width
                    * self.storage_dtype().itemsize)
         return payload + self.scale_bytes_per_block
 
     def bytes(self) -> int:
-        """HBM footprint of the pool (both K and V, scale sidecar
+        """HBM footprint of the pool (whole rows, scale sidecar
         included when quantized)."""
         return self.num_blocks * self.bytes_per_block
 
@@ -264,8 +297,8 @@ def pool_specs(axis):
 def init_kv_cache(cfg: KVCacheConfig, sharding=None,
                   scale_sharding=None):
     """Allocate the zeroed pool: ``{"kv"}`` of shape
-    (L, num_slots, H * 2 * D) in the storage dtype (the layout the
-    module docstring derives), plus — under ``quantize="int8"`` — the
+    (L, num_slots, ``cfg.row_width``) in the storage dtype (the layout
+    the module docstring derives), plus — under ``quantize="int8"`` — the
     fp32 scale sidecar ``{"k_scale", "v_scale"}`` each
     (L, num_slots, H).  Token slots are axis 1 of every leaf.
 
@@ -276,8 +309,7 @@ def init_kv_cache(cfg: KVCacheConfig, sharding=None,
     ``out_shardings``), never allocated whole and scattered.
     ``scale_sharding`` is the sidecar's placement, so scales live on
     the same shard as the heads they dequantize."""
-    shape = (cfg.num_layers, cfg.num_slots,
-             cfg.num_heads * 2 * cfg.head_dim)
+    shape = (cfg.num_layers, cfg.num_slots, cfg.row_width)
     dt = cfg.storage_dtype()
 
     def build():
@@ -335,8 +367,10 @@ def slot_index(block_tables, positions, block_size: int):
 def write_layer(cache, layer, kv, slots):
     """Write one layer's fresh rows into the (donated) pool, in place.
 
-    kv: ``(k, v)`` each (B, S, H, D); slots: (B, S) flat slot indices
-    (padded positions pointed at the garbage block by the caller).
+    kv: ``(k, v)`` each (B, S, H, D), or the rows themselves (B, S, W)
+    where the model makes them whole (a latent row; zeros fill the
+    pool's lane padding); slots: (B, S) flat slot indices (padded
+    positions pointed at the garbage block by the caller).
     Under quantization kv is ``((k_q, k_scale), (v_q, v_scale))`` with
     int8 payloads and (B, S, H) fp32 scales — ALREADY quantized by the
     model's projection path, so the pool receives byte-for-byte the
@@ -346,18 +380,22 @@ def write_layer(cache, layer, kv, slots):
     ``[layer, slots]`` compiles to an update of the donated buffer with
     no temporary, where one over every layer at once (``[:, slots]``)
     makes XLA:TPU transpose the whole leaf there and back."""
-    k, v = kv
     flat = slots.reshape(-1)
     out = dict(cache)
-    if "k_scale" in cache:
-        (k, ks), (v, vs) = k, v
-        out["k_scale"] = cache["k_scale"].at[layer, flat].set(
-            ks.reshape(-1, ks.shape[-1]))
-        out["v_scale"] = cache["v_scale"].at[layer, flat].set(
-            vs.reshape(-1, vs.shape[-1]))
-    rows = pack_rows(k, v).astype(cache["kv"].dtype)
+    if isinstance(kv, tuple):
+        k, v = kv
+        if "k_scale" in cache:
+            (k, ks), (v, vs) = k, v
+            out["k_scale"] = cache["k_scale"].at[layer, flat].set(
+                ks.reshape(-1, ks.shape[-1]))
+            out["v_scale"] = cache["v_scale"].at[layer, flat].set(
+                vs.reshape(-1, vs.shape[-1]))
+        rows = pack_rows(k, v)
+    else:
+        rows = jnp.pad(kv, [(0, 0)] * (kv.ndim - 1) + [
+            (0, cache["kv"].shape[-1] - kv.shape[-1])])
     out["kv"] = cache["kv"].at[layer, flat].set(
-        rows.reshape(-1, rows.shape[-1]))
+        rows.astype(cache["kv"].dtype).reshape(-1, rows.shape[-1]))
     return out
 
 
@@ -436,6 +474,17 @@ def gather_context(cache, block_tables, block_size: int, num_heads: int,
     return k, v
 
 
+POOL_LEAVES = ("kv", "k_scale", "v_scale")
+
+
+def pool_leaves(cache):
+    """The leaves of the cache pytree that are indexed by token slot:
+    the payload and, quantized, its scale sidecar.  What else a family
+    carries beside the pool (``cfg.serving_counters()``) moves with no
+    block."""
+    return {n: a for n, a in cache.items() if n in POOL_LEAVES}
+
+
 def pool_dtype(cache):
     """The dtype the pool's K/V payload is stored in."""
     return cache["kv"].dtype
@@ -483,7 +532,7 @@ def read_blocks(cache, block_ids, block_size: int):
         [lax.dynamic_slice_in_dim(arr, block_ids[i] * block_size,
                                   block_size, 1)
          for i in range(block_ids.shape[0])], axis=1)
-        for name, arr in cache.items()}
+        for name, arr in pool_leaves(cache).items()}
 
 
 def write_blocks(cache, block_ids, leaves, block_size: int):
@@ -497,10 +546,10 @@ def write_blocks(cache, block_ids, leaves, block_size: int):
                                           block_size, 1),
             block_ids[i] * block_size, 1)
 
-    return {name: lax.fori_loop(
+    return {**cache, **{name: lax.fori_loop(
         0, block_ids.shape[0],
         functools.partial(put, rows=leaves[name].astype(arr.dtype)), arr)
-        for name, arr in cache.items()}
+        for name, arr in pool_leaves(cache).items()}}
 
 
 def copy_blocks_across(dst_cache, src_cache, src, dst, block_size: int):
@@ -545,9 +594,10 @@ def copy_blocks(cache, src, dst, block_size: int):
 # what the model sees of the pool
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("block_size",))
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "scale", "latent_value"))
 def _attend_in_place(pool, layer, q, kv, tables, start, slots, *,
-                     block_size):
+                     block_size, scale=None, latent_value=None):
     """One layer of the table path: write the fed rows, then attend
     the pool in place.  A jitted function with ``layer`` an array, so
     that a program of many layers traces and lowers it once and calls
@@ -559,52 +609,85 @@ def _attend_in_place(pool, layer, q, kv, tables, start, slots, *,
 
     pool = write_layer({"kv": pool}, layer, kv, slots)["kv"]
     return paged_attention(q, pool, layer, tables, start,
-                           block_size=block_size), pool
-
+                           block_size=block_size, scale=scale,
+                           latent_value=latent_value), pool
 
 
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=("cache", "tables", "start", "slots"),
-    meta_fields=("block_size", "num_heads", "table"))
+    meta_fields=("block_size", "row", "table"))
 @dataclasses.dataclass(frozen=True)
 class CacheView:
     """The pool as one serving launch sees it, threaded through the
     model's layers: each layer's attention calls :meth:`attend` with its
-    queries and fresh K/V and gets back its context and the view with
+    queries and fresh rows and gets back its context and the view with
     the pool updated.  The model never learns the pool's layout.
 
     ``tables`` (B, blocks_per_seq) and ``start`` (B,) — each
     sequence's cached length, the position of its first fed row —
     address the reads; ``slots`` (B, S) are the flat slots the S fed
     rows are written to (invalid ones pointed at the garbage block).
-    ``table`` picks the attention path, fixed when the engine builds
-    its programs (``DecodeEngine.attention_paths``):
+    ``row`` is what the model's family keeps a token and layer
+    (``models.family.CacheRow``).  ``table`` picks the attention path,
+    fixed when the engine builds its programs
+    (``DecodeEngine.attention_paths``):
 
     - ``True``, *attend through the table*: the layer's rows are
       written first, then :func:`ops.decode_attention.paged_attention`
       reads the pool in place, only the pages up to each sequence's
       last row.  No gathered copy, no bias row, no concatenation.
     - ``False``, *gathered*: the layer's context is gathered block by
-      block (:func:`gather_layer`), the fresh K/V concatenated behind
-      it, and the jnp-or-kernel ops of the gathered form
-      (``ops.cached_attention`` for one row,
-      ``ops.chunk_cached_attention`` for more) attend it; then the rows
-      are written.  The int8 pool, a mesh and the CPU take this
-      path."""
+      block (:func:`gather_layer`) and the jnp-or-kernel ops of the
+      gathered form attend it (``ops.cached_attention`` for one row,
+      ``ops.chunk_cached_attention`` for more, over the fresh K/V
+      concatenated behind the context; a latent pool's
+      ``ops.decode_attention.latent_attention_reference`` over the
+      context with the rows written into it).  The int8 pool, a mesh
+      and the CPU take this path.
+
+    ``cache`` is the engine's whole cache pytree: the pool's leaves
+    and, beside them, the counters a family carries through its
+    programs (:meth:`count`)."""
 
     cache: dict
     tables: jax.Array
     start: jax.Array
     slots: jax.Array
     block_size: int
-    num_heads: int
+    row: CacheRow
     table: bool
 
-    def attend(self, layer: int, q, kv):
-        """``q`` (B, S, H, D) and the layer's fresh ``kv`` — ``(k, v)``
-        or, quantized, ``((k_q, k_scale), (v_q, v_scale))`` — to
-        ``(context (B, S, H, D), the view after the write)``."""
+    @property
+    def live(self):
+        """(B, S) which fed rows are tokens: the rows of idle slots and
+        padding were pointed at the garbage block."""
+        return self.slots >= self.block_size
+
+    def count(self, name: str, index: int, amounts):
+        """The view with ``amounts`` added to row ``index`` of the
+        counter ``name`` the family declared
+        (``cfg.serving_counters()``): accumulated on the device, read
+        only when somebody asks (``stats()``)."""
+        cache = dict(self.cache)
+        cache[name] = cache[name].at[index].add(
+            amounts.astype(cache[name].dtype))
+        return dataclasses.replace(self, cache=cache)
+
+    def attend(self, layer: int, q, kv, scale=None):
+        """The fed rows' attention over their cached past and
+        themselves, causally, and the view after the write.
+
+        A ``"kv"`` row: ``q`` (B, S, H, D) and the layer's fresh ``kv``
+        — ``(k, v)`` or, quantized, ``((k_q, k_scale), (v_q,
+        v_scale))`` — to ``(context (B, S, H, D), view)``.  A
+        ``"latent"`` row: ``q`` (B, S, H, used) the absorbed queries
+        ``q_lat | q_pe``, ``kv`` (B, S, used) the rows ``c | k_pe``,
+        ``scale`` the expanded form's (a ``"kv"`` row's is always
+        ``1/sqrt(D)``) — to ``(context over the value
+        lanes (B, S, H, rank), view)``."""
+        if self.row.shared:
+            return self._attend_latent(layer, q, kv, scale)
         from apex_tpu.ops.decode_attention import (
             cached_attention,
             chunk_cached_attention,
@@ -614,9 +697,10 @@ class CacheView:
             ctx, pool = _attend_in_place(
                 self.cache["kv"], np.int32(layer), q, kv, self.tables,
                 self.start, self.slots, block_size=self.block_size)
-            return ctx, dataclasses.replace(self, cache={"kv": pool})
+            return ctx, dataclasses.replace(
+                self, cache={**self.cache, "kv": pool})
         ctx_kv = gather_layer(self.cache, layer, self.tables,
-                              self.block_size, self.num_heads)
+                              self.block_size, self.row.groups)
         k, v = kv
         ks = vs = None
         if "k_scale" in self.cache:
@@ -641,6 +725,32 @@ class CacheView:
                                          k_scale=ks, v_scale=vs)
         return ctx, dataclasses.replace(
             self, cache=write_layer(self.cache, layer, kv, self.slots))
+
+    def _attend_latent(self, layer, q, rows, scale):
+        """The latent row's :meth:`attend`: one absorbed path for
+        decode, verify and chunk alike."""
+        from apex_tpu.ops.decode_attention import (
+            latent_attention_reference,
+        )
+
+        width, rank = self.row.width, self.row.value[1]
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, width - q.shape[-1]),))
+        if self.table:
+            ctx, pool = _attend_in_place(
+                self.cache["kv"], np.int32(layer), q, rows, self.tables,
+                self.start, self.slots, block_size=self.block_size,
+                scale=float(scale), latent_value=rank)
+            return ctx, dataclasses.replace(
+                self, cache={**self.cache, "kv": pool})
+        cache = write_layer(self.cache, layer, rows, self.slots)
+        b, mb = self.tables.shape
+        ctx_rows = _by_block(cache["kv"], self.block_size)[layer][
+            self.tables].reshape(b, mb * self.block_size, width)
+        positions = self.start[:, None] + jnp.arange(
+            q.shape[1], dtype=jnp.int32)[None, :]
+        ctx = latent_attention_reference(q, ctx_rows, positions,
+                                         value=rank, scale=float(scale))
+        return ctx, dataclasses.replace(self, cache=cache)
 
 
 # ---------------------------------------------------------------------------

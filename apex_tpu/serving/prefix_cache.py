@@ -442,16 +442,25 @@ class PrefixCache:
         ones sitting in the LRU (a descendant still referenced by a
         live table merely loses shareability)."""
         freed = 0
-        for child in list(self._children.get(blk, ())):
-            freed += self._evict_subtree(child)
-        h = self._hash_of.get(blk)    # before _unregister drops it
-        self._unregister(blk)
-        if blk in self._lru:
-            del self._lru[blk]
-            if self._offload is not None and h is not None:
-                self._demote_pending.append((blk, h))
-            self.allocator.release_to_free(blk)
-            freed += 1
+        # children before their parent, without recursion: a prompt of
+        # 16k tokens is a chain of a thousand blocks, deeper than the
+        # interpreter's stack allows
+        stack = [(blk, False)]
+        while stack:
+            blk, children_done = stack.pop()
+            if not children_done:
+                stack.append((blk, True))
+                stack.extend((child, False) for child in reversed(
+                    list(self._children.get(blk, ()))))
+                continue
+            h = self._hash_of.get(blk)    # before _unregister drops it
+            self._unregister(blk)
+            if blk in self._lru:
+                del self._lru[blk]
+                if self._offload is not None and h is not None:
+                    self._demote_pending.append((blk, h))
+                self.allocator.release_to_free(blk)
+                freed += 1
         return freed
 
     def _flush_demotes(self) -> None:

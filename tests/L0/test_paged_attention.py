@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from apex_tpu import models
+from apex_tpu.models import CacheRow
 from apex_tpu.ops.decode_attention import (
     _reference,
     chunk_cached_attention,
@@ -111,7 +112,7 @@ def test_table_path_matches_gathered_oracle(start, rows, heads, dtype):
     cfg, cache, tables, starts, slots, q, fresh = _scene(
         start, rows, heads, 64, dtype)
     view = CacheView(cache, tables, starts, slots, block_size=BS,
-                     num_heads=heads, table=True)
+                     row=CacheRow.kv(heads, 64), table=True)
     layer = 1
     got, after = view.attend(layer, q, fresh)
     want = _oracle(cfg, cache, tables, starts, q, fresh, layer)
@@ -136,13 +137,13 @@ def test_gathered_view_is_the_oracle(rows):
     cfg, cache, tables, starts, slots, q, fresh = _scene(
         BS + 1, rows, 4, 64, jnp.float32)
     view = CacheView(cache, tables, starts, slots, block_size=BS,
-                     num_heads=4, table=False)
+                     row=CacheRow.kv(4, 64), table=False)
     got, after = view.attend(0, q, fresh)
     want = _oracle(cfg, cache, tables, starts, q, fresh, 0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-6, rtol=1e-6)
     table_view = CacheView(cache, tables, starts, slots, block_size=BS,
-                           num_heads=4, table=True)
+                           row=CacheRow.kv(4, 64), table=True)
     np.testing.assert_array_equal(
         np.asarray(after.cache["kv"]),
         np.asarray(table_view.attend(0, q, fresh)[1].cache["kv"]))

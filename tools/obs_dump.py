@@ -1,7 +1,16 @@
 """Pretty-print observability artifacts: registry snapshots and traces.
 
-Two subcommands over the two export formats of
-``apex_tpu.observability`` (``docs/observability.md``):
+Subcommands over the two export formats of
+``apex_tpu.observability`` (``docs/observability.md``) and over a
+server's ``stats()``:
+
+``stats PATH``
+    PATH is ``json.dump(server.stats(), f)``.  Prints what the pool
+    keeps a token and layer (``memory.cache_kind``, its bytes as
+    stored, the pool's bytes) and, for a family with expert layers, the
+    ``experts`` block: rows routed in all, and for each expert layer
+    its largest-over-mean ratio and its busiest and idlest experts
+    (``docs/observability.md``, "Expert load").
 
 ``metrics PATH``
     PATH is either a ``MetricsRegistry.emit_jsonl`` scrape file (each
@@ -44,6 +53,7 @@ name absent from the trace — exits 1 with a ``FAIL: ...`` line,
 never a traceback.
 
 Usage:
+    python tools/obs_dump.py stats stats.json
     python tools/obs_dump.py metrics scrape.jsonl
     python tools/obs_dump.py trace trace.json --require admit --require decode
     python tools/obs_dump.py trace trace.json --require 'engine_oom{site=decode}'
@@ -296,9 +306,44 @@ def dump_trace(args) -> int:
     return rc
 
 
+def dump_stats(args) -> int:
+    try:
+        with open(args.path) as f:
+            st = json.load(f)
+        mem = st["memory"]
+    except (OSError, ValueError, KeyError, TypeError) as e:
+        print(f"FAIL: {args.path} is no stats() dump: {e!r}",
+              file=sys.stderr)
+        return 1
+    print(f"cache: kind {mem.get('cache_kind', '?')}, "
+          f"{mem.get('row_bytes_per_token_layer', '?')} B a token and "
+          f"layer, pool {mem['pool_bytes']} B "
+          f"({mem['pool_bytes_per_device']} B a device), "
+          f"{mem['bytes_per_block']} B a block, dtype "
+          f"{mem['cache_dtype']}")
+    ex = st.get("experts") or {"enabled": False}
+    if not ex.get("enabled"):
+        print("experts: none (this family has no expert layers)")
+        return 0
+    print(f"experts: {ex['layers']} layers x {ex['experts_held']} held, "
+          f"{ex['rows_routed']} rows routed")
+    for i, (routed, ratio) in enumerate(zip(ex["routed"],
+                                            ex["max_over_mean"])):
+        order = sorted(range(len(routed)), key=routed.__getitem__)
+        print(f"  layer {i}: max/mean {_fmt(ratio)}  busiest "
+              + " ".join(f"{e}:{routed[e]}" for e in order[:-4:-1])
+              + "  idlest "
+              + " ".join(f"{e}:{routed[e]}" for e in order[:3]))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     sub = ap.add_subparsers(dest="cmd", required=True)
+    sp = sub.add_parser("stats", help="the cache's kind and bytes and "
+                        "the expert load of a stats() JSON dump")
+    sp.add_argument("path")
+    sp.set_defaults(fn=dump_stats)
     mp = sub.add_parser("metrics",
                         help="pretty-print a registry snapshot / "
                         "JSON-lines scrape")
