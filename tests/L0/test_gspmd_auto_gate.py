@@ -32,28 +32,46 @@ def _mesh():
     return Mesh(dev, ("data", "model"))
 
 
-def test_default_block_divides_padded_seq():
+@pytest.mark.parametrize("s,narrow,wide", [
+    # length; the block where a head's row is at most 256 bytes, as 64
+    # or 128 in bfloat16 (up to 1,024: a grid step costs what it costs
+    # whatever its size, PR 28's sweep on one v5e); the block of wider
+    # rows (up to 512, what every caller had before: at 128 in float32
+    # the dkv kernel with dropout does not fit VMEM at 1,024)
+    (1, 128, 128),
+    (64, 128, 128),
+    (128, 128, 128),
+    (200, 256, 256),
+    (384, 384, 384),
+    (512, 512, 512),
+    (640, 640, 320),      # 5*128: the whole length, or its half
+    (768, 768, 384),      # not 512: a 512 block would pad 768 to 1,024
+    (896, 896, 320),      # 7*128: 320 pads by 64, within an eighth
+    (1024, 1024, 512),    # the training cells: one block a head
+    (1152, 384, 384),     # 9*128: the widest exact divisor
+    (1536, 768, 512),
+    (1664, 896, 256),     # 13*128 has no wide divisor: bounded re-pad
+    (2048, 1024, 512),
+    (4096, 1024, 512),
+    (16384, 1024, 512),
+])
+def test_default_block_by_shape(s, narrow, wide):
     """The adaptive flash tile default must never induce significant
     padding beyond the 128 grain: the chosen block divides the
     128-padded sequence exactly when any wide candidate can, and may
-    otherwise re-pad by at most 1/8 of the work (code-review finding,
-    round 5, relaxed per ADVICE round 5 — a 512 block at S=768 would
-    silently run 1.78x the real FLOPs and stays rejected, while
+    otherwise re-pad by at most 1/8 of the work (a 512 block at S=768
+    would silently run 1.78x the real FLOPs and stays rejected, while
     1664 = 13*128 with no wide divisor at all escapes the 128-tile
     floor for a few percent of masked padding)."""
     from apex_tpu.ops.flash_attention import _default_block
 
-    for s in (1, 64, 128, 200, 384, 512, 640, 768, 896, 1024, 1152,
-              1536, 1664, 2048, 4096, 16384):
-        b = _default_block(s)
+    for want, d, itemsize in ((narrow, 64, 2), (narrow, 128, 2),
+                              (narrow, 64, 4), (wide, 128, 4),
+                              (wide, 256, 2)):
+        assert _default_block(s, d, itemsize) == want, (d, itemsize)
         sp = -(-s // 128) * 128
-        assert (-(-sp // b) * b) - sp <= sp // 8, (s, b)
-        assert 128 <= b <= 512
-    assert _default_block(2048) == 512   # the measured s2048 sweet spot
-    assert _default_block(768) == 384    # not 512: divisibility rule
-    assert _default_block(640) == 320    # 5*128: widest exact divisor
-    assert _default_block(1664) > 128    # 13*128: bounded re-pad beats
-    #                                      a 128-wide tile floor
+        assert (-(-sp // want) * want) - sp <= sp // 8
+        assert 128 <= want <= 1024
 
 
 def test_auto_gate_warns_once_on_tpu_downgrade(monkeypatch):

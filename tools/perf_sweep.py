@@ -55,43 +55,97 @@ def sweep_stem(iters, batch=128):
               flush=True)
 
 
-def sweep_flash(blocks, iters):
+FLASH_KERNELS = ("fwd", "dq", "dkv")
+
+
+def _kernel_ms(fn, args, iters, kernel):
+    """Milliseconds a launch of the Pallas kernel named ``kernel`` on
+    the DEVICE's clock: ``iters`` launches under the profiler, the
+    kernel's events summed from the trace (a host clock around calls of
+    a millisecond reads the dispatch too: 1.33 ms where the trace says
+    1.13, PR 28).  The first call, which compiles, is apart."""
+    import jax
+    from benchmarks.harness.trace import SubWindow
+    jax.block_until_ready(fn(*args))
+    window = SubWindow()
+    window.start()
+    try:
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+    finally:
+        window.stop()
+    seconds, events = window.reduce().kernel_seconds(kernel)
+    if events != iters:
+        raise RuntimeError(f"{events} events of {kernel} in the trace, "
+                           f"{iters} launched")
+    return seconds / iters * 1e3
+
+
+def sweep_flash(shape=(8, 1024, 16, 64), blocks=(128, 256, 512, 1024),
+                iters=10, causal=True, dtype="bfloat16",
+                kernels=FLASH_KERNELS):
+    """Each of the three flash kernels ALONE (no ``_layout`` copies, no
+    ``delta``) at ``shape`` = (batch, sequence, heads, head size), for
+    every ``(block_q, block_k)`` of ``blocks`` squared that divides the
+    sequence.  One JSON line a point: the milliseconds, the share of
+    the MXU's bfloat16 peak that the kernel's causal (or square) count
+    of products reaches, and how many blocks of the grid were skipped,
+    masked and unmasked (``block_kinds``).  A point Mosaic refuses (the
+    blocks do not fit VMEM) prints its error.  Returns the rows."""
+    import functools
+    import importlib
+
     import jax
     import jax.numpy as jnp
-    from apex_tpu.ops.flash_attention import flash_attention
+    # the package exports the function under the module's name
+    fa = importlib.import_module("apex_tpu.ops.flash_attention")
 
-    b, s, h, d = 4, 2048, 8, 64
-    ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    q, k, v = (jax.random.normal(kk, (b, s, h, d), jnp.bfloat16)
-               for kk in ks)
-    flops = 3.5 * 4 * b * h * s * s * d * 0.5  # fwd+bwd, causal
-
-    for bq in blocks:
-        for bk in blocks:
-            try:
-                @jax.jit
-                def fwd_bwd(q, k, v):
-                    f = lambda q, k, v: flash_attention(
-                        q, k, v, causal=True, use_pallas=True,
-                        interpret=False, block_q=bq,
-                        block_k=bk).astype(jnp.float32).sum()
-                    return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
-                l, g = fwd_bwd(q, k, v)
-                float(l)
-                t0 = time.perf_counter()
-                for _ in range(iters):
-                    l, g = fwd_bwd(q, k, v)
-                float(l)
-                dt = (time.perf_counter() - t0) / iters
-                print(json.dumps({
-                    "sweep": "flash_fwd_bwd", "block_q": bq, "block_k": bk,
-                    "ms": round(dt * 1e3, 2),
-                    "tflops": round(flops / dt / 1e12, 2)}), flush=True)
-            except Exception as e:
-                print(json.dumps({"sweep": "flash_fwd_bwd", "block_q": bq,
-                                  "block_k": bk,
-                                  "error": f"{type(e).__name__}: {e}"}),
-                      flush=True)
+    b, s, h, d = shape
+    dt = jnp.dtype(dtype)
+    q, k, v, do = (jax.random.normal(kk, (b * h, s, d), dt)
+                   for kk in jax.random.split(jax.random.PRNGKey(2), 4))
+    mask = jnp.zeros((b, s), jnp.float32)
+    seed = jnp.zeros((5,), jnp.int32)
+    static = dict(scale=1.0 / d ** 0.5, causal=causal, h=h,
+                  interpret=not fa.on_tpu(),
+                  has_mask=False)
+    base = min(512, s)
+    o, lse = fa._fwd_pallas(q, k, v, mask, seed, bq=base, bk=base, **static)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    bwd_args = (q, k, v, do, lse, delta, mask, seed)
+    calls = {   # the launcher, its operands, the (s, s, d) products it runs
+        "fwd": (fa._fwd_pallas, (q, k, v, mask, seed), 2, "_fwd_kernel"),
+        "dq": (fa._bwd_dq_pallas, bwd_args, 3, "_bwd_dq_kernel"),
+        "dkv": (fa._bwd_dkv_pallas, bwd_args, 4, "_bwd_dkv_kernel"),
+    }
+    # a product is 2*s*s*d a head; half of the square under the diagonal
+    product = 2.0 * b * h * s * s * d * (0.5 if causal else 1.0)
+    rows = []
+    for kernel in kernels:
+        fn, args, products, name = calls[kernel]
+        for bq in blocks:
+            for bk in blocks:
+                if s % bq or s % bk:
+                    continue
+                row = {"sweep": "flash", "kernel": kernel,
+                       "shape": list(shape), "causal": causal,
+                       "dtype": dt.name, "block_q": bq, "block_k": bk,
+                       **fa.block_kinds(s, s, bq, bk, causal)}
+                try:
+                    ms = _kernel_ms(
+                        functools.partial(fn, bq=bq, bk=bk, **static), args,
+                        iters, name)
+                    row["ms"] = round(ms, 4)
+                    if fa.on_tpu():   # a share of the v5e's 197 TFLOP/s
+                        row["mxu_peak_pct"] = round(
+                            100 * products * product / (ms * 1e-3) / 197e12,
+                            2)
+                except Exception as e:
+                    row["error"] = f"{type(e).__name__}: {e}"[:200]
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+    return rows
 
 
 def main():
@@ -112,8 +166,14 @@ def main():
     iters = 5 if args.quick else 20
     sweep_resnet([128] if args.quick else [64, 128, 256], iters)
     sweep_stem(iters)
-    sweep_flash([128] if args.quick else [128, 256, 512],
-                3 if args.quick else 10)
+    if args.quick:
+        sweep_flash(blocks=(256, 512), iters=3)
+    else:
+        # the grid the defaults of ``_default_block`` were chosen from
+        for d, h in ((64, 16), (128, 8)):
+            for s_ in (1024, 2048, 4096):
+                for causal in (True, False):
+                    sweep_flash((8192 // s_, s_, h, d), causal=causal)
     try:
         print(json.dumps({"sweep": "fused_adam",
                           **bench.bench_fused_adam()}), flush=True)
