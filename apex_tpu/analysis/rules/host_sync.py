@@ -44,7 +44,8 @@ default_options = {
     "paths": ["apex_tpu/serving/api.py", "apex_tpu/serving/engine.py"],
     # PLAN/LAUNCH bodies; every *_impl function (the jitted program
     # bodies) is hot implicitly via impl_suffix
-    "hot_functions": ["_step", "_launch_decode", "_launch_verify",
+    "hot_functions": ["_step", "_admit", "_launch_chunk",
+                      "_launch_decode", "_launch_verify",
                       "_decode_inputs", "_verify_inputs"],
     "impl_suffix": "_impl",
     # the documented RETIRE/materialization points, exempt from the

@@ -112,17 +112,11 @@ class DeepseekV3Config:
 
     # -- what the serving engine asks a family (models/family.py) ---------
 
-    def build_model(self, attention_fn=None, kv_quant: bool = False):
+    def build_model(self, kv_quant: bool = False):
         if kv_quant:
             raise NotImplementedError(
                 "an int8 pool keeps one scale a head for a K|V pair; a "
                 "latent row has no heads to scale by (ROADMAP.md Reach)")
-        if attention_fn is not None:
-            raise NotImplementedError(
-                "attention_fn is a causal multi-head kernel over (q, k, "
-                "v) of one width; latent attention's keys are 192 wide "
-                "and its values 128, so the full forward pass attends "
-                "in jnp")
         return DeepseekV3LMHeadModel(self)
 
     def cache_row(self) -> CacheRow:
@@ -180,12 +174,11 @@ class DeepseekV3Attention(nn.Module):
     cfg: DeepseekV3Config
 
     @nn.compact
-    def __call__(self, x, positions, attn_bias, cache_view=None,
-                 layer: int = 0):
+    def __call__(self, x, positions, cache_view=None, layer: int = 0):
         """``x`` (B, S, hidden) after its norm, ``positions`` (B, S).
-        Returns ``(out, rows or view)``: without a view the new rows
-        ``c | k_pe`` (B, S, rank + rope) for the engine to write; with
-        one the view after this layer's write."""
+        Returns ``(out, view)``: the view after this layer's write of
+        the new rows ``c | k_pe`` (B, S, rank + rope), None without
+        one."""
         cfg = self.cfg
         h, nh, rank = cfg.hidden_size, cfg.num_attention_heads, \
             cfg.kv_lora_rank
@@ -206,7 +199,6 @@ class DeepseekV3Attention(nn.Module):
         q_pe = apply_rotary(q_pe, cos[:, :, None], sin[:, :, None],
                             cfg.rope_interleave)
         k_pe = apply_rotary(kva[..., rank:], cos, sin, cfg.rope_interleave)
-        rows = jnp.concatenate([c, k_pe], -1)           # (B, S, rank + dr)
         scale = float(dn + dr) ** -0.5
 
         if cache_view is not None:
@@ -214,13 +206,13 @@ class DeepseekV3Attention(nn.Module):
             with jax.named_scope("latent_attention"):
                 q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope,
                                    wkvb[..., :dn])
-                ctx, kept = cache_view.attend(
-                    layer, jnp.concatenate([q_lat, q_pe], -1), rows,
+                ctx, cache_view = cache_view.attend(
+                    layer, jnp.concatenate([q_lat, q_pe], -1),
+                    jnp.concatenate([c, k_pe], -1),  # (B, S, rank + dr)
                     scale=scale)
                 o = jnp.einsum("bsnr,rnd->bsnd", ctx, wkvb[..., dn:])
         else:
             # expanded: the published form, for the full forward pass
-            kept = rows
             with jax.named_scope("latent_attention"):
                 kv = jnp.einsum("bsr,rnd->bsnd", c, wkvb)
                 s = (jnp.einsum("bqnd,bknd->bnqk", q_nope, kv[..., :dn])
@@ -229,12 +221,10 @@ class DeepseekV3Attention(nn.Module):
                 t = x.shape[1]
                 causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
                 s = jnp.where(causal[None, None], s, NEG_INF)
-                if attn_bias is not None:
-                    s = s + attn_bias
                 p = jax.nn.softmax(s, axis=-1)
                 o = jnp.einsum("bnqk,bknd->bqnd", p.astype(x.dtype),
                                kv[..., dn:])
-        return jnp.einsum("bsnd,ndh->bsh", o, wo), kept
+        return jnp.einsum("bsnd,ndh->bsh", o, wo), cache_view
 
 
 class GatedMLP(nn.Module):
@@ -334,11 +324,12 @@ class DeepseekV3Block(nn.Module):
     layer: int
 
     @nn.compact
-    def __call__(self, x, positions, attn_bias, live, cache_view=None):
+    def __call__(self, x, positions, cache_view=None):
         cfg = self.cfg
+        live = cache_view.live if cache_view is not None else None
         a, kept = DeepseekV3Attention(cfg, name="attention")(
             RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(x),
-            positions, attn_bias, cache_view, self.layer)
+            positions, cache_view, self.layer)
         x = x + a
         h = RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(x)
         if self.layer < cfg.first_k_dense_replace:
@@ -358,19 +349,16 @@ class DeepseekV3LMHeadModel(nn.Module):
     The serving hooks are ``models.gpt.GPTLMHeadModel``'s:
     ``positions`` (B, S) explicit positions (default ``arange``);
     ``cache_views`` the launch's ``serving.kv_cache.CacheView``,
-    threaded through the blocks, with ``return_kv=True`` returned after
-    the last in place of the rows; ``return_kv`` without a view: also
-    the per-layer new rows ``c | k_pe`` (B, S, rank + rope) for the
-    engine to write (the monolithic prefill);
-    ``attention_mask`` (B, S) 1/0 padding mask on key positions (its
-    rows are also routed to no expert)."""
+    threaded through the blocks and, with ``return_kv=True``, returned
+    after the last beside the logits.  Without a view the call is the
+    plain causal forward over whole rows of tokens."""
 
     cfg: DeepseekV3Config
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None,
-                 deterministic: bool = True, positions=None,
-                 cache_views=None, return_kv: bool = False):
+    def __call__(self, input_ids, deterministic: bool = True,
+                 positions=None, cache_views=None,
+                 return_kv: bool = False):
         del deterministic                    # no dropout in this family
         cfg = self.cfg
         init = _init(cfg)
@@ -381,27 +369,15 @@ class DeepseekV3LMHeadModel(nn.Module):
             positions = jnp.broadcast_to(
                 jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None],
                 input_ids.shape)
-        bias = live = None
-        if attention_mask is not None:
-            live = attention_mask > 0
-            bias = jnp.where(live[:, None, None, :], 0.0,
-                             NEG_INF).astype(jnp.float32)
         view = cache_views
-        if view is not None:
-            live = view.live
-        rows = []
         for i in range(cfg.num_hidden_layers):
-            x, kept = DeepseekV3Block(cfg, i, name=f"block_{i}")(
-                x, positions, bias, live, view)
-            if view is None:
-                rows.append(kept)
-            else:
-                view = kept
+            x, view = DeepseekV3Block(cfg, i, name=f"block_{i}")(
+                x, positions, view)
         x = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
         head = self.param("lm_head", init,
                           (cfg.hidden_size, cfg.vocab_size))
         logits = jnp.einsum("bsh,hv->bsv", x, head,
                             preferred_element_type=jnp.float32)
         if return_kv:
-            return logits, (rows if view is None else view)
+            return logits, view
         return logits

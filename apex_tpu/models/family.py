@@ -3,11 +3,16 @@
 ``serving.DecodeEngine`` and ``serving.InferenceServer`` take a
 configuration object and ask it, not its class name, three things:
 
-(a) ``cfg.build_model(attention_fn=None, kv_quant=False)``: the flax
-    module to run, with the call signature the engine uses
-    (``input_ids, attention_mask=, positions=, cache_views=,
-    return_kv=, deterministic=``; ``models.gpt.GPTLMHeadModel`` is the
-    pattern);
+(a) ``cfg.build_model(kv_quant=False)``: the flax module to run.  The
+    engine's three program bodies (chunk prefill, decode, verify) make
+    one call of it, ``model.apply(variables, input_ids, positions=,
+    cache_views=view, return_kv=True, deterministic=True) -> (logits,
+    view)``: ``view`` is the launch's ``serving.kv_cache.CacheView``,
+    through which every layer writes the fed tokens' rows and attends
+    (``view.attend``), handed on from layer to layer and returned
+    after the last.  The engine never calls the model without a view,
+    so a family owes serving no other forward pass
+    (``models.gpt.GPTLMHeadModel`` is the pattern);
 (b) ``cfg.cache_row()``: a :class:`CacheRow`, what one token keeps in
     one layer of the paged pool;
 (c) ``cfg.vocab_size``, ``cfg.num_hidden_layers`` and

@@ -61,9 +61,8 @@ class GPTConfig:
 
     # -- what the serving engine asks a family (models/family.py) ---------
 
-    def build_model(self, attention_fn=None, kv_quant: bool = False):
-        return GPTLMHeadModel(self, attention_fn=attention_fn,
-                              kv_quant=kv_quant)
+    def build_model(self, kv_quant: bool = False):
+        return GPTLMHeadModel(self, kv_quant=kv_quant)
 
     def cache_row(self) -> CacheRow:
         """Every head's ``K_h | V_h`` pair a token and layer."""
@@ -134,8 +133,7 @@ class GPTSelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_bias, deterministic: bool = True,
-                 cache_view=None, return_kv: bool = False,
-                 layer: int = 0):
+                 cache_view=None, layer: int = 0):
         """``cache_view``: serving mode — the launch's view of the KV
         pool (``serving.kv_cache.CacheView``), ``layer`` this block's
         index in it.  The view's ``attend`` takes the queries and the
@@ -144,28 +142,25 @@ class GPTSelfAttention(nn.Module):
         causally) and the view with this layer's rows written; how the
         pool is laid out and whether it is read in place or gathered is
         the view's business.  ``attention_fn`` (a causal full-sequence
-        kernel) is deliberately bypassed there.  ``return_kv``: also
-        return the view after the write, or without a view this call's
-        freshly projected ``(k, v)`` for the engine to write (the
-        monolithic prefill).  Both default off — the training path is
-        byte-identical to before.
+        kernel) is deliberately bypassed there.  With a view the call
+        returns ``(out, view after the write)``; without one, ``out``
+        — the training path is byte-identical to before.
 
         ``kv_quant`` (a field, set where the model is built:
         ``GPTConfig.build_model``): int8-quantized-pool serving (``docs/serving.md``,
         "Quantized KV cache").  The freshly projected K/V quantize AT
         THE SOURCE (:func:`ops.kv_quant.quantize_kv`, per token per
-        head) and attention everywhere operates on the QUANTIZED grid
-        — the view hands the int8 context with its scale sidecar to
-        ops that widen at read, the fed rows' own K/V join it as int8
-        with their fresh scales, and the no-cache causal forward
-        attends the dequantized values.  That uniformity is the
-        bit-stability argument: a (query, key) pair's score is
-        identical whether the key is fresh this call, fresh earlier in
-        the same chunk, or read back from the pool — so chunking
-        boundaries, preemption re-prefill, COW, and speculation cannot
-        move a logit.  The fresh K/V are then ``((k_q, k_scale),
-        (v_q, v_scale))`` — byte-for-byte what attention uses."""
-        cfg, kv_quant = self.cfg, self.kv_quant
+        head) and attention operates on the QUANTIZED grid — the view
+        hands the int8 context with its scale sidecar to ops that
+        widen at read, and the fed rows' own K/V join it as int8 with
+        their fresh scales.  That uniformity is the bit-stability
+        argument: a (query, key) pair's score is identical whether the
+        key is fresh this call, fresh earlier in the same chunk, or
+        read back from the pool — so chunking boundaries, preemption
+        re-prefill, COW, and speculation cannot move a logit.  The
+        fresh K/V are then ``((k_q, k_scale), (v_q, v_scale))`` —
+        byte-for-byte what attention uses."""
+        cfg = self.cfg
         h, nh = cfg.hidden_size, cfg.num_attention_heads
         init = _init(cfg)
 
@@ -174,14 +169,13 @@ class GPTSelfAttention(nn.Module):
                                    name=name)(x)
 
         q, k, v = proj("query"), proj("key"), proj("value")
-        kv_out = (k, v)
-        if kv_quant:
-            from apex_tpu.ops.kv_quant import dequantize_kv, quantize_kv
-
-            (k_q, k_s), (v_q, v_s) = quantize_kv(k), quantize_kv(v)
-            kv_out = ((k_q, k_s), (v_q, v_s))
         if cache_view is not None:
-            ctx, kv_out = cache_view.attend(layer, q, kv_out)
+            kv = (k, v)
+            if self.kv_quant:
+                from apex_tpu.ops.kv_quant import quantize_kv
+
+                kv = (quantize_kv(k), quantize_kv(v))
+            ctx, cache_view = cache_view.attend(layer, q, kv)
         else:
             dropout_fn = None
             if cfg.attention_probs_dropout_prob > 0 and not deterministic:
@@ -197,31 +191,20 @@ class GPTSelfAttention(nn.Module):
                         self.make_rng("dropout"), (), 0,
                         jnp.iinfo(jnp.int32).max, dtype=jnp.int32)
             attn = self.attention_fn or causal_dot_product_attention
-            if kv_quant:
-                # quantized serving's monolithic prefill: attend the
-                # DEQUANTIZED k/v — the same grid every later chunk,
-                # decode, or verify step reads back from the pool —
-                # through the unchanged causal path (attention_fn
-                # included; it is just a different k/v operand)
-                k_at = dequantize_kv(k_q, k_s, k.dtype)
-                v_at = dequantize_kv(v_q, v_s, v.dtype)
-            else:
-                k_at, v_at = k, v
-            ctx = attn(q, k_at, v_at, bias=attn_bias,
-                       dropout_fn=dropout_fn)
+            ctx = attn(q, k, v, bias=attn_bias, dropout_fn=dropout_fn)
         out = nn.DenseGeneral(h, axis=(-2, -1), kernel_init=init,
                               name="output")(ctx)
-        if return_kv:
-            return out, kv_out
+        if cache_view is not None:
+            return out, cache_view
         return out
 
 
 class GPTBlock(nn.Module):
     """Pre-LN: x + Attn(LN(x)); x + MLP(LN(x)).
 
-    ``cache_view``/``layer``/``return_kv`` thread straight through to
-    :class:`GPTSelfAttention` (serving decode/prefill); the training
-    call sites never pass them."""
+    ``cache_view``/``layer`` thread straight through to
+    :class:`GPTSelfAttention` (serving: the call then returns
+    ``(x, view)``); the training call sites never pass them."""
 
     cfg: GPTConfig
     attention_fn: Optional[Callable] = None
@@ -229,8 +212,7 @@ class GPTBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_bias, deterministic: bool = True,
-                 cache_view=None, return_kv: bool = False,
-                 layer: int = 0):
+                 cache_view=None, layer: int = 0):
         cfg = self.cfg
         init = _init(cfg)
         drop = nn.Dropout(cfg.hidden_dropout_prob,
@@ -241,11 +223,9 @@ class GPTBlock(nn.Module):
                              name="attention")(h, attn_bias,
                                                deterministic,
                                                cache_view=cache_view,
-                                               return_kv=return_kv,
                                                layer=layer)
-        kv = None
-        if return_kv:
-            h, kv = h
+        if cache_view is not None:
+            h, cache_view = h
         x = x + drop(h)
         h = FusedLayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
                            name="mlp_ln")(x)
@@ -254,8 +234,8 @@ class GPTBlock(nn.Module):
         h = nn.gelu(h, approximate=True)
         h = nn.Dense(cfg.hidden_size, kernel_init=init,
                      name="mlp_out")(h)
-        if return_kv:
-            return x + drop(h), kv
+        if cache_view is not None:
+            return x + drop(h), cache_view
         return x + drop(h)
 
 
@@ -282,17 +262,12 @@ class GPTLMHeadModel(nn.Module):
       the blocks: each attends its cached context plus the fed rows
       through it (decode, S == 1; verify and chunked prefill, S > 1,
       causal among the rows) and hands on the view with its rows
-      written.  Passed with ``return_kv=True``, which then returns the
-      view after the last block in place of the K/V list;
-    - ``return_kv``: without a view, also return the per-layer freshly
-      projected ``(k, v)`` list so the engine can write them into the
-      cache (the monolithic prefill: the normal causal forward,
-      optionally through the flash ``attention_fn``);
-    - ``kv_quant`` (a field): int8-quantized-pool serving — fresh K/V quantize at
-      projection and attention runs on the quantized grid everywhere,
-      and ``return_kv`` without a view yields per-layer
-      ``((k_q, k_scale), (v_q, v_scale))`` (``docs/serving.md``,
-      "Quantized KV cache").
+      written;
+    - ``return_kv``: passed with ``cache_views``; the call then
+      returns ``(logits, view after the last block)``;
+    - ``kv_quant`` (a field): int8-quantized-pool serving — under a
+      view fresh K/V quantize at projection and attention runs on the
+      quantized grid (``docs/serving.md``, "Quantized KV cache").
     """
 
     cfg: GPTConfig
@@ -312,23 +287,18 @@ class GPTLMHeadModel(nn.Module):
             bias = jnp.where(attention_mask[:, None, None, :] > 0,
                              0.0, NEG_INF).astype(jnp.float32)
         block = GPTBlock
-        if cfg.remat and not return_kv:
+        view = cache_views
+        if cfg.remat and view is None:
             # deterministic (argnum 3; self=0) is the static arg — the
-            # bias is a traced array (same as models.bert). Inference
-            # (return_kv) never remats: there is no backward to save
-            # memory for, and the kv pytree output confuses the policy.
+            # bias is a traced array (same as models.bert). Serving
+            # (a view) never remats: there is no backward to save
+            # memory for, and the view's pytree confuses the policy.
             block = nn.remat(GPTBlock, static_argnums=(3,))
-        kvs, view = [], cache_views
         for i in range(cfg.num_hidden_layers):
-            if return_kv:
-                x, kv = block(cfg, self.attention_fn, self.kv_quant,
-                              name=f"block_{i}")(
-                    x, bias, deterministic, cache_view=view,
-                    return_kv=True, layer=i)
-                if view is None:
-                    kvs.append(kv)
-                else:
-                    view = kv
+            if view is not None:
+                x, view = block(cfg, self.attention_fn, self.kv_quant,
+                                name=f"block_{i}")(
+                    x, bias, deterministic, cache_view=view, layer=i)
             else:
                 x = block(cfg, self.attention_fn, name=f"block_{i}")(
                     x, bias, deterministic)
@@ -343,8 +313,7 @@ class GPTLMHeadModel(nn.Module):
         # weight-tied head: logits = x @ wte^T
         logits = wte.attend(x)
         if return_kv:
-            return logits.astype(jnp.float32), (kvs if view is None
-                                                else view)
+            return logits.astype(jnp.float32), view
         return logits.astype(jnp.float32)
 
 

@@ -245,7 +245,7 @@ class OpsServer:
             status = "closed"
         elif srv.draining:
             status = "draining"
-        elif srv.breaker is not None and srv.breaker.state == "open":
+        elif srv.breaker.state == "open":
             status = "breaker_open"
         else:
             status = "ok"
@@ -253,8 +253,7 @@ class OpsServer:
         body = {
             "status": status,
             "iter": srv._iter,
-            "breaker": (srv.breaker.state if srv.breaker is not None
-                        else "disabled"),
+            "breaker": srv.breaker.state,
             "pressure": round(srv.pressure_gauge.val, 4),
             # the router-scrape trio (docs/serving.md, "Multi-replica
             # routing"): one cheap machine-readable probe carries the

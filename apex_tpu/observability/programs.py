@@ -5,7 +5,7 @@ chunk_prefill / decode / verify spans) and the pipeline block (PR 8)
 into a host/device share — but none of them answer the question an
 engine owner actually asks when a step gets slow: **which compiled
 program is the time going to**, per shape variant?  A server runs a
-small, closed set of XLA programs (one per prefill bucket, one per
+small, closed set of XLA programs (one per
 chunk width, one decode, one per verify width, their fused-sampling
 twins, and the COW block copy); this module tallies each of them.
 

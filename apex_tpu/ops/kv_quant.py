@@ -48,7 +48,7 @@ def quantize_kv(x):
     pool and :func:`dequantize_kv` returns exact zeros.  The math is
     elementwise per (token, head) vector, so the SAME value quantizes
     to the SAME bytes no matter how the writes were batched
-    (monolithic prefill, chunks, decode singles, verify columns) —
+    (chunks of any width, decode singles, verify columns) —
     the determinism every bit-stability oracle leans on."""
     xf = x.astype(jnp.float32)
     scale = jnp.max(jnp.abs(xf), axis=-1) / INT8_QMAX

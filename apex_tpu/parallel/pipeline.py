@@ -30,7 +30,7 @@ Contract (classic GPipe):
 
 Use :func:`pipeline_apply` for the packaged shard_map wrapper, or
 :func:`gpipe_spmd` directly inside your own shard_map when composing
-with other axes (see ``tests/distributed/test_pipeline.py`` for a
+with other axes (see ``tests/distributed/test_pipeline_parallel.py`` for a
 (data, pipe) composition).
 """
 

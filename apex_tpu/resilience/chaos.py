@@ -495,11 +495,7 @@ class ChaosEngine:
             raise MemoryError(
                 f"chaos: injected engine OOM at iteration {self.iter}")
 
-    def prefill(self, tokens, block_table):
-        self._oom_gate()
-        return self.inner.prefill(tokens, block_table)
-
-    def chunk_prefill(self, tokens, start, block_table, pad_to=None):
+    def chunk_prefill(self, tokens, start, block_table, pad_to):
         self._oom_gate()
         return self.inner.chunk_prefill(tokens, start, block_table,
                                         pad_to=pad_to)
@@ -568,13 +564,8 @@ class ChaosEngine:
     # materialization, so injection never collapses the dispatch-ahead
     # window it is trying to fault.
 
-    def prefill_sampled(self, tokens, block_table, sampling=None):
-        self._oom_gate()
-        return self.inner.prefill_sampled(tokens, block_table,
-                                          sampling=sampling)
-
     def chunk_prefill_sampled(self, tokens, start, block_table,
-                              pad_to=None, sampling=None):
+                              pad_to, sampling=None):
         self._oom_gate()
         return self.inner.chunk_prefill_sampled(tokens, start,
                                                 block_table,
@@ -768,9 +759,9 @@ class ReplicaKillSwitch:
     that kept its host state), which the router's half-open probes
     must discover on their own."""
 
-    _GATED = ("prefill", "chunk_prefill", "copy_blocks", "decode",
-              "verify", "prefill_sampled", "chunk_prefill_sampled",
-              "decode_sampled", "verify_sampled")
+    _GATED = ("chunk_prefill", "copy_blocks", "decode", "verify",
+              "chunk_prefill_sampled", "decode_sampled",
+              "verify_sampled")
 
     def __init__(self, inner):
         self.inner = inner

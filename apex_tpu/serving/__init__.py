@@ -8,10 +8,9 @@ serve".  Three layers, bottom-up:
   pool (vLLM's PagedAttention memory model, fixed-shape for
   jit-stability; dtype from the amp half policy) with a host-side
   free-list allocator;
-- :mod:`serving.engine` — the jitted device steps: bucketed causal
-  prefill (reusing the training forward, flash-attention pluggable)
-  and a single-token batched decode through
-  ``ops.cached_attention``;
+- :mod:`serving.engine` — the jitted device steps: fixed-width chunk
+  prefill, a single-token batched decode and the speculative verify
+  step, each attending the pool through the block table;
 - :mod:`serving.prefix_cache` — a block-level prefix index
   (RadixAttention-style, keyed on full-block token chunks chained by
   physical parent id) over the allocator's refcounts: shared-prefix
@@ -50,8 +49,7 @@ serve".  Three layers, bottom-up:
   to stochastic drafts via rejection sampling (Gumbel-max coupling:
   accept a draft iff it equals the column's own sample), so sampled
   traffic keeps BOTH fast paths instead of falling back to the
-  synchronous logits path (a legacy custom ``sample_fn`` still
-  forces the fallback, now with a loud warning);
+  synchronous logits path;
 - tensor-parallel sharded serving (``docs/serving.md``,
   "Tensor-parallel serving"): pass ``mesh=`` (+ optional
   ``tp_rules=``) and the engine lowers every compiled program through
@@ -135,14 +133,13 @@ Quick start::
     completions = server.generate(prompts, max_new_tokens=64,
                                   eos_id=eos)
 
-See ``docs/serving.md`` for cache-sizing math and the
-bucket/recompile tradeoff; ``tools/serving_bench.py`` measures
-continuous batching against naive one-request-at-a-time decoding.
+See ``docs/serving.md`` for cache-sizing math; ``benchmarks/run.py``
+measures the server on the chip (``PERF.md``).
 """
 
 from apex_tpu.ops.sampling import SamplingParams
 from apex_tpu.serving.api import InferenceServer, greedy_sample
-from apex_tpu.serving.engine import DecodeEngine, default_prefill_buckets
+from apex_tpu.serving.engine import DecodeEngine
 from apex_tpu.serving.kv_cache import (
     BlockAllocator,
     KVCacheConfig,
@@ -194,7 +191,6 @@ __all__ = [
     "SocketTransport",
     "TransportError",
     "TransportPolicy",
-    "default_prefill_buckets",
     "dequantize_kv",
     "greedy_sample",
     "init_kv_cache",

@@ -44,12 +44,11 @@ per program/shape key so "where does the step go" is answerable per
 program, not just per phase.
 
 Pipelined serve loop (``docs/serving.md``, "Pipelined serve loop"; ON
-by default, ``enable_pipeline=False`` opts out, a custom ``sample_fn``
-auto-disables): each :meth:`step` first RETIRES the previous
-iteration's launched decode/verify results (token ids + finite flags,
-sampled on device by the engine's fused programs), then plans and
-LAUNCHES this iteration's programs without materializing them — so
-host scheduling for step N+1 overlaps device compute for step N, and
+by default, ``enable_pipeline=False`` opts out): each :meth:`step`
+first RETIRES the previous iteration's launched decode/verify results
+(token ids + finite flags, sampled on device by the engine's fused
+programs), then plans and LAUNCHES this iteration's programs without
+materializing them — so host scheduling for step N+1 overlaps device compute for step N, and
 the per-step device→host transfer is a ``(B,)`` int32 vector instead
 of a ``(B, V)`` logits block.  Output is bit-identical to the
 synchronous loop: greedy argmax is computed by the same rule on
@@ -65,12 +64,12 @@ needs.  A live service would run :meth:`step` on its event loop and
 stream ``Request.generated`` as it grows; both drive the identical
 scheduler/engine machinery, so the offline numbers transfer.
 
-Serving-perf layers (all ON by default; ``enable_prefix_cache=False``
-/ ``enable_chunked_prefill=False`` / ``enable_speculation=False`` opt
-out): block-level prefix caching shares cached full blocks at
-admission so only the uncached tail prefills, chunked prefill
-advances ONE chunk per prefilling request per iteration so a long
-prompt stalls the decode batch by at most one chunk, and speculative
+Serving-perf layers (ON by default; ``enable_prefix_cache=False`` /
+``enable_speculation=False`` opt out): block-level prefix caching
+shares cached full blocks at admission so only the uncached tail
+prefills; every prompt is prefilled in chunks of ``prefill_chunk``,
+ONE chunk per prefilling request per iteration, so a long prompt
+stalls the decode batch by at most one chunk; and speculative
 decoding drafts up to ``spec_tokens`` guesses per request per
 iteration (zero-weight prompt-lookup by default), scores them in one
 fixed-width verify launch, and accepts exactly the prefix matching
@@ -97,7 +96,7 @@ failure is counted by reason in a
 :meth:`InferenceServer.stats`.
 
 Overload control & lifecycle (``docs/resilience.md``, "Overload
-policy & lifecycle"; both ON by default): requests carry a
+policy & lifecycle"): requests carry a
 ``priority`` class and a block-cost estimate; under queue/pool
 pressure the scheduler sheds the lowest-priority, newest waiting work
 (``finish_reason="shed"``) and preempts worst-priority-first
@@ -117,7 +116,6 @@ import contextlib
 import faulthandler
 import os
 import time
-import warnings
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
@@ -128,7 +126,6 @@ from apex_tpu.observability import (
     JOURNEYS_ENV,
     NULL_FLIGHT_RECORDER,
     NULL_JOURNEY_LOG,
-    NULL_PROGRAM_ACCOUNTING,
     NULL_WATCHDOG,
     OPS_PORT_ENV,
     POSTMORTEM_ENV,
@@ -288,15 +285,6 @@ class InferenceServer:
     per-slot per-head scale sidecar; ``APEX_TPU_KV_QUANT=int8`` is
     its env twin, the kwarg wins — ``docs/serving.md``, "Quantized
     KV cache"):
-      sample_fn: LEGACY escape hatch — (…, V) numpy logits -> (…,)
-        token ids, run on host with per-step (B, V) logits.  Passing
-        one warns loudly: it forces the synchronous logits path
-        (speculation + pipeline OFF) and ignores per-request
-        ``SamplingParams``.  For temperature/top-k/top-p use
-        ``submit(..., sampling=SamplingParams(...))`` instead — the
-        on-device sampling suite keeps both fast paths ON with
-        deterministic counter-keyed streams (``docs/serving.md``,
-        "Stochastic sampling").
       max_waiting: bound on the waiting queue; a submit past it comes
         back already finished with ``finish_reason="rejected"``
         (explicit backpressure at the front door).
@@ -306,13 +294,10 @@ class InferenceServer:
         (:mod:`serving.prefix_cache`) — shared-prefix traffic skips
         re-prefilling cached full blocks.  Opt out for strictly
         private workloads or A/B baselines.
-      enable_chunked_prefill: split long prefill tails into
-        ``prefill_chunk``-token chunks, one per iteration, so a long
-        prompt stalls running decodes by at most one chunk.  Opt out
-        to restore monolithic bucketed prefills.
-      prefill_chunk: chunk width in tokens (default
-        ``min(256, max_context)``); ignored when chunked prefill is
-        off.
+      prefill_chunk: the width in tokens of the one compiled chunk
+        program (default ``min(256, max_context)``).  Every prompt is
+        prefilled in chunks of it, one per iteration, so a long prompt
+        stalls running decodes by at most one chunk.
       enable_speculation: speculative decoding with bit-exact greedy
         acceptance (``docs/serving.md``): each decode iteration,
         requests with a draft feed the pending token plus up to
@@ -324,8 +309,7 @@ class InferenceServer:
         via rejection sampling — acceptance compares drafts against
         each column's counter-keyed sample, so the output
         distribution (and, by the Gumbel-max coupling, the exact
-        stream) is unchanged.  A legacy custom ``sample_fn`` still
-        disables speculation, loudly.  Opt out for strictly
+        stream) is unchanged.  Opt out for strictly
         non-repetitive traffic where drafting is pure overhead.
       spec_tokens: max drafted tokens per verify step (default 4); the
         verify program is ``spec_tokens + 1`` columns wide and
@@ -339,10 +323,8 @@ class InferenceServer:
         bit-identical to the synchronous loop (sampling — argmax or
         counter-keyed stochastic — is computed by the same rule on
         device; every host decision sees post-retire state).
-        Stochastic requests keep the pipeline ON; a legacy custom
-        ``sample_fn`` needs the logits on host and falls back to the
-        synchronous path, loudly.  Opt out to restore the strictly
-        serial loop.
+        Stochastic requests keep the pipeline ON.  Opt out to restore
+        the strictly serial loop.
       draft_source: the :class:`serving.speculation.DraftSource`
         proposing drafts (default: zero-weight
         :class:`~serving.speculation.NgramDraft` prompt-lookup over
@@ -353,14 +335,13 @@ class InferenceServer:
         driving priority-aware load shedding (queue-full
         displacement, pressure shedding of best-effort waiting work,
         worst-priority preemption).  Default: a policy with stock
-        thresholds; ``enable_overload=False`` opts out (queue-full
-        strictly rejects, preemption is youngest-first).
+        thresholds.
       breaker: the :class:`apex_tpu.resilience.CircuitBreaker`
         guarding ``submit`` (default: stock thresholds on the
         server's ``clock``); after a streak of non-finite/OOM
         failures submissions fast-reject with
         ``finish_reason="breaker_open"`` until a half-open probe
-        completes.  ``enable_breaker=False`` opts out.
+        completes.
       registry: the :class:`apex_tpu.observability.MetricsRegistry`
         holding every counter/gauge/histogram this server feeds
         (default: a fresh private one).  Pass a shared registry to
@@ -392,15 +373,6 @@ class InferenceServer:
         :func:`resilience.chaos.run_soak`).
         ``APEX_TPU_POSTMORTEM=/dir`` is the env twin.  On-demand
         bundles go wherever :meth:`dump_postmortem` is pointed.
-      enable_program_accounting: per-compiled-program launch tallies
-        (``docs/observability.md``, "Ops plane & watchdog"; ON by
-        default): every engine program launch — prefill / chunk /
-        decode / verify, logits and sampled twins, per bucket/width
-        key — feeds the pinned ``stats()["programs"]`` table and the
-        ``serving_program_*`` registry counters with call count, host
-        wall time, and compile count/time, so "where does the step
-        go" is answerable per program.  Accounting never feeds back
-        into scheduling; opt out to shave the per-launch clock reads.
       watchdog: a :class:`observability.HangWatchdog` arming hang
         detection on this server's step loop: :meth:`step` feeds it
         heartbeats, and a step (or a step *gap* with work pending)
@@ -496,31 +468,24 @@ class InferenceServer:
                  block_size: int = 16,
                  cache_dtype=None,
                  kv_quant: Optional[str] = None,
-                 attention_fn=None,
-                 prefill_buckets=None,
                  mesh=None,
                  tp_rules=None,
                  tp_axis: str = "model",
-                 sample_fn: Optional[Callable] = None,
                  max_waiting: Optional[int] = None,
                  clock: Callable[[], float] = time.monotonic,
                  enable_prefix_cache: bool = True,
-                 enable_chunked_prefill: bool = True,
                  prefill_chunk: Optional[int] = None,
                  enable_speculation: bool = True,
                  spec_tokens: Optional[int] = None,
                  draft_source: Optional[DraftSource] = None,
                  enable_pipeline: bool = True,
-                 enable_overload: bool = True,
                  overload_policy: Optional[OverloadPolicy] = None,
-                 enable_breaker: bool = True,
                  breaker: Optional[CircuitBreaker] = None,
                  registry: Optional[MetricsRegistry] = None,
                  tracer=None,
                  slo_policy: Optional[SLOPolicy] = None,
                  flight_recorder: Optional[FlightRecorder] = None,
                  postmortem_dir: Optional[str] = None,
-                 enable_program_accounting: bool = True,
                  watchdog: Optional[HangWatchdog] = None,
                  ops_port: Optional[int] = None,
                  enable_disagg: bool = False,
@@ -550,10 +515,9 @@ class InferenceServer:
                              else NULL_FLIGHT_RECORDER)
         self.slo = SLOTracker(slo_policy, registry=self.registry)
         # per-compiled-program accounting (docs/observability.md,
-        # "Ops plane & watchdog"): observation only, so on by default
-        self.programs = (ProgramAccounting(registry=self.registry)
-                         if enable_program_accounting
-                         else NULL_PROGRAM_ACCOUNTING)
+        # "Ops plane & watchdog"): every engine launch feeds the
+        # stats()["programs"] table; observation only
+        self.programs = ProgramAccounting(registry=self.registry)
         # quantized KV pool (docs/serving.md, "Quantized KV cache"):
         # the APEX_TPU_KV_QUANT env twin turns it on fleet-wide
         # without touching call sites; a PROVIDED kwarg wins — None
@@ -568,7 +532,6 @@ class InferenceServer:
             max_context=max_context, num_blocks=num_blocks,
             block_size=block_size, cache_dtype=cache_dtype,
             kv_quant=self.kv_quant,
-            attention_fn=attention_fn, prefill_buckets=prefill_buckets,
             tracer=self.tracer, programs=self.programs,
             mesh=mesh, tp_rules=tp_rules, tp_axis=tp_axis)
         self.failures = CounterMeter(registry=self.registry,
@@ -576,14 +539,12 @@ class InferenceServer:
                                      label="reason")
         self.prefix = CounterMeter(registry=self.registry,
                                    name="serving_prefix", label="event")
-        self.prefill_chunk = None
-        if enable_chunked_prefill:
-            self.prefill_chunk = int(
-                prefill_chunk if prefill_chunk is not None
-                else min(DEFAULT_PREFILL_CHUNK, self.engine.max_context))
-        self.overload_policy = (
-            overload_policy if overload_policy is not None
-            else OverloadPolicy()) if enable_overload else None
+        self.prefill_chunk = int(
+            prefill_chunk if prefill_chunk is not None
+            else min(DEFAULT_PREFILL_CHUNK, self.engine.max_context))
+        self.overload_policy = (overload_policy
+                                if overload_policy is not None
+                                else OverloadPolicy())
         # predictive admission (docs/resilience.md): learns service
         # rates from finished timelines and sheds provably
         # deadline-doomed arrivals at the front door.  Gated on the
@@ -593,8 +554,7 @@ class InferenceServer:
             AdmissionEstimator(
                 min_history=self.overload_policy.admission_min_history,
                 margin=self.overload_policy.admission_margin)
-            if self.overload_policy is not None
-            and self.overload_policy.predictive_admission else None)
+            if self.overload_policy.predictive_admission else None)
         # disaggregated prefill/decode pools (docs/serving.md,
         # "Disaggregated prefill/decode"; OFF by default): a second
         # engine with its OWN KV pool runs every prefill, and the main
@@ -631,8 +591,6 @@ class InferenceServer:
                 num_blocks=int(disagg_prefill_blocks),
                 block_size=block_size, cache_dtype=cache_dtype,
                 kv_quant=self.kv_quant,
-                attention_fn=attention_fn,
-                prefill_buckets=prefill_buckets,
                 tracer=self.tracer, programs=self.programs,
                 mesh=mesh, tp_rules=tp_rules, tp_axis=tp_axis)
         # the prefix cache lives with whichever pool runs prefills:
@@ -753,34 +711,13 @@ class InferenceServer:
                                      label="event")
         self.handoff_pending = GaugeMeter(registry=self.registry,
                                           name="serving_handoff_pending")
-        self.sample_fn = sample_fn or greedy_sample
-        if self.sample_fn is not greedy_sample:
-            # the historical escape hatch, now a LOUD downgrade: a
-            # custom sample_fn needs materialized host logits, which
-            # turns OFF speculative decoding AND the pipelined loop
-            # and ignores per-request SamplingParams.  The supported
-            # stochastic path (docs/serving.md, "Stochastic
-            # sampling") keeps both fast paths on.
-            warnings.warn(
-                "custom sample_fn disables the serving fast paths: "
-                "speculative decoding and the pipelined "
-                "(dispatch-ahead) serve loop fall back to the "
-                "synchronous logits path, and per-request "
-                "SamplingParams are ignored.  Pass "
-                "SamplingParams(temperature=..., top_k=..., "
-                "top_p=..., seed=...) per request instead — the "
-                "on-device sampling suite keeps speculation and the "
-                "pipeline ON (docs/serving.md, 'Stochastic "
-                "sampling').", UserWarning, stacklevel=2)
         # per-class request accounting for stats()["sampling"]
         # (greedy / temperature / top_k / top_p / top_k_top_p)
         self.sampling_classes = CounterMeter(
             registry=self.registry, name="serving_sampling_requests",
             label="class")
         self.clock = clock
-        # speculation (docs/serving.md): greedy-only by contract — the
-        # acceptance rule compares drafts against argmax rows, which
-        # only reproduces plain decode when sampling IS argmax
+        # speculation (docs/serving.md)
         self.spec_tokens = int(spec_tokens if spec_tokens is not None
                                else DEFAULT_SPEC_TOKENS)
         if self.spec_tokens < 1:
@@ -788,14 +725,11 @@ class InferenceServer:
                 f"spec_tokens must be >= 1, got {self.spec_tokens}")
         self.draft_source = (draft_source if draft_source is not None
                              else NgramDraft())
-        self.speculating = bool(enable_speculation
-                                and self.sample_fn is greedy_sample)
+        self.speculating = bool(enable_speculation)
         # pipelined serve loop (docs/serving.md, "Pipelined serve
-        # loop"): greedy-only by contract — sampling must happen on
-        # device for the host to skip materializing logits, and the
-        # fused programs sample by argmax
-        self.pipelining = bool(enable_pipeline
-                               and self.sample_fn is greedy_sample)
+        # loop"): the fused programs sample on device, so the host
+        # never materializes logits
+        self.pipelining = bool(enable_pipeline)
         self._inflight: Optional[_InflightStep] = None
         self._verify_compiled = set()   # _compile_verify_beside's kinds
         self._pending_produced = 0   # retired outside step() (submit)
@@ -814,12 +748,10 @@ class InferenceServer:
         self.breaker_events = CounterMeter(registry=self.registry,
                                            name="serving_breaker",
                                            label="event")
-        self.breaker = (
-            breaker if breaker is not None
-            else CircuitBreaker(clock=clock,
-                                counters=self.breaker_events)
-        ) if enable_breaker else None
-        if self.breaker is not None and self.breaker.counters is None:
+        self.breaker = (breaker if breaker is not None
+                        else CircuitBreaker(clock=clock,
+                                            counters=self.breaker_events))
+        if self.breaker.counters is None:
             # a caller-built breaker without its own counters reports
             # through the server's registry, so stats() reconciles
             self.breaker.counters = self.breaker_events
@@ -880,9 +812,7 @@ class InferenceServer:
         self._iter = 0              # scheduler iterations served
         self._finalized = 0         # scheduler.finished timeline cursor
         self._rec_cursor = 0        # flight-recorder finished cursor
-        self._last_breaker_state = (self.breaker.state
-                                    if self.breaker is not None
-                                    else "disabled")
+        self._last_breaker_state = self.breaker.state
         # hang watchdog (docs/observability.md, "Ops plane &
         # watchdog"): the server owns the stall handler — thread
         # stacks + postmortem bundle + counter — and the thread's
@@ -947,8 +877,7 @@ class InferenceServer:
         requests keep BOTH fast paths — speculation and the pipelined
         loop — and are deterministic per (prompt, params, seed)
         thanks to counter-based keys (``docs/serving.md``,
-        "Stochastic sampling").  Ignored (with a construction-time
-        warning) when the server runs a legacy custom ``sample_fn``.
+        "Stochastic sampling").
 
         A request can come back already finished instead of enqueued
         — always with ``finished_at`` stamped at submission and never
@@ -1042,7 +971,7 @@ class InferenceServer:
                                     priority=req.priority)
         if self._draining:
             return self._finish_at_submit(req, reasons.DRAINING)
-        if self.breaker is not None and not self.breaker.allow():
+        if not self.breaker.allow():
             return self._finish_at_submit(req, reasons.BREAKER_OPEN)
         # predictive admission: a wall-deadlined arrival that cannot
         # meet its deadline even at the fastest service ever observed
@@ -1208,86 +1137,15 @@ class InferenceServer:
             self.pressure_gauge.update(sched.pressure())
             shed = sched.shed_overload()
 
-            with tr.span("admit"):
-                admitted = sched.admit()
-            if admitted:
-                now = self.clock()
-                for req in admitted:
-                    if req.admitted_at is None:
-                        req.admitted_at = now
-                    if tr.enabled:
-                        tr.instant(
-                            "request_admit", uid=req.uid,
-                            cached_tokens=req.cached_prefix_tokens)
-            # whole-context cache hits first duplicate their final
-            # shared block (copy-on-write) so the tail re-write stays
-            # private
-            cows = [r for r in sched._admit_order if r.pending_cow]
-            if cows:
-                try:
-                    with tr.span("cow_copy", blocks=len(cows)):
-                        engine.copy_blocks(
-                            [r.pending_cow for r in cows])
-                except MemoryError:
-                    # transient HBM burst: nothing was accounted, the
-                    # same copies re-launch next iteration
-                    # bit-identically
-                    self._note_oom("copy_blocks")
-                else:
-                    for req in cows:
-                        sched.cow_done(req)
+            admitted = self._admit(sched, engine)
 
         chunks = 0
         pipelined = self.pipelining
         for req in [r for r in sched._admit_order if r.prefilling]:
-            with tr.span("plan", uid=req.uid):
-                tokens, start, is_last = sched.prefill_plan(req)
-                # the per-request stochastic params ride the fused
-                # twin only when this launch's token will actually be
-                # sampled (final chunk of a fresh prefill) —
-                # mid-prefill chunks and preemption re-prefills keep
-                # the greedy program
-                samp1 = (sched.prefill_sampling(req)
-                         if pipelined and is_last and req.prefill_sample
-                         else None)
-                # kwarg omitted when greedy so duck-typed engine
-                # wrappers predating the stochastic twins keep working
-                skw = {"sampling": samp1} if samp1 is not None else {}
-                # no cached prefix, no chunking: the monolithic
-                # bucketed prefill (the pre-chunking path, bit-for-bit)
-                mono = (start == 0 and is_last
-                        and self.prefill_chunk is None)
-            # one span for the request's share of this step: the
-            # launch, its accounting and, on a final chunk, the read
-            # of its token
-            with tr.span("prefill" if mono else "chunk_prefill",
-                         uid=req.uid, tokens=len(tokens), start=start,
-                         **_rid(req)):
-                try:
-                    if mono:
-                        out = (engine.prefill_sampled(
-                            tokens, req.block_table,
-                            **skw) if pipelined
-                            else engine.prefill(tokens,
-                                                req.block_table))
-                    else:
-                        out = (engine.chunk_prefill_sampled(
-                            tokens, start, req.block_table,
-                            pad_to=self.prefill_chunk,
-                            **skw) if pipelined
-                            else engine.chunk_prefill(
-                                tokens, start, req.block_table,
-                                pad_to=self.prefill_chunk))
-                        chunks += 1
-                except MemoryError:
-                    # chunk_done not called: this exact chunk replays
-                    # next iteration, so generation stays bit-stable
-                    self._note_oom("prefill")
-                    continue
-                if self._phase is not None:
-                    self._phase["prefill_launches"] += 1
-                    self._phase["prefill_tokens"] += len(tokens)
-                done = sched.chunk_done(req, len(tokens))
+            with self._launch_chunk(sched, engine, req) as (out, done):
+                if out is None:
+                    continue          # out of memory: replays next step
+                chunks += 1
                 if not done or not req.prefill_sample:
                     # mid-prefill, or resumed after preemption (the
                     # pending token continues instead of these logits)
@@ -1312,16 +1170,14 @@ class InferenceServer:
                             tok = self._sample_prefill_host(req, logits)
                 if not finite:
                     sched.fail(req, reasons.NONFINITE)
-                    if self.breaker is not None:
-                        self.breaker.record_failure()
+                    self.breaker.record_failure()
                     continue
                 req.record_token(tok)
                 self._note_first_token(req)
                 produced += 1
                 if req.finished:
                     sched.retire(req)
-                    if self.breaker is not None:
-                        self.breaker.record_success()
+                    self.breaker.record_success()
 
         if sched.running:
             with tr.span("plan"):
@@ -1358,6 +1214,84 @@ class InferenceServer:
             self._account_step(produced, retired, chunks, admitted,
                                shed, plan_start, step_start, marks)
         return produced
+
+    def _admit(self, sched, engine) -> List[Request]:
+        """Admit what ``sched`` can take now: stamp ``admitted_at``,
+        emit the ``request_admit`` instants, and launch the
+        copy-on-write of whole-context cache hits on ``engine``.
+        Returns the admitted requests."""
+        tr = self.tracer
+        with tr.span("admit"):
+            admitted = sched.admit()
+        if admitted:
+            now = self.clock()
+            for req in admitted:
+                if req.admitted_at is None:
+                    req.admitted_at = now
+                if tr.enabled:
+                    tr.instant("request_admit", uid=req.uid,
+                               cached_tokens=req.cached_prefix_tokens)
+        # whole-context cache hits first duplicate their final shared
+        # block (copy-on-write) so the tail re-write stays private
+        cows = [r for r in sched._admit_order if r.pending_cow]
+        if cows:
+            try:
+                with tr.span("cow_copy", blocks=len(cows)):
+                    engine.copy_blocks([r.pending_cow for r in cows])
+            except MemoryError:
+                # transient HBM burst: nothing was accounted, the same
+                # copies re-launch next iteration bit-identically
+                self._note_oom("copy_blocks")
+            else:
+                for req in cows:
+                    sched.cow_done(req)
+        return admitted
+
+    @contextlib.contextmanager
+    def _launch_chunk(self, sched, engine, req):
+        """Launch ``req``'s next prefill chunk on ``engine``: plan it
+        (``plan`` span), then, under the request's ``chunk_prefill``
+        span, launch the sampled or the logits twin, count the phase
+        and tell ``sched`` the chunk is in (``chunk_done``).  Yields
+        ``(handles, done)`` INSIDE that span, so what the caller does
+        with a finished prompt (``_step`` reads its token at once) is
+        timed as the request's share of the step: the launch's
+        un-materialized result (token id and finite flag, or logits
+        in the synchronous loop) and whether the prompt is all in.
+        ``handles`` is None where the launch ran out of memory:
+        ``chunk_done`` was not called, so this exact chunk replays
+        next iteration and generation stays bit-stable."""
+        tr, pipelined = self.tracer, self.pipelining
+        with tr.span("plan", uid=req.uid):
+            tokens, start, is_last = sched.prefill_plan(req)
+            # the per-request stochastic params ride the fused twin
+            # only when this launch's token will actually be sampled
+            # (final chunk of a fresh prefill) — mid-prefill chunks and
+            # preemption re-prefills keep the greedy program
+            samp = (sched.prefill_sampling(req)
+                    if pipelined and is_last and req.prefill_sample
+                    else None)
+            # kwarg omitted when greedy so duck-typed engine wrappers
+            # predating the stochastic twins keep working
+            skw = {"sampling": samp} if samp is not None else {}
+        with tr.span("chunk_prefill", uid=req.uid, tokens=len(tokens),
+                     start=start, **_rid(req)):
+            try:
+                out = (engine.chunk_prefill_sampled(
+                    tokens, start, req.block_table,
+                    pad_to=self.prefill_chunk, **skw) if pipelined
+                    else engine.chunk_prefill(
+                        tokens, start, req.block_table,
+                        pad_to=self.prefill_chunk))
+            except MemoryError:
+                self._note_oom("prefill")
+                out, done = None, False
+            else:
+                if self._phase is not None:
+                    self._phase["prefill_launches"] += 1
+                    self._phase["prefill_tokens"] += len(tokens)
+                done = sched.chunk_done(req, len(tokens))
+            yield out, done
 
     def _account_step(self, produced, retired, chunks, admitted, shed,
                       plan_start, step_start, marks) -> None:
@@ -1419,9 +1353,7 @@ class InferenceServer:
                         self.spec.count("accepted_tokens") - accepted0,
                 },
                 "pressure": round(self.pressure_gauge.val, 4),
-                "breaker": (self.breaker.state
-                            if self.breaker is not None
-                            else "disabled"),
+                "breaker": self.breaker.state,
                 "memory": {
                     "free": alloc.num_free,
                     "live": alloc.num_live,
@@ -1456,25 +1388,24 @@ class InferenceServer:
             self._phase = None
         # breaker-open transition: the moment worth a black box — dump
         # a bundle while the ring still holds the steps leading up
-        if self.breaker is not None:
-            state = self.breaker.state
-            if state != self._last_breaker_state:
-                self._last_breaker_state = state
-                if state == "open":
-                    self._auto_postmortem("breaker_open")
+        state = self.breaker.state
+        if state != self._last_breaker_state:
+            self._last_breaker_state = state
+            if state == "open":
+                self._auto_postmortem("breaker_open")
 
     def _sample_prefill_host(self, req, logits) -> int:
         """Sample one request's prefill token from materialized
         ``(V,)`` logits — the synchronous loop's half of the sampling
-        contract.  Greedy requests (and every request on a legacy
-        custom ``sample_fn``) keep the historical ``sample_fn`` call
-        byte-for-byte; stochastic requests draw through the SAME
+        contract.  Greedy requests take the host argmax
+        (:func:`greedy_sample`); stochastic requests draw through the
+        SAME
         jitted :func:`ops.sample_tokens` the fused programs use, with
         the same counter key (the token's sequence index ==
         ``num_cached`` after the final chunk accounted), so the two
         loops emit identical streams."""
-        if req.sampling.is_greedy or self.sample_fn is not greedy_sample:
-            return int(self.sample_fn(logits))
+        if req.sampling.is_greedy:
+            return int(greedy_sample(logits))
         samp = self.scheduler.prefill_sampling(req)
         counter = np.asarray([req.num_cached], np.int32)
         ids, _fin = sample_tokens_host(logits[None], *samp, counter)
@@ -1527,8 +1458,7 @@ class InferenceServer:
     def _decode_step(self, running) -> int:
         """One batched single-token decode over ``running``,
         materialized and applied in the same call (the synchronous
-        loop; also the custom-``sample_fn`` path).  Returns tokens
-        produced."""
+        loop).  Returns tokens produced."""
         engine, tr = self.engine, self.tracer
         with tr.span("inputs", program="decode"):
             tokens, positions, tables = self._decode_inputs(running)
@@ -1547,10 +1477,9 @@ class InferenceServer:
                 self._phase["decode_launches"] += 1
                 self._phase["decode_tokens"] += len(running)
             finite = np.all(np.isfinite(logits), axis=-1)
-            samp = (self.scheduler.sampling_inputs(running)
-                    if self.sample_fn is greedy_sample else None)
+            samp = self.scheduler.sampling_inputs(running)
             if samp is None:
-                toks = self.sample_fn(logits)
+                toks = greedy_sample(logits)
             else:
                 # the synchronous stochastic path: the SAME jitted
                 # sampler as the fused twin, fed the same counter keys
@@ -1643,8 +1572,7 @@ class InferenceServer:
                 continue      # failed between launch and retire
             if not finite[req.slot]:
                 sched.fail(req, reasons.NONFINITE)
-                if self.breaker is not None:
-                    self.breaker.record_failure(now)
+                self.breaker.record_failure(now)
                 continue
             req.num_cached += 1
             req.record_token(int(toks[req.slot]))
@@ -1652,8 +1580,7 @@ class InferenceServer:
             produced += 1
             if req.finished:
                 sched.retire(req)
-                if self.breaker is not None:
-                    self.breaker.record_success()
+                self.breaker.record_success()
             else:
                 # index any block this token just filled so a later
                 # shared-prefix request can match it
@@ -1754,10 +1681,9 @@ class InferenceServer:
                 self._phase["verify_columns"] += (
                     len(running) + sum(len(d) for d in drafts.values()))
             finite = np.all(np.isfinite(logits), axis=-1)      # (B, K)
-            samp = (self.scheduler.sampling_inputs(running)
-                    if self.sample_fn is greedy_sample else None)
+            samp = self.scheduler.sampling_inputs(running)
             if samp is None:
-                row_toks = self.sample_fn(logits)              # (B, K)
+                row_toks = greedy_sample(logits)               # (B, K)
             else:
                 # every verify column sampled with its own positional
                 # counter key — acceptance below compares drafts to
@@ -1835,8 +1761,7 @@ class InferenceServer:
             n = int(lengths[req.slot])
             if not np.all(finite[req.slot, :n]):
                 sched.fail(req, reasons.NONFINITE)
-                if self.breaker is not None:
-                    self.breaker.record_failure(now)
+                self.breaker.record_failure(now)
                 continue
             toks = row_toks[req.slot]                      # (K,)
             d = drafts.get(req.uid, ())
@@ -1886,8 +1811,7 @@ class InferenceServer:
                         self.spec.incr("stoch_resamples")
             if req.finished:
                 sched.retire(req)
-                if self.breaker is not None:
-                    self.breaker.record_success()
+                self.breaker.record_success()
             else:
                 # index any blocks the accepted tokens just filled,
                 # then release lookahead blocks holding only
@@ -2059,9 +1983,7 @@ class InferenceServer:
                         self.spec.count("accepted_tokens") - accepted0,
                 },
                 "pressure": round(self.pressure_gauge.val, 4),
-                "breaker": (self.breaker.state
-                            if self.breaker is not None
-                            else "disabled"),
+                "breaker": self.breaker.state,
                 "memory": {
                     "free": alloc.num_free,
                     "live": alloc.num_live,
@@ -2097,12 +2019,11 @@ class InferenceServer:
                     if r.journey is not None}
             rec.record(step_rec)
             self._phase = None
-        if self.breaker is not None:
-            state = self.breaker.state
-            if state != self._last_breaker_state:
-                self._last_breaker_state = state
-                if state == "open":
-                    self._auto_postmortem("breaker_open")
+        state = self.breaker.state
+        if state != self._last_breaker_state:
+            self._last_breaker_state = state
+            if state == "open":
+                self._auto_postmortem("breaker_open")
         return produced
 
     def _prefill_slice(self):
@@ -2114,65 +2035,19 @@ class InferenceServer:
         under pipelining), so the slice costs the host little more
         than dispatch.  Returns ``(chunk launches, tokens produced,
         admitted requests)``."""
-        psched, engine, tr = (self.prefill_scheduler,
-                              self.prefill_engine, self.tracer)
+        psched, engine = self.prefill_scheduler, self.prefill_engine
         pipelined = self.pipelining
-        with tr.span("admit"):
-            admitted = psched.admit()
-        if admitted:
-            now = self.clock()
-            for req in admitted:
-                if req.admitted_at is None:
-                    req.admitted_at = now
-                if tr.enabled:
-                    tr.instant("request_admit", uid=req.uid,
-                               cached_tokens=req.cached_prefix_tokens)
-        cows = [r for r in psched._admit_order if r.pending_cow]
-        if cows:
-            try:
-                with tr.span("cow_copy", blocks=len(cows)):
-                    engine.copy_blocks([r.pending_cow for r in cows])
-            except MemoryError:
-                self._note_oom("copy_blocks")
-            else:
-                for req in cows:
-                    psched.cow_done(req)
+        admitted = self._admit(psched, engine)
         chunks = 0
         produced = 0
         for req in [r for r in psched._admit_order if r.prefilling]:
-            tokens, start, is_last = psched.prefill_plan(req)
-            samp1 = (psched.prefill_sampling(req)
-                     if pipelined and is_last and req.prefill_sample
-                     else None)
-            skw = {"sampling": samp1} if samp1 is not None else {}
-            try:
-                if (start == 0 and is_last
-                        and self.prefill_chunk is None):
-                    with tr.span("prefill", uid=req.uid,
-                                 tokens=len(tokens)):
-                        out = (engine.prefill_sampled(
-                            tokens, req.block_table,
-                            **skw) if pipelined
-                            else engine.prefill(tokens,
-                                                req.block_table))
-                else:
-                    with tr.span("chunk_prefill", uid=req.uid,
-                                 tokens=len(tokens), start=start):
-                        out = (engine.chunk_prefill_sampled(
-                            tokens, start, req.block_table,
-                            pad_to=self.prefill_chunk,
-                            **skw) if pipelined
-                            else engine.chunk_prefill(
-                                tokens, start, req.block_table,
-                                pad_to=self.prefill_chunk))
-                    chunks += 1
-            except MemoryError:
-                self._note_oom("prefill")
-                continue
-            if self._phase is not None:
-                self._phase["prefill_launches"] += 1
-                self._phase["prefill_tokens"] += len(tokens)
-            done = psched.chunk_done(req, len(tokens))
+            # the launch alone is the chunk's span here: a finished
+            # prompt waits for its hand-off, nothing is read
+            with self._launch_chunk(psched, engine, req) as (out, done):
+                pass
+            if out is None:
+                continue              # out of memory: replays next step
+            chunks += 1
             if not done:
                 continue
             if not req.prefill_sample:
@@ -2191,8 +2066,7 @@ class InferenceServer:
             logits = np.asarray(out)
             if not np.all(np.isfinite(logits)):
                 psched.fail(req, reasons.NONFINITE)
-                if self.breaker is not None:
-                    self.breaker.record_failure()
+                self.breaker.record_failure()
                 continue
             tok = self._sample_prefill_host(req, logits)
             req.record_token(tok)
@@ -2200,8 +2074,7 @@ class InferenceServer:
             produced += 1
             if req.finished:
                 psched.retire(req)
-                if self.breaker is not None:
-                    self.breaker.record_success()
+                self.breaker.record_success()
                 continue
             self._handoff.append(_Handoff(req))
         return chunks, produced, admitted
@@ -2232,8 +2105,7 @@ class InferenceServer:
                 ent.handles = None
                 if not bool(np.asarray(fin)[0]):
                     psched.fail(req, reasons.NONFINITE)
-                    if self.breaker is not None:
-                        self.breaker.record_failure()
+                    self.breaker.record_failure()
                     q.popleft()
                     continue
                 req.record_token(int(np.asarray(ids)[0]))
@@ -2241,8 +2113,7 @@ class InferenceServer:
                 produced += 1
                 if req.finished:
                     psched.retire(req)
-                    if self.breaker is not None:
-                        self.breaker.record_success()
+                    self.breaker.record_success()
                     self.handoffs.incr("finished_at_prefill")
                     q.popleft()
                     continue
@@ -2407,8 +2278,7 @@ class InferenceServer:
         iteration; the circuit breaker counts it as a failure so a
         sustained OOM burst trips fast rejection at the front door."""
         self.oom.incr(site)
-        if self.breaker is not None:
-            self.breaker.record_failure()
+        self.breaker.record_failure()
         if self.tracer.enabled:
             self.tracer.instant("engine_oom", site=site)
 
@@ -3084,8 +2954,7 @@ class InferenceServer:
         """The ``stats()["programs"]`` block: the per-compiled-program
         table (call count, host wall time, compile count/time,
         steady-state per-call ms per program/shape key) plus the
-        totals — empty ``by_program`` when accounting is off — and
-        ``attention``, which way each serving program family was
+        totals and ``attention``, which way each serving program family was
         built to attend: ``"table"`` (the pool read in place through
         the block table) or ``"gathered"``, fixed when the engine
         built its programs."""
@@ -3155,9 +3024,7 @@ class InferenceServer:
             # "Overload policy & lifecycle")
             "pressure": round(self.pressure_gauge.val, 3),
             "pressure_peak": round(self.pressure_gauge.peak, 3),
-            "breaker_state": (self.breaker.state
-                              if self.breaker is not None
-                              else "disabled"),
+            "breaker_state": self.breaker.state,
             "breaker_events": self.breaker_events.as_dict(),
             "oom_events": self.oom.total,
             "draining": self._draining,
@@ -3187,16 +3054,13 @@ class InferenceServer:
                     self.spec_accepted_hist),
             },
             # stochastic sampling (docs/serving.md, "Stochastic
-            # sampling"): per-class request traffic, the legacy
-            # custom-sample_fn downgrade flag, and the
+            # sampling"): per-class request traffic and the
             # rejection-sampling accounting — stochastic drafts
             # accept with prob p(draft) under the Gumbel-max
             # coupling, each first rejection emitting one residual
             # resample
             "sampling": {
                 "requests": self.sampling_classes.as_dict(),
-                "custom_sample_fn":
-                    self.sample_fn is not greedy_sample,
                 "rejection": {
                     "drafted_tokens":
                         self.spec.count("stoch_drafted_tokens"),

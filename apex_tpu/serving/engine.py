@@ -1,22 +1,16 @@
-"""Jit-compiled prefill + single-token decode steps over the KV cache.
+"""Jit-compiled chunk prefill + single-token decode steps over the KV cache.
 
 Compiled programs, all fixed-shape so the continuous-batching loop
 never recompiles in steady state:
 
-- **prefill** (one request, prompt padded to a length *bucket*): the
-  model's ordinary causal forward — for GPT optionally through the
-  flash kernel via ``attention_fn`` — with ``return_kv=True``; the
-  per-layer rows (a head's K and V, or a latent row: what the model's
-  family keeps, ``models/family.py``) are scattered into the request's
-  blocks in the same program.  One
-  trace per bucket length, so the compile count is bounded by
-  ``len(prefill_buckets)``, not by the distribution of prompt lengths.
 - **chunk prefill** (one request, one fixed-width chunk at a carried
-  KV position): the chunked-prefill and prefix-cached-tail workhorse —
-  each layer writes the chunk's K/V at its block-offset slots and the
-  chunk attends the request's already-cached context through its block
-  table plus itself causally.  A fixed chunk size means ONE trace
-  however long prompts get.
+  KV position): the one way a prompt, or a prefix-cached prompt's
+  tail, gets into the pool — each layer writes the chunk's rows (a
+  head's K and V, or a latent row: what the model's family keeps,
+  ``models/family.py``) at its block-offset slots and the chunk
+  attends the request's already-cached context through its block
+  table plus itself causally.  One trace a chunk width, so a fixed
+  chunk size means ONE trace however long prompts get.
 - **decode** (the whole running batch, always ``max_batch_size``
   wide): the model runs on one token per slot at its own position;
   each layer writes the token's K/V into the pool and attends through
@@ -35,8 +29,8 @@ never recompiles in steady state:
 - **block copy** (fixed-width (src, dst) id batch): whole-block
   duplication inside the pool — the device half of the prefix cache's
   copy-on-write.  Compiled exactly once.
-- **sampled variants** (``prefill_sampled`` / ``chunk_prefill_sampled``
-  / ``decode_sampled`` / ``verify_sampled``): the same programs with
+- **sampled variants** (``chunk_prefill_sampled`` /
+  ``decode_sampled`` / ``verify_sampled``): the same programs with
   greedy argmax and the non-finite row guard fused in
   (:func:`ops.greedy_argmax` / :func:`ops.finite_rows`), returning
   token ids + per-row finite flags instead of logits.  The per-step
@@ -91,7 +85,7 @@ from __future__ import annotations
 
 import functools
 import warnings
-from typing import Optional, Sequence, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -119,7 +113,6 @@ from apex_tpu.serving.kv_cache import (
     resolve_kv_quant,
     slot_index,
     write_blocks,
-    write_layer,
 )
 
 # CPU backends can't honor donation; the fallback copy is exactly the
@@ -128,31 +121,6 @@ warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
 
-def default_prefill_buckets(max_context: int,
-                            smallest: int = 16) -> Tuple[int, ...]:
-    """Power-of-two bucket ladder capped at ``max_context`` — each
-    prompt pads to the next rung, so at most ``log2`` distinct prefill
-    shapes ever compile and no prompt pads to more than 2x its
-    length."""
-    buckets = []
-    b = smallest
-    while b < max_context:
-        buckets.append(b)
-        b *= 2
-    buckets.append(max_context)
-    return tuple(buckets)
-
-
-def pick_bucket(length: int, buckets: Sequence[int]) -> int:
-    """Smallest bucket >= ``length`` (buckets ascending); raises past
-    the largest — one definition shared by ``DecodeEngine.bucket_for``
-    and its edge-case tests."""
-    for b in buckets:
-        if length <= b:
-            return b
-    raise ValueError(
-        f"length {length} exceeds the largest bucket {buckets[-1]}")
-
 # padded width of one copy_blocks launch: COW duplicates arrive one or
 # two at a time, so a single fixed shape keeps the program count at 1
 _COPY_WIDTH = 8
@@ -160,7 +128,7 @@ _COPY_WIDTH = 8
 
 class DecodeEngine:
     """The device half of the serving stack: owns the cache pool, the
-    compiled prefill/decode programs, and nothing else — admission,
+    compiled chunk-prefill/decode programs, and nothing else — admission,
     batching composition, and termination live in
     ``serving.scheduler``/``serving.api``.
 
@@ -193,21 +161,15 @@ class DecodeEngine:
         "Quantized KV cache").  ``cache_dtype`` keeps naming the
         compute dtype the values widen to.  Default ``None`` (the
         historical full-width pool, byte-identical programs).
-      attention_fn: optional fused attention for the PREFILL pass
-        (``make_flash_attention(causal=True)`` on TPU); decode always
-        attends the pool, through the block table or gathered
-        (``attention_paths``).
-      prefill_buckets: ascending prompt-length buckets; None =
-        :func:`default_prefill_buckets`.
       tracer: optional :class:`apex_tpu.observability.SpanTracer`;
-        when enabled, every first-compile of a prefill/chunk/decode/
+        when enabled, every first-compile of a chunk/decode/verify/
         copy program emits a ``compile`` instant event (recompiles in
         steady state are exactly what the trace is for catching).
       programs: optional
         :class:`apex_tpu.observability.ProgramAccounting` — every
         host-API launch is tallied per program key
-        (``prefill[<bucket>]`` / ``chunk_prefill[<width>]`` /
-        ``decode`` / ``verify[<width>]`` / sampled twins /
+        (``chunk_prefill[<width>]`` / ``decode`` /
+        ``verify[<width>]`` / sampled twins /
         ``copy_blocks``): call count, host wall time, compile count,
         compile time.  Default: the zero-overhead disabled instance
         (``InferenceServer`` passes a registry-backed one).
@@ -239,8 +201,6 @@ class DecodeEngine:
                  block_size: int = 16,
                  cache_dtype=None,
                  kv_quant: Optional[str] = None,
-                 attention_fn=None,
-                 prefill_buckets: Optional[Sequence[int]] = None,
                  tracer=None,
                  programs=None,
                  mesh=None,
@@ -279,7 +239,7 @@ class DecodeEngine:
             if mesh.size > 1 and on_tpu():
                 # the decode programs lower through GSPMD, which
                 # cannot partition the Mosaic kernels in them
-                # (cached_attention, FusedLayerNorm, flash prefill):
+                # (cached_attention, FusedLayerNorm):
                 # refuse here rather than fail in the first launch or
                 # serve from the jnp references unannounced
                 raise NotImplementedError(
@@ -349,20 +309,11 @@ class DecodeEngine:
         self.attention_paths = dict.fromkeys(
             ("decode", "verify", "chunk_prefill"),
             "table" if in_place else "gathered")
-        self.model = cfg.build_model(attention_fn=attention_fn,
-                                     kv_quant=self.quantized)
+        self.model = cfg.build_model(kv_quant=self.quantized)
         # what the family's programs carry beside the pool
         self._counters = dict(getattr(cfg, "serving_counters",
                                       dict)())
         self.cache = self._fresh_cache()
-        if prefill_buckets is None:
-            prefill_buckets = default_prefill_buckets(self.max_context)
-        self.prefill_buckets = tuple(sorted(int(b)
-                                            for b in prefill_buckets))
-        if self.prefill_buckets[-1] < self.max_context:
-            raise ValueError(
-                f"largest prefill bucket {self.prefill_buckets[-1]} "
-                f"< max_context {self.max_context}")
 
         # under a mesh every program pins its output placements so
         # GSPMD keeps the (donated) pool head-sharded and replicates
@@ -382,8 +333,6 @@ class DecodeEngine:
             if self.quantized:
                 cache_sh["k_scale"] = self._scale_shard
                 cache_sh["v_scale"] = self._scale_shard
-        self._prefill_jit = _jit(self._prefill_impl, (1,),
-                                 (cache_sh, repl))
         self._decode_jit = _jit(self._decode_impl, (1,),
                                 (cache_sh, repl))
         self._chunk_jit = _jit(self._chunk_impl, (1,),
@@ -414,9 +363,6 @@ class DecodeEngine:
         # so trading the (already-copied-anyway) in-place update for
         # an async launch is the right side of the bargain there.
         sampled_cache = (1,) if jax.default_backend() != "cpu" else ()
-        self._prefill_sampled_jit = _jit(self._prefill_sampled_impl,
-                                         sampled_cache,
-                                         (cache_sh, repl, repl))
         self._chunk_sampled_jit = _jit(self._chunk_sampled_impl,
                                        sampled_cache,
                                        (cache_sh, repl, repl))
@@ -436,9 +382,6 @@ class DecodeEngine:
         # stochastic program only compiles once the first stochastic
         # request is actually batched.  Greedy rows INSIDE a
         # stochastic launch still take the bit-exact argmax lane.
-        self._prefill_stoch_jit = _jit(self._prefill_stoch_impl,
-                                       sampled_cache,
-                                       (cache_sh, repl, repl))
         self._chunk_stoch_jit = _jit(self._chunk_stoch_impl,
                                      sampled_cache,
                                      (cache_sh, repl, repl))
@@ -466,26 +409,6 @@ class DecodeEngine:
             cache, tables, start.astype(jnp.int32), slots,
             block_size=self.block_size, row=self.row,
             table=self.attention_paths[program] == "table")
-
-    def _prefill_impl(self, params, cache, ids, length, table):
-        """ids (1, Sb) zero-padded prompt; length (1,) true length;
-        table (1, blocks_per_seq).  Returns (cache, last-token logits
-        (1, V))."""
-        sb = ids.shape[1]
-        pos = jnp.arange(sb, dtype=jnp.int32)[None, :]
-        mask = (pos < length[:, None]).astype(jnp.int32)
-        logits, kvs = self.model.apply(
-            {"params": params}, ids, attention_mask=mask,
-            deterministic=True, return_kv=True)
-        # padded positions scatter into the garbage block (slot 0)
-        slots = jnp.where(mask > 0,
-                          slot_index(table, pos, self.block_size), 0)
-        for layer, kv in enumerate(kvs):     # each an in-place update
-            cache = write_layer(cache, layer, kv, slots)
-        last = jnp.take_along_axis(
-            logits, (length[:, None, None] - 1).astype(jnp.int32),
-            axis=1)[:, 0]                             # (1, V)
-        return cache, last
 
     def _chunk_impl(self, params, cache, ids, start, length, table):
         """One prefill CHUNK at a carried KV position: ids (1, Cb)
@@ -601,11 +524,6 @@ class DecodeEngine:
                                          self.tp_axis)
         return greedy_argmax(logits), finite_rows(logits)
 
-    def _prefill_sampled_impl(self, params, cache, ids, length, table):
-        cache, last = self._prefill_impl(params, cache, ids, length,
-                                         table)
-        return (cache,) + self._sample(last)                   # (1,)
-
     def _chunk_sampled_impl(self, params, cache, ids, start, length,
                             table):
         cache, last = self._chunk_impl(params, cache, ids, start,
@@ -650,15 +568,6 @@ class DecodeEngine:
             return vocab_parallel_sample_tokens(
                 logits, *args, counters, self.mesh, self.tp_axis)
         return sample_tokens(logits, *args, counters)
-
-    def _prefill_stoch_impl(self, params, cache, ids, length, table,
-                            temp, tk, tp_, seed):
-        cache, last = self._prefill_impl(params, cache, ids, length,
-                                         table)
-        # the prefill-sampled token's sequence index == prompt length
-        ids_out, fin = self._sample_stoch(last, length, temp, tk,
-                                          tp_, seed)
-        return cache, ids_out, fin                             # (1,)
 
     def _chunk_stoch_impl(self, params, cache, ids, start, length,
                           table, temp, tk, tp_, seed):
@@ -732,21 +641,13 @@ class DecodeEngine:
     def _qkey(self, key=None):
         """The :class:`ProgramAccounting` bucket/width key for one
         launch, grown a ``q8`` tag under quantization — quant-on
-        traces account under distinct keys (``prefill[64q8]``,
-        ``decode[q8]``) so compile-count and wall-time audits can
-        bound the quantized program variants separately
+        traces account under distinct keys
+        (``chunk_prefill[64q8]``, ``decode[q8]``) so compile-count and
+        wall-time audits can bound the quantized program variants separately
         (``tools/ops_probe.py --programs``)."""
         if not self.quantized:
             return key
         return "q8" if key is None else f"{key}q8"
-
-    def bucket_for(self, length: int) -> int:
-        try:
-            return pick_bucket(length, self.prefill_buckets)
-        except ValueError:
-            raise ValueError(
-                f"prompt length {length} exceeds max_context "
-                f"{self.max_context}") from None
 
     def _put(self, *arrays):
         """ONE host→device handoff for a launch's whole argument
@@ -762,71 +663,22 @@ class DecodeEngine:
             return jax.device_put(arrays, self._repl)
         return jax.device_put(arrays)
 
-    def _prefill_args(self, prompt, block_table, sampling=None):
-        """The prefill launch struct: (ids, length, table[, sampling
-        params]) on device in one transfer, plus the bucket the
-        prompt padded to."""
-        n = len(prompt)
-        sb = self.bucket_for(n)
-        ids = np.zeros((1, sb), np.int32)
-        ids[0, :n] = prompt
-        table = np.zeros((1, self.blocks_per_seq), np.int32)
-        table[0, :len(block_table)] = block_table
-        extra = tuple(sampling) if sampling is not None else ()
-        return self._put(ids, np.asarray([n], np.int32), table,
-                         *extra), sb
-
     def _chunk_args(self, tokens, start, block_table, pad_to,
                     sampling=None):
         """The chunk launch struct: (ids, start, length, table[,
-        sampling params]) on device in one transfer, plus the
-        compiled chunk width."""
+        sampling params]) on device in one transfer, the chunk padded
+        to the compiled width ``pad_to``."""
         n = len(tokens)
-        cb = pad_to if pad_to is not None else self.bucket_for(n)
-        if n > cb:
+        if n > pad_to:
             raise ValueError(
-                f"chunk of {n} tokens exceeds pad_to={cb}")
-        ids = np.zeros((1, cb), np.int32)
+                f"chunk of {n} tokens exceeds pad_to={pad_to}")
+        ids = np.zeros((1, pad_to), np.int32)
         ids[0, :n] = tokens
         table = np.zeros((1, self.blocks_per_seq), np.int32)
         table[0, :len(block_table)] = block_table
         extra = tuple(sampling) if sampling is not None else ()
         return self._put(ids, np.asarray([start], np.int32),
-                         np.asarray([n], np.int32), table, *extra), cb
-
-    def prefill(self, prompt, block_table) -> jax.Array:
-        """Run one prompt through the bucketed prefill, writing its
-        K/V into ``block_table``'s blocks.  Returns the last-token
-        logits (V,)."""
-        args, sb = self._prefill_args(prompt, block_table)
-        mark = self._mark(self._prefill_jit)
-        self.cache, last = self._prefill_jit(self.params, self.cache,
-                                             *args)
-        self._account(self._prefill_jit, mark, "prefill",
-                      key=self._qkey(sb), bucket=sb)
-        return last[0]
-
-    def prefill_sampled(self, prompt, block_table, sampling=None):
-        """The fused-sampling twin of :meth:`prefill`: returns
-        ``(token_ids (1,) int32, finite (1,) bool)`` device arrays —
-        the prompt's next token and its non-finite guard — without
-        materializing logits on the host.  ``sampling=None`` (the
-        default) launches the greedy argmax program; a
-        ``(temperature, top_k, top_p, seed)`` tuple of ``(1,)``
-        arrays launches the stochastic twin (``docs/serving.md``,
-        "Stochastic sampling"; a 0-temperature row inside it is still
-        bit-exact argmax)."""
-        args, sb = self._prefill_args(prompt, block_table,
-                                      sampling=sampling)
-        if sampling is None:
-            jit_fn, name = self._prefill_sampled_jit, "prefill_sampled"
-        else:
-            jit_fn, name = self._prefill_stoch_jit, "prefill_stoch"
-        mark = self._mark(jit_fn)
-        self.cache, ids, fin = jit_fn(self.params, self.cache, *args)
-        self._account(jit_fn, mark, name, key=self._qkey(sb),
-                      bucket=sb)
-        return ids, fin
+                         np.asarray([n], np.int32), table, *extra)
 
     def swap_params(self, params) -> None:
         """In-place weight swap: rebind ``self.params`` to a new
@@ -845,35 +697,37 @@ class DecodeEngine:
         self.params = params
 
     def chunk_prefill(self, tokens, start: int, block_table,
-                      pad_to: Optional[int] = None) -> jax.Array:
+                      pad_to: int) -> jax.Array:
         """Run one prefill chunk — ``tokens`` at absolute positions
         ``start..start+len-1`` — writing its K/V through
         ``block_table``; K/V for positions < start must already be
         materialized (earlier chunks or shared prefix-cache blocks).
         Returns the chunk's last-token logits (V,).
 
-        ``pad_to`` is the compiled chunk width (default: the prompt
-        bucket for ``len(tokens)``); a steady chunked-prefill loop
-        passes its fixed chunk size so exactly one chunk program ever
+        ``pad_to`` is the compiled chunk width: the serve loop passes
+        its fixed ``prefill_chunk``, so exactly one chunk program ever
         compiles."""
-        args, cb = self._chunk_args(tokens, start, block_table, pad_to)
+        args = self._chunk_args(tokens, start, block_table, pad_to)
         mark = self._mark(self._chunk_jit)
         self.cache, last = self._chunk_jit(self.params, self.cache,
                                            *args)
         self._account(self._chunk_jit, mark, "chunk_prefill",
-                      key=self._qkey(cb), width=cb)
+                      key=self._qkey(pad_to), width=pad_to)
         return last[0]
 
     def chunk_prefill_sampled(self, tokens, start: int, block_table,
-                              pad_to: Optional[int] = None,
-                              sampling=None):
+                              pad_to: int, sampling=None):
         """The fused-sampling twin of :meth:`chunk_prefill`: returns
         ``(token_ids (1,) int32, finite (1,) bool)`` device arrays for
         the chunk's last valid token (only meaningful on the final
-        chunk, exactly like the logits twin).  ``sampling`` as in
-        :meth:`prefill_sampled`."""
-        args, cb = self._chunk_args(tokens, start, block_table,
-                                    pad_to, sampling=sampling)
+        chunk, exactly like the logits twin) without materializing
+        logits on the host.  ``sampling=None`` (the default) launches
+        the greedy argmax program; a ``(temperature, top_k, top_p,
+        seed)`` tuple of ``(1,)`` arrays launches the stochastic twin
+        (``docs/serving.md``, "Stochastic sampling"; a 0-temperature
+        row inside it is still bit-exact argmax)."""
+        args = self._chunk_args(tokens, start, block_table, pad_to,
+                                sampling=sampling)
         if sampling is None:
             jit_fn, name = (self._chunk_sampled_jit,
                             "chunk_prefill_sampled")
@@ -881,8 +735,8 @@ class DecodeEngine:
             jit_fn, name = self._chunk_stoch_jit, "chunk_prefill_stoch"
         mark = self._mark(jit_fn)
         self.cache, ids, fin = jit_fn(self.params, self.cache, *args)
-        self._account(jit_fn, mark, name, key=self._qkey(cb),
-                      width=cb)
+        self._account(jit_fn, mark, name, key=self._qkey(pad_to),
+                      width=pad_to)
         return ids, fin
 
     def copy_blocks(self, pairs) -> None:
@@ -974,8 +828,8 @@ class DecodeEngine:
         ``block_ids`` (same count, same geometry).  Verifies the
         per-leaf checksums first and raises :class:`ValueError` on any
         mismatch — a torn hand-off must be rejected whole (the caller
-        falls back to a fresh monolithic prefill, which is
-        bit-identical), never half-imported."""
+        falls back to a fresh prefill, which is bit-identical), never
+        half-imported."""
         import zlib
 
         if payload.get("block_size") != self.block_size \
@@ -1124,21 +978,16 @@ class DecodeEngine:
     # -- introspection ----------------------------------------------------
 
     def compile_counts(self):
-        """(prefill traces, decode traces) — the recompile audit the
-        scheduler tests pin: prefill (monolithic buckets + chunk
-        widths) <= len(prefill_buckets), decode == 1 regardless of
-        traffic.  A fixed-chunk loop contributes exactly one chunk
-        trace (``chunk_prefill(pad_to=...)``).  Logits, sampled, and
-        stochastic twins count together: greedy-only traffic runs
-        exactly one path per program (the historical bounds hold
-        unchanged), and the first stochastic request adds at most one
+        """(chunk-prefill traces, decode traces) — the recompile audit
+        the scheduler tests pin: one chunk trace a width (a server
+        feeds one, its ``prefill_chunk``), decode == 1 regardless of
+        traffic.  Logits, sampled, and stochastic twins count
+        together: greedy-only traffic runs exactly one path per
+        program, and the first stochastic request adds at most one
         extra trace per program family — still O(1) per shape key,
         never per request."""
-        return (self._prefill_jit._cache_size()
-                + self._chunk_jit._cache_size()
-                + self._prefill_sampled_jit._cache_size()
+        return (self._chunk_jit._cache_size()
                 + self._chunk_sampled_jit._cache_size()
-                + self._prefill_stoch_jit._cache_size()
                 + self._chunk_stoch_jit._cache_size(),
                 self._decode_jit._cache_size()
                 + self._decode_sampled_jit._cache_size()
@@ -1184,12 +1033,11 @@ class DecodeEngine:
         if self.mesh is None:
             return 0
         return sum(j._cache_size() for j in (
-            self._prefill_jit, self._chunk_jit, self._decode_jit,
-            self._verify_jit, self._copy_jit,
-            self._prefill_sampled_jit, self._chunk_sampled_jit,
+            self._chunk_jit, self._decode_jit, self._verify_jit,
+            self._copy_jit, self._chunk_sampled_jit,
             self._decode_sampled_jit, self._verify_sampled_jit,
-            self._prefill_stoch_jit, self._chunk_stoch_jit,
-            self._decode_stoch_jit, self._verify_stoch_jit))
+            self._chunk_stoch_jit, self._decode_stoch_jit,
+            self._verify_stoch_jit))
 
     def memory_info(self) -> dict:
         """Static pool geometry for ``stats()["memory"]`` and

@@ -839,9 +839,7 @@ class RouterFleet:
         """Fleet aggregates + the pinned ``stats()["router"]`` block
         (``docs/serving.md``, "Multi-replica routing").  Aggregate
         prefix-cache counters sum the replicas' — the fleet-level
-        hit rate is what the affinity policy exists to raise
-        (``tools/serving_bench.py --router`` floors it vs random
-        placement)."""
+        hit rate is what the affinity policy exists to raise."""
         with (self._ops_lock or _NO_LOCK):
             return self._stats()
 

@@ -29,10 +29,9 @@ over chain descendants — a dangling parent must take its children
 with it, exactly the :class:`~serving.prefix_cache.PrefixCache`
 eviction rule, because a child key embeds its parent's node id.
 
-``kind="random"`` (seeded) exists for the bench's control arm
-(``tools/serving_bench.py --router``): the A/B that proves affinity
-actually concentrates cache hits is affinity-vs-random on identical
-shared-prefix traffic.
+``kind="random"`` (seeded) is the control arm: the A/B that proves
+affinity actually concentrates cache hits is affinity-vs-random on
+identical shared-prefix traffic.
 """
 
 from __future__ import annotations

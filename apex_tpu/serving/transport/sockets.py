@@ -49,7 +49,7 @@ units in ``tests/L0/test_transport.py`` pin directly.
 When NOT to use this backend: same-process pools (the default
 everywhere).  It exists for the cross-process topology and costs a
 host serialize/deserialize round-trip per transfer plus a connection
-setup — ``serving_bench --transport`` records the gap.
+setup.
 """
 
 from __future__ import annotations

@@ -411,33 +411,6 @@ def phase_server(out, cfg, params, *, prompt_lens=PROMPT_LENS,
     assert server.closed
 
 
-def phase_server_prefill(out, cfg, params, *, prompt_len=200, max_new=4,
-                         gap_tol=0.1, seed=1):
-    """The monolithic prefill program with the flash kernel: under the
-    default chunked prefill every prompt goes through the chunk
-    program, so the bucketed prefill (and ``attention_fn``) only runs
-    with chunking off, as here."""
-    rng = np.random.RandomState(seed)
-    prompts = [rng.randint(0, cfg.vocab_size, prompt_len).tolist()
-               for _ in range(2)]
-    server = InferenceServer(
-        cfg, params, max_batch_size=2, enable_chunked_prefill=False,
-        attention_fn=make_flash_attention(causal=True))
-    reqs = server.generate(prompts, max_new, return_requests=True)
-    st = server.stats()
-    assert all(r.finish_reason == "length" for r in reqs)
-    assert st["requests_failed_total"] == 0, st["requests_failed"]
-    calls = sum(rec["calls"] for key, rec in
-                st["programs"]["by_program"].items()
-                if key.startswith("prefill_sampled"))
-    out["prefill_calls"] = calls
-    assert calls == len(prompts), st["programs"]["by_program"]
-    gaps, valid = _recompute_gaps(cfg, params, reqs, max_new)
-    out["logit_gap_max"] = round(float(gaps.max()), 5)
-    assert gaps.max() <= gap_tol, out["logit_gap_max"]
-    server.close()
-
-
 # -- entry -------------------------------------------------------------------
 
 def _versions():
@@ -492,8 +465,6 @@ def main():
             jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))["params"]
         with _phase("server", clock, results) as out:
             phase_server(out, cfg, params)
-        with _phase("server_prefill", clock, results) as out:
-            phase_server_prefill(out, cfg, params)
         del params
     if "trainer4" in phases and n_dev >= 4:
         with _phase("trainer4", clock, results) as out:

@@ -8,11 +8,8 @@ matter (tokens/s, batch occupancy, queue depth, compile counts).
 Synthetic token prompts — the point is the serving machinery, not the
 tokenizer.
 
-On TPU pass ``--flash`` to run the prefill pass on the fused causal
-flash kernel; decode always takes the ``ops.cached_attention`` path.
-
     python examples/serving/serve_gpt.py --config tiny --requests 12
-    python examples/serving/serve_gpt.py --config small --flash \
+    python examples/serving/serve_gpt.py --config small \
         --batch-size 16 --max-new 128            # TPU
 """
 
@@ -46,8 +43,6 @@ def parse_args():
     p.add_argument("--checkpoint", default=None,
                    help="utils.checkpoint dir to restore params from "
                    "(default: random init)")
-    p.add_argument("--flash", action="store_true",
-                   help="flash-attention prefill (Pallas on TPU)")
     p.add_argument("--tp", type=int, default=None, metavar="N",
                    help="tensor-parallel serving over the first N "
                    "devices (docs/serving.md, 'Tensor-parallel "
@@ -104,11 +99,6 @@ def main():
     args = parse_args()
     enable_compile_cache()
     cfg, params = build(args)
-    attention_fn = None
-    if args.flash:
-        from apex_tpu.ops import make_flash_attention
-        attention_fn = make_flash_attention(causal=True)
-
     mesh = None
     if args.tp:
         from jax.sharding import Mesh
@@ -123,8 +113,7 @@ def main():
         cfg, params, max_batch_size=args.batch_size,
         max_context=args.max_context, block_size=args.block_size,
         kv_quant="int8" if args.kv_quant else None,
-        enable_disagg=args.disagg,
-        attention_fn=attention_fn, ops_port=args.ops_port, mesh=mesh)
+        enable_disagg=args.disagg, ops_port=args.ops_port, mesh=mesh)
     if server.ops is not None:
         print(f"ops plane: http://127.0.0.1:{server.ops.port} "
               f"(/healthz /metrics /statusz /debug/flight)")
@@ -154,11 +143,10 @@ def main():
                                     4, max(8, max_ctx // 4)))))
                for _ in range(args.requests)]
 
-    # warm the compile caches (every bucket this workload touches,
-    # plus the decode program) outside the timed window
-    warm = sorted({server.engine.bucket_for(len(p)) for p in prompts})
-    server.generate([[1] * (b if b < max_ctx else b - 1)
-                     for b in warm], max_new_tokens=2)
+    # warm the compile caches (the one chunk program every prompt
+    # goes through, plus the decode program) outside the timed window
+    server.generate([[1] * min(server.prefill_chunk, max_ctx - 1)],
+                    max_new_tokens=2)
     server.engine.reset_cache()
     server.reset_meters()
 

@@ -527,8 +527,7 @@ def test_reset_meters_realigns_flight_window(tiny, tmp_path):
 
 
 _PHASE_FAMILIES = {
-    "prefill_launches": {"prefill", "prefill_sampled", "prefill_stoch",
-                         "chunk_prefill", "chunk_prefill_sampled",
+    "prefill_launches": {"chunk_prefill", "chunk_prefill_sampled",
                          "chunk_prefill_stoch"},
     "decode_launches": {"decode", "decode_sampled", "decode_stoch"},
     "verify_launches": {"verify", "verify_sampled", "verify_stoch"},

@@ -435,7 +435,7 @@ def test_offload_promote_journey_stamps_block_counts(tiny):
     cfg, params = tiny
     server = _single(
         cfg, params, max_batch_size=2, num_blocks=13,
-        enable_prefix_cache=True, enable_chunked_prefill=True,
+        enable_prefix_cache=True,
         enable_kv_offload=True, kv_offload_host_bytes=8 << 20,
         enable_journeys=True)
     rng = np.random.RandomState(7)

@@ -300,7 +300,7 @@ def test_quant_memory_stats_and_q8_program_keys(tiny):
     pre, dec = srv.engine.compile_counts()
     assert dec <= 1
     assert srv.engine.verify_compiles() <= 1
-    assert pre <= len(srv.engine.prefill_buckets) + 1
+    assert pre == 1          # one chunk width, one chunk program
 
 
 def test_env_twin_turns_quant_on(tiny, monkeypatch):
@@ -372,9 +372,14 @@ def test_quant_bit_stable_composed_stress(tiny):
     rng = np.random.RandomState(7)
     shared = list(rng.randint(0, VOCAB, size=12))
     rep = [1, 2, 3, 1, 2, 3, 1, 2] * 2
+    # wave 1 overflows the pool (preemption, and eviction takes rep's
+    # blocks with the rest); wave 2 is rep alone, so that it finishes
+    # with nothing after it to evict its blocks; wave 3 re-sends rep
+    # (the whole-context COW hit) beside a shared-prefix sibling
     waves = [[rep,
               shared + [5, 6, 7, 8],
               list(rng.randint(0, VOCAB, size=8))],
+             [list(rep)],
              [list(rep),
               shared + [9, 8, 7, 6]]]
     stress_kw = dict(max_batch_size=3, max_context=64, block_size=4,
@@ -394,6 +399,38 @@ def test_quant_bit_stable_composed_stress(tiny):
     assert st["speculation"]["accepted_tokens"] >= 1
     assert st["pipeline"]["launches"] >= 1
     assert st["memory"]["quantize"] == "int8"
+
+
+def test_quant_pool_at_equal_bytes_churns_no_more(tiny):
+    """What the headroom buys: the bf16 pool's byte budget re-spent on
+    int8 blocks with their scale sidecar holds more blocks (the live
+    arrays within the budget, not only the price list), and the
+    schedule that preempts and evicts on the bf16 pool does neither
+    more often on the int8 one."""
+    cfg, params = tiny
+    rng = np.random.RandomState(7)
+    shared = list(rng.randint(0, VOCAB, size=12))
+    waves = [[[1, 2, 3, 1, 2, 3, 1, 2] * 2, shared + [5, 6, 7, 8],
+              list(rng.randint(0, VOCAB, size=8))],
+             [shared + [9, 8, 7, 6], list(rng.randint(0, VOCAB, size=8))]]
+    kw = dict(max_batch_size=3, max_context=64, block_size=4,
+              prefill_chunk=8, cache_dtype=jnp.bfloat16)
+    base = _server(cfg, params, kv_quant="off", num_blocks=21, **kw)
+    budget = base.stats()["memory"]["pool_bytes"]
+    per_block = _server(cfg, params, num_blocks=2,
+                        **kw).stats()["memory"]["bytes_per_block"]
+    quant = _server(cfg, params, num_blocks=budget // per_block, **kw)
+    churn = []
+    for srv in (base, quant):
+        for w in waves:
+            _audited_generate(srv, w, 20)
+        st = srv.stats()
+        churn.append((st["preemptions"], st["prefix_evicted_blocks"]))
+    assert quant.stats()["memory"]["pool_bytes"] <= budget
+    assert quant.stats()["memory"]["blocks_usable"] \
+        > base.stats()["memory"]["blocks_usable"]
+    assert churn[0][0] >= 1 and churn[0][1] >= 1, churn   # not vacuous
+    assert churn[1][0] <= churn[0][0] and churn[1][1] <= churn[0][1], churn
 
 
 @pytest.mark.slow

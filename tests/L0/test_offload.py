@@ -373,7 +373,7 @@ def _server(cfg, params, offload, num_blocks, **kw):
     return InferenceServer(
         cfg, params, max_batch_size=2, max_context=128, block_size=8,
         cache_dtype=jnp.float32, enable_prefix_cache=True,
-        enable_chunked_prefill=True, enable_kv_offload=offload,
+        enable_kv_offload=offload,
         num_blocks=num_blocks, **kw)
 
 
@@ -531,7 +531,7 @@ def test_server_parity_disagg_prefill_pool_is_cache_home(tiny):
     on = InferenceServer(
         cfg, params, max_batch_size=2, max_context=128, block_size=8,
         cache_dtype=jnp.float32, enable_prefix_cache=True,
-        enable_chunked_prefill=True, enable_disagg=True,
+        enable_disagg=True,
         disagg_prefill_blocks=17, enable_kv_offload=True)
     got = _session_traffic(on, prompts)
     st = on.stats()["offload"]

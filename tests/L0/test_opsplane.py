@@ -31,6 +31,7 @@ The live-observability acceptance oracles (``docs/observability.md``,
 
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -150,8 +151,12 @@ def test_ops_endpoints_serve_live_data(tiny, tmp_path):
             "text/plain; version=0.0.4; charset=utf-8"
         text = body.decode()
         assert "serving_step_s_count" in text
-        assert 'serving_program_calls{program="decode_sampled"}' \
-            in text
+        # the decode phase's program, whichever launched: so short a
+        # prompt is extended by the n-gram drafter itself, and every
+        # step of it may be a verify launch
+        assert re.search(r'serving_program_calls\{program="'
+                         r'(decode_sampled|verify_sampled\[5\])"\}',
+                         text)
         # live scrape equals the in-process exposition modulo the ops
         # request counters the scrape itself bumps
         assert text.startswith("# HELP")
@@ -352,15 +357,27 @@ def test_forced_hang_trips_once_flips_healthz_and_dumps_bundle(
         server.watchdog.deadline_s = 0.4
 
         class HangOnce:
+            """Wedges the decode phase's first launch, whichever
+            program it is: the n-gram drafter extends so short a
+            prompt by itself, so every step of this request may be a
+            ``verify_sampled`` launch and never a ``decode_sampled``
+            one."""
+
             def __init__(self, inner):
                 self.inner = inner
-                self.hung = False
+                self.hung = None
+
+            def _hang_once(self, name, a, kw):
+                if self.hung is None:
+                    self.hung = name
+                    time.sleep(1.6)
+                return getattr(self.inner, name)(*a, **kw)
 
             def decode_sampled(self, *a, **kw):
-                if not self.hung:
-                    self.hung = True
-                    time.sleep(1.6)
-                return self.inner.decode_sampled(*a, **kw)
+                return self._hang_once("decode_sampled", a, kw)
+
+            def verify_sampled(self, *a, **kw):
+                return self._hang_once("verify_sampled", a, kw)
 
             def __getattr__(self, name):
                 return getattr(self.inner, name)
@@ -394,7 +411,8 @@ def test_forced_hang_trips_once_flips_healthz_and_dumps_bundle(
         assert man["extra"]["stall"]["where"] == "in_step"
         threads = open(os.path.join(
             bundle, man["extra"]["thread_stacks"])).read()
-        assert "decode_sampled" in threads       # the wedged frame
+        assert "_hang_once" in threads           # the wedged frame
+        assert server.engine.hung in threads
         import postmortem as pm_cli
         assert pm_cli.main([bundle, "--assert-complete"]) == 0
         assert pm_cli.main([bundle, "--last-n-steps", "3"]) == 0
@@ -494,23 +512,30 @@ def test_program_table_reconciles_with_compile_audit(tiny):
     for key, row in table.items():
         assert row["calls"] >= row["compiles"] >= 0, key
         assert row["wall_ms"] >= row["compile_ms"] >= 0, key
-    # the decode path ran more than it compiled
-    decode_key = [k for k in table if k.startswith("decode")]
-    assert decode_key
+    # the decode phase launched (as plain decode or, where the n-gram
+    # drafter extends so short a prompt, as verify)
+    assert [k for k in table if k.startswith(("decode", "verify"))]
     assert st["programs"]["total_wall_ms"] == pytest.approx(
         sum(r["wall_ms"] for r in table.values()), abs=0.01)
 
 
-def test_program_accounting_opt_out(tiny):
+def test_engine_without_accounting_tallies_nothing(tiny):
+    """``DecodeEngine(programs=None)`` (an engine built without a
+    server) carries the null accounting: launches run, nothing is
+    tallied and no clock is read for them."""
+    from apex_tpu.observability import NULL_PROGRAM_ACCOUNTING
+    from apex_tpu.serving import DecodeEngine
+
     cfg, params = tiny
-    server = _server(cfg, params, enable_program_accounting=False)
-    server.generate([[1, 2, 3]], max_new_tokens=3)
-    st = server.stats()["programs"]
-    assert st == {"enabled": False, "by_program": {},
-                  "attention": server.engine.attention_paths,
-                  "total_wall_ms": 0.0, "total_compile_ms": 0.0}
-    assert not any("serving_program" in k
-                   for k in server.registry.snapshot())
+    engine = DecodeEngine(cfg, params, max_batch_size=2, max_context=64,
+                          block_size=8, cache_dtype=jnp.float32)
+    assert engine.programs is NULL_PROGRAM_ACCOUNTING
+    assert engine._mark(engine._chunk_jit) == (0.0, 0)
+    engine.chunk_prefill([1, 2, 3], 0, engine.allocator.alloc(1),
+                         pad_to=8)
+    assert engine.compile_counts() == (1, 0)
+    assert engine.programs.enabled is False
+    assert engine.programs.table() == {}
 
 
 # -- pinned stats blocks (the PR-7 slo/memory pin pattern) -----------------

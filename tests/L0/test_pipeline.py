@@ -372,25 +372,6 @@ def test_lookahead_bounded_while_window_pending(tiny):
         + srv.scheduler.prefix_cache.num_evictable == usable
 
 
-def test_custom_sample_fn_falls_back_to_synchronous_loop(tiny):
-    """A custom sampler needs host logits: pipelining auto-disables
-    (like speculation) and the logits path serves unchanged."""
-    cfg, params, _ = tiny
-
-    def sample(logits):
-        return np.argmax(np.asarray(logits), axis=-1)
-
-    srv = InferenceServer(cfg, params, max_batch_size=2,
-                          max_context=64, block_size=8,
-                          cache_dtype=jnp.float32, sample_fn=sample)
-    assert srv.pipelining is False
-    st0 = srv.stats()["pipeline"]
-    assert st0["enabled"] is False and st0["depth"] == 0
-    out = srv.generate([[1, 2, 3]], max_new_tokens=8)[0]
-    assert len(out) == 8
-    assert srv.stats()["pipeline"]["launches"] == 0
-
-
 # -- observability ----------------------------------------------------------
 
 def test_pipeline_stats_and_flight_fields_pinned(tiny):
@@ -427,17 +408,16 @@ def test_pipeline_stats_and_flight_fields_pinned(tiny):
 
 def test_pipelined_compile_counts_match_audit_bounds(tiny):
     """The compile audit holds on the pipelined path: one decode
-    program (the sampled twin), prefill bounded by the bucket set,
-    one verify width."""
+    program (the sampled twin), one chunk program for the one chunk
+    width, one verify width."""
     cfg, params, _ = tiny
     rng = np.random.RandomState(0)
     prompts = [list(rng.randint(0, VOCAB, size=n))
                for n in (3, 9, 14, 17, 25, 31, 6, 23)]
     srv = _server(cfg, params, pipeline=True, max_batch_size=3,
-                  max_context=64, block_size=8,
-                  prefill_buckets=(16, 32, 64))
+                  max_context=64, block_size=8)
     srv.generate(prompts, max_new_tokens=12)
     pre, dec = srv.engine.compile_counts()
     assert dec == 1, f"decode recompiled: {dec} programs"
-    assert pre <= 3, f"prefill compiled {pre} > bucket set"
+    assert pre == 1, f"{pre} chunk programs for one chunk width"
     assert srv.engine.verify_compiles() <= 1

@@ -43,11 +43,13 @@ def tiny():
 
 
 def _server(cfg, params, on=True, **kw):
+    """``on=False`` is the baseline the parity tests compare with: no
+    prefix cache, and (unless the test names a ``prefill_chunk``) a
+    chunk as wide as the context, so every prompt goes in whole."""
     kw.setdefault("cache_dtype", jnp.float32)
     kw.setdefault("max_context", 128)
     kw.setdefault("block_size", 8)
-    return InferenceServer(cfg, params, enable_prefix_cache=on,
-                           enable_chunked_prefill=on, **kw)
+    return InferenceServer(cfg, params, enable_prefix_cache=on, **kw)
 
 
 def _audited_generate(server, prompts, max_new, eos_id=None):
@@ -294,25 +296,28 @@ def test_whole_context_hit_takes_cow_and_stays_exact(tiny):
 
 
 def test_opt_out_flags_restore_cacheless_behavior(tiny):
-    """enable_prefix_cache=False / enable_chunked_prefill=False must
-    fall back to the monolithic bucketed path: no prefix structures,
-    no chunk traces, identical outputs."""
+    """enable_prefix_cache=False leaves no prefix structure behind:
+    every prompt is prefilled whole, chunk by chunk, on every
+    submission, and the outputs are those of the cached server."""
     cfg, params = tiny
-    prompts = [[5, 4, 3, 2, 1], [1, 2, 3]]
-    srv = _server(cfg, params, on=False, max_batch_size=2)
+    prompts = [[5, 4, 3, 2, 1] * 4, [1, 2, 3]]
+    srv = _server(cfg, params, on=False, max_batch_size=2,
+                  prefill_chunk=8)
     assert srv.prefix_cache is None
     assert srv.scheduler.prefix_cache is None
-    assert srv.prefill_chunk is None
     out = _audited_generate(srv, prompts, 16)
-    assert (srv.engine._chunk_jit._cache_size()
-            + srv.engine._chunk_sampled_jit._cache_size()) == 0
-    assert (srv.engine._prefill_jit._cache_size()        # monolithic
-            + srv.engine._prefill_sampled_jit._cache_size()) >= 1
+    assert _audited_generate(srv, prompts, 16) == out   # a second wave
     st = srv.stats()
     assert "prefix_hit_tokens" not in st
-    assert st["prefill_chunks"] == 0
-    on = _server(cfg, params, on=True, max_batch_size=2)
+    assert st["prefill_chunks"] == 2 * (3 + 1)   # 20 and 3 tokens by 8
+    assert srv.engine.compile_counts()[0] == 1
+    on = _server(cfg, params, on=True, max_batch_size=2,
+                 prefill_chunk=8)
     _assert_parity(_audited_generate(on, prompts, 16), out, "opt-out")
+    _assert_parity(_audited_generate(on, prompts, 16), out, "opt-out")
+    st = on.stats()
+    assert st["prefix_hit_tokens"] >= 16         # the second wave hit
+    assert st["prefill_chunks"] < 2 * (3 + 1)
 
 
 def test_chunked_prefill_interleaves_with_decode(tiny):
@@ -410,8 +415,7 @@ def test_preemption_between_prefill_chunks_resumes_carried_position(
         return InferenceServer(
             cfg, params, max_batch_size=2, max_context=128,
             block_size=8, cache_dtype=jnp.float32,
-            enable_prefix_cache=cache_on,
-            enable_chunked_prefill=True, prefill_chunk=8,
+            enable_prefix_cache=cache_on, prefill_chunk=8,
             enable_speculation=False)
     want = _audited_generate(mk(), [prompt], 8)[0]
 

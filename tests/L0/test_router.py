@@ -425,7 +425,7 @@ def test_replica_kill_switch_passthrough_and_refusals():
         def decode(self, *a):
             return "logits"
 
-        def prefill(self, *a):
+        def chunk_prefill(self, *a):
             return "pre"
 
     kill = ReplicaKillSwitch(FakeEngine())
@@ -436,10 +436,10 @@ def test_replica_kill_switch_passthrough_and_refusals():
     with pytest.raises(RuntimeError, match="replica killed"):
         kill.decode()
     with pytest.raises(RuntimeError, match="replica killed"):
-        kill.prefill()
+        kill.chunk_prefill()
     assert kill.kills == 2
     kill.dead = False
-    assert kill.prefill() == "pre"
+    assert kill.chunk_prefill() == "pre"
 
 
 def test_breaker_snapshot_after_reset():

@@ -289,6 +289,12 @@ def test_stochastic_keeps_fast_paths(tiny):
     rej = st["sampling"]["rejection"]
     assert rej["drafted_tokens"] > 0
     assert rej["resamples"] + rej["accepted_tokens"] > 0
+    # the same seeds replayed on the synchronous loop without
+    # speculation (the logits programs, sampled on the host's side of
+    # the same sampler): the same streams
+    plain = _server(cfg, params, enable_pipeline=False,
+                    enable_speculation=False)
+    assert plain.generate(prompts, 16, sampling=samp) == outs
 
 
 def test_pinned_sampling_stats_block(tiny):
@@ -298,31 +304,11 @@ def test_pinned_sampling_stats_block(tiny):
     server = _server(cfg, params)
     server.generate([[1, 2, 3]], 4)
     st = server.stats()["sampling"]
-    assert set(st.keys()) == {"requests", "custom_sample_fn",
-                              "rejection"}
+    assert set(st.keys()) == {"requests", "rejection"}
     assert set(st["rejection"].keys()) == {
         "drafted_tokens", "accepted_tokens", "acceptance_rate",
         "resamples"}
-    assert st["custom_sample_fn"] is False
     assert st["requests"] == {"greedy": 1}
-
-
-def test_custom_sample_fn_warns_and_falls_back(tiny):
-    """The silent downgrade is now loud: a custom sample_fn warns at
-    construction naming the disabled features, still works, and is
-    flagged in stats."""
-    cfg, params = tiny
-
-    def topless(logits):
-        return np.argmax(logits, axis=-1)
-
-    with pytest.warns(UserWarning,
-                      match="speculative decoding and the pipelined"):
-        server = _server(cfg, params, sample_fn=topless)
-    assert not server.pipelining and not server.speculating
-    outs = server.generate([[1, 2, 3, 4]], 8)
-    assert len(outs[0]) == 8
-    assert server.stats()["sampling"]["custom_sample_fn"] is True
 
 
 def test_submit_rejects_non_sampling_params(tiny):
@@ -352,8 +338,9 @@ def test_stochastic_eos_termination(tiny):
 def test_stochastic_replay_and_path_invariance(tiny):
     """One stochastic workload, byte-identical across: same-seed
     replay, speculation on/off, pipeline on/off, a starved pool
-    (forced preemption + prefix-cache eviction), and chunked
-    prefill off — the Gumbel-max coupling makes every fast path a
+    (forced preemption + prefix-cache eviction), and prompts fed
+    in several chunks where the reference (a chunk as wide as the
+    context) feeds each in one — the Gumbel-max coupling makes every fast path a
     pure reordering for stochastic traffic too."""
     cfg, params = tiny
     prompts, samp = _prompts_and_params(5)
@@ -365,7 +352,7 @@ def test_stochastic_replay_and_path_invariance(tiny):
         "both_off": {"enable_pipeline": False,
                      "enable_speculation": False},
         "starved_pool": {"num_blocks": 30},
-        "no_chunking": {"enable_chunked_prefill": False},
+        "chunks_of_4": {"prefill_chunk": 4},
         "no_prefix_cache": {"enable_prefix_cache": False},
     }
     for name, kw in variants.items():

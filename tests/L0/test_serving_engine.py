@@ -21,7 +21,6 @@ import pytest
 
 from apex_tpu import models
 from apex_tpu.serving import InferenceServer
-from apex_tpu.serving.engine import default_prefill_buckets, pick_bucket
 from apex_tpu.serving.kv_cache import pool_dtype
 
 pytestmark = pytest.mark.serving
@@ -83,24 +82,23 @@ def test_cached_decode_matches_full_recompute(tiny):
 
 
 def test_mixed_lengths_parity_and_bounded_compiles(tiny):
-    """More requests than slots, prompt lengths spread across two
-    buckets: every completion matches the oracle, requests retire and
-    admit mid-flight, and the compile counts stay inside the bucket
-    set (exactly 1 decode program)."""
+    """More requests than slots, prompt lengths from 3 to 31: every
+    completion matches the oracle, requests retire and admit
+    mid-flight, and whatever the lengths one chunk program and one
+    decode program compile."""
     cfg, params, oracle_step = tiny
     rng = np.random.RandomState(0)
     prompts = [list(rng.randint(0, VOCAB, size=n))
                for n in (3, 9, 14, 17, 25, 31, 6, 23)]
     server = InferenceServer(cfg, params, max_batch_size=3,
                              max_context=64, block_size=8,
-                             cache_dtype=jnp.float32,
-                             prefill_buckets=(16, 32, 64))
+                             cache_dtype=jnp.float32)
     outs = server.generate(prompts, max_new_tokens=12)
     for p, o in zip(prompts, outs):
         assert o == naive_generate(oracle_step, p, 12), p
     pre, dec = server.engine.compile_counts()
     assert dec == 1, f"decode recompiled: {dec} programs"
-    assert pre <= 3, f"prefill compiled {pre} > bucket set"
+    assert pre == 1, f"{pre} chunk programs for one chunk width"
     st = server.stats()
     assert st["requests_finished"] == 8
     assert st["queue_depth_peak"] >= 1        # batching was actually
@@ -326,61 +324,17 @@ def test_greedy_sample_rejects_ints_and_breaks_ties_low(tiny):
     assert greedy_sample(tied.astype(np.float16)).tolist() == [2, 0, 0]
 
 
-def test_prefill_buckets_ladder():
-    assert default_prefill_buckets(128) == (16, 32, 64, 128)
-    assert default_prefill_buckets(100) == (16, 32, 64, 100)
-    assert default_prefill_buckets(16) == (16,)
-
-
-def test_prefill_buckets_edge_cases():
-    """max_context off the power-of-two grid, below the first rung,
-    and between rungs — the ladder must always top out at exactly
-    max_context and never emit a rung above it."""
-    # non-power-of-two tops cap the ladder without a pow2 overshoot
-    assert default_prefill_buckets(100) == (16, 32, 64, 100)
-    assert default_prefill_buckets(33) == (16, 32, 33)
-    # smaller than the first rung: the single bucket IS max_context
-    assert default_prefill_buckets(10) == (10,)
-    assert default_prefill_buckets(1) == (1,)
-    # exactly a rung: no duplicate, no extra rung above
-    assert default_prefill_buckets(64) == (16, 32, 64)
-    for top in (1, 10, 33, 64, 100, 128):
-        buckets = default_prefill_buckets(top)
-        assert buckets[-1] == top
-        assert list(buckets) == sorted(set(buckets))
-
-
-def test_bucket_for_exact_boundaries():
-    """pick_bucket at and around every rung: exact lengths land on
-    their own rung (no padding), rung+1 rolls to the next, and lengths
-    past the top raise instead of silently clamping."""
-    buckets = (16, 32, 64, 100)
-    assert pick_bucket(1, buckets) == 16
-    assert pick_bucket(16, buckets) == 16      # exact rung: no roll
-    assert pick_bucket(17, buckets) == 32
-    assert pick_bucket(32, buckets) == 32
-    assert pick_bucket(33, buckets) == 64
-    assert pick_bucket(64, buckets) == 64
-    assert pick_bucket(65, buckets) == 100     # non-pow2 top rung
-    assert pick_bucket(100, buckets) == 100
-    with pytest.raises(ValueError):
-        pick_bucket(101, buckets)
-    # the degenerate single-rung ladder (max_context < smallest)
-    assert pick_bucket(10, (10,)) == 10
-    with pytest.raises(ValueError):
-        pick_bucket(11, (10,))
-
-
-def test_engine_bucket_for_matches_pick_bucket(tiny):
-    """DecodeEngine.bucket_for is pick_bucket over its own ladder, and
-    names max_context in its overflow error."""
+def test_chunk_longer_than_its_program_is_refused(tiny):
+    """The chunk program's width is the caller's to name (the server
+    passes its ``prefill_chunk``): a chunk longer than it raises
+    instead of being cut."""
     cfg, params, _ = tiny
     server = InferenceServer(cfg, params, max_batch_size=2,
                              max_context=100, block_size=8,
                              cache_dtype=jnp.float32)
+    assert server.prefill_chunk == 100        # min(256, max_context)
     eng = server.engine
-    assert eng.prefill_buckets == (16, 32, 64, 100)
-    for n in (1, 16, 17, 99, 100):
-        assert eng.bucket_for(n) == pick_bucket(n, eng.prefill_buckets)
-    with pytest.raises(ValueError, match="max_context"):
-        eng.bucket_for(101)
+    blocks = eng.allocator.alloc(3)
+    with pytest.raises(ValueError, match="exceeds pad_to=16"):
+        eng.chunk_prefill(list(range(17)), 0, blocks, pad_to=16)
+    assert eng.compile_counts() == (0, 0)

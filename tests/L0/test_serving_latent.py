@@ -134,28 +134,30 @@ def test_verify_rows_match_the_references_full_pass(params,
                                    rtol=3e-4)
 
 
-def test_the_monolithic_prefill_writes_the_same_rows(params):
-    """The bucketed prefill (the expanded pass) and chunked prefill (the
-    absorbed one) leave the same rows in the pool."""
+def test_the_chunk_width_does_not_move_the_rows(params):
+    """A prompt fed in chunks of 16 and fed whole in one chunk of 32
+    leaves the same rows in the pool, and both end on the logits of the
+    model's plain forward pass (the expanded form) over the prompt."""
     prompt = _prompt(41, 21)
-    pools = []
+    pools, lasts = [], []
     with jax.default_matmul_precision("highest"):
-        for chunked in (False, True):
+        for width in (16, 32):
             e = _engine(params)
             blocks = e.allocator.alloc(e.blocks_per_seq)
-            if chunked:
-                for start in range(0, 21, 16):
-                    last = e.chunk_prefill(prompt[start:start + 16], start,
-                                           blocks, pad_to=16)
-            else:
-                mono = e.prefill(prompt, blocks)
+            for start in range(0, 21, width):
+                last = e.chunk_prefill(prompt[start:start + width], start,
+                                       blocks, pad_to=width)
             slots = np.asarray(slot_index(
                 jnp.asarray([blocks], jnp.int32),
                 jnp.arange(21, dtype=jnp.int32)[None], BS))[0]
             pools.append(np.asarray(e.cache["kv"])[:, slots])
+            lasts.append(np.asarray(last))
+        plain = np.asarray(jax.jit(CFG.build_model().apply)(
+            {"params": params}, jnp.asarray([prompt], jnp.int32)))[0, -1]
     np.testing.assert_allclose(pools[0], pools[1], atol=2e-5)
-    np.testing.assert_allclose(np.asarray(mono), np.asarray(last),
-                               atol=3e-4, rtol=3e-4)
+    assert pools[0].any()
+    for last in lasts:
+        np.testing.assert_allclose(last, plain, atol=3e-4, rtol=3e-4)
 
 
 # -- through InferenceServer -----------------------------------------------------

@@ -118,12 +118,15 @@ def test_tp_parity_composed_stress(tiny):
     rng = np.random.RandomState(7)
     shared = list(rng.randint(0, VOCAB, size=12))
     rep = [1, 2, 3, 1, 2, 3, 1, 2] * 2
-    # wave 1 populates the prefix cache (and overflows the pool);
-    # wave 2 re-sends the whole rep prompt (whole-context COW hit)
-    # plus a shared-prefix sibling (partial hit)
+    # wave 1 populates the prefix cache and overflows the pool
+    # (eviction takes rep's blocks with the rest); wave 2 is rep
+    # alone, so that it finishes with nothing after it to evict its
+    # blocks; wave 3 re-sends the whole rep prompt (whole-context COW
+    # hit) plus a shared-prefix sibling (partial hit)
     waves = [[rep,                            # speculation fodder
               shared + [5, 6, 7, 8],          # prefix-cache feeder
               list(rng.randint(0, VOCAB, size=8))],
+             [list(rep)],
              [list(rep),                      # whole-context COW hit
               shared + [9, 8, 7, 6]]]         # prefix hit
     kw = dict(max_batch_size=3, max_context=64, block_size=4,
@@ -208,12 +211,11 @@ def test_tp_compile_counts_one_program_per_logical_shape(tiny):
                for n in (3, 9, 14, 17, 25, 31)]
     srv = _server(cfg, params, _mesh(2), max_batch_size=3,
                   max_context=64, block_size=8,
-                  prefill_buckets=(16, 32, 64),
                   enable_speculation=False)
     srv.generate(prompts, max_new_tokens=12)
     pre, dec = srv.engine.compile_counts()
     assert dec == 1, f"decode recompiled: {dec} programs"
-    assert pre <= 3, f"prefill compiled {pre} > bucket set"
+    assert pre == 1, f"{pre} chunk programs for one chunk width"
     assert srv.engine.verify_compiles() == 0
     assert srv.engine.collective_programs() == \
         pre + dec + srv.engine.verify_compiles() \

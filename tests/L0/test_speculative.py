@@ -310,21 +310,6 @@ def test_draft_budget_never_overshoots_max_new_tokens(tiny):
 
 # -- configuration seams --------------------------------------------------
 
-def test_custom_sampler_disables_speculation(tiny):
-    """The bit-exact acceptance rule is greedy-only: a custom
-    sample_fn server must fall back to one-token decode (and still
-    work)."""
-    cfg, params, _ = tiny
-    srv = _server(cfg, params, spec=True, max_batch_size=2,
-                  sample_fn=lambda lg: np.argmax(lg, axis=-1))
-    assert srv.speculating is False
-    out = srv.generate([[1, 2, 3]], max_new_tokens=6)[0]
-    assert len(out) == 6
-    st = srv.stats()["speculation"]
-    assert st["enabled"] is False
-    assert st["verify_steps"] == 0 and st["verify_compiles"] == 0
-
-
 def test_opt_out_restores_one_token_decode(tiny):
     cfg, params, _ = tiny
     srv = _server(cfg, params, spec=False, max_batch_size=2)

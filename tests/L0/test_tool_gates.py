@@ -212,7 +212,7 @@ def test_elastic_flags_advertised_by_gating_tools():
     """The build-matrix ``elastic`` axis invokes every tool below
     with ``--elastic`` — a dropped flag would fail the axis with an
     argparse error instead of a judged result."""
-    for tool in ("chaos_soak.py", "serving_bench.py", "ops_probe.py"):
+    for tool in ("chaos_soak.py", "ops_probe.py"):
         res = subprocess.run(
             [sys.executable, str(REPO / "tools" / tool), "--help"],
             capture_output=True, text=True, timeout=60)
@@ -273,12 +273,10 @@ def test_ops_probe_offload_gates_on_disabled_tier(stub_ops):
 
 
 def test_kv_offload_flags_advertised_by_gating_tools():
-    """The build-matrix ``kv_offload`` axis invokes chaos_soak and
-    serving_bench with ``--kv-offload`` and ops_probe with
-    ``--offload`` — a dropped flag would fail the axis with an
+    """The build-matrix ``kv_offload`` axis invokes chaos_soak with
+    ``--kv-offload`` and ops_probe with ``--offload`` — a dropped flag would fail the axis with an
     argparse error instead of a judged result."""
     for tool, flag in (("chaos_soak.py", "--kv-offload"),
-                       ("serving_bench.py", "--kv-offload"),
                        ("ops_probe.py", "--offload")):
         res = subprocess.run(
             [sys.executable, str(REPO / "tools" / tool), "--help"],
@@ -609,11 +607,10 @@ def test_ops_probe_transport_gates_on_missing_block(stub_ops):
 
 def test_transport_flags_advertised_by_gating_tools():
     """The build-matrix ``transport`` axis invokes chaos_soak with
-    ``--transport-faults``, serving_bench with ``--transport``, and
-    ops_probe with ``--transport`` — a dropped flag would fail the
+    ``--transport-faults`` and ops_probe with ``--transport`` — a
+    dropped flag would fail the
     axis with an argparse error instead of a judged result."""
     for tool, flag in (("chaos_soak.py", "--transport-faults"),
-                       ("serving_bench.py", "--transport"),
                        ("ops_probe.py", "--transport")):
         res = subprocess.run(
             [sys.executable, str(REPO / "tools" / tool), "--help"],

@@ -81,68 +81,26 @@ echo "=== build-matrix axis: crash-resume ==="
 env JAX_PLATFORMS=cpu python tools/crash_resume_smoke.py
 results[crash_resume]=$?
 
-# serving smoke: the inference path's CPU-safe bench — asserts the
-# continuous-batching >= 2x floor over naive decode and token parity
-# between the two (tools/serving_bench.py --smoke, docs/serving.md)
-echo "=== build-matrix axis: serving-smoke ==="
-env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke --out -
-results[serving]=$?
-
-# serving-perf smoke: prefix caching + chunked prefill — asserts the
-# >= 2x TTFT floor on a shared-system-prompt workload vs cacheless,
-# that the monolithic prefill stall is >= 2x the chunked one, and
-# cached-vs-cacheless / chunked-vs-monolithic greedy-token parity,
-# with the scheduler refcount audit after every step of both
-# workloads (tools/serving_bench.py --shared-prefix, docs/serving.md)
-echo "=== build-matrix axis: serving-prefix-smoke ==="
-env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke --shared-prefix --out -
-results[serving_prefix]=$?
-
-# serving-speculative smoke: speculative decoding with bit-exact
-# greedy acceptance (docs/serving.md) — asserts token-for-token parity
-# speculation-on vs off on both workloads and the >= 2x decoded-
-# tokens-per-engine-step floor on repetitive-suffix traffic (random
-# traffic is reported, never floored), auditing the scheduler
-# refcounts every step (tools/serving_bench.py --speculative)
-echo "=== build-matrix axis: serving-speculative-smoke ==="
-env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke --speculative --out -
-results[serving_spec]=$?
-
 # pipelined serve loop: the dispatch-ahead axis (docs/serving.md,
-# "Pipelined serve loop") — three gates in one:
-#   1. serving_bench --pipeline: pipelined-vs-synchronous A/B over
-#      identical decode-heavy traffic; bit-exact greedy parity always,
-#      >= 1.25x step-throughput floor on overlap-capable (>= 2 core)
-#      hosts, no-regression floor on single-core ones;
-#   2. an 800-iteration seed-0 chaos soak with pipelining explicitly
-#      on — every composed fault retires across the dispatch-ahead
-#      window with the same invariants as the main soak;
-#   3. the traced bench run must emit the pipelined loop's launch and
-#      retire spans (tools/obs_dump.py --require, exit 1 if missing).
+# "Pipelined serve loop") — an 800-iteration seed-0 chaos soak with
+# pipelining explicitly on: every composed fault retires across the
+# dispatch-ahead window with the same invariants as the main soak
+# (pipelined-vs-synchronous parity is tests/L0/test_pipeline.py's; the
+# trace-smoke axis below requires the loop's launch and retire spans)
 echo "=== build-matrix axis: pipeline ==="
-pipe_trace=$(mktemp -u).trace.json
-env JAX_PLATFORMS=cpu APEX_TPU_TRACE="$pipe_trace" \
-    python tools/serving_bench.py --smoke --pipeline --out - \
-  && python tools/obs_dump.py trace "$pipe_trace" \
-      --require launch --require retire \
-  && env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 \
-      --iters 800 --pipeline
+env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 \
+    --iters 800 --pipeline
 results[pipeline]=$?
-rm -f "$pipe_trace"
 
 # tensor-parallel serving: the GSPMD sharding axis (docs/serving.md,
-# "Tensor-parallel serving") — three gates under an emulated 8-device
+# "Tensor-parallel serving") — two gates under an emulated 8-device
 # host-platform mesh (the same trick tests/conftest.py uses):
 #   1. the L0 sharding tier: bit-exact tp∈{2,4} greedy parity vs the
 #      unsharded engine (incl. prefix-cache COW hits, forced
 #      preemption/eviction, chunked prefill, speculation, pipeline,
 #      per-step audits) plus the vocab-parallel argmax unit oracle
 #      incl. cross-shard lowest-global-id ties;
-#   2. serving_bench --tp 2: parity always asserted + the
-#      backend-aware throughput floor (>= 0.9x no-regression on the
-#      emulated CPU mesh; the >= scaling floor arms itself on real
-#      multi-chip backends — BENCH_NOTES);
-#   3. an 800-iteration seed-0 chaos soak with the soaked server
+#   2. an 800-iteration seed-0 chaos soak with the soaked server
 #      sharded tp=2 while the replay oracle stays UNSHARDED — every
 #      healthy bit-exact replay doubles as sharded-vs-unsharded
 #      parity under the full composed-fault surface.
@@ -152,14 +110,11 @@ env JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
       tests/L0/test_vocab_parallel.py -q -x --no-header \
   && env JAX_PLATFORMS=cpu \
       XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-      python tools/serving_bench.py --smoke --tp 2 --out - \
-  && env JAX_PLATFORMS=cpu \
-      XLA_FLAGS="--xla_force_host_platform_device_count=8" \
       python tools/chaos_soak.py --seed 0 --iters 800 --tp 2
 results[serving_tp]=$?
 
 # multi-replica router: the front-door axis (docs/serving.md,
-# "Multi-replica routing") — three gates under the emulated 8-device
+# "Multi-replica routing") — two gates under the emulated 8-device
 # mesh flags (the Router x TP test shards 2 replicas x tp=2):
 #   1. the L0 router tier: 64-token greedy parity through a 3-replica
 #      fleet vs the single-replica engine — incl. a forced replica
@@ -167,10 +122,7 @@ results[serving_tp]=$?
 #      and a rolling drain with zero healthy-request loss — plus the
 #      pinned stats()["router"] block, breaker snapshots, affinity
 #      index units, and the Router x TP parity oracle;
-#   2. serving_bench --router 3: affinity-vs-random placement A/B on
-#      grouped shared-prefix traffic (>= 1.5x aggregate prefix-hit
-#      ratio floor, parity always);
-#   3. an 800-iteration seed-0 router chaos soak over a
+#   2. an 800-iteration seed-0 router chaos soak over a
 #      killed-then-recovered replica (exactly-once terminals,
 #      per-replica finished == injected, bit-exact single-replica
 #      replay, failover + recovery asserted).
@@ -179,14 +131,11 @@ env JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m pytest tests/L0/test_router.py -q -x --no-header \
   && env JAX_PLATFORMS=cpu \
       XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-      python tools/serving_bench.py --smoke --router 3 --out - \
-  && env JAX_PLATFORMS=cpu \
-      XLA_FLAGS="--xla_force_host_platform_device_count=8" \
       python tools/chaos_soak.py --seed 0 --iters 800 --replicas 3
 results[router]=$?
 
 # quantized KV cache: the int8-pool axis (docs/serving.md, "Quantized
-# KV cache") — three gates under the emulated 8-device mesh flags
+# KV cache") — two gates under the emulated 8-device mesh flags
 # (the L0 tier's tp∈{1,2,4} stability oracle head-shards the scale
 # sidecar):
 #   1. the L0 quant tier: quantize/dequantize unit oracles (absmax
@@ -195,24 +144,18 @@ results[router]=$?
 #      tolerance oracle, and quant-on bit-stability across COW /
 #      preemption / eviction / chunked prefill / speculation /
 #      pipeline / tp (slow tier included — this axis owns it);
-#   2. serving_bench --kv-quant: the decode-parity budget (always)
-#      plus the fixed-pool-bytes capacity A/B (>= 1.8x usable-block
-#      headroom net of the fp32 scale sidecar, preemptions/evictions
-#      on the quant arm bounded by the baseline's);
-#   3. an 800-iteration seed-0 chaos soak with kv_quant=int8 in BOTH
+#   2. an 800-iteration seed-0 chaos soak with kv_quant=int8 in BOTH
 #      the soaked server and the replay oracle — bit-exact replay
 #      proves quantized blocks survive every composed fault.
 echo "=== build-matrix axis: kv-quant ==="
 env JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m pytest tests/L0/test_kv_quant.py -q -x --no-header \
-  && env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke \
-      --kv-quant --out - \
   && env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 \
       --iters 800 --kv-quant
 results[kv_quant]=$?
 
 # stochastic sampling: the on-device sampling axis (docs/serving.md,
-# "Stochastic sampling") — three gates under the emulated 8-device
+# "Stochastic sampling") — two gates under the emulated 8-device
 # mesh flags (the L0 tier's vocab-parallel stochastic parity oracle
 # shards tp∈{2,4}):
 #   1. the L0 sampling tier: SamplingParams validation, fixed-key
@@ -222,12 +165,7 @@ results[kv_quant]=$?
 #      eviction / speculation / pipelining, rejection-sampling
 #      exactness (chi-square on a small vocab), and the sharded
 #      sampler's bit-parity vs unsharded;
-#   2. serving_bench --sampling: seeded stochastic traffic with
-#      pipeline+speculation ON vs the forced logits fallback —
-#      cross-arm stream parity + same-seed replay always, the
-#      per-axis floors (pipeline wall ratio, speculation
-#      tokens-per-engine-step >= 1.25x) asserted;
-#   3. an 800-iteration seed-0 chaos soak with the stochastic traffic
+#   2. an 800-iteration seed-0 chaos soak with the stochastic traffic
 #      class ON (40% of arrivals carry seeded temperature/top-k/top-p
 #      params, speculation + pipeline + repetitive prompts on) — the
 #      bit-exact-replay oracle holds unchanged because counter-keyed
@@ -235,38 +173,29 @@ results[kv_quant]=$?
 echo "=== build-matrix axis: sampling ==="
 env JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m pytest tests/L0/test_sampling.py -q -x --no-header \
-  && env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke \
-      --sampling --out - \
   && env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 \
       --iters 800 --sampling
 results[sampling]=$?
 
 # disaggregated prefill/decode: the phase-separation axis
-# (docs/serving.md, "Disaggregated prefill/decode") — three gates:
+# (docs/serving.md, "Disaggregated prefill/decode") — two gates:
 #   1. the L0 disagg tier (slow tier included — this axis owns it):
 #      bit-exact parity disagg vs monolithic across chunked prefill /
 #      COW hits / forced preemption / hand-off deferral / torn and
 #      delayed cross-pool transfers, the export->ingest cross-replica
 #      roundtrip with checksum torn-detection, and the prefill-role /
 #      decode-role fleet with torn-payload monolithic fallback;
-#   2. serving_bench --disagg: decode ITL p99 under 10x long-prompt
-#      pressure — the monolithic arm must SHOW the interference
-#      (>= 1.5x solo), disaggregation must cut the tail (>= 1.25x
-#      reduction), and the <= 1.1x-of-solo flatness floor arms on
-#      >= 2-core hosts (phase_overlap_capable — the PR-8 precedent);
-#      greedy parity across all three arms ALWAYS;
-#   3. an 800-iteration seed-0 chaos soak with enable_disagg=True and
+#   2. an 800-iteration seed-0 chaos soak with enable_disagg=True and
 #      the hand-off fault class armed (torn + delayed transfers)
 #      against a MONOLITHIC replay oracle — bit-exact replay proves
 #      phase separation moves placement, never tokens.
 echo "=== build-matrix axis: disagg ==="
 env JAX_PLATFORMS=cpu python -m pytest tests/L0/test_disagg.py -q -x --no-header \
-  && env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke --disagg --out - \
   && env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 --iters 800 --disagg
 results[disagg]=$?
 
 # streaming delivery & disconnect cancellation (docs/serving.md,
-# "Streaming & cancellation") — three gates:
+# "Streaming & cancellation") — two gates:
 #   1. the L0 streaming tier: broker order/dedup/bounding/backfill,
 #      byte-identical delivery greedy + counter-keyed stochastic,
 #      every cancellation edge (queued / between-prefill-chunks /
@@ -274,12 +203,7 @@ results[disagg]=$?
 #      deduplicated across a forced failover, the SSE front door +
 #      disconnect-cancel over real HTTP, and the finish-reason
 #      constants exhaustiveness scan;
-#   2. serving_bench --streaming: delivered-ITL p99 within 1.1x of
-#      the polling baseline (delivery fan-out must be noise), plus
-#      the cancellation capacity arm — hang up on a full pool
-#      mid-decode, blocks_live must hit 0, and a fresh batch must
-#      finish healthy on the reclaimed blocks;
-#   3. an 800-iteration seed-0 chaos soak with streams opened per
+#   2. an 800-iteration seed-0 chaos soak with streams opened per
 #      request and the client-disconnect fault class armed, against
 #      the non-streaming bit-exact replay oracle — disconnected
 #      streams deliver an exact prefix and end "cancelled",
@@ -288,12 +212,11 @@ results[disagg]=$?
 echo "=== build-matrix axis: streaming ==="
 env JAX_PLATFORMS=cpu python -m pytest tests/L0/test_streaming.py \
       tests/L0/test_reasons.py -q -x --no-header \
-  && env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke --streaming --out - \
   && env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 --iters 800 --streaming
 results[streaming]=$?
 
 # elastic fleet: the capacity axis (docs/serving.md, "Elastic
-# fleet") — three gates:
+# fleet") — two gates:
 #   1. the L0 elastic tier (slow tier included — this axis owns it):
 #      the autoscaler's hysteresis up/down loop with zero
 #      healthy-request loss, cooldown/bound enforcement, the
@@ -302,12 +225,7 @@ results[streaming]=$?
 #      learned submit-time shed), breaker half-open backoff decay +
 #      legacy cadence, the bounded hanging-ops health probe, the
 #      restore_latest revive parity, and the mini mid-crowd soak;
-#   2. serving_bench --elastic: the goodput A/B — the same
-#      deadline-carrying flash-crowd schedule through the autoscaling
-#      fleet vs the fleet pinned at one replica (>= 1.25x goodput
-#      floor, scale-up observed, token parity on commonly-served
-#      requests ALWAYS);
-#   3. an 800-iteration seed-0 elastic chaos soak: sustained flash
+#   2. an 800-iteration seed-0 elastic chaos soak: sustained flash
 #      crowd + a zero-downtime weight rollout fired MID-crowd —
 #      exactly-once terminals across membership churn, scale-up +
 #      reconvergence, single final weights version, SLO debt bounded
@@ -316,12 +234,11 @@ results[streaming]=$?
 #      seeds stay valid).
 echo "=== build-matrix axis: elastic ==="
 env JAX_PLATFORMS=cpu python -m pytest tests/L0/test_elastic.py -q -x --no-header \
-  && env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke --elastic --out - \
   && env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 --iters 800 --elastic
 results[elastic]=$?
 
 # hierarchical KV offload: the host-RAM/disk tier axis
-# (docs/serving.md, "Hierarchical KV offload") — three gates:
+# (docs/serving.md, "Hierarchical KV offload") — two gates:
 #   1. the L0 offload tier: the OffloadStore unit oracles (LRU byte
 #      bound, spill-or-drop, atomic write-tmp -> rename publish,
 #      manifest verification deleting torn entries whole, startup
@@ -332,12 +249,7 @@ results[elastic]=$?
 #      counter-keyed stochastic) vs an offload-off oracle across
 #      demote / host-promote / disk-spill / corrupt-spill / disagg
 #      traffic with per-step scheduler audits;
-#   2. serving_bench --kv-offload: the session-continuation A/B at
-#      fixed device pool bytes — resumed-session TTFT >= 2x faster
-#      than the offload-off cold re-prefill (promotes and demotes
-#      both observed), cold-pass AND resumed-pass token parity plus
-#      stochastic-stream parity ALWAYS;
-#   3. an 800-iteration seed-0 chaos soak with the offload tier ON
+#   2. an 800-iteration seed-0 chaos soak with the offload tier ON
 #      (resume traffic class + torn-spill + promote-at-capacity
 #      fault twins armed, a real disk spill dir, a host tier small
 #      enough to force spills) — bit-exact replay vs an offload-OFF
@@ -347,12 +259,11 @@ results[elastic]=$?
 #      above pin enable_kv_offload=False, so their seeds stay valid).
 echo "=== build-matrix axis: kv-offload ==="
 env JAX_PLATFORMS=cpu python -m pytest tests/L0/test_offload.py -q -x --no-header \
-  && env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke --kv-offload --out - \
   && env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 --iters 800 --kv-offload
 results[kv_offload]=$?
 
 # KV transport: the block-movement robustness axis (docs/serving.md,
-# "KV transport") — three gates:
+# "KV transport") — two gates:
 #   1. the L0 transport tier: the frame codec units (split reads
 #      across frame boundaries, oversized-frame messaged rejection
 #      with nothing partially ingested, crc-mismatch whole-rejection,
@@ -363,12 +274,7 @@ results[kv_offload]=$?
 #      pass-through), the socket-vs-inprocess byte-parity oracle, and
 #      the cancel-racing-hand-off leak regression (slow tier included
 #      — this axis owns the fleet-over-TCP token-parity gate);
-#   2. serving_bench --transport: blocks/s + hand-off-latency A/B
-#      across direct / in-process / socket arms — landed-crc parity
-#      on every arm ALWAYS, zero failures on the healthy loopback,
-#      >= 0.9x in-process-vs-direct no-regression floor
-#      (BENCH_serving_transport.json);
-#   3. an 800-iteration seed-0 chaos soak with the transport fault
+#   2. an 800-iteration seed-0 chaos soak with the transport fault
 #      class armed (connection reset, reset-after-dispatch, stall
 #      past deadline, duplicated delivery, corrupt frame) over the
 #      offload-promote consumer — bit-exact replay vs the fault-free
@@ -377,7 +283,6 @@ results[kv_offload]=$?
 #      transport_skips == transport failures).
 echo "=== build-matrix axis: transport ==="
 env JAX_PLATFORMS=cpu python -m pytest tests/L0/test_transport.py -q -x --no-header \
-  && env JAX_PLATFORMS=cpu python tools/serving_bench.py --smoke --transport --out - \
   && env JAX_PLATFORMS=cpu python tools/chaos_soak.py --seed 0 --iters 800 --transport-faults
 results[transport]=$?
 
@@ -482,16 +387,19 @@ results[opsplane]=$?
 rm -rf "$ops_pm"
 
 # trace smoke: the observability axis (docs/observability.md) — the
-# serving smoke re-runs with APEX_TPU_TRACE set; the exported Chrome
+# serving example runs with APEX_TPU_TRACE set; the exported Chrome
 # trace must parse, its B/E spans must pair up, and it must contain
-# the scheduler-phase spans + request-lifecycle and compile instants
+# the scheduler-phase spans (the pipelined loop's launch and retire
+# among them) + request-lifecycle and compile instants
 # (tools/obs_dump.py trace --require, exit 1 on any missing name)
 echo "=== build-matrix axis: trace-smoke ==="
 trace_file=$(mktemp -u).trace.json
-env JAX_PLATFORMS=cpu APEX_TPU_TRACE="$trace_file" \
-    python tools/serving_bench.py --smoke --out - \
+env JAX_PLATFORMS=cpu PYTHONPATH=. APEX_TPU_TRACE="$trace_file" \
+    python examples/serving/serve_gpt.py --config tiny --requests 6 \
+      --max-new 8 \
   && python tools/obs_dump.py trace "$trace_file" \
-      --require admit --require chunk_prefill --require decode \
+      --require admit --require chunk_prefill --require launch \
+      --require retire \
       --require compile --require request_enqueue \
       --require request_first_token --require request_finish
 results[trace]=$?
