@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from apex_tpu.ops.pallas_utils import gspmd_auto_axes
+
 Pytree = Any
 
 
@@ -122,17 +124,39 @@ def flatten_grouped(tree: Pytree, group_ids: Sequence[int], dtype=None,
 def flatten_like(tree: Pytree, spec: FlatSpec, dtype=None,
                  pad_to: int = 1) -> jax.Array:
     """Flatten ``tree`` (matching ``spec``'s structure) without rebuilding
-    spec, honoring the spec's (possibly grouped) buffer layout."""
+    spec, honoring the spec's (possibly grouped) buffer layout.
+
+    This is the gather a flat optimizer step makes of its parameters and
+    of its gradients every step, so it is ONE buffer of the final length
+    (``spec.total`` rounded up to ``pad_to``), each leaf written into it
+    at ``spec.offsets`` by ``dynamic_update_slice``: the updates are in
+    place, so the buffer is written once to initialise it and once by
+    the leaves.  A ``concatenate`` of 388 leaves compiles on TPU to two
+    half buffers and a pass that joins them (PERF.md, PR 30: 4.5 ms a
+    step more at GPT-2 medium's 354.8M floats, and twice the
+    temporaries).
+
+    Where the SPMD partitioner owns mesh axes the ``concatenate`` stays:
+    through a chain of updates it carries a sharded consumer's placement
+    (ZeRO-1's flat state) back into every leaf, and so into the backward
+    pass that made the gradients.
+    """
     leaves = jax.tree_util.tree_leaves(tree)
     if not leaves:
         return jnp.zeros((0,), dtype or jnp.float32)
     if dtype is None:
         dtype = jnp.result_type(*[x.dtype for x in leaves])
-    if spec.perm:
-        leaves = [leaves[i] for i in spec.perm]
-    return _pad_flat(
-        jnp.concatenate([x.astype(dtype).reshape(-1) for x in leaves]),
-        pad_to)
+    if gspmd_auto_axes():
+        if spec.perm:
+            leaves = [leaves[i] for i in spec.perm]
+        return _pad_flat(
+            jnp.concatenate([x.astype(dtype).reshape(-1) for x in leaves]),
+            pad_to)
+    flat = jnp.zeros((-(-spec.total // pad_to) * pad_to,), dtype)
+    for x, off in zip(leaves, spec.offsets):
+        flat = jax.lax.dynamic_update_slice(
+            flat, x.astype(dtype).reshape(-1), (off,))
+    return flat
 
 
 def unflatten(flat: jax.Array, spec: FlatSpec, *, cast_back: bool = True) -> Pytree:
@@ -140,10 +164,19 @@ def unflatten(flat: jax.Array, spec: FlatSpec, *, cast_back: bool = True) -> Pyt
 
     ``cast_back=False`` keeps the flat buffer's dtype (used when the flat
     buffer holds fp32 master values for bf16 model params).
+
+    The leaves are cut (and cast) first, as 1-D pieces, and reshaped only
+    behind one ``optimization_barrier``: written ``slice(...).reshape(shape)``
+    XLA:TPU moves the reshape above the slice and relays out the WHOLE
+    buffer once for every family of leaf shapes before cutting from the
+    copies (three passes over GPT-2 medium's 1.42 GB a step; PERF.md,
+    PR 30).  Behind the barrier each leaf is relaid out on its own.
     """
-    leaves = []
+    pieces = []
     for shape, dt, off in zip(spec.shapes, spec.dtypes, spec.offsets):
         size = int(np.prod(shape)) if shape else 1
-        piece = jax.lax.dynamic_slice_in_dim(flat, off, size).reshape(shape)
-        leaves.append(piece.astype(dt) if cast_back else piece)
+        piece = jax.lax.slice_in_dim(flat, off, off + size)
+        pieces.append(piece.astype(dt) if cast_back else piece)
+    pieces = jax.lax.optimization_barrier(pieces)
+    leaves = [x.reshape(shape) for x, shape in zip(pieces, spec.shapes)]
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)

@@ -1,17 +1,18 @@
-"""Shared helpers for Pallas TPU kernels: platform probing and 1-D tiling."""
+"""Shared helpers for Pallas TPU kernels: platform probing and tile sizes."""
 
 from __future__ import annotations
 
-import math
 import warnings
 
 import jax
-import jax.numpy as jnp
 from jax._src import mesh as _mesh_lib
 from jax.sharding import AxisType
 
 # VPU lane width; last dim of every tile must be 128.
 LANES = 128
+# rows of one float32 vector register: a block's second-to-last dim is a
+# multiple of 8 or the array's own.
+SUBLANES = 8
 # Default sublane rows per program for elementwise kernels: 512 rows x 128
 # lanes x 4 B = 256 KiB per fp32 buffer, comfortably inside 16 MB VMEM even
 # with several operands.
@@ -128,17 +129,3 @@ def pallas_auto_gate(flag=None) -> bool:
                 RuntimeWarning, stacklevel=3)
         return False
     return True
-
-
-def pad_to_tiles(flat: jax.Array, rows: int = DEFAULT_ROWS):
-    """Pad a 1-D array to a multiple of rows*LANES and reshape to
-    (n_tiles*rows, LANES). Returns (tiled, original_length)."""
-    n = flat.shape[0]
-    tile = rows * LANES
-    padded = math.ceil(max(n, 1) / tile) * tile
-    flat = jnp.pad(flat, (0, padded - n))
-    return flat.reshape(padded // LANES, LANES), n
-
-
-def untile(tiled: jax.Array, n: int) -> jax.Array:
-    return tiled.reshape(-1)[:n]

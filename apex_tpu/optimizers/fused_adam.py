@@ -19,10 +19,14 @@ exactly as ``fused_adam.py:98-104``.
 TPU design: instead of one CUDA launch per parameter tensor (reference
 loops params at ``fused_adam.py:133-146``), one Pallas kernel updates every
 parameter. The moments m/v live as contiguous flat fp32 buffers in the
-optimizer state for the life of training; params and grads are concatenated
-into matching flat buffers at each step (a fused copy under jit) and the
-result is sliced back to the pytree layout. A pure-jnp path
-(``use_pallas=False``) provides the CPU fallback and the parity oracle.
+optimizer state for the life of training. What a step moves (PR 30): the
+params and the grads are gathered into matching flat buffers, each leaf
+written once into one buffer (``ops.flatten.flatten_like``); all four
+buffers enter the kernel as ``(n // 128, 128)`` views and its outputs leave
+as views, so the donated state is updated where it lies (no pad, no
+slice); and every leaf is cut from the kernel's output once
+(``ops.flatten.unflatten``). A pure-jnp path (``use_pallas=False``)
+provides the CPU fallback and the parity oracle.
 
 The optax ``GradientTransformation`` protocol (init/update) is also
 provided so FusedAdam slots into ``amp.initialize`` as the inner optimizer.
@@ -41,8 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.flatten import (FlatSpec, flatten, flatten_grouped,
                                   flatten_like, unflatten)
-from apex_tpu.ops.pallas_utils import (LANES, on_tpu, pad_to_tiles,
-                                       pallas_auto_gate, union_vma, untile)
+from apex_tpu.ops.pallas_utils import (DEFAULT_ROWS, LANES, SUBLANES, on_tpu,
+                                       pallas_auto_gate, union_vma)
 from apex_tpu.optimizers.param_groups import (group_hparams,
                                               resolve_group_ids)
 
@@ -114,22 +118,40 @@ def _adam_kernel(scalars_ref, p_ref, m_ref, v_ref, g_ref,
 @functools.partial(jax.jit, static_argnames=("eps_inside_sqrt", "rows",
                                              "interpret"))
 def _adam_flat_pallas(p, m, v, g, scalars, *, eps_inside_sqrt: bool,
-                      rows: int = 512, interpret: bool = False):
-    """Run the fused kernel over tiled flat fp32 buffers."""
+                      rows: int = DEFAULT_ROWS, interpret: bool = False):
+    """Run the fused kernel over flat fp32 buffers of one length ``n``.
+
+    Where ``n`` is a multiple of ``LANES`` and fills a sublane tile (every
+    state made by ``init``: ``pad_to`` defaults to 128) the buffers enter
+    as their ``(n // LANES, LANES)`` VIEW and the outputs leave as views:
+    the grid is ``cdiv`` over the rows, the last block is ragged, and
+    Pallas drops what it writes past the end.  No pad, no slice: with the
+    state donated, ``input_output_aliases`` update the state's own memory
+    (at GPT-2 medium's 354.8M floats the pads and slices were five passes
+    over 1.42 GB a step; PERF.md, PR 30).  Any other length (a group's
+    slice at unaligned ``group_bounds``, a tree of tens of elements) is
+    padded to whole blocks and cut back."""
     n = p.shape[0]
-    pt, _ = pad_to_tiles(p, rows)
-    mt, _ = pad_to_tiles(m, rows)
-    vt, _ = pad_to_tiles(v, rows)
-    gt, _ = pad_to_tiles(g, rows)
-    total_rows = pt.shape[0]
-    grid = (total_rows // rows,)
+    view = n % LANES == 0 and n // LANES >= SUBLANES
+    if view:
+        total_rows = n // LANES
+        # a buffer shorter than one block is its own (whole-array) block
+        rows = min(rows, total_rows)
+    else:
+        total_rows = pl.cdiv(max(n, 1), rows * LANES) * rows
+
+    def tile(x):
+        if not view:
+            x = jnp.pad(x, (0, total_rows * LANES - n))
+        return x.reshape(total_rows, LANES)
+
     tile_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct(pt.shape, jnp.float32,
+    out_shape = jax.ShapeDtypeStruct((total_rows, LANES), jnp.float32,
                                      vma=union_vma(p, m, v, g, scalars))
     kernel = functools.partial(_adam_kernel, eps_inside_sqrt=eps_inside_sqrt)
-    p2, m2, v2 = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(pl.cdiv(total_rows, rows),),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             tile_spec, tile_spec, tile_spec, tile_spec,
@@ -141,8 +163,14 @@ def _adam_flat_pallas(p, m, v, g, scalars, *, eps_inside_sqrt: bool,
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
         name="_adam_kernel",
-    )(scalars, pt, mt, vt, gt)
-    return untile(p2, n), untile(m2, n), untile(v2, n)
+    )(scalars, tile(p), tile(m), tile(v), tile(g))
+    return tuple(x.reshape(-1) if view else x.reshape(-1)[:n] for x in out)
+
+
+def _with_dtypes(spec: FlatSpec, dtypes) -> FlatSpec:
+    """``spec`` with other leaf dtypes, so that ``unflatten`` casts each
+    leaf as it cuts it (one pass) instead of over a cut float32 tree."""
+    return spec._replace(dtypes=tuple(jnp.dtype(d) for d in dtypes))
 
 
 class FusedAdam:
@@ -181,11 +209,12 @@ class FusedAdam:
       unscale+update+skip-select into one HBM pass and kernel-launch
       count is irrelevant (no CUDA-style per-launch cost, the thing the
       reference's multi_tensor_apply exists to amortize) — while the
-      flat layout pays a params+grads concat, a pad, and an unflatten
-      slice-back EVERY step (~1.5-2 ms at ResNet-50 scale on v5e,
-      xprof-measured, BENCH_NOTES.md). Same update semantics, group
-      support, and skip protocol; state is per-leaf (like optax), so
-      checkpoints are layout-specific.
+      flat layout gathers the params and the grads into flat buffers
+      and cuts the leaves back out EVERY step (at GPT-2 medium's
+      354.8M parameters on one v5e the whole training step is 216.0 ms
+      flat and 177.9 ms tree; PERF.md, PR 30). Same update
+      semantics, group support, and skip protocol; state is per-leaf
+      (like optax), so checkpoints are layout-specific.
 
     Tensor-parallel params need ``layout="tree"``: the flat layout's
     whole-model concat cannot preserve per-param Megatron placements
@@ -403,10 +432,9 @@ class FusedAdam:
             return updates, new_state
         new_flat, new_state, old_flat = self._step_flat(
             params, grads, state, scale, grad_norm, skip=skip)
-        updates = unflatten(new_flat - old_flat, state.spec, cast_back=False)
         # match param leaf dtypes (masters are fp32; O3 runs half params)
-        updates = jax.tree_util.tree_map(
-            lambda u, p: u.astype(p.dtype), updates, params)
+        updates = unflatten(new_flat - old_flat, _with_dtypes(
+            state.spec, [p.dtype for p in jax.tree_util.tree_leaves(params)]))
         return updates, new_state
 
     # -- apex-style step --------------------------------------------------
@@ -432,13 +460,11 @@ class FusedAdam:
             return new_params, new_state
         new_flat, new_state, _ = self._step_flat(params, grads, state, scale,
                                                  grad_norm, skip=skip)
+        spec = state.spec
         if output_params_dtype is not None:
-            new_params = jax.tree_util.tree_map(
-                lambda x: x.astype(output_params_dtype),
-                unflatten(new_flat, state.spec, cast_back=False))
-        else:
-            new_params = unflatten(new_flat, state.spec)
-        return new_params, new_state
+            spec = _with_dtypes(spec,
+                                [output_params_dtype] * len(spec.dtypes))
+        return unflatten(new_flat, spec), new_state
 
     # -- core -------------------------------------------------------------
     def _step_group(self, p, m, v, g, hp, step, scale, grad_norm,
@@ -522,7 +548,7 @@ class FusedAdam:
     def _step_tree(self, params, grads, state: FusedAdamState, scale,
                    grad_norm, skip=None):
         """Per-leaf update (``layout="tree"``): same math as the flat
-        kernel, one fused HBM pass per leaf, no concat/pad/slice-back.
+        kernel, one fused HBM pass per leaf, no gather and no cut.
         Returns ``(new_params_tree, new_state)``."""
         hps = group_hparams(self._defaults(), self.param_groups)
         ids = (resolve_group_ids(params, self.param_groups)
@@ -592,20 +618,14 @@ class FusedAdam:
 
     def _step_flat(self, params, grads, state: FusedAdamState, scale,
                    grad_norm, skip=None):
-        # pad p/g (independently — a pre-padded params tree arrives at
-        # full length while grads may not) to the state buffers' length,
-        # not self.pad_to: a state restored from a checkpoint must keep
-        # ITS layout
+        # gather p/g at the state buffers' length (the one multiple of it
+        # that holds ``spec.total``), not self.pad_to: a state restored
+        # from a checkpoint must keep ITS layout
         buf_len = state.m.shape[0]
-
-        def to_buf_len(x):
-            if x.shape[0] < buf_len:
-                x = jnp.concatenate(
-                    [x, jnp.zeros((buf_len - x.shape[0],), jnp.float32)])
-            return x
-
-        p = to_buf_len(flatten_like(params, state.spec, dtype=jnp.float32))
-        g = to_buf_len(flatten_like(grads, state.spec, dtype=jnp.float32))
+        p = flatten_like(params, state.spec, dtype=jnp.float32,
+                         pad_to=buf_len)
+        g = flatten_like(grads, state.spec, dtype=jnp.float32,
+                         pad_to=buf_len)
         if skip is None:
             keep = None
             step = state.step + 1

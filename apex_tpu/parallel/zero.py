@@ -225,20 +225,15 @@ def zero2_update(optimizer, params: Pytree, grads: Pytree, opt_state,
     shard_len = opt_state.m.shape[0]
     buf_len = shard_len * n
 
-    def to_buf_len(x):
-        if x.shape[0] < buf_len:
-            x = jnp.concatenate(
-                [x, jnp.zeros((buf_len - x.shape[0],), jnp.float32)])
-        return x
-
-    g = to_buf_len(flatten_like(grads, spec, dtype=jnp.float32))
+    # gathered at the whole (unsharded) buffer's length, as _step_flat does
+    g = flatten_like(grads, spec, dtype=jnp.float32, pad_to=buf_len)
     # THE ZeRO-2 move: one reduce-scatter replaces all-reduce — each
     # device receives only the summed slice its m/v shard covers
     g_shard = lax.psum_scatter(g, axis, scatter_dimension=0, tiled=True)
     if average:
         g_shard = g_shard / n
 
-    p = to_buf_len(flatten_like(params, spec, dtype=jnp.float32))
+    p = flatten_like(params, spec, dtype=jnp.float32, pad_to=buf_len)
     idx = lax.axis_index(axis)
     p_shard = lax.dynamic_slice_in_dim(p, idx * shard_len, shard_len)
 

@@ -383,3 +383,117 @@ def test_tree_layout_add_param_group():
     p2, state3 = opt2.step(bigger, g2, state2)
     assert p2["extra"].shape == (5, 5)
     assert not np.allclose(np.asarray(p2["extra"]), 0.0)
+
+
+# -- the flat step's routes into the kernel (PR 30) -------------------------
+# A buffer whose length is a multiple of 128 and fills a sublane tile
+# enters ``_adam_kernel`` as its (n // 128, 128) view over a cdiv grid
+# with a ragged last block; any other length is padded to whole blocks.
+# Each layout below is stepped through the interpreted kernel and through
+# ``use_pallas=False``.
+
+def _tree_of(sizes, seed):
+    rng = np.random.RandomState(seed)
+    return {f"p{i}": jnp.asarray(rng.randn(*s), jnp.float32)
+            for i, s in enumerate(sizes)}
+
+
+def _two_device_zero(opt):
+    from jax.sharding import Mesh
+    return opt.with_zero(Mesh(np.asarray(jax.devices()[:2]), ("data",)))
+
+
+# name -> (leaf shapes, FusedAdam keywords, wrap, buffer length, the
+# lengths the kernel is called on)
+FLAT_ROUTES = {
+    # one whole block of 512 x 128
+    "whole_blocks": ([(256, 128), (256, 128)], {}, None, 65536, [65536]),
+    # 700 rows of 128: the second block holds 188
+    "ragged_last_block": ([(300, 128), (400, 128)], {}, None, 89600,
+                          [89600]),
+    # 12 rows of 128: shorter than one block, the array is the block
+    "shorter_than_a_block": ([(12, 128)], {}, None, 1536, [1536]),
+    # 1,037 elements: the padded route
+    "not_a_multiple_of_128": ([(37, 13), (556,)], {"pad_to": 1}, None, 1037,
+                              [1037]),
+    # group 0 is p1 (1,000 elements), group 1 is p0 (300) and p2 (1,536):
+    # slices at 0 and 1,000, neither length a multiple of 128
+    "unaligned_group_bounds": (
+        [(300,), (1000,), (12, 128)],
+        {"param_groups": [{"match": lambda path: "p1" in path,
+                           "lr": 3e-2, "weight_decay": 0.0}]},
+        None, 2944, [1000, 1836]),
+    # 2 shards of 350 rows each: the ragged grid inside shard_map
+    "with_zero_2_devices": ([(300, 128), (400, 128)], {}, _two_device_zero,
+                            89600, [44800]),
+}
+
+
+@pytest.mark.parametrize("skip", [False, True], ids=["step", "skip"])
+@pytest.mark.parametrize("route", list(FLAT_ROUTES))
+def test_flat_routes_match_jnp(route, skip, monkeypatch):
+    """The Pallas route (interpreted) against ``use_pallas=False`` on
+    parameters, ``m``, ``v`` and ``step``, to the tolerance
+    ``test_pallas_interpret_matches_jnp`` holds; a skipped step leaves
+    every element, the ragged tail included, exactly as it was."""
+    import apex_tpu.optimizers.fused_adam as fa
+    sizes, kw, wrap, buf_len, kernel_lens = FLAT_ROUTES[route]
+    params, grads = _tree_of(sizes, 0), _tree_of(sizes, 1)
+    bad = jax.tree.map(lambda x: jnp.full_like(x, jnp.inf), grads)
+
+    seen = []
+    real = fa._adam_flat_pallas
+
+    def spy(p, *a, **k):
+        seen.append(p.shape[0])
+        return real(p, *a, **k)
+
+    monkeypatch.setattr(fa, "_adam_flat_pallas", spy)
+
+    outs = {}
+    for use_pallas in (False, True):
+        opt = FusedAdam(lr=1e-2, weight_decay=0.01, use_pallas=use_pallas,
+                        **kw)
+        if wrap is not None:
+            opt = wrap(opt)
+        state = opt.init(params)
+        assert state.m.shape == (buf_len,)
+        # a real step first, so that a skipped one has moments to keep
+        p, state = opt.step(params, grads, state)
+        before = (p, state)
+        p, state = opt.step(p, bad if skip else grads, state,
+                            skip=jnp.asarray(skip))
+        outs[use_pallas] = (p, state)
+        if skip:
+            for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(
+                    (p, state))):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            assert int(state.step) == 1
+        else:
+            assert int(state.step) == 2
+    assert sorted(set(seen)) == sorted(kernel_lens)
+    for a, b in zip(jax.tree.leaves(outs[False]), jax.tree.leaves(
+            outs[True])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("rows_of_128", [700, 12])
+def test_view_route_is_the_padded_route_bit_for_bit(rows_of_128):
+    """The same elements through the view (ragged last block, or the
+    array as its own block) and, one element longer, through the padded
+    route: elementwise work, so every shared element is the same bits."""
+    from apex_tpu.optimizers.fused_adam import _adam_flat_pallas
+    n = rows_of_128 * 128
+    rng = np.random.RandomState(5)
+    p, m, g = (jnp.asarray(rng.randn(n + 1), jnp.float32) for _ in range(3))
+    v = jnp.asarray(rng.rand(n + 1), jnp.float32)
+    scalars = jnp.asarray([1e-2, 0.9, 0.999, 1e-8, 2.0, 0.01, 1.0],
+                          jnp.float32)
+    padded = _adam_flat_pallas(p, m, v, g, scalars, eps_inside_sqrt=False,
+                               interpret=True)
+    view = _adam_flat_pallas(p[:n], m[:n], v[:n], g[:n], scalars,
+                             eps_inside_sqrt=False, interpret=True)
+    for a, b in zip(padded, view):
+        assert b.shape == (n,)
+        np.testing.assert_array_equal(np.asarray(a)[:n], np.asarray(b))
