@@ -31,6 +31,7 @@ from apex_tpu.models.gpt import (
 from apex_tpu.models.moe import EP_RULES, MoEMlp, ep_rules
 from apex_tpu.models.family import CacheRow
 from apex_tpu.models.deepseek import DeepseekV3Config, DeepseekV3LMHeadModel
+from apex_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeLMHeadModel
 from apex_tpu.models.bert import (
     BertConfig,
     BertEncoder,
@@ -46,6 +47,8 @@ __all__ = [
     "DeepseekV3Config",
     "DeepseekV3LMHeadModel",
     "EP_RULES",
+    "ExaoneMoeConfig",
+    "ExaoneMoeLMHeadModel",
     "GPTConfig",
     "GPTLMHeadModel",
     "PipelinedGPT",

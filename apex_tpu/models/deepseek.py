@@ -33,7 +33,9 @@ What differs from ``models.gpt``, block by block:
   shared experts (one gated feed-forward of ``n_shared_experts`` times
   the width).  No capacity and no dropped token: the (token, expert)
   pairs are sorted by expert and go through
-  ``ops.grouped_matmul`` three times.  The layer is told which experts
+  ``ops.grouped_matmul`` three times
+  (``models.routed_experts.RoutedExperts``, which the ``exaone_moe``
+  family shares).  The layer is told which experts
   it holds (``experts_held``, default all): it routes over all
   ``n_routed_experts`` and computes its own experts' part, which is
   what expert parallelism asks of it; the exchange is not here.
@@ -61,7 +63,18 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.models.family import CacheRow
-from apex_tpu.ops.grouped_matmul import grouped_matmul
+# what this family shares with ``models.exaone_moe``; the names stay
+# importable from here
+from apex_tpu.models.routed_experts import (  # noqa: F401  (re-export)
+    ExpertsSpec,
+    GatedMLP,
+    RMSNorm,
+    RoutedExperts,
+    apply_rotary,
+    check_held,
+    rotary_angles,
+    route,
+)
 
 NEG_INF = -1e9
 
@@ -96,11 +109,7 @@ class DeepseekV3Config:
     experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
-        first, count = self.held
-        if first < 0 or count < 1 or first + count > self.n_routed_experts:
-            raise ValueError(
-                f"experts_held={self.experts_held} is no range of the "
-                f"{self.n_routed_experts} routed experts")
+        check_held(self.held, self.n_routed_experts)
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -109,6 +118,16 @@ class DeepseekV3Config:
     @property
     def num_expert_layers(self) -> int:
         return max(0, self.num_hidden_layers - self.first_k_dense_replace)
+
+    def experts_spec(self) -> ExpertsSpec:
+        return ExpertsSpec(
+            router_width=self.n_routed_experts,
+            top_k=self.num_experts_per_tok,
+            width=self.moe_intermediate_size, held=self.held,
+            shared_width=self.n_shared_experts * self.moe_intermediate_size,
+            scaling=self.routed_scaling_factor,
+            normalise=self.norm_topk_prob,
+            init_range=self.initializer_range)
 
     # -- what the serving engine asks a family (models/family.py) ---------
 
@@ -133,41 +152,6 @@ class DeepseekV3Config:
 
 def _init(cfg):
     return nn.initializers.normal(cfg.initializer_range)
-
-
-class RMSNorm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        xf = x.astype(jnp.float32)
-        y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + self.eps)
-        return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def rotary_angles(positions, dim: int, theta: float):
-    """``(cos, sin)`` (..., dim // 2) in float32 for ``positions``
-    (...,): pair ``i`` turns by ``position * theta ** (-2i / dim)``."""
-    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def apply_rotary(x, cos, sin, interleave: bool):
-    """Rotate the pairs of ``x`` (..., dim) by ``cos``/``sin``
-    (..., dim // 2).  ``interleave``: the pairs are ``(2i, 2i + 1)``
-    and are first brought to the half-split order ``(i, i + dim/2)``,
-    in which the result stays (queries and keys alike, so their
-    products do not notice)."""
-    xf = x.astype(jnp.float32)
-    half = x.shape[-1] // 2
-    if interleave:
-        x1, x2 = xf[..., 0::2], xf[..., 1::2]
-    else:
-        x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
 
 
 class DeepseekV3Attention(nn.Module):
@@ -227,92 +211,11 @@ class DeepseekV3Attention(nn.Module):
         return jnp.einsum("bsnd,ndh->bsh", o, wo), cache_view
 
 
-class GatedMLP(nn.Module):
-    """``W_down(silu(W_gate x) * (W_up x))``."""
-
-    cfg: DeepseekV3Config
-    width: int
-
-    @nn.compact
-    def __call__(self, x):
-        init = _init(self.cfg)
-        h = x.shape[-1]
-        gate = self.param("gate_proj", init, (h, self.width))
-        up = self.param("up_proj", init, (h, self.width))
-        down = self.param("down_proj", init, (self.width, h))
-        return (nn.silu(x @ gate) * (x @ up)) @ down
-
-
-def route(scores, bias, k: int, scaling: float, normalise: bool):
-    """``scores`` (T, E) float32 sigmoid scores, ``bias`` (E,) the
-    selection bias: the ``k`` experts with the largest ``score + bias``
-    and their weights ``score / sum(chosen scores) * scaling``.  The
-    bias selects and does not weigh."""
-    _, chosen = lax.top_k(scores + bias, k)
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    if normalise:
-        picked = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
-    return chosen, picked * scaling
-
-
-class DeepseekV3MoE(nn.Module):
-    cfg: DeepseekV3Config
-
-    @nn.compact
-    def __call__(self, x, live=None):
-        """``x`` (B, S, hidden) after its norm; ``live`` (B, S) which
-        rows are tokens (None: all).  Returns the layer's output and
-        the rows each expert held here was given (held,)."""
-        cfg = self.cfg
-        b, s, h = x.shape
-        e, k, f = cfg.n_routed_experts, cfg.num_experts_per_tok, \
-            cfg.moe_intermediate_size
-        first, held = cfg.held
-        init = _init(cfg)
-        router = self.param("router", init, (h, e))
-        bias = self.param("e_score_correction_bias",
-                          nn.initializers.zeros, (e,))
-        w_gate = self.param("experts_gate_proj", init, (held, h, f))
-        w_up = self.param("experts_up_proj", init, (held, h, f))
-        w_down = self.param("experts_down_proj", init, (held, f, h))
-        xt = x.reshape(b * s, h)
-
-        with jax.named_scope("moe_router"):
-            scores = jax.nn.sigmoid(jnp.dot(
-                xt.astype(jnp.float32), router.astype(jnp.float32),
-                precision=lax.Precision.HIGHEST))
-            chosen, weights = route(scores, bias.astype(jnp.float32), k,
-                                    cfg.routed_scaling_factor,
-                                    cfg.norm_topk_prob)
-            # for whoever asks (``mutable=["intermediates"]``): tests
-            # bound how often a precision picks another expert
-            self.sow("intermediates", "chosen", chosen.reshape(b, s, k))
-
-        with jax.named_scope("moe_experts"):
-            mine = (chosen >= first) & (chosen < first + held)
-            if live is not None:
-                mine = mine & live.reshape(b * s, 1)
-            # pairs sorted by expert; those that are nobody's here go
-            # last, past every group, where nothing is computed
-            key = jnp.where(mine, chosen - first, held).reshape(-1)
-            order = jnp.argsort(key, stable=True)
-            sizes = jnp.bincount(key, length=held + 1)[:held].astype(
-                jnp.int32)
-            rows = xt[order // k]                           # (T * k, h)
-            act = nn.silu(grouped_matmul(rows, w_gate, sizes)) \
-                * grouped_matmul(rows, w_up, sizes)
-            out = grouped_matmul(act, w_down, sizes)        # (T * k, h)
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(order.shape[0], dtype=order.dtype))
-            out = out[back].reshape(b * s, k, h).astype(jnp.float32)
-            routed = jnp.sum(
-                out * jnp.where(mine, weights, 0.0)[..., None], axis=1)
-
-        with jax.named_scope("moe_shared"):
-            shared = GatedMLP(cfg, cfg.n_shared_experts * f,
-                              name="shared_experts")(xt)
-        y = (routed + shared.astype(jnp.float32)).astype(x.dtype)
-        return y.reshape(b, s, h), sizes
+def DeepseekV3MoE(cfg: DeepseekV3Config, name=None):
+    """The family's expert layer: the shared
+    ``models.routed_experts.RoutedExperts`` at this configuration's
+    sizes."""
+    return RoutedExperts(cfg.experts_spec(), name=name)
 
 
 class DeepseekV3Block(nn.Module):
@@ -333,7 +236,8 @@ class DeepseekV3Block(nn.Module):
         x = x + a
         h = RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(x)
         if self.layer < cfg.first_k_dense_replace:
-            return x + GatedMLP(cfg, cfg.intermediate_size,
+            return x + GatedMLP(cfg.intermediate_size,
+                                cfg.initializer_range,
                                 name="mlp")(h), kept
         y, sizes = DeepseekV3MoE(cfg, name="moe")(h, live)
         if cache_view is not None and "routed" in kept.cache:

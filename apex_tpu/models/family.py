@@ -16,23 +16,40 @@ configuration object and ask it, not its class name, three things:
 (b) ``cfg.cache_row()``: a :class:`CacheRow`, what one token keeps in
     one layer of the paged pool;
 (c) ``cfg.vocab_size``, ``cfg.num_hidden_layers`` and
-    ``cfg.max_position_embeddings``.
+    ``cfg.max_position_embeddings``;
+(d) which tokens EACH layer keeps, ``cfg.layer_windows()``: one entry a
+    layer, ``None`` for a layer that attends every token before it
+    (its rows live in the block table for as long as the sequence
+    does) or ``w`` for one whose row at position ``p`` attends the
+    positions ``p - w + 1 .. p`` alone (its rows live in a ring the
+    slot owns and are let go as they slide out:
+    ``serving.kv_cache``).  A family whose layers all keep everything
+    need not have the method (:func:`layer_windows`).
 
 A family whose programs carry counters beside the pool (the tokens an
 expert layer routed) also has ``cfg.serving_counters()``:
 ``{name: shape}`` of int32 arrays the engine allocates zeroed and the
 model adds to through ``CacheView.count``.
 
-``models.gpt.GPTConfig`` and ``models.deepseek.DeepseekV3Config``
-implement it; the engine imports neither for it.
+``models.gpt.GPTConfig``, ``models.deepseek.DeepseekV3Config`` and
+``models.exaone_moe.ExaoneMoeConfig`` implement it; the engine imports
+none of them for it.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 LANES = 128
+
+
+def layer_windows(cfg) -> Tuple[Optional[int], ...]:
+    """What each layer of ``cfg`` keeps (the contract's (d)): the
+    family's answer, or "every token" for each where it gives none."""
+    if hasattr(cfg, "layer_windows"):
+        return tuple(cfg.layer_windows())
+    return (None,) * cfg.num_hidden_layers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,9 +57,12 @@ class CacheRow:
     """One token's row in one layer of the pool: ``groups`` groups of
     ``group_width`` stored values side by side.
 
-    - ``kind`` ``"kv"``: a group is one head's ``K_h | V_h`` pair
-      (``group_width = 2 * head_dim``), read by that head alone
-      (``heads_per_group`` 1); the value is the group's upper half.
+    - ``kind`` ``"kv"``: a group is one key-value head's ``K_h | V_h``
+      pair (``group_width = 2 * head_dim``), read by
+      ``heads_per_group`` query heads: 1 for multi-head attention,
+      more where query heads share key-value heads (query head ``i``
+      reads group ``i // heads_per_group``); the value is the group's
+      upper half.
     - ``kind`` ``"latent"``: one group, the compressed key-value row
       ``c | k_pe`` padded to whole 128-lane tiles, shared by all
       ``heads_per_group`` query heads; its first ``value[1]`` values
@@ -72,13 +92,19 @@ class CacheRow:
     @property
     def shared(self) -> bool:
         """Whether several query heads read one group."""
-        return self.kind == "latent"
+        return self.heads_per_group > 1
 
     @classmethod
-    def kv(cls, num_heads: int, head_dim: int) -> "CacheRow":
-        """Multi-head attention: every head's ``K_h`` beside its
-        ``V_h``."""
-        return cls("kv", num_heads, 2 * head_dim, 1,
+    def kv(cls, num_heads: int, head_dim: int,
+           num_kv_heads: Optional[int] = None) -> "CacheRow":
+        """Every key-value head's ``K_h`` beside its ``V_h``:
+        ``num_heads`` of them for multi-head attention, ``num_kv_heads``
+        where ``num_heads // num_kv_heads`` query heads read each."""
+        groups = num_kv_heads or num_heads
+        if num_heads % groups:
+            raise ValueError(f"{num_heads} query heads do not divide "
+                             f"over {groups} key-value heads")
+        return cls("kv", groups, 2 * head_dim, num_heads // groups,
                    (head_dim, 2 * head_dim), 2 * head_dim)
 
     @classmethod

@@ -365,8 +365,15 @@ def _query_row(m, shared):
     return m // shared
 
 
+def _first_key(start, first_row, reach):
+    """The first key any row of a tile may see: none lies more than
+    ``reach - 1`` before the tile's first row."""
+    return jnp.maximum(start + first_row - (reach - 1), 0)
+
+
 def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
-                  scale, block_size, rows, groups, shared, value, pages):
+                  scale, block_size, rows, groups, shared, value, pages,
+                  reach=None):
     """One (slot, row tile, ``pages``-page window) step of the
     streaming softmax over the pool itself.  ``rest`` is the window's
     page blocks (``(block_size, groups * width)`` each: one row a
@@ -379,22 +386,35 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
     group for a head's ``K_h | V_h`` pair (the product of the
     probabilities with the same tile then carries ``p . V_h`` in its
     upper lanes, which the caller takes), or a whole number of leading
-    lane tiles for a latent row.  ``shared`` query heads read one
+    lane tiles for a latent row.  Where a group's key is whole lane
+    tiles itself (``head_dim`` a multiple of 128) the queries are as
+    wide as the key alone and ``value`` is the group's upper half: both
+    slices are aligned, and the products run over half the lanes.  ``shared`` query heads read one
     group; their rows lie side by side in the tile (row ``m`` is query
     row ``m // shared``), so a sequence's heads meet a page as one
-    matrix."""
+    matrix.
+
+    ``reach``: a row sees the ``reach`` keys that end with its own and
+    no earlier one (a window layer).  The grid's last dimension then
+    counts windows from the one that holds the tile's first visible
+    key, so the pages before it are neither fetched nor visited."""
     del layer_ref, tables_ref          # the index maps read them
     page_refs = rest[:pages]
     o_ref, acc_ref, m_ref, l_ref = rest[pages:]
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     span = pages * block_size           # keys in one window
-    tile, width = q_ref.shape[1], q_ref.shape[2]
+    tile, q_width = q_ref.shape[1], q_ref.shape[2]
+    width = page_refs[0].shape[1] // groups
 
     query_row = functools.partial(_query_row, shared=shared)
     start = starts_ref[b]
     # the tile's last live row: beyond it the tile holds padding
     last = start + query_row(
         i * tile + jnp.minimum(tile, rows * shared - i * tile) - 1)
+
+    # the window of keys this step holds
+    w = j if reach is None else j + _first_key(
+        start, query_row(i * tile), reach) // span
 
     @pl.when(j == 0)
     def _init():
@@ -405,17 +425,21 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
     # a row sees every key at or before its own position (the rows were
     # written before this call); windows past the tile's last row hold
     # nothing any of its rows may see
-    @pl.when(j * span <= last)
+    @pl.when(w * span <= last)
     def _window():
-        key = j * span + lax.broadcasted_iota(jnp.int32, (tile, span), 1)
+        key = w * span + lax.broadcasted_iota(jnp.int32, (tile, span), 1)
         row = start + query_row(
             i * tile + lax.broadcasted_iota(jnp.int32, (tile, span), 0))
         seen = key <= row
+        if reach is not None:
+            seen = seen & (key > row - reach)
 
         def group(g, carry):
             lanes = pl.ds(pl.multiple_of(g * width, width), width)
             kv = jnp.concatenate([r[:, lanes] for r in page_refs], axis=0)
-            s = lax.dot_general(q_ref[g], kv, (((1,), (1,)), ((), ())),
+            s = lax.dot_general(q_ref[g], kv if q_width == width
+                                else kv[:, :q_width],
+                                (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
             s = jnp.where(seen, s * scale, NEG_INF)      # (tile, span)
             m_prev = m_ref[g]
@@ -448,6 +472,12 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
 # query rows one grid step holds: more rows a step reuse each K/V tile
 # the MXU has latched for more work, and cost VMEM
 _ROW_TILE = 128
+# ... where ``heads_per_group`` query heads read one key-value head's
+# ``K | V`` pair (or a layer attends a window): 64 positions of 8 heads
+# a tile and 256 keys a step.  Every group's accumulator is in VMEM at
+# once (8 groups of 512 rows of 128 float32 lanes are 2 MiB)
+_GROUP_ROW_TILE = 512
+_GROUP_PAGES = 16
 # ... and where heads share a row: 32 positions of 32 heads a tile and
 # 512 keys a step.  The accumulator (row tile x value, float32) is
 # rescaled once a step whatever the step's keys, so more keys a step
@@ -462,15 +492,17 @@ _SHARED_PAGES = 32
 
 @functools.partial(jax.jit, static_argnames=(
     "block_size", "rows", "shared", "value", "scale", "name", "interpret",
-    "window", "tile"))
+    "window", "tile", "reach"))
 def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
                   shared, value, scale, name, interpret, window=_PAGES,
-                  tile=None):
+                  tile=None, reach=None):
     """q4: (B, G, Mp, W) queries, one row of a group's width a tile
     row, zero over the lanes that are not key, Mp a whole number of row
     tiles (``shared`` heads' rows of one position side by side); pages:
     the pool leaf (L, num_slots, G * W); layer (1,), tables
-    (B * blocks_per_seq,), starts (B,) int32 are prefetched scalars."""
+    (B * blocks_per_seq,), starts (B,) int32 are prefetched scalars.
+    With ``reach`` the table is a ring: the page of positions
+    ``n * block_size ..`` is entry ``n % blocks_per_seq``."""
     b, g, mp, gw = q4.shape
     nb = tables.shape[0] // b
     width = pages.shape[2]
@@ -486,7 +518,16 @@ def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
             last = (starts_ref[bi] + _query_row(jnp.minimum(
                 (ii + 1) * tile, rows * shared) - 1, shared)
                     ) // block_size
-            blk = jnp.minimum(jnp.minimum(ji * window + k, last), nb - 1)
+            if reach is None:
+                blk = jnp.minimum(jnp.minimum(ji * window + k, last),
+                                  nb - 1)
+            else:
+                # ... and at the first visible key's page before it
+                first = _first_key(starts_ref[bi],
+                                   _query_row(ii * tile, shared), reach)
+                blk = jnp.clip(
+                    (first // (window * block_size) + ji) * window + k,
+                    first // block_size, last) % nb
             return layer_ref[0], tables_ref[bi * nb + blk], 0
         return pl.BlockSpec((None, block_size, width), index)
 
@@ -496,7 +537,13 @@ def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
 
     kernel = functools.partial(_paged_kernel, scale=scale,
                                block_size=block_size, rows=rows, groups=g,
-                               shared=shared, value=value, pages=window)
+                               shared=shared, value=value, pages=window,
+                               reach=reach)
+    # windows a tile's rows can reach: all of the table's, or those
+    # that ``reach`` keys before the first row up to the last row touch
+    steps = _cdiv(nb, window) if reach is None else (
+        reach + _cdiv(tile, shared) + window * block_size - 3
+    ) // (window * block_size) + 1
     itemsize = jnp.dtype(q4.dtype).itemsize
     vmem = (g * tile * (vw + 2 * LANES) * 4         # acc, m, l
             + 2 * g * tile * (gw + vw) * itemsize   # q and out, twice
@@ -505,7 +552,7 @@ def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, mp // tile, _cdiv(nb, window)),
+            grid=(b, mp // tile, steps),
             in_specs=[rows_spec(gw)] + [page_spec(k)
                                         for k in range(window)],
             out_specs=rows_spec(vw),
@@ -545,6 +592,8 @@ def _kernel_name(rows: int, prefix: str = "") -> str:
 def paged_attention(q, pages, layer, block_tables, starts, *,
                     block_size: int, scale: Optional[float] = None,
                     latent_value: Optional[int] = None,
+                    heads_per_group: int = 1,
+                    window: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Attention of freshly written rows over a paged KV pool, read in
     place through the block table.
@@ -558,9 +607,10 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
         each head's absorbed query ``q_lat | q_pe`` as wide as the
         pool's row, zeros over its padding.
       pages: the pool leaf as ``serving.kv_cache`` lays it out, one row
-        a token slot: (L, num_slots, H * 2 * D), every head's ``K_h``
-        beside its ``V_h``; or (L, num_slots, W), one latent row
-        ``c | k_pe | padding`` that all H heads read.
+        a token slot: (L, num_slots, G * 2 * D), every key-value head's
+        ``K_h`` beside its ``V_h`` (G = H / ``heads_per_group``); or
+        (L, num_slots, W), one latent row ``c | k_pe | padding`` that
+        all H heads read.
       layer: int32 scalar, the layer whose pages to read.
       block_tables: (B, blocks_per_seq) int32 physical block ids;
         unallocated entries are 0 (the garbage block) and lie beyond
@@ -572,6 +622,14 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
         gives it (the width of the expanded query, not of the row).
       latent_value: for a latent pool, how many of a row's leading
         values are also the value (whole lane tiles).
+      heads_per_group: query heads that read one ``K | V`` group: head
+        ``i`` reads group ``i // heads_per_group``.
+      window: a row attends the ``window`` keys that end with its own
+        and none before them, and ``block_tables`` is a RING: the page
+        of positions ``n * block_size ..`` is entry ``n %
+        blocks_per_seq``, which has to hold at least ``window`` plus
+        the R rows.  Only the pages that hold ``p - window + 1 .. p``
+        are read.
       interpret: Pallas interpret mode (defaults to not-on-TPU).
 
     Only the pages up to each sequence's last row are streamed; nothing
@@ -581,8 +639,9 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
     (B, R, H, latent_value).  The Pallas call is named
     ``_decode_kernel`` for one row, ``_verify_kernel`` for up to a
     sublane tile of them and ``_chunk_kernel`` beyond, with
-    ``_latent`` in front for a latent pool, so a trace tells the
-    programs apart.  Inference only."""
+    ``_latent`` in front for a latent pool and ``_window`` for a
+    window layer, so a trace tells the programs and the kinds of layer
+    apart.  Inference only."""
     b, r, h, d = q.shape
     if interpret is None:
         interpret = not on_tpu()
@@ -615,16 +674,48 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
             interpret=bool(interpret), window=_SHARED_PAGES,
             tile=_SHARED_ROW_TILE)
         return out[:, 0, :m].reshape(b, r, h, latent_value).astype(q.dtype)
-    if pages.ndim != 3 or pages.shape[2] != h * 2 * d:
+    g = h // heads_per_group
+    if pages.ndim != 3 or pages.shape[2] != g * 2 * d or h % heads_per_group:
         raise ValueError(
-            f"pages must be (L, num_slots, H*2*D) = (.., .., {h * 2 * d}) "
-            f"for q={q.shape}; got {pages.shape}")
+            f"pages must be (L, num_slots, G*2*D) = (.., .., {g * 2 * d}) "
+            f"for q={q.shape} and heads_per_group={heads_per_group}; got "
+            f"{pages.shape}")
     if not paged_attention_fits(d, block_size, pages.dtype):
         raise ValueError(
             f"paged_attention cannot tile head_dim={d}, "
             f"block_size={block_size}, dtype={pages.dtype}")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if heads_per_group > 1 or window is not None:
+        ring = block_tables.shape[1] * block_size
+        if window is not None and ring < window + r:
+            raise ValueError(
+                f"a ring of {ring} rows cannot hold a window of {window} "
+                f"beside {r} fed rows")
+        # a group's heads of one position side by side: row m of group
+        # g is (position m // heads_per_group, head g * heads_per_group
+        # + m % heads_per_group)
+        m = r * heads_per_group
+        mp = _cdiv(m, 2 * _QROWS) * 2 * _QROWS
+        if mp > _GROUP_ROW_TILE:
+            mp = _cdiv(m, _GROUP_ROW_TILE) * _GROUP_ROW_TILE
+        # a key of whole lane tiles is sliced from its group for nothing:
+        # the queries are then as wide as the key and the value is the
+        # group's upper half; a narrower one meets the whole group
+        split = d % LANES == 0
+        q4 = jnp.moveaxis(q.reshape(b, r, g, heads_per_group, d), 2, 1)
+        q4 = jnp.pad(q4.astype(pages.dtype).reshape(b, g, m, d),
+                     ((0, 0), (0, 0), (0, mp - m), (0, 0 if split else d)))
+        out = _paged_pallas(
+            *scalars, q4, pages, block_size=int(block_size), rows=int(r),
+            shared=int(heads_per_group),
+            value=(d, 2 * d) if split else (0, 2 * d), scale=float(scale),
+            name=_kernel_name(r, "" if window is None else "_window"),
+            interpret=bool(interpret), window=_GROUP_PAGES,
+            tile=_GROUP_ROW_TILE,
+            reach=None if window is None else int(window))
+        out = out[:, :, :m, -d:].reshape(b, g, r, heads_per_group, d)
+        return jnp.moveaxis(out, 1, 2).reshape(b, r, h, d).astype(q.dtype)
     rp = _cdiv(r, _QROWS) * _QROWS
     if rp > _ROW_TILE:
         rp = _cdiv(r, _ROW_TILE) * _ROW_TILE
@@ -655,3 +746,32 @@ def latent_attention_reference(q, rows, positions, *, value: int,
     out = _einsum("bhrt,btv->brhv", p.astype(q.dtype),
                   rows[..., :value].astype(q.dtype))
     return out.astype(q.dtype)
+
+
+def grouped_attention_reference(q, k, v, q_pos, k_pos, *,
+                                window: Optional[int] = None,
+                                scale: Optional[float] = None):
+    """The jnp form of attention by positions, the parity oracle of
+    :func:`paged_attention` with ``heads_per_group`` or ``window`` and
+    what the CPU runs for them: ``q`` (B, R, H, D), ``k`` and ``v``
+    (B, T, G, D) gathered rows with the fed rows already among them,
+    query head ``i`` reading group ``i // (H / G)``; ``q_pos`` (B, R)
+    and ``k_pos`` (B, T) the positions the rows stand for (a negative
+    ``k_pos``: no key).  A row sees the keys at or before itself and,
+    with ``window``, none more than ``window - 1`` before.  fp32 scores
+    and softmax; returns (B, R, H, D) in q.dtype."""
+    b, r, h, d = q.shape
+    g = k.shape[2]
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    s = _einsum("brgpd,btgd->bgprt", q.reshape(b, r, g, h // g, d),
+                k.astype(q.dtype)).astype(jnp.float32) * scale
+    ahead = q_pos[:, :, None] - k_pos[:, None, :]             # (B, R, T)
+    seen = (ahead >= 0) & (k_pos[:, None, :] >= 0)
+    if window is not None:
+        seen = seen & (ahead < window)
+    s = jnp.where(seen[:, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = _einsum("bgprt,btgd->brgpd", p.astype(q.dtype),
+                  v.astype(q.dtype))
+    return out.reshape(b, r, h, d).astype(q.dtype)

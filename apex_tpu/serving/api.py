@@ -542,6 +542,30 @@ class InferenceServer:
         self.prefill_chunk = int(
             prefill_chunk if prefill_chunk is not None
             else min(DEFAULT_PREFILL_CHUNK, self.engine.max_context))
+        # a model with window layers (``models/family.py`` (d)) keeps
+        # their rows in a ring a slot: what a launch may feed is
+        # bounded by it, the prefix cache cannot keep a finished
+        # request's window rows, and no block mover carries a ring
+        windowed = self.engine.max_fed_rows is not None
+        if windowed:
+            if prefill_chunk is None:
+                self.prefill_chunk = min(self.prefill_chunk,
+                                         self.engine.max_fed_rows)
+            if self.prefill_chunk > self.engine.max_fed_rows:
+                # (the engine holds a verify launch to the same bound)
+                raise ValueError(
+                    f"prefill_chunk={self.prefill_chunk} is more than a "
+                    f"window layer's ring holds beside its window "
+                    f"({self.engine.max_fed_rows} rows)")
+            if enable_disagg or resolve_kv_offload(
+                    enable_kv_offload if enable_kv_offload is not None
+                    else os.environ.get(KV_OFFLOAD_ENV)):
+                raise NotImplementedError(
+                    "enable_disagg / enable_kv_offload move a request's "
+                    "blocks between pools, and a window layer's rows "
+                    "are in no block but in its slot's ring: the block "
+                    "movers do not carry them.  Open work: ROADMAP.md "
+                    "Reach.")
         self.overload_policy = (overload_policy
                                 if overload_policy is not None
                                 else OverloadPolicy())
@@ -599,10 +623,13 @@ class InferenceServer:
         # otherwise
         cache_alloc = (self.prefill_engine.allocator if self.disagg
                        else self.engine.allocator)
+        # no prefix hit is taken for a model with window layers: a hit
+        # of P tokens needs those layers' rows P - window .. P - 1,
+        # which the ring of the request that made them has let go
         self.prefix_cache = (
             PrefixCache(cache_alloc, self.engine.block_size,
                         counters=self.prefix)
-            if enable_prefix_cache else None)
+            if enable_prefix_cache and not windowed else None)
         # hierarchical KV offload (docs/serving.md, "Hierarchical KV
         # offload"; OFF by default): cold evictable prefix blocks
         # demote into a bounded host-RAM store (optionally spilling
@@ -688,7 +715,8 @@ class InferenceServer:
             prefix_cache=None if self.disagg else self.prefix_cache,
             chunk_size=self.prefill_chunk,
             overload=self.overload_policy,
-            tracer=self.tracer, journeys=self.journeys)
+            tracer=self.tracer, journeys=self.journeys,
+            ring_rows=self.engine.ring_rows or None)
         if self.disagg:
             self.prefill_scheduler = Scheduler(
                 self.prefill_engine.allocator,
@@ -1276,13 +1304,15 @@ class InferenceServer:
             skw = {"sampling": samp} if samp is not None else {}
         with tr.span("chunk_prefill", uid=req.uid, tokens=len(tokens),
                      start=start, **_rid(req)):
+            # whose ring a model's window layers write
+            ring = {"slot": req.slot} if sched.ring_rows else {}
             try:
                 out = (engine.chunk_prefill_sampled(
                     tokens, start, req.block_table,
-                    pad_to=self.prefill_chunk, **skw) if pipelined
+                    pad_to=self.prefill_chunk, **skw, **ring) if pipelined
                     else engine.chunk_prefill(
                         tokens, start, req.block_table,
-                        pad_to=self.prefill_chunk))
+                        pad_to=self.prefill_chunk, **ring))
             except MemoryError:
                 self._note_oom("prefill")
                 out, done = None, False
@@ -2853,7 +2883,26 @@ class InferenceServer:
             # to at read (None / == cache_dtype when off)
             "quantize": info["quantize"],
             "compute_dtype": info["compute_dtype"],
+            # the pool by kind of layer (``models/family.py`` (d)):
+            # blocks of the layers that keep every token; and, where
+            # the model has window layers, the rows of their rings
+            "by_kind": {"full": {**info["by_kind"]["full"],
+                                 "blocks_live": live}},
         }
+        if "window" in info["by_kind"]:
+            ring = self.engine.ring_rows
+            cached = [r.num_cached for r in sched.running.values()]
+            out["by_kind"]["window"] = {
+                **info["by_kind"]["window"],
+                # a layer's ring rows that hold a cached token's row
+                "rows_live": sum(min(n, ring) for n in cached),
+                # cached tokens' rows a layer's rings have let go:
+                # of the running requests, and of every request since
+                # the start
+                "rows_let_go_live": sum(max(0, n - ring) for n in cached),
+                "rows_let_go": sched.ring_rows_let_go + sum(
+                    max(0, n - ring) for n in cached),
+            }
         return out
 
     def _expert_stats(self) -> dict:

@@ -83,6 +83,7 @@ warning is filtered.)
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import warnings
 from typing import Optional
@@ -92,6 +93,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from apex_tpu.models.family import layer_windows
 from apex_tpu.observability import NULL_PROGRAM_ACCOUNTING, NULL_TRACER
 from apex_tpu.ops.decode_attention import paged_attention_fits
 from apex_tpu.ops.pallas_utils import LANES, on_tpu, pallas_auto_gate
@@ -101,6 +103,7 @@ from apex_tpu.ops.vocab_parallel import (
     vocab_parallel_sample_tokens,
 )
 from apex_tpu.serving.kv_cache import (
+    WINDOW_LEAF,
     BlockAllocator,
     CacheView,
     KVCacheConfig,
@@ -111,6 +114,7 @@ from apex_tpu.serving.kv_cache import (
     pool_specs,
     read_blocks,
     resolve_kv_quant,
+    ring_rows,
     slot_index,
     write_blocks,
 )
@@ -138,10 +142,15 @@ class DecodeEngine:
         the model (``cfg.build_model``), what one token keeps in one
         layer of the pool (``cfg.cache_row()``: every head's ``K | V``
         pair for ``models.GPTConfig``, one latent row shared by all
-        heads for ``models.DeepseekV3Config``), and ``vocab_size``,
-        ``num_hidden_layers``, ``max_position_embeddings``.  The
-        family is told from the object; there is no switch.  What a
-        latent pool does not do yet (``kv_quant="int8"``, ``mesh``)
+        heads for ``models.DeepseekV3Config``, 8 key-value heads'
+        pairs read by 8 query heads each for
+        ``models.ExaoneMoeConfig``), which tokens each layer keeps
+        (``models.family.layer_windows``: a window layer's rows live in
+        a ring its slot owns, a pool leaf of their own), and
+        ``vocab_size``, ``num_hidden_layers``,
+        ``max_position_embeddings``.  The family is told from the
+        object; there is no switch.  What a row that several query
+        heads share does not do yet (``kv_quant="int8"``, ``mesh``)
         raises here with its reason.
       params: the model's ``{"params": ...}["params"]`` pytree (pass
         amp-cast params to serve in half).
@@ -220,16 +229,17 @@ class DecodeEngine:
         self._pool_shard = None   # the pool's head-sharded placement
         self._scale_shard = None  # the scale sidecar's (heads last)
         if row.shared and (self.quantized or mesh is not None):
-            # both split or scale the row by heads, and a row that all
-            # heads share has none
+            # both split or scale the row by query heads, and a group
+            # that several of them share is not one head's
             raise NotImplementedError(
-                f"a {row.kind!r} pool keeps one row of {row.used} values "
-                f"for all {row.heads} query heads: "
+                f"a {row.kind!r} pool keeps {row.groups} group(s) of "
+                f"{row.used} values for {row.heads} query heads: "
                 + ("kv_quant='int8' stores one scale a head for a K|V "
-                   "pair" if self.quantized else
-                   "mesh=... shards the pool's row by whole heads")
-                + ", and this row has neither.  Open work: ROADMAP.md "
-                "Reach.")
+                   "pair of its own" if self.quantized else
+                   "mesh=... shards the pool's row by whole heads, each "
+                   "with a K|V pair of its own")
+                + ", and this row's groups are shared.  Open work: "
+                "ROADMAP.md Reach.")
         if mesh is not None:
             if tp_axis not in mesh.shape:
                 raise ValueError(
@@ -284,8 +294,26 @@ class DecodeEngine:
         if num_blocks is None:
             # every slot can hold a full-context request, +1 garbage
             num_blocks = self.max_batch_size * self.blocks_per_seq + 1
+        # which tokens each layer keeps: the layers that keep every one
+        # share the leaf the block tables address, a window layer's
+        # rows live in its slot's ring (kv_cache, "kv_window")
+        windows = layer_windows(cfg)
+        kept = [i for i, w in enumerate(windows) if w is None]
+        slide = [i for i, w in enumerate(windows) if w is not None]
+        self.window = windows[slide[0]] if slide else None
+        if slide and (len(set(windows)) > 2 or not kept
+                      or row.kind != "kv"):
+            raise NotImplementedError(
+                f"window layers of one width beside layers that keep "
+                f"every token, over a 'kv' row, is what the pool lays "
+                f"out; got windows {sorted(set(windows), key=str)} over "
+                f"a {row.kind!r} row")
+        self.layers = tuple(
+            ("kv", kept.index(i), None) if w is None
+            else (WINDOW_LEAF, slide.index(i), w)
+            for i, w in enumerate(windows)) if slide else None
         self.cache_cfg = KVCacheConfig(
-            num_layers=cfg.num_hidden_layers,
+            num_layers=len(kept),
             num_heads=row.groups,
             head_dim=row.group_width // 2,
             num_blocks=int(num_blocks),
@@ -293,6 +321,13 @@ class DecodeEngine:
             dtype=cache_dtype,
             quantize=self.kv_quant)
         self.allocator = BlockAllocator(self.cache_cfg)
+        # the window layers' leaf: a ring a slot and garbage block 0
+        self.ring_rows = (ring_rows(self.window, self.block_size)
+                          if slide else 0)
+        self.window_cfg = dataclasses.replace(
+            self.cache_cfg, num_layers=len(slide),
+            num_blocks=self.max_batch_size * self.ring_rows
+            // self.block_size + 1) if slide else None
         # which way each serving program attends, decided once from
         # what can be seen here: an unquantized pool on one TPU device
         # whose geometry the kernel tiles is read in place through the
@@ -398,19 +433,37 @@ class DecodeEngine:
         """The zeroed pool and, beside it, the family's counters."""
         cache = init_kv_cache(self.cache_cfg, sharding=self._pool_shard,
                               scale_sharding=self._scale_shard)
+        if self.window_cfg is not None:
+            cache[WINDOW_LEAF] = init_kv_cache(self.window_cfg)["kv"]
         for name, shape in self._counters.items():
             cache[name] = jnp.zeros(shape, jnp.int32)
         return cache
 
-    def _view(self, program, cache, tables, start, slots):
+    def _view(self, program, cache, tables, start, slots, ring=None):
         """The model's view of the pool for one launch of ``program``
-        (``kv_cache.CacheView``)."""
+        (``kv_cache.CacheView``).  ``ring`` (B,): whose ring each row's
+        window layers write, where the model has such layers: the
+        decode slot's, which is the row's own number in a launch of the
+        whole batch (None)."""
+        if self.layers is None:
+            ring = None
+        elif ring is None:
+            ring = jnp.arange(tables.shape[0], dtype=jnp.int32)
         return CacheView(
             cache, tables, start.astype(jnp.int32), slots,
             block_size=self.block_size, row=self.row,
-            table=self.attention_paths[program] == "table")
+            table=self.attention_paths[program] == "table",
+            ring=ring, layers=self.layers)
 
-    def _chunk_impl(self, params, cache, ids, start, length, table):
+    @property
+    def max_fed_rows(self) -> Optional[int]:
+        """The most rows one launch may feed a sequence (a prefill
+        chunk, a verify launch): what a window layer's ring holds
+        beside its window.  None where no layer is one."""
+        return self.ring_rows - self.window if self.layers else None
+
+    def _chunk_impl(self, params, cache, ids, start, length, table,
+                    slot=None):
         """One prefill CHUNK at a carried KV position: ids (1, Cb)
         zero-padded chunk tokens; start (1,) absolute position of
         ``ids[0]`` (== tokens already materialized through ``table``);
@@ -419,9 +472,11 @@ class DecodeEngine:
         context (slots < start) plus the chunk causally and writes the
         chunk's K/V at its block-offset slots (:meth:`_fed_rows`).
         Returns (cache, last-valid-token logits (1, V)) — the logits
-        only matter on the final chunk."""
+        only matter on the final chunk.  ``slot`` (1,): the request's
+        decode slot, whose ring its window layers write (a model with
+        such layers alone is given it)."""
         logits, cache = self._fed_rows("chunk_prefill", params, cache,
-                                       ids, start, length, table)
+                                       ids, start, length, table, slot)
         last = jnp.take_along_axis(
             logits, (length[:, None, None] - 1).astype(jnp.int32),
             axis=1)[:, 0]                                  # (1, V)
@@ -447,7 +502,7 @@ class DecodeEngine:
         return cache, logits                               # (B, K, V)
 
     def _fed_rows(self, program, params, cache, ids, start, length,
-                  tables):
+                  tables, ring=None):
         """The body verify and chunk prefill share: ids (B, K) fed at
         positions ``start + 0..K-1``, the first ``length`` of each row
         valid.  Every layer attends through the launch's view of the
@@ -463,7 +518,8 @@ class DecodeEngine:
         logits, view = self.model.apply(
             {"params": params}, ids, positions=pos_emb,
             deterministic=True,
-            cache_views=self._view(program, cache, tables, start, slots),
+            cache_views=self._view(program, cache, tables, start, slots,
+                                   ring),
             return_kv=True)
         return logits, view.cache
 
@@ -525,9 +581,9 @@ class DecodeEngine:
         return greedy_argmax(logits), finite_rows(logits)
 
     def _chunk_sampled_impl(self, params, cache, ids, start, length,
-                            table):
+                            table, slot=None):
         cache, last = self._chunk_impl(params, cache, ids, start,
-                                       length, table)
+                                       length, table, slot)
         return (cache,) + self._sample(last)                   # (1,)
 
     def _decode_sampled_impl(self, params, cache, tokens, positions,
@@ -570,9 +626,9 @@ class DecodeEngine:
         return sample_tokens(logits, *args, counters)
 
     def _chunk_stoch_impl(self, params, cache, ids, start, length,
-                          table, temp, tk, tp_, seed):
+                          table, temp, tk, tp_, seed, slot=None):
         cache, last = self._chunk_impl(params, cache, ids, start,
-                                       length, table)
+                                       length, table, slot)
         # final chunk: start + length == the full context length
         ids_out, fin = self._sample_stoch(last, start + length, temp,
                                           tk, tp_, seed)
@@ -664,21 +720,37 @@ class DecodeEngine:
         return jax.device_put(arrays)
 
     def _chunk_args(self, tokens, start, block_table, pad_to,
-                    sampling=None):
+                    sampling=None, slot=0):
         """The chunk launch struct: (ids, start, length, table[,
         sampling params]) on device in one transfer, the chunk padded
-        to the compiled width ``pad_to``."""
+        to the compiled width ``pad_to``; and, as keywords, the
+        request's ``slot`` where the model has window layers."""
         n = len(tokens)
         if n > pad_to:
             raise ValueError(
                 f"chunk of {n} tokens exceeds pad_to={pad_to}")
+        self._check_fed(pad_to)
         ids = np.zeros((1, pad_to), np.int32)
         ids[0, :n] = tokens
         table = np.zeros((1, self.blocks_per_seq), np.int32)
         table[0, :len(block_table)] = block_table
-        extra = tuple(sampling) if sampling is not None else ()
-        return self._put(ids, np.asarray([start], np.int32),
-                         np.asarray([n], np.int32), table, *extra)
+        arrays = (ids, np.asarray([start], np.int32),
+                  np.asarray([n], np.int32), table)
+        if sampling is not None:
+            arrays += tuple(sampling)
+        if self.layers is None:
+            return self._put(*arrays), {}
+        *args, slot = self._put(*arrays, np.asarray([slot], np.int32))
+        return tuple(args), {"slot": slot}
+
+    def _check_fed(self, rows: int) -> None:
+        """A window layer's ring holds its window and ``max_fed_rows``
+        more: a launch that fed more would overwrite rows it attends."""
+        if self.layers is not None and rows > self.max_fed_rows:
+            raise ValueError(
+                f"a launch of {rows} rows a sequence: the window layers' "
+                f"ring of {self.ring_rows} rows holds the window of "
+                f"{self.window} and {self.max_fed_rows} fed rows")
 
     def swap_params(self, params) -> None:
         """In-place weight swap: rebind ``self.params`` to a new
@@ -697,7 +769,7 @@ class DecodeEngine:
         self.params = params
 
     def chunk_prefill(self, tokens, start: int, block_table,
-                      pad_to: int) -> jax.Array:
+                      pad_to: int, slot: int = 0) -> jax.Array:
         """Run one prefill chunk — ``tokens`` at absolute positions
         ``start..start+len-1`` — writing its K/V through
         ``block_table``; K/V for positions < start must already be
@@ -706,17 +778,19 @@ class DecodeEngine:
 
         ``pad_to`` is the compiled chunk width: the serve loop passes
         its fixed ``prefill_chunk``, so exactly one chunk program ever
-        compiles."""
-        args = self._chunk_args(tokens, start, block_table, pad_to)
+        compiles.  ``slot``: the request's decode slot (a model with
+        window layers keeps their rows in the slot's ring)."""
+        args, kw = self._chunk_args(tokens, start, block_table, pad_to,
+                                    slot=slot)
         mark = self._mark(self._chunk_jit)
         self.cache, last = self._chunk_jit(self.params, self.cache,
-                                           *args)
+                                           *args, **kw)
         self._account(self._chunk_jit, mark, "chunk_prefill",
                       key=self._qkey(pad_to), width=pad_to)
         return last[0]
 
     def chunk_prefill_sampled(self, tokens, start: int, block_table,
-                              pad_to: int, sampling=None):
+                              pad_to: int, sampling=None, slot: int = 0):
         """The fused-sampling twin of :meth:`chunk_prefill`: returns
         ``(token_ids (1,) int32, finite (1,) bool)`` device arrays for
         the chunk's last valid token (only meaningful on the final
@@ -726,15 +800,16 @@ class DecodeEngine:
         seed)`` tuple of ``(1,)`` arrays launches the stochastic twin
         (``docs/serving.md``, "Stochastic sampling"; a 0-temperature
         row inside it is still bit-exact argmax)."""
-        args = self._chunk_args(tokens, start, block_table, pad_to,
-                                sampling=sampling)
+        args, kw = self._chunk_args(tokens, start, block_table, pad_to,
+                                    sampling=sampling, slot=slot)
         if sampling is None:
             jit_fn, name = (self._chunk_sampled_jit,
                             "chunk_prefill_sampled")
         else:
             jit_fn, name = self._chunk_stoch_jit, "chunk_prefill_stoch"
         mark = self._mark(jit_fn)
-        self.cache, ids, fin = jit_fn(self.params, self.cache, *args)
+        self.cache, ids, fin = jit_fn(self.params, self.cache, *args,
+                                      **kw)
         self._account(jit_fn, mark, name, key=self._qkey(pad_to),
                       width=pad_to)
         return ids, fin
@@ -758,6 +833,16 @@ class DecodeEngine:
 
     # -- disaggregated hand-off (docs/serving.md) --------------------------
 
+    def _no_rings(self, what: str) -> None:
+        """The block movers carry the leaves the tables address; a
+        window layer's rows are in its slot's ring."""
+        if self.layers is not None:
+            raise NotImplementedError(
+                f"{what} moves a request's blocks, and this model's "
+                f"window layers keep their rows in no block but in the "
+                f"slot's ring, which no block mover carries.  Open work: "
+                f"ROADMAP.md Reach.")
+
     def copy_blocks_from(self, src_engine, pairs) -> None:
         """Copy physical blocks ``[(src, dst), ...]`` from ANOTHER
         engine's pool into this one — the same-host disaggregated
@@ -768,6 +853,7 @@ class DecodeEngine:
         them that way).  Fixed-width ``_COPY_WIDTH`` launches, exactly
         like :meth:`copy_blocks`, so one program serves every
         hand-off."""
+        self._no_rings("copy_blocks_from")
         for i in range(0, len(pairs), _COPY_WIDTH):
             batch = pairs[i:i + _COPY_WIDTH]
             src = np.zeros((_COPY_WIDTH,), np.int32)
@@ -799,6 +885,7 @@ class DecodeEngine:
         whole-leaf crc already covers a one-shot transfer."""
         import zlib
 
+        self._no_rings("export_blocks")
         if len(block_ids):
             leaves = self._export_jit(
                 self.cache, *self._put(np.asarray(block_ids, np.int32)))
@@ -832,6 +919,7 @@ class DecodeEngine:
         half-imported."""
         import zlib
 
+        self._no_rings("import_blocks")
         if payload.get("block_size") != self.block_size \
                 or payload.get("num_blocks") != len(block_ids):
             raise ValueError(
@@ -939,6 +1027,7 @@ class DecodeEngine:
         with a fixed speculation depth compiles this exactly once."""
         args = self._verify_args(tokens, lengths, positions, tables)
         kw = int(np.asarray(tokens).shape[1])
+        self._check_fed(kw)
         mark = self._mark(self._verify_jit)
         self.cache, logits = self._verify_jit(self.params, self.cache,
                                               *args)
@@ -965,6 +1054,7 @@ class DecodeEngine:
         args = self._verify_args(tokens, lengths, positions, tables,
                                  sampling=sampling)
         kw = int(np.asarray(tokens).shape[1])
+        self._check_fed(kw)
         if sampling is None:
             jit_fn, name = self._verify_sampled_jit, "verify_sampled"
         else:
@@ -1065,15 +1155,33 @@ class DecodeEngine:
             analysis = self._decode_compiled().memory_analysis()
             temp = (int(analysis.temp_size_in_bytes)
                     if analysis is not None else None)
+        leaves = dict(pool_leaves(self.cache))
+        if self.window_cfg is not None:
+            leaves[WINDOW_LEAF] = self.cache[WINDOW_LEAF]
         per_device = sum(
             int(np.prod(arr.sharding.shard_shape(arr.shape)))
             * jnp.dtype(arr.dtype).itemsize
-            for arr in pool_leaves(self.cache).values())
+            for arr in leaves.values())
+        by_kind = {"full": {"layers": cfg.num_layers,
+                            "blocks_usable": cfg.num_blocks - 1,
+                            "bytes": cfg.bytes()}}
+        if self.window_cfg is not None:
+            by_kind["window"] = {
+                "layers": self.window_cfg.num_layers,
+                "window": self.window,
+                "rows_a_slot": self.ring_rows,
+                "rows_usable": self.max_batch_size * self.ring_rows,
+                "bytes": self.window_cfg.bytes()}
         return {
+            # what each kind of layer keeps (models/family.py (d)):
+            # the table's blocks for the layers that keep every token,
+            # a ring a slot for the window layers
+            "by_kind": by_kind,
             "blocks_usable": cfg.num_blocks - 1,
             "block_size": cfg.block_size,
             "pool_tokens": cfg.usable_tokens,
-            "pool_bytes": cfg.bytes(),
+            "pool_bytes": cfg.bytes() + (
+                self.window_cfg.bytes() if self.window_cfg else 0),
             "pool_bytes_per_device": per_device,
             "bytes_per_block": cfg.bytes_per_block,
             "cache_kind": self.row.kind,

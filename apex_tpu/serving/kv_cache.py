@@ -55,6 +55,32 @@ What a row holds is the model family's to say
 Writes, block copies, hand-off payloads, the allocator and the prefix
 cache's block hashing see rows of some width and nothing else.
 
+Which tokens a LAYER keeps is the family's to say too
+(``models.family.layer_windows``), and the pool has a leaf for each
+kind of layer:
+
+- ``"kv"``: the layers that attend every token before them.  A
+  sequence's rows live in the blocks of its table for as long as it
+  does, as above; this is the only leaf of a model without window
+  layers, and layer ``l`` is its row ``l``.
+- ``"kv_window"``: the layers whose row at ``p`` attends
+  ``p - w + 1 .. p`` alone.  Each decode slot OWNS a ring of
+  ``ring_rows`` rows a layer (:func:`ring_rows`: the window and the
+  most rows one launch may feed), position ``p`` at ring row ``p %
+  ring_rows``: a row is overwritten, and so let go, once it lies
+  ``ring_rows`` behind the newest.  No table grows, nothing is
+  allocated or freed, and the host does nothing as a sequence slides:
+  the ring's block ids are the slot's (:func:`ring_tables`).  Why a
+  ring and not a second table whose blocks are freed as they slide
+  out: that would take a second allocator, admission over two kinds
+  of block, and host work in every step, to save memory that is a
+  twentieth of the full layers' already.  What it costs: a ring is its
+  slot's, so the prefix cache cannot keep a finished request's window
+  rows, and a model with window layers takes no prefix hit
+  (``InferenceServer``).  A rejected draft needs no rollback here: its
+  rows lie past the accepted length, and what they overwrote lay
+  ``ring_rows`` behind them, outside every later row's window.
+
 Token slots are axis 1 of every leaf (the scale sidecar's too), which
 is what the hand-off and offload payloads slice by.  The forms that
 index ACROSS layers at once (``arr.at[:, slots]``) are the ones to
@@ -475,6 +501,32 @@ def gather_context(cache, block_tables, block_size: int, num_heads: int,
 
 
 POOL_LEAVES = ("kv", "k_scale", "v_scale")
+# the window layers' leaf: indexed by the slot's ring, not by block
+# table, so no block mover carries it
+WINDOW_LEAF = "kv_window"
+
+
+def ring_rows(window: int, block_size: int) -> int:
+    """Rows of a window layer's ring: the ``window`` a row attends and
+    up to three windows of rows one launch may feed behind it (a
+    prefill chunk, a verify launch), in whole blocks."""
+    return -(-4 * window // block_size) * block_size
+
+
+def ring_tables(ring, ring_blocks: int):
+    """The block ids of each row's ring, (B, ring_blocks): ring ``n``
+    owns the blocks ``1 + n * ring_blocks ..`` of the window leaf
+    (block 0 is its garbage sink)."""
+    return (1 + ring.astype(jnp.int32)[:, None] * ring_blocks
+            + jnp.arange(ring_blocks, dtype=jnp.int32)[None, :])
+
+
+def ring_slots(ring, positions, live, rows: int, block_size: int):
+    """Flat slots in the window leaf of ``positions`` (B, S) of rings
+    ``ring`` (B,): position ``p`` at ring row ``p % rows``; rows that
+    are no tokens (``live`` false) at the garbage block."""
+    base = block_size + ring.astype(jnp.int32)[:, None] * rows
+    return jnp.where(live, base + positions % rows, 0)
 
 
 def pool_leaves(cache):
@@ -595,9 +647,10 @@ def copy_blocks(cache, src, dst, block_size: int):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_size", "scale", "latent_value"))
+    "block_size", "scale", "latent_value", "heads_per_group", "window"))
 def _attend_in_place(pool, layer, q, kv, tables, start, slots, *,
-                     block_size, scale=None, latent_value=None):
+                     block_size, scale=None, latent_value=None,
+                     heads_per_group=1, window=None):
     """One layer of the table path: write the fed rows, then attend
     the pool in place.  A jitted function with ``layer`` an array, so
     that a program of many layers traces and lowers it once and calls
@@ -610,13 +663,15 @@ def _attend_in_place(pool, layer, q, kv, tables, start, slots, *,
     pool = write_layer({"kv": pool}, layer, kv, slots)["kv"]
     return paged_attention(q, pool, layer, tables, start,
                            block_size=block_size, scale=scale,
-                           latent_value=latent_value), pool
+                           latent_value=latent_value,
+                           heads_per_group=heads_per_group,
+                           window=window), pool
 
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=("cache", "tables", "start", "slots"),
-    meta_fields=("block_size", "row", "table"))
+    data_fields=("cache", "tables", "start", "slots", "ring"),
+    meta_fields=("block_size", "row", "table", "layers"))
 @dataclasses.dataclass(frozen=True)
 class CacheView:
     """The pool as one serving launch sees it, threaded through the
@@ -648,7 +703,14 @@ class CacheView:
 
     ``cache`` is the engine's whole cache pytree: the pool's leaves
     and, beside them, the counters a family carries through its
-    programs (:meth:`count`)."""
+    programs (:meth:`count`).
+
+    ``layers`` says where each layer's rows live, ``(leaf, row of the
+    leaf, window)`` a layer: ``("kv", l, None)`` for a layer that keeps
+    every token, read through ``tables``; ``("kv_window", n, w)`` for
+    a window layer, read through the ring of each sequence's slot,
+    ``ring`` (B,).  :meth:`attend` picks by the layer; the model never
+    learns which.  None: every layer is ``("kv", l, None)``."""
 
     cache: dict
     tables: jax.Array
@@ -657,6 +719,8 @@ class CacheView:
     block_size: int
     row: CacheRow
     table: bool
+    ring: Optional[jax.Array] = None
+    layers: Optional[tuple] = None
 
     @property
     def live(self):
@@ -679,27 +743,33 @@ class CacheView:
         themselves, causally, and the view after the write.
 
         A ``"kv"`` row: ``q`` (B, S, H, D) and the layer's fresh ``kv``
-        — ``(k, v)`` or, quantized, ``((k_q, k_scale), (v_q,
-        v_scale))`` — to ``(context (B, S, H, D), view)``.  A
+        — ``(k, v)`` each (B, S, G, D), G the key-value heads, or,
+        quantized, ``((k_q, k_scale), (v_q, v_scale))`` — to ``(context
+        (B, S, H, D), view)``; a window layer's rows go to the slot's
+        ring and it attends its window alone.  A
         ``"latent"`` row: ``q`` (B, S, H, used) the absorbed queries
         ``q_lat | q_pe``, ``kv`` (B, S, used) the rows ``c | k_pe``,
         ``scale`` the expanded form's (a ``"kv"`` row's is always
         ``1/sqrt(D)``) — to ``(context over the value
         lanes (B, S, H, rank), view)``."""
-        if self.row.shared:
+        if self.row.kind == "latent":
             return self._attend_latent(layer, q, kv, scale)
         from apex_tpu.ops.decode_attention import (
             cached_attention,
             chunk_cached_attention,
         )
 
+        leaf, index, window = (self.layers[layer] if self.layers
+                               else ("kv", layer, None))
+        if window is not None or self.row.shared:
+            return self._attend_grouped(leaf, index, window, q, kv)
         if self.table:
             ctx, pool = _attend_in_place(
-                self.cache["kv"], np.int32(layer), q, kv, self.tables,
+                self.cache["kv"], np.int32(index), q, kv, self.tables,
                 self.start, self.slots, block_size=self.block_size)
             return ctx, dataclasses.replace(
                 self, cache={**self.cache, "kv": pool})
-        ctx_kv = gather_layer(self.cache, layer, self.tables,
+        ctx_kv = gather_layer(self.cache, index, self.tables,
                               self.block_size, self.row.groups)
         k, v = kv
         ks = vs = None
@@ -724,7 +794,47 @@ class CacheView:
             ctx = chunk_cached_attention(q, k_full, v_full, bias,
                                          k_scale=ks, v_scale=vs)
         return ctx, dataclasses.replace(
-            self, cache=write_layer(self.cache, layer, kv, self.slots))
+            self, cache=write_layer(self.cache, index, kv, self.slots))
+
+    def _attend_grouped(self, leaf, index, window, q, kv):
+        """A ``"kv"`` row that several query heads read, or a window
+        layer: the rows are written first, into the table's blocks or
+        the slot's ring, and attention goes by positions."""
+        from apex_tpu.ops.decode_attention import (
+            grouped_attention_reference,
+        )
+
+        bs = self.block_size
+        q_pos = self.start[:, None] + jnp.arange(
+            q.shape[1], dtype=jnp.int32)[None, :]
+        if window is None:
+            tables, slots = self.tables, self.slots
+        else:
+            blocks = ring_rows(window, bs) // bs
+            tables = ring_tables(self.ring, blocks)
+            slots = ring_slots(self.ring, q_pos, self.live, blocks * bs, bs)
+        if self.table:
+            ctx, pool = _attend_in_place(
+                self.cache[leaf], np.int32(index), q, kv, tables,
+                self.start, slots, block_size=bs,
+                heads_per_group=self.row.heads_per_group, window=window)
+            return ctx, dataclasses.replace(
+                self, cache={**self.cache, leaf: pool})
+        pool = write_layer({"kv": self.cache[leaf]}, index, kv,
+                           slots)["kv"]
+        b, nb = tables.shape
+        k, v = unpack_rows(_by_block(pool, bs)[index][tables].reshape(
+            b, nb * bs, -1), self.row.groups)
+        k_pos = jnp.arange(nb * bs, dtype=jnp.int32)[None, :]
+        if window is not None:
+            # ring row i holds the newest position at or before the
+            # last fed row that is i modulo the ring (negative: none)
+            newest = q_pos[:, -1:]
+            k_pos = newest - (newest - k_pos) % (nb * bs)
+        ctx = grouped_attention_reference(q, k, v, q_pos, k_pos,
+                                          window=window)
+        return ctx, dataclasses.replace(
+            self, cache={**self.cache, leaf: pool})
 
     def _attend_latent(self, layer, q, rows, scale):
         """The latent row's :meth:`attend`: one absorbed path for

@@ -295,8 +295,15 @@ class Scheduler:
                  prefix_cache: Optional[PrefixCache] = None,
                  chunk_size: Optional[int] = None,
                  overload: Optional[OverloadPolicy] = None,
-                 tracer=None, journeys=None):
+                 tracer=None, journeys=None,
+                 ring_rows: Optional[int] = None):
         self.allocator = allocator
+        # a model with window layers keeps their rows in a ring of
+        # ``ring_rows`` a slot (``serving.kv_cache``): a slot is a ring,
+        # so admission counts nothing more; what the rings let go is
+        # tallied where a request leaves its slot
+        self.ring_rows = ring_rows
+        self.ring_rows_let_go = 0
         self.tracer = tracer if tracer is not None else NULL_TRACER
         # journey correlation plane (``observability.journey``): the
         # server's hop log; scheduler decisions (admit / preempt /
@@ -900,6 +907,8 @@ class Scheduler:
             self.counters.incr(f"requests_failed_{reason}")
 
     def _release(self, req: Request) -> None:
+        if self.ring_rows:
+            self.ring_rows_let_go += max(0, req.num_cached - self.ring_rows)
         del self.running[req.slot]
         self._admit_order.remove(req)
         self.inflight.pop(req.uid, None)

@@ -63,7 +63,9 @@ def as_on_tpu(monkeypatch):
     import apex_tpu.normalization.fused_layer_norm  # noqa: F401
     import apex_tpu.ops.decode_attention  # noqa: F401
     monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    import apex_tpu.ops.grouped_matmul  # noqa: F401
     for mod in ("apex_tpu.ops.decode_attention",
+                "apex_tpu.ops.grouped_matmul",
                 "apex_tpu.normalization.fused_layer_norm",
                 "apex_tpu.serving.engine"):
         monkeypatch.setattr(sys.modules[mod], "on_tpu", lambda: True)
@@ -232,3 +234,129 @@ def test_cpu_decode_writes_in_place_at_a_small_size():
     assert not [n for n, c, _ in instructions(text)
                 if c in (every_layer, 2 * every_layer)]
     assert e.memory_info()["decode_temp_bytes"] is not None
+
+
+# -- a model with window layers: the pool by kind of layer ---------------------
+
+KEXAONE = dict(vocab_size=19200, num_hidden_layers=8, num_experts=128,
+               experts_held=(0, 8))
+
+
+def _shape_engine(cfg, slots, max_context, sharding, monkeypatch):
+    """An engine of ``cfg`` that holds shapes only: the parameters and
+    the pool are described, never allocated."""
+    model = cfg.build_model()
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=sharding), params)
+    import apex_tpu.serving.engine as engine_mod
+    real = engine_mod.init_kv_cache
+    monkeypatch.setattr(
+        engine_mod, "init_kv_cache",
+        lambda cfg, **kw: jax.eval_shape(lambda: real(cfg)))
+    monkeypatch.setattr(
+        engine_mod.DecodeEngine, "_fresh_cache",
+        lambda self, fresh=engine_mod.DecodeEngine._fresh_cache:
+        jax.eval_shape(lambda: fresh(self)))
+    engine = DecodeEngine(cfg, params, max_batch_size=slots,
+                          max_context=max_context)
+    engine.cache = _shapes(engine.cache, sharding)
+    return engine
+
+
+def kernels_launched(text):
+    """How often each Pallas kernel's call stands in a compiled module's
+    text, by the kernel's name."""
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%(_\w*kernel)(?:\.\d+)? = ", line)
+        if m and "custom-call" in line:
+            out[m.group(1)] = out.get(m.group(1), 0) + 1
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "verify", "chunk"])
+def test_kexaone_programs_at_the_cells_size(one_chip, as_on_tpu, monkeypatch,
+                                            program):
+    """``k-exaone-236b-a23b`` as its cell runs it: 16 slots of 32,768,
+    two full layers through the table and six window layers through a
+    ring of 512 rows a slot.  Each kind's kernel is in the text under
+    its own name, nothing pool-sized is copied, and weights, both pools
+    and temporaries fit the chip."""
+    cfg = models.ExaoneMoeConfig(**KEXAONE)
+    e = _shape_engine(cfg, 16, 32768, one_chip, monkeypatch)
+    assert set(e.attention_paths.values()) == {"table"}
+    assert e.ring_rows == 512 and e.max_fed_rows == 384
+    assert e.cache["kv"].shape == (2, (16 * 2048 + 1) * 16, 2048)
+    assert e.cache["kv_window"].shape == (6, (16 * 32 + 1) * 16, 2048)
+    b, nb = 16, e.blocks_per_seq
+    if program == "decode":
+        exe = _compile(e._decode_jit, e, _ints(one_chip, b),
+                       _ints(one_chip, b), _ints(one_chip, b, nb))
+        names = {"_decode_kernel": 2, "_window_decode_kernel": 6}
+    elif program == "verify":
+        exe = _compile(e._verify_jit, e, _ints(one_chip, b, 5),
+                       _ints(one_chip, b), _ints(one_chip, b),
+                       _ints(one_chip, b, nb))
+        names = {"_verify_kernel": 2, "_window_verify_kernel": 6}
+    else:
+        exe = e._chunk_jit.lower(
+            e.params, e.cache, _ints(one_chip, 1, 256), _ints(one_chip, 1),
+            _ints(one_chip, 1), _ints(one_chip, 1, nb),
+            slot=_ints(one_chip, 1)).compile()
+        names = {"_chunk_kernel": 2, "_window_chunk_kernel": 6}
+    text = exe.as_text()
+    launched = kernels_launched(text)
+    assert {k: v for k, v in launched.items() if "moe" not in k} == names
+    assert launched["_moe_gmm_kernel"] == 7 * 3
+    for leaf in ("kv", "kv_window"):
+        assert not pool_sized_strays(text, int(np.prod(e.cache[leaf].shape)))
+    m = exe.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    print(program, "arguments", m.argument_size_in_bytes / GIB, "temp",
+          m.temp_size_in_bytes / GIB, "total", total / GIB)
+    assert total < 15 * GIB
+    assert m.temp_size_in_bytes < 1.5 * GIB
+
+
+@pytest.mark.parametrize("family", ["gpt2", "deepseek_v3"])
+def test_the_families_that_keep_every_token_launch_what_they_did(
+        one_chip, as_on_tpu, monkeypatch, family):
+    """The programs of a model without window layers are the ones they
+    were before the pool had kinds: one leaf, the kernels of one kind
+    under their names, once a layer, and the chunk program takes no slot
+    (an argument it has no use for is not in its compiled text)."""
+    if family == "gpt2":
+        cfg = models.GPTConfig(**XL)
+        kernels, layers = ("_decode_kernel", "_verify_kernel",
+                           "_chunk_kernel"), 48
+        context = 1024
+    else:
+        cfg = models.DeepseekV3Config(num_hidden_layers=3)
+        kernels, layers = ("_latent_decode_kernel", "_latent_verify_kernel",
+                           "_latent_chunk_kernel"), 3
+        context = 4096
+    e = _shape_engine(cfg, 8, context, one_chip, monkeypatch)
+    assert e.layers is None and e.window_cfg is None \
+        and e.max_fed_rows is None
+    assert set(e.cache) - {"routed"} == {"kv"}
+    b, nb = 8, e.blocks_per_seq
+    texts = [
+        _compile(e._decode_jit, e, _ints(one_chip, b), _ints(one_chip, b),
+                 _ints(one_chip, b, nb)).as_text(),
+        _compile(e._verify_jit, e, _ints(one_chip, b, 5),
+                 _ints(one_chip, b), _ints(one_chip, b),
+                 _ints(one_chip, b, nb)).as_text(),
+        _compile(e._chunk_jit, e, _ints(one_chip, 1, 256),
+                 _ints(one_chip, 1), _ints(one_chip, 1),
+                 _ints(one_chip, 1, nb)).as_text()]
+    for text, kernel in zip(texts, kernels):
+        launched = {k: v for k, v in kernels_launched(text).items()
+                    if "moe" not in k and "layer_norm" not in k
+                    and "ln_" not in k}
+        assert launched == {kernel: layers}, launched
+    args, kw = e._chunk_args([1, 2, 3], 0, [1], 256, slot=5)
+    assert kw == {} and len(args) == 4
