@@ -211,42 +211,98 @@ def sampling_noise(seeds, positions, vocab: int):
     return jnp.reshape(g, shape + (vocab,))
 
 
+# the signed 32-bit image of a float32: ``image(a) < image(b)`` exactly
+# where ``a < b`` (and -0.0 one below +0.0).  The map is its own inverse.
+_MAGNITUDE = 0x7FFFFFFF
+_IMAGE_NEG_INF = -0x7F800001        # image(-inf): below every finite value
+_IMAGE_ABOVE_INF = 0x7F800001       # image(+inf) + 1: above every value
+_TURNS = 32                         # halvings that close the span between them
+
+
+def _flip(x):
+    """int32 bits of a float32 <-> its order-preserving image (sign bit
+    kept, the magnitude bits inverted under a set sign)."""
+    return x ^ ((x >> 31) & _MAGNITUDE)
+
+
+def _thresholds(scaled, k, top_p):
+    """``(N, V)`` float32 rows, ``(N,)`` int32 ``k`` in ``[1, V]`` and
+    ``(N,)`` float32 ``top_p`` -> ``(kth, pth)``, each ``(N, 1)``: the
+    largest value ``t`` of the row with ``count(row >= t) >= k``, and
+    the largest with ``sum(e[row >= t]) / sum(e) >= top_p``.  Both
+    predicates fall monotonically as ``t`` rises (a float32 sum taken
+    in one fixed order never shrinks when a non-negative term joins
+    it), so each threshold is where its predicate turns, found by
+    halving the span of float32 IMAGES between ``-inf`` and ``+inf``:
+    32 turns, each one compare and two masked sums over the row.  A
+    candidate image is compared as the float it stands for, which
+    orders as the images do, so no image of the vocabulary is ever
+    written; nothing is sorted, accumulated or gathered."""
+    e = jnp.exp(scaled - jnp.max(scaled, axis=-1, keepdims=True))
+    total = jnp.sum(e, axis=-1, keepdims=True)
+    k, top_p = k[:, None], top_p[:, None]
+
+    def value(image):
+        return jax.lax.bitcast_convert_type(_flip(image), jnp.float32)
+
+    def halve(lo, hi, holds):
+        # floor((lo + hi) / 2) with no overflow; ``lo`` always holds
+        mid = (lo & hi) + ((lo ^ hi) >> 1)
+        keep = holds(scaled >= value(mid))
+        return jnp.where(keep, mid, lo), jnp.where(keep, hi, mid)
+
+    def turn(_, spans):
+        k_lo, k_hi, p_lo, p_hi = spans
+        return (
+            *halve(k_lo, k_hi, lambda m: jnp.sum(
+                m, axis=-1, keepdims=True, dtype=jnp.int32) >= k),
+            *halve(p_lo, p_hi, lambda m: jnp.sum(
+                jnp.where(m, e, 0.0), axis=-1, keepdims=True)
+                / total >= top_p))
+
+    lo = jnp.full(k.shape, _IMAGE_NEG_INF, jnp.int32)
+    hi = jnp.full(k.shape, _IMAGE_ABOVE_INF, jnp.int32)
+    k_lo, _, p_lo, _ = jax.lax.fori_loop(0, _TURNS, turn,
+                                         (lo, hi, lo, hi))
+    return value(k_lo), value(p_lo)
+
+
 def processed_logits(logits, temperature, top_k, top_p):
     """Temperature-scale then top-k/top-p-mask one batch of logits:
     ``(…, V)`` float logits + broadcast-shaped ``(…,)`` params ->
     ``(…, V)`` float32 masked scaled logits (dropped tokens at
-    ``-inf``).  The mask is a VALUE threshold — the k-th sorted value
+    ``-inf``).  The mask is a VALUE threshold — the k-th largest value
     and the nucleus-boundary value, whichever is higher — so ties at
     either boundary are all kept and the kept set is independent of
-    sort stability or shard layout.
+    any ordering of equal values or of a shard layout.
+
+    Both thresholds are found by SELECTION (:func:`_thresholds`): the
+    row is never sorted.  The nucleus boundary is the largest value
+    whose inclusive mass (it and everything above it) reaches
+    ``top_p``, so the token that crosses ``top_p`` is kept (pinned by
+    ``tests/L0/test_sampling.py``).  The kept set equals that of a
+    float64 reference (sort, cumulative sum, the same inclusion rule)
+    except where the inclusive mass at the boundary lies within
+    float32 accumulation error of ``top_p``; there it is the
+    reference's set with one more or one fewer distinct value.
 
     ``top_k <= 0`` disables the top-k filter; ``top_p >= 1`` disables
     the nucleus filter (never "keep only tokens above the underflowed
-    tail", which a literal cumsum threshold would produce when the
+    tail", which a literal mass threshold would produce when the
     scaled tail rounds to probability zero)."""
     v = logits.shape[-1]
     lg = logits.astype(jnp.float32)
     t = jnp.maximum(temperature, _TEMP_FLOOR)[..., None]
     scaled = lg / t
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
+    rows = scaled.shape[:-1]
     k = jnp.clip(jnp.where(top_k <= 0, v, top_k), 1, v)
-    kth = jnp.take_along_axis(sorted_desc, (k - 1)[..., None],
-                              axis=-1)
-    kth = jnp.where((top_k <= 0)[..., None], -jnp.inf, kth)
-    # nucleus boundary: the first sorted index whose INCLUSIVE
-    # cumulative probability reaches top_p — counting the positions
-    # still strictly below top_p lands exactly on it, so the
-    # boundary-crossing token is kept (pinned by test_sampling.py)
-    gmax = sorted_desc[..., :1]
-    e = jnp.exp(sorted_desc - gmax)
-    cum = jnp.cumsum(e, axis=-1) / jnp.sum(e, axis=-1, keepdims=True)
-    bnd = jnp.minimum(
-        jnp.sum((cum < top_p[..., None]).astype(jnp.int32), axis=-1,
-                keepdims=True), v - 1)
-    pth = jnp.take_along_axis(sorted_desc, bnd, axis=-1)
-    pth = jnp.where((top_p >= 1.0)[..., None], -jnp.inf, pth)
-    thresh = jnp.maximum(kth, pth)
-    return jnp.where(scaled >= thresh, scaled, -jnp.inf)
+    kth, pth = _thresholds(scaled.reshape(-1, v), k.reshape(-1),
+                           top_p.reshape(-1))
+    kth = jnp.where((top_k <= 0)[..., None], -jnp.inf,
+                    kth.reshape(rows + (1,)))
+    pth = jnp.where((top_p >= 1.0)[..., None], -jnp.inf,
+                    pth.reshape(rows + (1,)))
+    return jnp.where(scaled >= jnp.maximum(kth, pth), scaled, -jnp.inf)
 
 
 def sample_tokens(logits, temperature, top_k, top_p, seeds, positions):
@@ -261,7 +317,12 @@ def sample_tokens(logits, temperature, top_k, top_p, seeds, positions):
         ``token = argmax(processed_logits + gumbel(key(seed, pos)))``
 
     which samples the masked categorical exactly, with the counter key
-    of the module docstring's determinism contract.  ``finite`` is
+    of the module docstring's determinism contract.  The mask's two
+    thresholds are selected, never sorted for
+    (:func:`processed_logits`, which also says how far the kept set is
+    a sorted reference's): one path for every row shape and vocabulary,
+    with no short-list and no cap on ``top_k`` or on the nucleus (the
+    sharded twin's clamp to ``SHARD_CANDIDATES`` is its own).  ``finite`` is
     :func:`finite_rows` on the raw logits for every row — the serve
     loop's non-finite guard is sampling-agnostic.
 

@@ -54,8 +54,11 @@ from apex_tpu.ops.sampling import sampling_noise
 # Exactness holds while the kept set lives inside the global top-C
 # (always true for top_k <= C; true for top_p whenever the nucleus
 # fits in C tokens — the realistic serving regime by orders of
-# magnitude).  top_k is CLAMPED to C on the sharded path (documented;
-# the unsharded sampler honors any k).
+# magnitude).  top_k is CLAMPED to C on the sharded path (documented).
+# Both caps are this path's alone: the unsharded sampler selects its
+# thresholds over the whole row (``ops/sampling.py::_thresholds``) and
+# honors any k and any nucleus; 32 collectives a launch would be the
+# wrong cure here.
 SHARD_CANDIDATES = 128
 
 

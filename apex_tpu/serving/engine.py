@@ -413,7 +413,7 @@ class DecodeEngine:
         # (ops.sample_tokens; the vocab-parallel no-gather path under
         # a mesh).  Distinct traces from the greedy twins on purpose:
         # an all-greedy step keeps launching the argmax-only program
-        # — zero sort/noise cost for the default traffic — and the
+        # — zero selection/noise cost for the default traffic — and the
         # stochastic program only compiles once the first stochastic
         # request is actually batched.  Greedy rows INSIDE a
         # stochastic launch still take the bit-exact argmax lane.

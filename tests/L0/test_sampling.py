@@ -28,6 +28,7 @@ from apex_tpu import models
 from apex_tpu.ops.sampling import (
     SamplingParams,
     greedy_argmax,
+    processed_logits,
     sample_tokens_host,
 )
 from apex_tpu.serving import InferenceServer, greedy_sample
@@ -254,6 +255,111 @@ def test_rejection_sampling_exactness():
     residual[d] = 0.0
     residual /= residual.sum()
     assert _chi2(np.bincount(resampled, minlength=6), residual) < 20.5
+
+
+# -- the op: thresholds by selection against the sort-based oracle ----------
+
+def _oracle_thresholds(scaled, top_k, top_p):
+    """What ``processed_logits`` computed before it selected: one row
+    sorted, the k-th value by index, the nucleus boundary from a
+    cumulative sum, here in float64.  Returns ``(kth, pths)``:
+    ``pths`` holds the boundary value and, where the inclusive mass on
+    either side of it lies within 1e-5 of ``top_p`` (float32 sums of a
+    vocabulary cannot tell those apart), the neighbouring distinct
+    value on that side too."""
+    v = len(scaled)
+    desc = -np.sort(-scaled.astype(np.float64))
+    kth = desc[min(top_k, v) - 1] if top_k > 0 else -np.inf
+    if top_p >= 1.0:
+        return kth, [-np.inf]
+    e = np.exp(desc - desc[0])
+    cum = np.cumsum(e) / e.sum()
+    bnd = min(int(np.sum(cum < top_p)), v - 1)
+    pths = [desc[bnd]]
+    above = desc[desc > desc[bnd]]          # what a higher boundary keeps
+    if len(above) and top_p - cum[len(above) - 1] < 1e-5:
+        pths.append(above[-1])
+    through = int(np.sum(desc >= desc[bnd]))    # the boundary's ties too
+    if through < v and cum[through - 1] - top_p < 1e-5:
+        pths.append(desc[through])
+    return kth, pths
+
+
+_FILTERS = {"top_k": ((1, 5, 50, 300), (1.0,)),
+            "top_p": ((0,), (0.95, 0.8, 0.5, 0.1)),
+            "both": ((1, 5, 50, 300), (0.95, 0.8, 0.5, 0.1)),
+            "neither": ((0,), (1.0,))}
+_processed_jit = jax.jit(processed_logits)
+
+
+@pytest.mark.parametrize("filters", sorted(_FILTERS))
+@pytest.mark.parametrize("rows", [(6,), (2, 3)], ids=["B", "BxK"])
+@pytest.mark.parametrize("vocab", [257, 19200, 50257])
+def test_thresholds_by_selection_match_the_sorted_oracle(vocab, rows,
+                                                         filters):
+    """The kept set of every row is the float64 sort-and-cumsum
+    oracle's, or one of its two neighbours where the oracle's boundary
+    mass is within 1e-5 of ``top_p``: vocabularies that are and are
+    not multiples of 128, decode- and verify-shaped rows, ties at both
+    boundaries (logits on a grid of quarters), ``-inf`` columns, a
+    greedy row, and each filter alone, both and neither."""
+    ks, ps = _FILTERS[filters]
+    rng = np.random.RandomState(vocab + len(rows) + len(filters))
+    n = int(np.prod(rows))
+    lg = (rng.randn(n, vocab) * rng.choice([0.5, 1.0, 3.0], size=(n, 1))
+          ).astype(np.float32)
+    lg[1] = np.round(lg[1] * 4) / 4             # ties everywhere
+    lg[2] = np.round(lg[2])
+    lg[3, rng.randint(0, vocab, size=vocab // 8)] = -np.inf
+    lg[4] = np.where(np.arange(vocab) < 3, np.log([3.0, 1.0, 1.0])[
+        np.minimum(np.arange(vocab), 2)], -np.inf)   # masses 0.6 0.2 0.2
+    temp = rng.choice([0.5, 0.8, 1.0, 1.3], size=n).astype(np.float32)
+    temp[4] = 1.0
+    temp[5] = 0.0                               # the greedy lane's floor
+    tk = rng.choice(ks, size=n).astype(np.int32)
+    tp = rng.choice(ps, size=n).astype(np.float32)
+    if filters != "top_k":
+        tp[4] = 0.8 if filters != "neither" else 1.0    # 0.6 + 0.2: a tie
+    got = np.asarray(_processed_jit(
+        lg.reshape(rows + (vocab,)), temp.reshape(rows), tk.reshape(rows),
+        tp.reshape(rows))).reshape(n, vocab)
+    scaled = lg / np.maximum(temp, np.float32(1e-6))[:, None]
+    for r in range(n):
+        kth, pths = _oracle_thresholds(scaled[r], int(tk[r]), float(tp[r]))
+        sets = [np.where(scaled[r] >= max(kth, pth), scaled[r], -np.inf)
+                for pth in pths]
+        assert any(np.array_equal(got[r], want) for want in sets), (
+            r, int(tk[r]), float(tp[r]), int(np.sum(got[r] > -np.inf)),
+            [int(np.sum(w > -np.inf)) for w in sets])
+        assert np.sum(got[r] > -np.inf) >= 1
+
+
+def test_sweep_times_the_sampler_alone_and_gives_no_time_without_a_chip(
+        capsys):
+    """``tools/perf_sweep.py::sweep_sampler`` launches the sampler alone
+    at the shapes it is given and prints the share of a sampled row the
+    mask keeps; its time is the program's on the DEVICE's clock, so on
+    the CPU a shape carries an error and no number."""
+    import json
+    import os
+    import sys
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))), "tools")
+    sys.path.insert(0, tools)
+    try:
+        import perf_sweep
+    finally:
+        sys.path.remove(tools)
+    assert perf_sweep.SAMPLER_SHAPES == (
+        (8, 50257), (8, 5, 50257), (16, 5, 19200), (8, 128256))
+    rows = perf_sweep.sweep_sampler(shapes=((8, 257), (2, 5, 257)), iters=1)
+    assert [r["shape"] for r in rows] == [[8, 257], [2, 5, 257]]
+    for r in rows:
+        assert 60 < r["kept_pct"] < 90      # a wide nucleus, as the cells'
+        assert "ms" not in r and "error" in r
+    printed = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+               if l.startswith("{")]
+    assert printed == rows
 
 
 # -- the server: greedy default bit-parity + fast paths --------------------

@@ -360,3 +360,75 @@ def test_the_families_that_keep_every_token_launch_what_they_did(
         assert launched == {kernel: layers}, launched
     args, kw = e._chunk_args([1, 2, 3], 0, [1], 256, slot=5)
     assert kw == {} and len(args) == 4
+
+
+# -- the stochastic twins select their thresholds: no sort in the text ---------
+
+_TINY = {
+    "gpt2": lambda: models.GPTConfig(
+        vocab_size=97, hidden_size=64, num_hidden_layers=2,
+        num_attention_heads=2, intermediate_size=128,
+        max_position_embeddings=128, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0),
+    "deepseek_v3": lambda: models.DeepseekV3Config(
+        vocab_size=509, hidden_size=64, num_hidden_layers=3,
+        num_attention_heads=4, intermediate_size=128,
+        moe_intermediate_size=32, n_routed_experts=8, n_shared_experts=1,
+        num_experts_per_tok=2, first_k_dense_replace=1, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+        max_position_embeddings=256),
+}
+
+
+_SORTED = re.compile(
+    r'"stablehlo\.sort"\(.*?\}\) : \(([^)]*)\)|chlo\.top_k\([^)]*\) : (\S+)',
+    re.DOTALL)
+
+
+def _sorted_rows(text):
+    """The last dimension of every operand that a lowered module's text
+    sorts or takes a ``top_k`` of."""
+    return [int(dims.split("x")[-2])
+            for m in _SORTED.finditer(text)
+            for dims in re.findall(r"tensor<([^>]*)>",
+                                   m.group(1) or m.group(2))]
+
+
+@pytest.mark.parametrize("family", sorted(_TINY))
+def test_the_stochastic_twins_hold_no_sort(family):
+    """``chunk_prefill_stoch``, ``decode_stoch`` and ``verify_stoch`` as
+    they are lowered: the fused sampler finds its top-k and top-p
+    thresholds by selection (``ops/sampling.py``), so nothing as long
+    as the vocabulary is sorted (or short-listed by a ``top_k``) in any
+    of the three; an expert family's router still takes its few of a
+    few experts.  This is the record that the mechanism engages: it has
+    no counter because it has no other path."""
+    assert _sorted_rows(jax.jit(lambda x: -jnp.sort(-x)).lower(
+        jnp.zeros((4, 97))).as_text()) == [97], "the reading finds a sort"
+    cfg = _TINY[family]()
+    params = cfg.build_model().init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    e = DecodeEngine(cfg, params, max_batch_size=4, max_context=128,
+                     cache_dtype=jnp.float32)
+    b, nb = 4, e.blocks_per_seq
+
+    def sampling(n):
+        return (np.full((n,), 0.8, np.float32), np.zeros((n,), np.int32),
+                np.full((n,), 0.95, np.float32), np.arange(n, dtype=np.int32))
+
+    zeros, tables = np.zeros((b,), np.int32), np.zeros((b, nb), np.int32)
+    chunk_args, chunk_kw = e._chunk_args([1, 2, 3], 0, [1], 16,
+                                         sampling=sampling(1))
+    programs = {
+        "chunk_prefill_stoch": (e._chunk_stoch_jit, chunk_args, chunk_kw),
+        "decode_stoch": (e._decode_stoch_jit, e._decode_args(
+            zeros, zeros, tables, sampling=sampling(b)), {}),
+        "verify_stoch": (e._verify_stoch_jit, e._verify_args(
+            np.zeros((b, 5), np.int32), zeros, zeros, tables,
+            sampling=sampling(b)), {}),
+    }
+    for name, (jit_fn, args, kw) in programs.items():
+        text = jit_fn.lower(e.params, e.cache, *args, **kw).as_text()
+        assert "stablehlo.while" in text, name    # the 32 turns, one loop
+        assert cfg.vocab_size not in _sorted_rows(text), name
+        assert _sorted_rows(text) or family == "gpt2", name

@@ -6,7 +6,7 @@ this sweeps the knobs that move single-chip throughput and prints one JSON
 line per point, so block sizes / batch sizes can be chosen from data
 rather than defaults.
 
-Usage: ``python tools/perf_sweep.py [--quick]``
+Usage: ``python tools/perf_sweep.py [--quick | --sampler]``
 """
 
 import argparse
@@ -58,12 +58,12 @@ def sweep_stem(iters, batch=128):
 FLASH_KERNELS = ("fwd", "dq", "dkv")
 
 
-def _kernel_ms(fn, args, iters, kernel):
-    """Milliseconds a launch of the Pallas kernel named ``kernel`` on
-    the DEVICE's clock: ``iters`` launches under the profiler, the
-    kernel's events summed from the trace (a host clock around calls of
-    a millisecond reads the dispatch too: 1.33 ms where the trace says
-    1.13, PR 28).  The first call, which compiles, is apart."""
+def _traced(fn, args, iters):
+    """``iters`` launches of ``fn(*args)`` under the profiler, reduced
+    (``benchmarks/harness/trace.py``): times come from the DEVICE's
+    clock (a host clock around calls of a millisecond reads the dispatch
+    too: 1.33 ms where the trace says 1.13, PR 28).  The first call,
+    which compiles, is apart."""
     import jax
     from benchmarks.harness.trace import SubWindow
     jax.block_until_ready(fn(*args))
@@ -75,7 +75,13 @@ def _kernel_ms(fn, args, iters, kernel):
         jax.block_until_ready(out)
     finally:
         window.stop()
-    seconds, events = window.reduce().kernel_seconds(kernel)
+    return window.reduce()
+
+
+def _kernel_ms(fn, args, iters, kernel):
+    """Milliseconds a launch of the Pallas kernel named ``kernel``
+    takes: its events summed from the trace of ``iters`` launches."""
+    seconds, events = _traced(fn, args, iters).kernel_seconds(kernel)
     if events != iters:
         raise RuntimeError(f"{events} events of {kernel} in the trace, "
                            f"{iters} launched")
@@ -148,10 +154,68 @@ def sweep_flash(shape=(8, 1024, 16, 64), blocks=(128, 256, 512, 1024),
     return rows
 
 
+# what the serving cells launch the fused sampler over (PERF.md section 4):
+# gpt2-xl's decode and verify rows, k-exaone's verify rows, kanana-2's decode
+SAMPLER_SHAPES = ((8, 50257), (8, 5, 50257), (16, 5, 19200), (8, 128256))
+
+
+def sweep_sampler(shapes=SAMPLER_SHAPES, iters=20, sample_tokens=None):
+    """``ops.sampling.sample_tokens`` ALONE, jitted, at each of
+    ``shapes``, with the cells' parameters (temperature 0.8, top-p 0.95,
+    every eighth row greedy) over logits of deviation 0.8 (what
+    N(0, 0.02) weights give ``gpt2-xl``: a nucleus of three quarters of
+    the vocabulary).  One JSON line a shape: the milliseconds a launch
+    takes on the DEVICE's clock (the program's events in the trace) and
+    the share of a sampled row that the mask keeps.  ``sample_tokens``
+    takes another commit's function for the comparison.  On the CPU a
+    shape carries an error and no time.  Returns the rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from apex_tpu.ops import sampling
+
+    fn = sample_tokens or sampling.sample_tokens
+
+    def sampler_alone(*a):      # the program's name in the trace
+        return fn(*a)
+
+    launch = jax.jit(sampler_alone)
+    rows = []
+    for shape in shapes:
+        lead = shape[:-1]
+        rng = np.random.RandomState(len(shape) + shape[-1])
+        temp = np.full(lead, 0.8, np.float32)
+        temp.reshape(-1)[::8] = 0.0
+        args = [jnp.asarray(a) for a in (
+            (rng.randn(*shape) * 0.8).astype(np.float32), temp,
+            np.zeros(lead, np.int32), np.full(lead, 0.95, np.float32),
+            rng.randint(0, 2 ** 30, size=lead).astype(np.int32),
+            rng.randint(0, 1000, size=lead).astype(np.int32))]
+        kept = sampling.processed_logits(*args[:4]) > -jnp.inf
+        row = {"sweep": "sampler", "shape": list(shape),
+               "kept_pct": round(100 * float(jnp.mean(kept[temp > 0])), 2)}
+        try:
+            took = _traced(launch, args, iters).program_durations(
+                "jit_sampler_alone")
+            # the profiler can miss the first of launches this short
+            if len(took) < iters // 2:
+                raise RuntimeError(f"{len(took)} launches in the trace, "
+                                   f"{iters} made")
+            row["ms"] = round(float(np.median(took)) * 1e3, 4)
+        except Exception as e:
+            row["error"] = f"{type(e).__name__}: {e}"[:200]
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="fewer points / iterations")
+    ap.add_argument("--sampler", action="store_true",
+                    help="only the serving sampler at the cells' shapes")
     args = ap.parse_args()
 
     from apex_tpu.ops.pallas_utils import require_tpu
@@ -163,6 +227,9 @@ def main():
                       "compile_cache_dir": enable_compile_cache()}),
           flush=True)
 
+    if args.sampler:
+        sweep_sampler()
+        return
     iters = 5 if args.quick else 20
     sweep_resnet([128] if args.quick else [64, 128, 256], iters)
     sweep_stem(iters)
