@@ -32,6 +32,7 @@ from apex_tpu.models.moe import EP_RULES, MoEMlp, ep_rules
 from apex_tpu.models.family import CacheRow
 from apex_tpu.models.deepseek import DeepseekV3Config, DeepseekV3LMHeadModel
 from apex_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeLMHeadModel
+from apex_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeLMHeadModel
 from apex_tpu.models.bert import (
     BertConfig,
     BertEncoder,
@@ -51,6 +52,8 @@ __all__ = [
     "ExaoneMoeLMHeadModel",
     "GPTConfig",
     "GPTLMHeadModel",
+    "Lfm2MoeConfig",
+    "Lfm2MoeLMHeadModel",
     "PipelinedGPT",
     "gpt_medium",
     "gpt_small",

@@ -24,16 +24,25 @@ configuration object and ask it, not its class name, three things:
     positions ``p - w + 1 .. p`` alone (its rows live in a ring the
     slot owns and are let go as they slide out:
     ``serving.kv_cache``).  A family whose layers all keep everything
-    need not have the method (:func:`layer_windows`).
+    need not have the method (:func:`layer_windows`);
+(e) which layers keep a fixed STATE and no token at all,
+    ``cfg.layer_states()``: one entry a layer, None for a layer that
+    keeps tokens as (d) says, or ``(rows, width)`` for one whose
+    sequence carries ``rows`` rows of ``width`` values whatever its
+    length (a short convolution's last inputs).  Such a layer's model
+    hands ``CacheView.convolve`` its fed rows and never sees where the
+    state lives (a ring a slot: ``serving.kv_cache``).  A family without
+    the method keeps tokens in every layer (:func:`layer_states`).
 
 A family whose programs carry counters beside the pool (the tokens an
 expert layer routed) also has ``cfg.serving_counters()``:
 ``{name: shape}`` of int32 arrays the engine allocates zeroed and the
 model adds to through ``CacheView.count``.
 
-``models.gpt.GPTConfig``, ``models.deepseek.DeepseekV3Config`` and
-``models.exaone_moe.ExaoneMoeConfig`` implement it; the engine imports
-none of them for it.
+``models.gpt.GPTConfig``, ``models.deepseek.DeepseekV3Config``,
+``models.exaone_moe.ExaoneMoeConfig`` and
+``models.lfm2_moe.Lfm2MoeConfig`` implement it; the engine imports none
+of them for it.
 """
 
 from __future__ import annotations
@@ -49,6 +58,14 @@ def layer_windows(cfg) -> Tuple[Optional[int], ...]:
     family's answer, or "every token" for each where it gives none."""
     if hasattr(cfg, "layer_windows"):
         return tuple(cfg.layer_windows())
+    return (None,) * cfg.num_hidden_layers
+
+
+def layer_states(cfg) -> Tuple[Optional[Tuple[int, int]], ...]:
+    """Which layers of ``cfg`` keep a fixed state (the contract's (e)):
+    the family's answer, or "none does" where it gives none."""
+    if hasattr(cfg, "layer_states"):
+        return tuple(cfg.layer_states())
     return (None,) * cfg.num_hidden_layers
 
 

@@ -1,7 +1,8 @@
 """What the decoder families with RMSNorm, rotary positions, gated
 feed-forwards and routed experts share: ``models.deepseek`` (the
-``deepseek_v3`` type) and ``models.exaone_moe`` (the ``exaone_moe``
-type) build their blocks from these, and neither imports the other.
+``deepseek_v3`` type), ``models.exaone_moe`` (the ``exaone_moe`` type)
+and ``models.lfm2_moe`` (the ``lfm2_moe`` type) build their blocks from
+these, and none imports another.
 
 - :class:`RMSNorm`, float32 inside.
 - :func:`rotary_angles` / :func:`apply_rotary`: rotation of pairs, from
@@ -12,8 +13,8 @@ type) build their blocks from these, and neither imports the other.
   (the bias selects and does not weigh), their scores normalised and
   scaled, the chosen experts' gated feed-forwards summed with those
   weights beside the shared experts (one gated feed-forward of
-  ``shared_width``).  No capacity and no dropped token: the (token,
-  expert) pairs are sorted by expert and go through
+  ``shared_width``, none where it is 0).  No capacity and no dropped
+  token: the (token, expert) pairs are sorted by expert and go through
   ``ops.grouped_matmul`` three times.  The layer is told which experts
   it holds (``ExpertsSpec.held``): it routes over all ``router_width``
   and computes its own experts' part, which is what expert parallelism
@@ -88,15 +89,16 @@ class GatedMLP(nn.Module):
         return (nn.silu(x @ gate) * (x @ up)) @ down
 
 
-def route(scores, bias, k: int, scaling: float, normalise: bool):
+def route(scores, bias, k: int, scaling: float, normalise: bool,
+          eps: float = 1e-20):
     """``scores`` (T, E) float32 sigmoid scores, ``bias`` (E,) the
     selection bias: the ``k`` experts with the largest ``score + bias``
-    and their weights ``score / sum(chosen scores) * scaling``.  The
-    bias selects and does not weigh."""
+    and their weights ``score / (sum(chosen scores) + eps) * scaling``.
+    The bias selects and does not weigh."""
     _, chosen = lax.top_k(scores + bias, k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalise:
-        picked = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+        picked = picked / (jnp.sum(picked, -1, keepdims=True) + eps)
     return chosen, picked * scaling
 
 
@@ -105,7 +107,8 @@ class ExpertsSpec:
     """One expert layer by its sizes: the router's ``router_width``
     scores of which ``top_k`` are chosen, routed experts ``width`` wide
     of which this layer holds ``held`` = ``(first, count)``, the shared
-    experts as one gated feed-forward ``shared_width`` wide."""
+    experts as one gated feed-forward ``shared_width`` wide (0: none),
+    and ``norm_eps`` the normaliser's epsilon (:func:`route`)."""
 
     router_width: int
     top_k: int
@@ -115,6 +118,7 @@ class ExpertsSpec:
     scaling: float
     normalise: bool
     init_range: float
+    norm_eps: float = 1e-20
 
 
 def check_held(held, router_width: int):
@@ -152,7 +156,7 @@ class RoutedExperts(nn.Module):
                 xt.astype(jnp.float32), router.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST))
             chosen, weights = route(scores, bias.astype(jnp.float32), k,
-                                    sp.scaling, sp.normalise)
+                                    sp.scaling, sp.normalise, sp.norm_eps)
             # for whoever asks (``mutable=["intermediates"]``): tests
             # bound how often a precision picks another expert
             self.sow("intermediates", "chosen", chosen.reshape(b, s, k))
@@ -177,8 +181,10 @@ class RoutedExperts(nn.Module):
             routed = jnp.sum(
                 out * jnp.where(mine, weights, 0.0)[..., None], axis=1)
 
-        with jax.named_scope("moe_shared"):
-            shared = GatedMLP(sp.shared_width, sp.init_range,
-                              name="shared_experts")(xt)
-        y = (routed + shared.astype(jnp.float32)).astype(x.dtype)
+        if sp.shared_width:
+            with jax.named_scope("moe_shared"):
+                routed = routed + GatedMLP(
+                    sp.shared_width, sp.init_range,
+                    name="shared_experts")(xt).astype(jnp.float32)
+        y = routed.astype(x.dtype)
         return y.reshape(b, s, h), sizes

@@ -527,13 +527,22 @@ class InferenceServer:
         if kv_quant is None:
             kv_quant = os.environ.get(KV_QUANT_ENV)
         self.kv_quant = resolve_kv_quant(kv_quant)
+        # speculation (docs/serving.md): the verify program is
+        # spec_tokens + 1 rows a sequence, which a state layer's ring
+        # holds beside what a launch reads
+        self.spec_tokens = int(spec_tokens if spec_tokens is not None
+                               else DEFAULT_SPEC_TOKENS)
+        if self.spec_tokens < 1:
+            raise ValueError(
+                f"spec_tokens must be >= 1, got {self.spec_tokens}")
         self.engine = DecodeEngine(
             cfg, params, max_batch_size=max_batch_size,
             max_context=max_context, num_blocks=num_blocks,
             block_size=block_size, cache_dtype=cache_dtype,
             kv_quant=self.kv_quant,
             tracer=self.tracer, programs=self.programs,
-            mesh=mesh, tp_rules=tp_rules, tp_axis=tp_axis)
+            mesh=mesh, tp_rules=tp_rules, tp_axis=tp_axis,
+            verify_rows=self.spec_tokens + 1)
         self.failures = CounterMeter(registry=self.registry,
                                      name="serving_failures",
                                      label="reason")
@@ -544,9 +553,11 @@ class InferenceServer:
             else min(DEFAULT_PREFILL_CHUNK, self.engine.max_context))
         # a model with window layers (``models/family.py`` (d)) keeps
         # their rows in a ring a slot: what a launch may feed is
-        # bounded by it, the prefix cache cannot keep a finished
-        # request's window rows, and no block mover carries a ring
+        # bounded by it.  That, or state layers ((e)), a ring a slot
+        # too: the prefix cache cannot keep a finished request's rows
+        # or state, and no block mover carries a ring
         windowed = self.engine.max_fed_rows is not None
+        ringed = self.engine.layers is not None
         if windowed:
             if prefill_chunk is None:
                 self.prefill_chunk = min(self.prefill_chunk,
@@ -557,15 +568,16 @@ class InferenceServer:
                     f"prefill_chunk={self.prefill_chunk} is more than a "
                     f"window layer's ring holds beside its window "
                     f"({self.engine.max_fed_rows} rows)")
-            if enable_disagg or resolve_kv_offload(
-                    enable_kv_offload if enable_kv_offload is not None
-                    else os.environ.get(KV_OFFLOAD_ENV)):
-                raise NotImplementedError(
-                    "enable_disagg / enable_kv_offload move a request's "
-                    "blocks between pools, and a window layer's rows "
-                    "are in no block but in its slot's ring: the block "
-                    "movers do not carry them.  Open work: ROADMAP.md "
-                    "Reach.")
+        if ringed and (enable_disagg or resolve_kv_offload(
+                enable_kv_offload if enable_kv_offload is not None
+                else os.environ.get(KV_OFFLOAD_ENV))):
+            raise NotImplementedError(
+                "enable_disagg / enable_kv_offload move a request's "
+                "blocks between pools, and "
+                + ("a window layer's rows are" if windowed
+                   else "a state layer's state is")
+                + " in no block but in its slot's ring: the block "
+                "movers do not carry it.  Open work: ROADMAP.md Reach.")
         self.overload_policy = (overload_policy
                                 if overload_policy is not None
                                 else OverloadPolicy())
@@ -623,13 +635,15 @@ class InferenceServer:
         # otherwise
         cache_alloc = (self.prefill_engine.allocator if self.disagg
                        else self.engine.allocator)
-        # no prefix hit is taken for a model with window layers: a hit
-        # of P tokens needs those layers' rows P - window .. P - 1,
-        # which the ring of the request that made them has let go
+        # no prefix hit is taken for a model with window or state
+        # layers: a hit of P tokens needs the window layers' rows
+        # P - window .. P - 1, which the ring of the request that made
+        # them has let go, or the state layers' state at P, which the
+        # ring of that request has moved past
         self.prefix_cache = (
             PrefixCache(cache_alloc, self.engine.block_size,
                         counters=self.prefix)
-            if enable_prefix_cache and not windowed else None)
+            if enable_prefix_cache and not ringed else None)
         # hierarchical KV offload (docs/serving.md, "Hierarchical KV
         # offload"; OFF by default): cold evictable prefix blocks
         # demote into a bounded host-RAM store (optionally spilling
@@ -745,12 +759,6 @@ class InferenceServer:
             registry=self.registry, name="serving_sampling_requests",
             label="class")
         self.clock = clock
-        # speculation (docs/serving.md)
-        self.spec_tokens = int(spec_tokens if spec_tokens is not None
-                               else DEFAULT_SPEC_TOKENS)
-        if self.spec_tokens < 1:
-            raise ValueError(
-                f"spec_tokens must be >= 1, got {self.spec_tokens}")
         self.draft_source = (draft_source if draft_source is not None
                              else NgramDraft())
         self.speculating = bool(enable_speculation)
@@ -1304,8 +1312,8 @@ class InferenceServer:
             skw = {"sampling": samp} if samp is not None else {}
         with tr.span("chunk_prefill", uid=req.uid, tokens=len(tokens),
                      start=start, **_rid(req)):
-            # whose ring a model's window layers write
-            ring = {"slot": req.slot} if sched.ring_rows else {}
+            # whose rings a model's window and state layers use
+            ring = {"slot": req.slot} if engine.layers is not None else {}
             try:
                 out = (engine.chunk_prefill_sampled(
                     tokens, start, req.block_table,
@@ -2883,9 +2891,9 @@ class InferenceServer:
             # to at read (None / == cache_dtype when off)
             "quantize": info["quantize"],
             "compute_dtype": info["compute_dtype"],
-            # the pool by kind of layer (``models/family.py`` (d)):
+            # the pool by kind of layer (``models/family.py`` (d), (e)):
             # blocks of the layers that keep every token; and, where
-            # the model has window layers, the rows of their rings
+            # the model has window or state layers, their rings
             "by_kind": {"full": {**info["by_kind"]["full"],
                                  "blocks_live": live}},
         }
@@ -2903,6 +2911,11 @@ class InferenceServer:
                 "rows_let_go": sched.ring_rows_let_go + sum(
                     max(0, n - ring) for n in cached),
             }
+        if "state" in info["by_kind"]:
+            out["by_kind"]["state"] = {
+                **info["by_kind"]["state"],
+                # the slots whose rings hold a running request's state
+                "slots_live": len(sched.running)}
         return out
 
     def _expert_stats(self) -> dict:

@@ -93,7 +93,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from apex_tpu.models.family import layer_windows
+from apex_tpu.models.family import layer_states, layer_windows
 from apex_tpu.observability import NULL_PROGRAM_ACCOUNTING, NULL_TRACER
 from apex_tpu.ops.decode_attention import paged_attention_fits
 from apex_tpu.ops.pallas_utils import LANES, on_tpu, pallas_auto_gate
@@ -103,6 +103,7 @@ from apex_tpu.ops.vocab_parallel import (
     vocab_parallel_sample_tokens,
 )
 from apex_tpu.serving.kv_cache import (
+    STATE_LEAF,
     WINDOW_LEAF,
     BlockAllocator,
     CacheView,
@@ -116,6 +117,7 @@ from apex_tpu.serving.kv_cache import (
     resolve_kv_quant,
     ring_rows,
     slot_index,
+    state_rows,
     write_blocks,
 )
 
@@ -146,7 +148,10 @@ class DecodeEngine:
         pairs read by 8 query heads each for
         ``models.ExaoneMoeConfig``), which tokens each layer keeps
         (``models.family.layer_windows``: a window layer's rows live in
-        a ring its slot owns, a pool leaf of their own), and
+        a ring its slot owns, a pool leaf of their own), which layers
+        keep a fixed state instead (``models.family.layer_states``: a
+        ring of a few rows a slot, ``models.Lfm2MoeConfig``'s short
+        convolutions), and
         ``vocab_size``, ``num_hidden_layers``,
         ``max_position_embeddings``.  The family is told from the
         object; there is no switch.  What a row that several query
@@ -201,6 +206,11 @@ class DecodeEngine:
         ``tp_axis``).
       tp_axis: the mesh axis tensor parallelism shards over
         (default ``"model"``).
+      verify_rows: the most rows a verify launch feeds a sequence
+        (``InferenceServer`` passes ``spec_tokens + 1``); a state
+        layer's ring is sized to hold them beside the rows a launch
+        reads before its first.  Default 1: no verify launch wider than
+        a decode step.
     """
 
     def __init__(self, cfg, params, *,
@@ -214,7 +224,8 @@ class DecodeEngine:
                  programs=None,
                  mesh=None,
                  tp_rules=None,
-                 tp_axis: str = "model"):
+                 tp_axis: str = "model",
+                 verify_rows: int = 1):
         self.cfg = cfg
         self.row = row = cfg.cache_row()
         self.tracer = tracer if tracer is not None else NULL_TRACER
@@ -296,22 +307,34 @@ class DecodeEngine:
             num_blocks = self.max_batch_size * self.blocks_per_seq + 1
         # which tokens each layer keeps: the layers that keep every one
         # share the leaf the block tables address, a window layer's
-        # rows live in its slot's ring (kv_cache, "kv_window")
-        windows = layer_windows(cfg)
-        kept = [i for i, w in enumerate(windows) if w is None]
+        # rows live in its slot's ring (kv_cache, "kv_window"), and a
+        # layer that keeps a state keeps it in a ring of its own
+        # (kv_cache, "conv_state")
+        windows, states = layer_windows(cfg), layer_states(cfg)
+        fixed = [i for i, st in enumerate(states) if st is not None]
+        kept = [i for i, w in enumerate(windows)
+                if w is None and states[i] is None]
         slide = [i for i, w in enumerate(windows) if w is not None]
         self.window = windows[slide[0]] if slide else None
-        if slide and (len(set(windows)) > 2 or not kept
+        if slide and (len(set(windows)) > 2 or not kept or fixed
                       or row.kind != "kv"):
             raise NotImplementedError(
                 f"window layers of one width beside layers that keep "
                 f"every token, over a 'kv' row, is what the pool lays "
                 f"out; got windows {sorted(set(windows), key=str)} over "
-                f"a {row.kind!r} row")
+                f"a {row.kind!r} row"
+                + (" beside layers that keep a state" if fixed else ""))
+        self.state = states[fixed[0]] if fixed else None
+        if fixed and (len({states[i] for i in fixed}) > 1 or not kept):
+            raise NotImplementedError(
+                f"state layers of one shape beside layers that keep "
+                f"every token is what the pool lays out; got "
+                f"{sorted({states[i] for i in fixed})}")
         self.layers = tuple(
-            ("kv", kept.index(i), None) if w is None
-            else (WINDOW_LEAF, slide.index(i), w)
-            for i, w in enumerate(windows)) if slide else None
+            (WINDOW_LEAF, slide.index(i), w) if w is not None
+            else (STATE_LEAF, fixed.index(i), None) if i in fixed
+            else ("kv", kept.index(i), None)
+            for i, w in enumerate(windows)) if slide or fixed else None
         self.cache_cfg = KVCacheConfig(
             num_layers=len(kept),
             num_heads=row.groups,
@@ -328,6 +351,13 @@ class DecodeEngine:
             self.cache_cfg, num_layers=len(slide),
             num_blocks=self.max_batch_size * self.ring_rows
             // self.block_size + 1) if slide else None
+        # the state layers' leaf: a ring a slot that holds what a launch
+        # reads before its first row and a verify launch's fed rows
+        self.state_rows = (state_rows(self.state[0], int(verify_rows),
+                                      self.block_size) if fixed else 0)
+        self._state_shape = ((len(fixed), self.max_batch_size,
+                              self.state_rows, self.state[1])
+                             if fixed else None)
         # which way each serving program attends, decided once from
         # what can be seen here: an unquantized pool on one TPU device
         # whose geometry the kernel tiles is read in place through the
@@ -435,20 +465,20 @@ class DecodeEngine:
                               scale_sharding=self._scale_shard)
         if self.window_cfg is not None:
             cache[WINDOW_LEAF] = init_kv_cache(self.window_cfg)["kv"]
+        if self._state_shape is not None:
+            cache[STATE_LEAF] = jnp.zeros(self._state_shape, jnp.float32)
         for name, shape in self._counters.items():
             cache[name] = jnp.zeros(shape, jnp.int32)
         return cache
 
     def _view(self, program, cache, tables, start, slots, ring=None):
         """The model's view of the pool for one launch of ``program``
-        (``kv_cache.CacheView``).  ``ring`` (B,): whose ring each row's
-        window layers write, where the model has such layers: the
-        decode slot's, which is the row's own number in a launch of the
-        whole batch (None)."""
+        (``kv_cache.CacheView``).  ``ring`` (B,): whose rings each
+        row's window and state layers use, where the model has such
+        layers: the decode slot's, which is the row's own number in a
+        launch of the whole batch (None)."""
         if self.layers is None:
             ring = None
-        elif ring is None:
-            ring = jnp.arange(tables.shape[0], dtype=jnp.int32)
         return CacheView(
             cache, tables, start.astype(jnp.int32), slots,
             block_size=self.block_size, row=self.row,
@@ -460,7 +490,17 @@ class DecodeEngine:
         """The most rows one launch may feed a sequence (a prefill
         chunk, a verify launch): what a window layer's ring holds
         beside its window.  None where no layer is one."""
-        return self.ring_rows - self.window if self.layers else None
+        return self.ring_rows - self.window if self.window else None
+
+    @property
+    def max_verify_rows(self) -> Optional[int]:
+        """The most rows a verify launch may feed a sequence: the window
+        layers' bound, or what a state layer's ring holds beside the rows
+        a launch reads before its first (a chunk is never rolled back,
+        so it may feed any number).  None where no layer is either."""
+        if self.state is None:
+            return self.max_fed_rows
+        return self.state_rows - self.state[0]
 
     def _chunk_impl(self, params, cache, ids, start, length, table,
                     slot=None):
@@ -743,14 +783,25 @@ class DecodeEngine:
         *args, slot = self._put(*arrays, np.asarray([slot], np.int32))
         return tuple(args), {"slot": slot}
 
-    def _check_fed(self, rows: int) -> None:
+    def _check_fed(self, rows: int, verify: bool = False) -> None:
         """A window layer's ring holds its window and ``max_fed_rows``
-        more: a launch that fed more would overwrite rows it attends."""
-        if self.layers is not None and rows > self.max_fed_rows:
+        more: a launch that fed more would overwrite rows it attends.
+        A state layer's ring holds what a launch reads before its first
+        row and ``max_verify_rows`` more: a verify launch that fed more
+        would overwrite rows the next launch reads if drafts were
+        rejected."""
+        if self.window is not None and rows > self.max_fed_rows:
             raise ValueError(
                 f"a launch of {rows} rows a sequence: the window layers' "
                 f"ring of {self.ring_rows} rows holds the window of "
                 f"{self.window} and {self.max_fed_rows} fed rows")
+        if verify and self.state is not None \
+                and rows > self.max_verify_rows:
+            raise ValueError(
+                f"a verify launch of {rows} rows a sequence: the state "
+                f"layers' ring of {self.state_rows} rows holds the "
+                f"{self.state[0]} rows a launch reads before its first "
+                f"and {self.max_verify_rows} fed rows")
 
     def swap_params(self, params) -> None:
         """In-place weight swap: rebind ``self.params`` to a new
@@ -835,13 +886,15 @@ class DecodeEngine:
 
     def _no_rings(self, what: str) -> None:
         """The block movers carry the leaves the tables address; a
-        window layer's rows are in its slot's ring."""
+        window layer's rows and a state layer's state are in its slot's
+        ring."""
         if self.layers is not None:
+            kept = "window layers keep their rows" if self.window \
+                else "state layers keep their state"
             raise NotImplementedError(
                 f"{what} moves a request's blocks, and this model's "
-                f"window layers keep their rows in no block but in the "
-                f"slot's ring, which no block mover carries.  Open work: "
-                f"ROADMAP.md Reach.")
+                f"{kept} in no block but in the slot's ring, which no "
+                f"block mover carries.  Open work: ROADMAP.md Reach.")
 
     def copy_blocks_from(self, src_engine, pairs) -> None:
         """Copy physical blocks ``[(src, dst), ...]`` from ANOTHER
@@ -1027,7 +1080,7 @@ class DecodeEngine:
         with a fixed speculation depth compiles this exactly once."""
         args = self._verify_args(tokens, lengths, positions, tables)
         kw = int(np.asarray(tokens).shape[1])
-        self._check_fed(kw)
+        self._check_fed(kw, verify=True)
         mark = self._mark(self._verify_jit)
         self.cache, logits = self._verify_jit(self.params, self.cache,
                                               *args)
@@ -1054,7 +1107,7 @@ class DecodeEngine:
         args = self._verify_args(tokens, lengths, positions, tables,
                                  sampling=sampling)
         kw = int(np.asarray(tokens).shape[1])
-        self._check_fed(kw)
+        self._check_fed(kw, verify=True)
         if sampling is None:
             jit_fn, name = self._verify_sampled_jit, "verify_sampled"
         else:
@@ -1156,8 +1209,9 @@ class DecodeEngine:
             temp = (int(analysis.temp_size_in_bytes)
                     if analysis is not None else None)
         leaves = dict(pool_leaves(self.cache))
-        if self.window_cfg is not None:
-            leaves[WINDOW_LEAF] = self.cache[WINDOW_LEAF]
+        for name in (WINDOW_LEAF, STATE_LEAF):
+            if name in self.cache:
+                leaves[name] = self.cache[name]
         per_device = sum(
             int(np.prod(arr.sharding.shard_shape(arr.shape)))
             * jnp.dtype(arr.dtype).itemsize
@@ -1172,15 +1226,25 @@ class DecodeEngine:
                 "rows_a_slot": self.ring_rows,
                 "rows_usable": self.max_batch_size * self.ring_rows,
                 "bytes": self.window_cfg.bytes()}
+        state_bytes = 0
+        if self._state_shape is not None:
+            state_bytes = 4 * int(np.prod(self._state_shape))
+            by_kind["state"] = {
+                "layers": self._state_shape[0],
+                "rows_kept": self.state[0],
+                "rows_a_slot": self.state_rows,
+                "width": self.state[1],
+                "dtype": "float32",
+                "bytes": state_bytes}
         return {
-            # what each kind of layer keeps (models/family.py (d)):
+            # what each kind of layer keeps (models/family.py (d), (e)):
             # the table's blocks for the layers that keep every token,
-            # a ring a slot for the window layers
+            # a ring a slot for the window layers and for the state
             "by_kind": by_kind,
             "blocks_usable": cfg.num_blocks - 1,
             "block_size": cfg.block_size,
             "pool_tokens": cfg.usable_tokens,
-            "pool_bytes": cfg.bytes() + (
+            "pool_bytes": cfg.bytes() + state_bytes + (
                 self.window_cfg.bytes() if self.window_cfg else 0),
             "pool_bytes_per_device": per_device,
             "bytes_per_block": cfg.bytes_per_block,

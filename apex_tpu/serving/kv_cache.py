@@ -504,6 +504,8 @@ POOL_LEAVES = ("kv", "k_scale", "v_scale")
 # the window layers' leaf: indexed by the slot's ring, not by block
 # table, so no block mover carries it
 WINDOW_LEAF = "kv_window"
+# the state layers' leaf: a ring a slot, float32, no block table either
+STATE_LEAF = "conv_state"
 
 
 def ring_rows(window: int, block_size: int) -> int:
@@ -511,6 +513,13 @@ def ring_rows(window: int, block_size: int) -> int:
     up to three windows of rows one launch may feed behind it (a
     prefill chunk, a verify launch), in whole blocks."""
     return -(-4 * window // block_size) * block_size
+
+
+def state_rows(keep: int, fed: int, block_size: int) -> int:
+    """Rows of a state layer's ring: the ``keep`` rows a launch reads
+    before its first and the ``fed`` rows of a verify launch, whose
+    rejected drafts must not overwrite them, in whole blocks."""
+    return -(-(keep + fed) // block_size) * block_size
 
 
 def ring_tables(ring, ring_blocks: int):
@@ -709,8 +718,11 @@ class CacheView:
     leaf, window)`` a layer: ``("kv", l, None)`` for a layer that keeps
     every token, read through ``tables``; ``("kv_window", n, w)`` for
     a window layer, read through the ring of each sequence's slot,
-    ``ring`` (B,).  :meth:`attend` picks by the layer; the model never
-    learns which.  None: every layer is ``("kv", l, None)``."""
+    ``ring`` (B,); ``("conv_state", n, None)`` for a layer that keeps a
+    state, which the model reaches through :meth:`convolve`.
+    :meth:`attend` picks by the layer; the model never learns which.
+    None: every layer is ``("kv", l, None)``.  ``ring`` None: row ``b``
+    of the launch is slot ``b`` (a launch of the whole batch)."""
 
     cache: dict
     tables: jax.Array
@@ -727,6 +739,30 @@ class CacheView:
         """(B, S) which fed rows are tokens: the rows of idle slots and
         padding were pointed at the garbage block."""
         return self.slots >= self.block_size
+
+    @property
+    def rings(self):
+        """(B,) each row's slot, whose rings its window and state layers
+        use."""
+        if self.ring is not None:
+            return self.ring
+        return jnp.arange(self.slots.shape[0], dtype=jnp.int32)
+
+    def convolve(self, layer: int, bcx, taps):
+        """A state layer's short convolution (``ops.short_conv``):
+        ``bcx`` (B, S, 3W) the fed rows ``B | C | x``, ``taps`` (K, W).
+        The slot's ring is read for the positions before the first fed
+        row, then its last fed rows are written.  Returns ``(C * z
+        (B, S, W), view)``."""
+        from apex_tpu.ops.short_conv import short_conv
+
+        leaf, index, _ = self.layers[layer]
+        y, state = short_conv(
+            bcx, taps, self.cache[leaf], np.int32(index), self.rings,
+            self.start, jnp.sum(self.live, axis=1, dtype=jnp.int32),
+            consecutive=self.ring is None)
+        return y, dataclasses.replace(self,
+                                      cache={**self.cache, leaf: state})
 
     def count(self, name: str, index: int, amounts):
         """The view with ``amounts`` added to row ``index`` of the
@@ -811,8 +847,9 @@ class CacheView:
             tables, slots = self.tables, self.slots
         else:
             blocks = ring_rows(window, bs) // bs
-            tables = ring_tables(self.ring, blocks)
-            slots = ring_slots(self.ring, q_pos, self.live, blocks * bs, bs)
+            tables = ring_tables(self.rings, blocks)
+            slots = ring_slots(self.rings, q_pos, self.live, blocks * bs,
+                               bs)
         if self.table:
             ctx, pool = _attend_in_place(
                 self.cache[leaf], np.int32(index), q, kv, tables,

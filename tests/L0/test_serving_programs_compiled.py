@@ -64,8 +64,10 @@ def as_on_tpu(monkeypatch):
     import apex_tpu.ops.decode_attention  # noqa: F401
     monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
     import apex_tpu.ops.grouped_matmul  # noqa: F401
+    import apex_tpu.ops.short_conv  # noqa: F401
     for mod in ("apex_tpu.ops.decode_attention",
                 "apex_tpu.ops.grouped_matmul",
+                "apex_tpu.ops.short_conv",
                 "apex_tpu.normalization.fused_layer_norm",
                 "apex_tpu.serving.engine"):
         monkeypatch.setattr(sys.modules[mod], "on_tpu", lambda: True)
@@ -312,6 +314,59 @@ def test_kexaone_programs_at_the_cells_size(one_chip, as_on_tpu, monkeypatch,
     assert {k: v for k, v in launched.items() if "moe" not in k} == names
     assert launched["_moe_gmm_kernel"] == 7 * 3
     for leaf in ("kv", "kv_window"):
+        assert not pool_sized_strays(text, int(np.prod(e.cache[leaf].shape)))
+    m = exe.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    print(program, "arguments", m.argument_size_in_bytes / GIB, "temp",
+          m.temp_size_in_bytes / GIB, "total", total / GIB)
+    assert total < 15 * GIB
+    assert m.temp_size_in_bytes < 1.5 * GIB
+
+
+# -- a model whose conv layers keep a state: a ring of a few rows a slot ----------
+
+LFM2 = dict(num_hidden_layers=9, num_dense_layers=1,
+            layer_types=("conv", "full_attention", "conv", "conv", "conv",
+                         "full_attention", "conv", "conv", "conv"))
+
+
+@pytest.mark.parametrize("program", ["decode", "verify", "chunk"])
+def test_lfm2_programs_at_the_cells_size(one_chip, as_on_tpu, monkeypatch,
+                                         program):
+    """``lfm2-24b-a2b`` as its cell runs it: 64 slots of 6,144, two
+    attention layers through the table and seven conv layers through a
+    ring of 16 rows a slot.  The attention kernel stands once for each
+    attention layer, ``_short_conv_kernel`` once for each conv layer, the
+    grouped product three times for each expert layer; nothing
+    pool-sized is copied; weights, pool, state and temporaries fit the
+    chip."""
+    cfg = models.Lfm2MoeConfig(**LFM2)
+    e = _shape_engine(cfg, 64, 6144, one_chip, monkeypatch)
+    assert set(e.attention_paths.values()) == {"table"}
+    assert e.state_rows == 16 and e.max_verify_rows == 14
+    assert e.cache["kv"].shape == (2, (64 * 384 + 1) * 16, 1024)
+    assert e.cache["conv_state"].shape == (7, 64, 16, 2048)
+    b, nb = 64, e.blocks_per_seq
+    if program == "decode":
+        exe = _compile(e._decode_jit, e, _ints(one_chip, b),
+                       _ints(one_chip, b), _ints(one_chip, b, nb))
+        attention = "_decode_kernel"
+    elif program == "verify":
+        exe = _compile(e._verify_jit, e, _ints(one_chip, b, 5),
+                       _ints(one_chip, b), _ints(one_chip, b),
+                       _ints(one_chip, b, nb))
+        attention = "_verify_kernel"
+    else:
+        exe = e._chunk_jit.lower(
+            e.params, e.cache, _ints(one_chip, 1, 256), _ints(one_chip, 1),
+            _ints(one_chip, 1), _ints(one_chip, 1, nb),
+            slot=_ints(one_chip, 1)).compile()
+        attention = "_chunk_kernel"
+    text = exe.as_text()
+    assert kernels_launched(text) == {attention: 2, "_short_conv_kernel": 7,
+                                      "_moe_gmm_kernel": 8 * 3}
+    for leaf in ("kv", "conv_state"):
         assert not pool_sized_strays(text, int(np.prod(e.cache[leaf].shape)))
     m = exe.memory_analysis()
     total = (m.argument_size_in_bytes + m.temp_size_in_bytes
