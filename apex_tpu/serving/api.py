@@ -1633,16 +1633,23 @@ class InferenceServer:
         source's guesses, capped by the request's remaining token
         budget (drafting past ``max_new_tokens`` is wasted verify
         width) and by the lookahead blocks the scheduler can grant
-        without preempting anyone."""
+        without preempting anyone.  The draft source's index of each
+        history lives on the request (``Request.draft_index``)."""
         sched = self.scheduler
         drafts: Dict[int, List[int]] = {}
+        calls = rebuilds = 0
         for req in running:
             budget = min(self.spec_tokens,
                          req.max_new_tokens - len(req.generated) - 1)
             if budget < 1:
                 continue
-            d = self.draft_source.propose(
-                req.prompt + req.generated, budget)[:budget]
+            d, index = self.draft_source.propose_indexed(
+                req.prompt, req.generated, budget, req.draft_index)
+            calls += 1
+            if index is not req.draft_index:
+                req.draft_index = index
+                rebuilds += 1
+            d = d[:budget]
             # a draft is a hint from arbitrary user code: truncate at
             # the first out-of-vocab id rather than feeding it to the
             # embedding gather
@@ -1656,6 +1663,8 @@ class InferenceServer:
             d = d[:fit - 1]
             if d:
                 drafts[req.uid] = d
+        self.spec.incr("draft_calls", calls)
+        self.spec.incr("draft_index_rebuilds", rebuilds)
         return drafts
 
     def _verify_inputs(self, running, drafts):
@@ -3114,6 +3123,12 @@ class InferenceServer:
                 "drafted_per_step": _hist_counts(self.spec_drafted_hist),
                 "accepted_per_step": _hist_counts(
                     self.spec_accepted_hist),
+                # the draft source's calls, and the per-request indexes
+                # it built from scratch (a request's first call, a
+                # history it had not indexed, a compaction)
+                "draft_calls": self.spec.count("draft_calls"),
+                "draft_index_rebuilds": self.spec.count(
+                    "draft_index_rebuilds"),
             },
             # stochastic sampling (docs/serving.md, "Stochastic
             # sampling"): per-class request traffic and the

@@ -181,11 +181,14 @@ class Request:
 
     # speculation accounting (``serving.speculation``): lifetime drafted
     # and accepted token counts for this request — the per-request view
-    # behind the server-level acceptance rate.  Drafts themselves are
-    # stateless (recomputed from history each iteration), so nothing
-    # here needs resetting across preemption.
+    # behind the server-level acceptance rate.  Drafts are a function
+    # of the history, recomputed each iteration; ``draft_index`` is the
+    # draft source's cache of that history (``NgramDraft``'s n-gram
+    # index), which the history only ever extends, so nothing here
+    # needs resetting across preemption and it dies with the request.
     spec_drafted: int = 0
     spec_accepted: int = 0
+    draft_index: Optional[object] = None
 
     # prefill state machine (owned by the scheduler): the context being
     # chunk-prefilled, whether the final chunk's logits sample a token

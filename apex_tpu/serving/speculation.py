@@ -17,7 +17,11 @@ retrieval passages, code identifiers, and the self-generated suffix of
 any repetitive completion — so the best free guess for "what follows
 the current suffix" is "what followed it last time it appeared".  No
 extra weights, no extra compiled programs, no second model to keep in
-HBM.
+HBM.  The server's loop drafts for every running request every step,
+so :class:`NgramDraft` keeps an index of each request's history on the
+request (``Request.draft_index``): a call indexes only the tokens
+appended since the last one and answers each n-gram lookup from a
+dict, where a scan of the window would cost O(window * max_ngram).
 
 :class:`DraftSource` is the pluggable interface: a small-model drafter
 (the classic Leviathan et al. setup) is a subclass whose
@@ -40,9 +44,20 @@ per-step accounting, and drafts must be pure functions of history.
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import Any, List, Optional, Sequence, Tuple
 
 __all__ = ["DraftSource", "NgramDraft"]
+
+
+def _span(prompt: Sequence[int], generated: Sequence[int], a: int,
+          b: int) -> List[int]:
+    """``(prompt + generated)[a:b]``, without joining the two."""
+    n = len(prompt)
+    if a >= n:
+        return list(generated[a - n:b - n])
+    if b <= n:
+        return list(prompt[a:b])
+    return list(prompt[a:]) + list(generated[:b - n])
 
 
 class DraftSource:
@@ -71,6 +86,18 @@ class DraftSource:
     def propose(self, tokens: Sequence[int], k: int) -> List[int]:
         raise NotImplementedError
 
+    def propose_indexed(self, prompt: Sequence[int],
+                        generated: Sequence[int], k: int,
+                        index: Any = None) -> Tuple[List[int], Any]:
+        """``propose(prompt + generated, k)`` as the server's loop asks
+        it, once per running request per step: returns the drafts and
+        an ``index`` the caller keeps with the request and hands back
+        next time.  The index may only cache the history — the drafts
+        stay a function of ``prompt + generated`` — and a new object
+        means it was built from scratch.  The default joins the
+        history, calls :meth:`propose` and keeps no index."""
+        return self.propose(prompt + generated, k), None
+
     def reset(self) -> None:
         """Drop any cross-request state (stateless by default)."""
 
@@ -86,10 +113,12 @@ class NgramDraft(DraftSource):
     because generation drifts — what followed the suffix lately beats
     what followed it long ago.
 
-    ``history_window`` bounds the scan (the last N tokens of history);
-    the proposer is O(window * max_ngram) per call, so the default
-    keeps drafting cost trivially small next to a device step even for
-    long-context requests.
+    ``history_window`` bounds the lookup (the last N tokens of
+    history; ``None`` searches all of it).  :meth:`propose_indexed`
+    keeps a :class:`_NgramIndex` of the request's history, so a call
+    costs O(new tokens * max_ngram) to index and O(k * max_ngram) to
+    draft, and the index holds at most about twice the window;
+    :meth:`propose` builds a throwaway index, O(window * max_ngram).
     """
 
     def __init__(self, max_ngram: int = 3, min_ngram: int = 1,
@@ -106,34 +135,102 @@ class NgramDraft(DraftSource):
         self.history_window = history_window
 
     def propose(self, tokens: Sequence[int], k: int) -> List[int]:
-        hist = list(tokens)
-        if self.history_window is not None \
-                and len(hist) > self.history_window:
-            hist = hist[len(hist) - self.history_window:]
+        return self.propose_indexed(tokens, (), k)[0]
+
+    def propose_indexed(self, prompt: Sequence[int],
+                        generated: Sequence[int], k: int,
+                        index: Any = None) -> Tuple[List[int], Any]:
+        end = len(prompt) + len(generated)
+        w = self.history_window
+        start = 0 if w is None else max(0, end - w)
+        # reuse the index while the history extends what it indexed and
+        # its span stays within twice the window; else index the window
+        # afresh (a request's first call, a foreign history, compaction)
+        if (isinstance(index, _NgramIndex) and index.owner is self
+                and index.extended_by(prompt, generated, end)
+                and (w is None or end - index.base <= 2 * w)):
+            index.extend(_span(prompt, generated, index.end, end))
+        else:
+            index = _NgramIndex(self, start,
+                                _span(prompt, generated, start, end))
+        return index.draft(start, k), index
+
+
+class _NgramIndex:
+    """What :class:`NgramDraft` keeps of one request's history: the
+    tokens from ``base`` on, and for each n a dict from n-gram to the
+    latest start ``i`` whose n-gram has a follower in the history
+    (``i + n < end``).  The most recent occurrence in a window that
+    begins at ``start`` is the dict's entry if it is ``>= start``, and
+    there is none otherwise."""
+
+    __slots__ = ("owner", "base", "toks", "maps")
+
+    def __init__(self, owner: NgramDraft, base: int, toks: List[int]):
+        self.owner, self.base, self.toks = owner, base, []
+        self.maps: List[Optional[dict]] = [
+            {} if n >= owner.min_ngram else None
+            for n in range(owner.max_ngram + 1)]
+        self.extend(toks)
+
+    @property
+    def end(self) -> int:
+        return self.base + len(self.toks)
+
+    def extended_by(self, prompt, generated, end: int) -> bool:
+        """Whether the history is at least as long as what was indexed
+        and ends it with the same last tokens (the newest n-gram and its
+        follower)."""
+        toks, e = self.toks, self.end
+        m = min(len(toks), self.owner.max_ngram + 1)
+        return e <= end and (_span(prompt, generated, e - m, e)
+                             == toks[len(toks) - m:])
+
+    def extend(self, new: List[int]) -> None:
+        toks, base = self.toks, self.base
+        old = len(toks)
+        toks.extend(new)
+        for n in range(self.owner.min_ngram, self.owner.max_ngram + 1):
+            # the starts whose follower is one of the new tokens, in
+            # order, so that a later start overwrites an earlier one
+            a, b = max(0, old - n), len(toks) - n
+            if b > a:
+                grams = (toks[a:b] if n == 1 else
+                         zip(*[toks[a + j:b + j] for j in range(n)]))
+                self.maps[n].update(zip(grams, range(base + a, base + b)))
+
+    def draft(self, start: int, k: int) -> List[int]:
+        """Up to ``k`` drafts from the window ``[start, end)``: for n
+        from ``max_ngram`` down, the token that followed the most recent
+        earlier occurrence of the last n tokens.  Each guess joins the
+        working history — re-matching the EXTENDED suffix extrapolates a
+        periodic tail (the common repetitive-completion shape) to a full
+        k-token draft instead of stopping at the history's edge — but not
+        the index: the at most j starts whose n-gram or follower is one
+        of the j guesses so far are the most recent, and are scanned
+        first."""
+        lo, hi = self.owner.min_ngram, self.owner.max_ngram
+        toks, base, maps, end = self.toks, self.base, self.maps, self.end
+        t = min(len(toks), hi)
+        work = toks[len(toks) - t:]        # the last tokens, then guesses
+        off = end - t                      # the position of work[0]
         out: List[int] = []
-        # extend one token at a time, appending each guess to the
-        # working history — a single match only ever pins down the next
-        # token, and re-matching the EXTENDED suffix extrapolates
-        # periodic tails (the common repetitive-completion shape) to a
-        # full k-token draft instead of stopping at the history's edge
-        for _ in range(max(0, k)):
-            nxt = self._lookup_next(hist)
+        for m in range(end, end + max(0, k)):   # m: working length
+            nxt = None
+            for n in range(min(hi, m - start - 1), lo - 1, -1):
+                suffix = work[len(work) - n:]
+                for i in range(m - n - 1, max(start, end - n) - 1, -1):
+                    if work[i - off:i - off + n] == suffix:
+                        nxt = work[i - off + n]
+                        break
+                else:
+                    i = maps[n].get(suffix[0] if n == 1 else tuple(suffix))
+                    if i is not None and i >= start:
+                        nxt = toks[i - base + n]
+                if nxt is not None:
+                    break
             if nxt is None:
                 break
-            out.append(nxt)
-            hist.append(nxt)
+            out.append(int(nxt))
+            work.append(nxt)
         return out
-
-    def _lookup_next(self, hist: List[int]) -> Optional[int]:
-        """The token that followed the most recent earlier occurrence
-        of the longest matching suffix n-gram (None = no occurrence of
-        any n-gram down to ``min_ngram``)."""
-        n_hist = len(hist)
-        for n in range(min(self.max_ngram, n_hist - 1),
-                       self.min_ngram - 1, -1):
-            suffix = tuple(hist[n_hist - n:])
-            # most recent occurrence strictly before the suffix itself
-            for i in range(n_hist - n - 1, -1, -1):
-                if tuple(hist[i:i + n]) == suffix:
-                    return int(hist[i + n])
-        return None

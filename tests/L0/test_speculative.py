@@ -403,6 +403,164 @@ def test_ngram_draft_validates_params():
         NgramDraft(history_window=1)
 
 
+# -- the indexed drafter against the backward scan -------------------------
+
+def scan_propose(tokens, k, max_ngram=3, min_ngram=1, history_window=512):
+    """The plain backward scan the index must reproduce: for n from
+    max_ngram down, the token after the most recent earlier occurrence
+    of the last n tokens in the window, each guess joining the history."""
+    hist = list(tokens)
+    if history_window is not None and len(hist) > history_window:
+        hist = hist[len(hist) - history_window:]
+    out = []
+    for _ in range(max(0, k)):
+        nxt = None
+        n_hist = len(hist)
+        for n in range(min(max_ngram, n_hist - 1), min_ngram - 1, -1):
+            suffix = tuple(hist[n_hist - n:])
+            for i in range(n_hist - n - 1, -1, -1):
+                if tuple(hist[i:i + n]) == suffix:
+                    nxt = int(hist[i + n])
+                    break
+            if nxt is not None:
+                break
+        if nxt is None:
+            break
+        out.append(nxt)
+        hist.append(nxt)
+    return out
+
+
+class ScanDraft(DraftSource):
+    """The backward scan as a user's ``propose``-only draft source."""
+
+    def propose(self, tokens, k):
+        return scan_propose(tokens, k)
+
+
+def _history(vocab, kind, length, rng):
+    """Random ids, or a short random period with one token in ten
+    replaced: the repetitive tails drafts extrapolate."""
+    period = list(rng.randint(0, vocab, size=rng.randint(2, 8)))
+    out = []
+    for i in range(length):
+        if kind == "periodic" and rng.rand() >= 0.1:
+            out.append(int(period[i % len(period)]))
+        else:
+            out.append(int(rng.randint(0, vocab)))
+    return out
+
+
+def _grow_and_check(draft, hist, prompt_len, k, rng, **scan_kw):
+    """Grow ``generated`` by 1 to k + 1 tokens a call, as verify
+    accepts them, and hold the indexed drafts to the scan at every
+    call.  Returns the last index and the number of indexes built."""
+    prompt, generated = hist[:prompt_len], []
+    index, built, pos = None, 0, prompt_len
+    while True:
+        got, new = draft.propose_indexed(prompt, generated, k, index)
+        built += new is not index
+        index = new
+        want = scan_propose(prompt + generated, k, **scan_kw)
+        assert got == want, (len(prompt) + len(generated), got, want)
+        if pos >= len(hist):
+            return index, built
+        step = int(rng.randint(1, k + 2))
+        generated.extend(hist[pos:pos + step])
+        pos += step
+
+
+@pytest.mark.parametrize("k", [0, 1, 4])
+@pytest.mark.parametrize("max_ngram,min_ngram", [(3, 1), (2, 1), (3, 2)])
+@pytest.mark.parametrize("history_window", [None, 2, 4, 512])
+@pytest.mark.parametrize("kind", ["periodic", "random"])
+@pytest.mark.parametrize("vocab", [3, 50, 65536])
+def test_ngram_index_matches_backward_scan(vocab, kind, history_window,
+                                           max_ngram, min_ngram, k):
+    """The per-request index drafts exactly what the backward scan
+    drafts, call after call, across window compactions (a window of
+    512 is compacted past 1,024 tokens)."""
+    rng = np.random.RandomState(vocab * 7 + len(kind) + (k << 4))
+    length = 1100 if history_window == 512 else 300
+    hist = _history(vocab, kind, length, rng)
+    draft = NgramDraft(max_ngram, min_ngram, history_window)
+    _grow_and_check(draft, hist, int(rng.randint(1, 40)), k, rng,
+                    max_ngram=max_ngram, min_ngram=min_ngram,
+                    history_window=history_window)
+
+
+def test_ngram_index_rebuilds_on_a_history_it_did_not_index():
+    """A history that does not extend the indexed one — another
+    request's, or one cut short — is indexed afresh, and the drafts
+    follow the history handed in, not the index."""
+    d = NgramDraft()
+    a = [1, 2, 3, 1, 2, 3, 1, 2]
+    got, index = d.propose_indexed(a[:4], a[4:], 4)
+    assert got == scan_propose(a, 4) == [3, 1, 2, 3]
+    b = [7, 8, 9, 7, 8, 9, 7, 8, 9, 7]
+    got, again = d.propose_indexed(b[:4], b[4:], 4, index)
+    assert again is not index
+    assert got == scan_propose(b, 4) == [8, 9, 7, 8]
+    got, cut = d.propose_indexed(b[:4], b[4:6], 4, again)
+    assert cut is not again
+    assert got == scan_propose(b[:6], 4)
+    # an extension keeps the index; another drafter's index is not used
+    got, same = d.propose_indexed(b[:4], b[4:8], 4, cut)
+    assert same is cut and got == scan_propose(b[:8], 4)
+    _, other = NgramDraft(max_ngram=2).propose_indexed(b[:4], b[4:8], 4,
+                                                       same)
+    assert other is not same
+
+
+def test_ngram_index_stays_within_twice_the_window():
+    """Grown call by call to 10,000 tokens, the index keeps at most
+    twice the window, drafts as the scan does all the way, and is
+    rebuilt about once a window."""
+    rng = np.random.RandomState(3)
+    hist = _history(50, "periodic", 10000, rng)
+    window = 512
+    index, built = _grow_and_check(NgramDraft(history_window=window),
+                                   hist, 100, 4, rng)
+    assert len(index.toks) <= 2 * window
+    assert max(len(m) for m in index.maps if m is not None) <= 2 * window
+    assert built <= len(hist) // window + 1, built
+
+
+@pytest.mark.parametrize("stochastic", [False, True])
+def test_server_drafts_match_the_scan(tiny, stochastic):
+    """The server with its default indexed drafter and with the scan
+    as a user's ``propose``-only source: the same tokens out, and the
+    same drafts, verify launches and decode launches on the way."""
+    cfg, params, _ = tiny
+    rng = np.random.RandomState(11)
+    prompts = [list(rng.randint(0, VOCAB, size=n)) for n in (9, 30, 4)]
+    prompts.append([5, 6, 7] * 6)
+
+    def serve(source):
+        srv = _server(cfg, params, max_batch_size=2, draft_source=source)
+        reqs = [srv.submit(p, 40, sampling=(
+            SamplingParams(temperature=0.8, top_p=0.95, seed=i + 1)
+            if stochastic else SamplingParams()))
+            for i, p in enumerate(prompts)]
+        while srv.scheduler.has_work:
+            srv.step()
+        return [list(r.generated) for r in reqs], \
+            srv.stats()["speculation"]
+
+    got, sp = serve(NgramDraft())
+    want, sp_scan = serve(ScanDraft())
+    _assert_parity(got, want, "indexed-vs-scan")
+    for key in ("drafted_tokens", "accepted_tokens", "verify_steps",
+                "decode_steps", "draft_calls"):
+        assert sp[key] == sp_scan[key], (key, sp[key], sp_scan[key])
+    assert sp["drafted_tokens"] > 0
+    # sampled at 0.8, the tiny model's draws seldom repeat a draft
+    assert stochastic or sp["accepted_tokens"] > 0
+    # one index a request, none for a source that keeps none
+    assert sp["draft_index_rebuilds"] == len(prompts)
+    assert sp_scan["draft_index_rebuilds"] == 0
+
+
 # -- stats surface (satellite: pinned keys) --------------------------------
 
 def test_speculation_stats_keys_are_pinned(tiny):
@@ -416,7 +574,9 @@ def test_speculation_stats_keys_are_pinned(tiny):
         "enabled", "spec_tokens", "drafted_tokens", "accepted_tokens",
         "acceptance_rate", "verify_steps", "decode_steps",
         "decode_tokens", "tokens_per_engine_step", "verify_compiles",
-        "drafted_per_step", "accepted_per_step",
+        "drafted_per_step", "accepted_per_step", "draft_calls",
+        "draft_index_rebuilds",
     }
     assert sp["accepted_tokens"] <= sp["drafted_tokens"]
     assert sp["decode_tokens"] <= 8
+    assert 1 == sp["draft_index_rebuilds"] <= sp["draft_calls"]
