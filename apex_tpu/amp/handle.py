@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from apex_tpu.amp import _amp_state
 from apex_tpu.amp.optimizer import AmpOptimizerState
 from apex_tpu.amp.scaler import LossScalerState
+from apex_tpu.observability.scopes import device_scope
 
 
 def _resolve_scaler_state(state, loss_id: int) -> LossScalerState:
@@ -52,7 +53,9 @@ def scale_loss(loss, state, loss_id: int = 0):
         yield loss
         return
     sstate = _resolve_scaler_state(state, loss_id)
-    yield jnp.asarray(loss, jnp.float32) * sstate.loss_scale
+    with device_scope("optimizer"):
+        scaled = jnp.asarray(loss, jnp.float32) * sstate.loss_scale
+    yield scaled
 
 
 def scale(loss, state, loss_id: int = 0):

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from apex_tpu.amp import _amp_state
 from apex_tpu.amp.properties import Properties
+from apex_tpu.observability.scopes import device_scope
 from apex_tpu.utils.paths import path_components
 
 Pytree = Any
@@ -173,11 +174,14 @@ class AmpModel:
         p = self._properties
         if not p.enabled or _amp_state._amp_state.casts_disabled:
             return variables
-        if p.opt_level == "O0":
-            return cast_tree(variables, jnp.float32)
-        if self._compute_cast_needed():
-            return cast_tree(variables, self.half_dtype,
-                             except_patterns=self.keep_fp32_patterns)
+        # the masters' cast, and in the backward pass their gradients'
+        # cast back, are amp's side of the optimizer
+        with device_scope("optimizer"):
+            if p.opt_level == "O0":
+                return cast_tree(variables, jnp.float32)
+            if self._compute_cast_needed():
+                return cast_tree(variables, self.half_dtype,
+                                 except_patterns=self.keep_fp32_patterns)
         return variables
 
     def cast_inputs(self, args, kwargs):

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.amp.scaler import LossScaler, LossScalerState
+from apex_tpu.observability.scopes import device_scope
 
 Pytree = Any
 
@@ -89,27 +90,29 @@ class AmpOptimizer:
         (``scaler.py:184-210``), so intermediate microbatches pass False
         and the step ends with :meth:`update_scale` on the ORed flag.
         """
-        sstate = state.loss_scalers[loss_id]
-        if stashed is None:
-            g, overflow = self.loss_scaler.unscale(
-                grads, sstate, out_dtype=jnp.float32)
-        else:
-            g, overflow = self.loss_scaler.unscale_with_stashed(
-                grads, stashed, sstate)
-        if not update_scale:
-            return g, overflow, state
-        return g, overflow, self.update_scale(state, overflow, loss_id)
+        with device_scope("optimizer"):
+            sstate = state.loss_scalers[loss_id]
+            if stashed is None:
+                g, overflow = self.loss_scaler.unscale(
+                    grads, sstate, out_dtype=jnp.float32)
+            else:
+                g, overflow = self.loss_scaler.unscale_with_stashed(
+                    grads, stashed, sstate)
+            if not update_scale:
+                return g, overflow, state
+            return g, overflow, self.update_scale(state, overflow, loss_id)
 
     def update_scale(self, state: AmpOptimizerState, overflow,
                      loss_id: int = 0) -> AmpOptimizerState:
         """One dynamic-scale update from an (accumulated) overflow flag —
         the per-step half of the grad-accumulation protocol (see
         :meth:`unscale_grads`)."""
-        new_sstate = self.loss_scaler.update(
-            state.loss_scalers[loss_id], overflow)
-        scalers = tuple(new_sstate if i == loss_id else s
-                        for i, s in enumerate(state.loss_scalers))
-        return state._replace(loss_scalers=scalers)
+        with device_scope("optimizer"):
+            new_sstate = self.loss_scaler.update(
+                state.loss_scalers[loss_id], overflow)
+            scalers = tuple(new_sstate if i == loss_id else s
+                            for i, s in enumerate(state.loss_scalers))
+            return state._replace(loss_scalers=scalers)
 
     def apply_gradients(self, params: Pytree, grads: Pytree,
                         state: AmpOptimizerState, overflow) -> Tuple[Pytree, AmpOptimizerState]:
@@ -121,22 +124,25 @@ class AmpOptimizer:
         (~0.9 GB/step at ResNet-50 scale, measured on v5e,
         BENCH_NOTES.md), and the update-diff protocol costs another
         subtract + apply round-trip on top."""
-        keep = ~jnp.asarray(overflow)
-        if getattr(self.inner, "supports_fused_skip", False):
-            params_out, inner_out = self.inner.step(
-                params, grads, state.inner, skip=overflow)
-        else:
-            import optax
-            updates, new_inner = self.inner.update(grads, state.inner,
-                                                   params)
-            new_params = optax.apply_updates(params, updates)
-            params_out = _tree_select(keep, new_params, params)
-            inner_out = _tree_select(keep, new_inner, state.inner)
-        return params_out, state._replace(
-            inner=inner_out,
-            applied_steps=state.applied_steps + keep.astype(jnp.int32),
-            skipped_steps=state.skipped_steps + (~keep).astype(jnp.int32),
-        )
+        with device_scope("optimizer"):
+            keep = ~jnp.asarray(overflow)
+            if getattr(self.inner, "supports_fused_skip", False):
+                params_out, inner_out = self.inner.step(
+                    params, grads, state.inner, skip=overflow)
+            else:
+                import optax
+                updates, new_inner = self.inner.update(grads, state.inner,
+                                                       params)
+                new_params = optax.apply_updates(params, updates)
+                params_out = _tree_select(keep, new_params, params)
+                inner_out = _tree_select(keep, new_inner, state.inner)
+            return params_out, state._replace(
+                inner=inner_out,
+                applied_steps=state.applied_steps
+                + keep.astype(jnp.int32),
+                skipped_steps=state.skipped_steps
+                + (~keep).astype(jnp.int32),
+            )
 
     # -- fused one-call step ---------------------------------------------
     def step(self, params: Pytree, grads: Pytree, state: AmpOptimizerState,

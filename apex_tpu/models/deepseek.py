@@ -75,6 +75,7 @@ from apex_tpu.models.routed_experts import (  # noqa: F401  (re-export)
     rotary_angles,
     route,
 )
+from apex_tpu.observability.scopes import device_scope
 
 NEG_INF = -1e9
 
@@ -187,27 +188,24 @@ class DeepseekV3Attention(nn.Module):
 
         if cache_view is not None:
             # absorbed: attend in the latent space, expand the context
-            with jax.named_scope("latent_attention"):
-                q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope,
-                                   wkvb[..., :dn])
-                ctx, cache_view = cache_view.attend(
-                    layer, jnp.concatenate([q_lat, q_pe], -1),
-                    jnp.concatenate([c, k_pe], -1),  # (B, S, rank + dr)
-                    scale=scale)
-                o = jnp.einsum("bsnr,rnd->bsnd", ctx, wkvb[..., dn:])
+            q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, wkvb[..., :dn])
+            ctx, cache_view = cache_view.attend(
+                layer, jnp.concatenate([q_lat, q_pe], -1),
+                jnp.concatenate([c, k_pe], -1),  # (B, S, rank + dr)
+                scale=scale)
+            o = jnp.einsum("bsnr,rnd->bsnd", ctx, wkvb[..., dn:])
         else:
             # expanded: the published form, for the full forward pass
-            with jax.named_scope("latent_attention"):
-                kv = jnp.einsum("bsr,rnd->bsnd", c, wkvb)
-                s = (jnp.einsum("bqnd,bknd->bnqk", q_nope, kv[..., :dn])
-                     + jnp.einsum("bqnd,bkd->bnqk", q_pe, k_pe)
-                     ).astype(jnp.float32) * scale
-                t = x.shape[1]
-                causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-                s = jnp.where(causal[None, None], s, NEG_INF)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bnqk,bknd->bqnd", p.astype(x.dtype),
-                               kv[..., dn:])
+            kv = jnp.einsum("bsr,rnd->bsnd", c, wkvb)
+            s = (jnp.einsum("bqnd,bknd->bnqk", q_nope, kv[..., :dn])
+                 + jnp.einsum("bqnd,bkd->bnqk", q_pe, k_pe)
+                 ).astype(jnp.float32) * scale
+            t = x.shape[1]
+            causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+            s = jnp.where(causal[None, None], s, NEG_INF)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bnqk,bknd->bqnd", p.astype(x.dtype),
+                           kv[..., dn:])
         return jnp.einsum("bsnd,ndh->bsh", o, wo), cache_view
 
 
@@ -230,20 +228,23 @@ class DeepseekV3Block(nn.Module):
     def __call__(self, x, positions, cache_view=None):
         cfg = self.cfg
         live = cache_view.live if cache_view is not None else None
-        a, kept = DeepseekV3Attention(cfg, name="attention")(
-            RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(x),
-            positions, cache_view, self.layer)
-        x = x + a
+        with device_scope("attention"):
+            a, kept = DeepseekV3Attention(cfg, name="attention")(
+                RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(x),
+                positions, cache_view, self.layer)
+            x = x + a
         h = RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(x)
         if self.layer < cfg.first_k_dense_replace:
-            return x + GatedMLP(cfg.intermediate_size,
-                                cfg.initializer_range,
-                                name="mlp")(h), kept
+            h = GatedMLP(cfg.intermediate_size, cfg.initializer_range,
+                         name="mlp")(h)
+            with device_scope("mlp"):
+                return x + h, kept
         y, sizes = DeepseekV3MoE(cfg, name="moe")(h, live)
         if cache_view is not None and "routed" in kept.cache:
             kept = kept.count(
                 "routed", self.layer - cfg.first_k_dense_replace, sizes)
-        return x + y, kept
+        with device_scope("moe_experts"):
+            return x + y, kept
 
 
 class DeepseekV3LMHeadModel(nn.Module):
@@ -268,20 +269,22 @@ class DeepseekV3LMHeadModel(nn.Module):
         init = _init(cfg)
         embed = self.param("embed_tokens", init,
                            (cfg.vocab_size, cfg.hidden_size))
-        x = jnp.take(embed, input_ids, axis=0)
-        if positions is None:
-            positions = jnp.broadcast_to(
-                jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None],
-                input_ids.shape)
+        with device_scope("embed"):
+            x = jnp.take(embed, input_ids, axis=0)
+            if positions is None:
+                positions = jnp.broadcast_to(
+                    jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None],
+                    input_ids.shape)
         view = cache_views
         for i in range(cfg.num_hidden_layers):
             x, view = DeepseekV3Block(cfg, i, name=f"block_{i}")(
                 x, positions, view)
-        x = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
+        x = RMSNorm(cfg.rms_norm_eps, block="head", name="norm")(x)
         head = self.param("lm_head", init,
                           (cfg.hidden_size, cfg.vocab_size))
-        logits = jnp.einsum("bsh,hv->bsv", x, head,
-                            preferred_element_type=jnp.float32)
+        with device_scope("head"):
+            logits = jnp.einsum("bsh,hv->bsv", x, head,
+                                preferred_element_type=jnp.float32)
         if return_kv:
             return logits, view
         return logits

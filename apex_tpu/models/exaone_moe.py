@@ -59,6 +59,7 @@ from apex_tpu.models.routed_experts import (
     check_held,
     rotary_angles,
 )
+from apex_tpu.observability.scopes import device_scope
 
 NEG_INF = -1e9
 _KINDS = {"L": "sliding_attention", "G": "full_attention"}
@@ -192,23 +193,21 @@ class ExaoneMoeAttention(nn.Module):
             q = apply_rotary(q, cos[:, :, None], sin[:, :, None], False)
             k = apply_rotary(k, cos[:, :, None], sin[:, :, None], False)
 
-        with jax.named_scope("full_attention" if window is None
-                             else "window_attention"):
-            if cache_view is not None:
-                o, cache_view = cache_view.attend(layer, q, (k, v))
-            else:
-                # the full forward pass over whole rows of tokens
-                b, t = x.shape[:2]
-                s = jnp.einsum(
-                    "bqgpd,bkgd->bgpqk", q.reshape(b, t, nkv, nh // nkv, d),
-                    k).astype(jnp.float32) * float(d) ** -0.5
-                ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
-                seen = ahead >= 0
-                if window is not None:
-                    seen = seen & (ahead < window)
-                p = jax.nn.softmax(jnp.where(seen, s, NEG_INF), axis=-1)
-                o = jnp.einsum("bgpqk,bkgd->bqgpd", p.astype(x.dtype),
-                               v).reshape(b, t, nh, d)
+        if cache_view is not None:
+            o, cache_view = cache_view.attend(layer, q, (k, v))
+        else:
+            # the full forward pass over whole rows of tokens
+            b, t = x.shape[:2]
+            s = jnp.einsum(
+                "bqgpd,bkgd->bgpqk", q.reshape(b, t, nkv, nh // nkv, d),
+                k).astype(jnp.float32) * float(d) ** -0.5
+            ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+            seen = ahead >= 0
+            if window is not None:
+                seen = seen & (ahead < window)
+            p = jax.nn.softmax(jnp.where(seen, s, NEG_INF), axis=-1)
+            o = jnp.einsum("bgpqk,bkgd->bqgpd", p.astype(x.dtype),
+                           v).reshape(b, t, nh, d)
         return jnp.einsum("bsnd,ndh->bsh", o, wo), cache_view
 
 
@@ -224,19 +223,23 @@ class ExaoneMoeBlock(nn.Module):
     def __call__(self, x, positions, cache_view=None):
         cfg = self.cfg
         live = cache_view.live if cache_view is not None else None
-        a, kept = ExaoneMoeAttention(cfg, name="attention")(
-            RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(x),
-            positions, cache_view, self.layer)
-        x = x + a
+        with device_scope("attention"):
+            a, kept = ExaoneMoeAttention(cfg, name="attention")(
+                RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(x),
+                positions, cache_view, self.layer)
+            x = x + a
         h = RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(x)
         if self.layer < cfg.first_k_dense_replace:
-            return x + GatedMLP(cfg.intermediate_size,
-                                cfg.initializer_range, name="mlp")(h), kept
+            h = GatedMLP(cfg.intermediate_size, cfg.initializer_range,
+                         name="mlp")(h)
+            with device_scope("mlp"):
+                return x + h, kept
         y, sizes = RoutedExperts(cfg.experts_spec(), name="moe")(h, live)
         if cache_view is not None and "routed" in kept.cache:
             kept = kept.count(
                 "routed", self.layer - cfg.first_k_dense_replace, sizes)
-        return x + y, kept
+        with device_scope("moe_experts"):
+            return x + y, kept
 
 
 class ExaoneMoeLMHeadModel(nn.Module):
@@ -257,20 +260,22 @@ class ExaoneMoeLMHeadModel(nn.Module):
         init = _init(cfg)
         embed = self.param("embed_tokens", init,
                            (cfg.vocab_size, cfg.hidden_size))
-        x = jnp.take(embed, input_ids, axis=0)
-        if positions is None:
-            positions = jnp.broadcast_to(
-                jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None],
-                input_ids.shape)
+        with device_scope("embed"):
+            x = jnp.take(embed, input_ids, axis=0)
+            if positions is None:
+                positions = jnp.broadcast_to(
+                    jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None],
+                    input_ids.shape)
         view = cache_views
         for i in range(cfg.num_hidden_layers):
             x, view = ExaoneMoeBlock(cfg, i, name=f"block_{i}")(
                 x, positions, view)
-        x = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
+        x = RMSNorm(cfg.rms_norm_eps, block="head", name="norm")(x)
         head = self.param("lm_head", init,
                           (cfg.hidden_size, cfg.vocab_size))
-        logits = jnp.einsum("bsh,hv->bsv", x, head,
-                            preferred_element_type=jnp.float32)
+        with device_scope("head"):
+            logits = jnp.einsum("bsh,hv->bsv", x, head,
+                                preferred_element_type=jnp.float32)
         if return_kv:
             return logits, view
         return logits

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from apex_tpu.models.family import CacheRow
 from apex_tpu.models.pipelined_common import PipelinedCommon
 from apex_tpu.normalization import FusedLayerNorm
+from apex_tpu.observability.scopes import device_scope
 
 NEG_INF = -1e9
 
@@ -217,26 +218,31 @@ class GPTBlock(nn.Module):
         init = _init(cfg)
         drop = nn.Dropout(cfg.hidden_dropout_prob,
                           deterministic=deterministic)
-        h = FusedLayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
-                           name="attn_ln")(x)
-        h = GPTSelfAttention(cfg, self.attention_fn, self.kv_quant,
-                             name="attention")(h, attn_bias,
-                                               deterministic,
-                                               cache_view=cache_view,
-                                               layer=layer)
+        with device_scope("norm"):
+            h = FusedLayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
+                               name="attn_ln")(x)
+        with device_scope("attention"):
+            h = GPTSelfAttention(cfg, self.attention_fn, self.kv_quant,
+                                 name="attention")(h, attn_bias,
+                                                   deterministic,
+                                                   cache_view=cache_view,
+                                                   layer=layer)
+            if cache_view is not None:
+                h, cache_view = h
+            x = x + drop(h)
+        with device_scope("norm"):
+            h = FusedLayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
+                               name="mlp_ln")(x)
+        with device_scope("mlp"):
+            h = nn.Dense(cfg.intermediate_size, kernel_init=init,
+                         name="mlp_in")(h)
+            h = nn.gelu(h, approximate=True)
+            h = nn.Dense(cfg.hidden_size, kernel_init=init,
+                         name="mlp_out")(h)
+            x = x + drop(h)
         if cache_view is not None:
-            h, cache_view = h
-        x = x + drop(h)
-        h = FusedLayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
-                           name="mlp_ln")(x)
-        h = nn.Dense(cfg.intermediate_size, kernel_init=init,
-                     name="mlp_in")(h)
-        h = nn.gelu(h, approximate=True)
-        h = nn.Dense(cfg.hidden_size, kernel_init=init,
-                     name="mlp_out")(h)
-        if cache_view is not None:
-            return x + drop(h), cache_view
-        return x + drop(h)
+            return x, cache_view
+        return x
 
 
 class GPTLMHeadModel(nn.Module):
@@ -281,11 +287,13 @@ class GPTLMHeadModel(nn.Module):
                  positions=None, cache_views=None,
                  return_kv: bool = False):
         cfg = self.cfg
-        x, wte = _embed_block(cfg, input_ids, deterministic, positions)
+        with device_scope("embed"):
+            x, wte = _embed_block(cfg, input_ids, deterministic, positions)
         bias = None
         if attention_mask is not None:
-            bias = jnp.where(attention_mask[:, None, None, :] > 0,
-                             0.0, NEG_INF).astype(jnp.float32)
+            with device_scope("attention"):
+                bias = jnp.where(attention_mask[:, None, None, :] > 0,
+                                 0.0, NEG_INF).astype(jnp.float32)
         block = GPTBlock
         view = cache_views
         if cfg.remat and view is None:
@@ -302,19 +310,20 @@ class GPTLMHeadModel(nn.Module):
             else:
                 x = block(cfg, self.attention_fn, name=f"block_{i}")(
                     x, bias, deterministic)
-        x = FusedLayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
-                           name="final_ln")(x)
-        if return_hidden:
-            # for ops.vocab_parallel_lm_loss: under TP the (B, S, V)
-            # logits should never be materialized — hand back the
-            # pre-head hidden instead and let the vocab-parallel loss
-            # consume it with the sharded wte
-            return x
-        # weight-tied head: logits = x @ wte^T
-        logits = wte.attend(x)
+        with device_scope("head"):
+            x = FusedLayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
+                               name="final_ln")(x)
+            if return_hidden:
+                # for ops.vocab_parallel_lm_loss: under TP the (B, S, V)
+                # logits should never be materialized — hand back the
+                # pre-head hidden instead and let the vocab-parallel
+                # loss consume it with the sharded wte
+                return x
+            # weight-tied head: logits = x @ wte^T
+            logits = wte.attend(x).astype(jnp.float32)
         if return_kv:
-            return logits.astype(jnp.float32), view
-        return logits.astype(jnp.float32)
+            return logits, view
+        return logits
 
 
 def _lm_masked_sum(logits, input_ids, attention_mask):
@@ -325,9 +334,11 @@ def _lm_masked_sum(logits, input_ids, attention_mask):
     mean, independent of padding skew (see PipelinedGPT)."""
     import optax
 
-    per_tok = optax.softmax_cross_entropy_with_integer_labels(
-        logits[:, :-1], input_ids[:, 1:])
-    return (per_tok * attention_mask[:, 1:].astype(per_tok.dtype)).sum()
+    with device_scope("head"):
+        per_tok = optax.softmax_cross_entropy_with_integer_labels(
+            logits[:, :-1], input_ids[:, 1:])
+        return (per_tok * attention_mask[:, 1:].astype(
+            per_tok.dtype)).sum()
 
 
 def lm_loss(logits, input_ids, attention_mask=None):
@@ -337,14 +348,16 @@ def lm_loss(logits, input_ids, attention_mask=None):
     positions."""
     import optax
 
-    if attention_mask is None:
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :-1], input_ids[:, 1:]).mean()
-    # one definition of the shift-and-mask numerator (shared with the
-    # 1F1B per-microbatch contribution) so the conventions cannot drift
-    keep = attention_mask[:, 1:].sum().astype(logits.dtype)
-    return (_lm_masked_sum(logits, input_ids, attention_mask)
-            / jnp.maximum(keep, 1.0))
+    with device_scope("head"):
+        if attention_mask is None:
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits[:, :-1], input_ids[:, 1:]).mean()
+        # one definition of the shift-and-mask numerator (shared with
+        # the 1F1B per-microbatch contribution) so the conventions
+        # cannot drift
+        keep = attention_mask[:, 1:].sum().astype(logits.dtype)
+        return (_lm_masked_sum(logits, input_ids, attention_mask)
+                / jnp.maximum(keep, 1.0))
 
 
 class GPTStage(nn.Module):
@@ -370,7 +383,8 @@ class GPTEmbed(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, deterministic: bool = True):
-        x, _ = _embed_block(self.cfg, input_ids, deterministic)
+        with device_scope("embed"):
+            x, _ = _embed_block(self.cfg, input_ids, deterministic)
         return x
 
 

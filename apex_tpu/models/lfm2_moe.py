@@ -55,6 +55,7 @@ from apex_tpu.models.routed_experts import (
     apply_rotary,
     rotary_angles,
 )
+from apex_tpu.observability.scopes import device_scope
 
 NEG_INF = -1e9
 _KINDS = ("conv", "full_attention")
@@ -201,11 +202,10 @@ class Lfm2ShortConv(nn.Module):
         taps = self.param("conv", init, (cfg.conv_L_cache, h))
         w_out = self.param("out_proj", init, (h, h))
         bcx = x @ w_in
-        with jax.named_scope("short_conv"):
-            if cache_view is not None:
-                y, cache_view = cache_view.convolve(layer, bcx, taps)
-            else:
-                y = causal_short_conv(bcx, taps)
+        if cache_view is not None:
+            y, cache_view = cache_view.convolve(layer, bcx, taps)
+        else:
+            y = causal_short_conv(bcx, taps)
         return y @ w_out, cache_view
 
 
@@ -235,19 +235,18 @@ class Lfm2Attention(nn.Module):
         q = apply_rotary(q, cos[:, :, None], sin[:, :, None], False)
         k = apply_rotary(k, cos[:, :, None], sin[:, :, None], False)
 
-        with jax.named_scope("attention"):
-            if cache_view is not None:
-                o, cache_view = cache_view.attend(layer, q, (k, v))
-            else:
-                # the full forward pass over whole rows of tokens
-                b, t = x.shape[:2]
-                s = jnp.einsum(
-                    "bqgpd,bkgd->bgpqk", q.reshape(b, t, nkv, nh // nkv, d),
-                    k).astype(jnp.float32) * float(d) ** -0.5
-                seen = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-                p = jax.nn.softmax(jnp.where(seen, s, NEG_INF), axis=-1)
-                o = jnp.einsum("bgpqk,bkgd->bqgpd", p.astype(x.dtype),
-                               v).reshape(b, t, nh, d)
+        if cache_view is not None:
+            o, cache_view = cache_view.attend(layer, q, (k, v))
+        else:
+            # the full forward pass over whole rows of tokens
+            b, t = x.shape[:2]
+            s = jnp.einsum(
+                "bqgpd,bkgd->bgpqk", q.reshape(b, t, nkv, nh // nkv, d),
+                k).astype(jnp.float32) * float(d) ** -0.5
+            seen = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+            p = jax.nn.softmax(jnp.where(seen, s, NEG_INF), axis=-1)
+            o = jnp.einsum("bgpqk,bkgd->bqgpd", p.astype(x.dtype),
+                           v).reshape(b, t, nh, d)
         return jnp.einsum("bsnd,ndh->bsh", o, wo), cache_view
 
 
@@ -265,22 +264,27 @@ class Lfm2MoeBlock(nn.Module):
         live = cache_view.live if cache_view is not None else None
         h = RMSNorm(cfg.norm_eps, name="operator_norm")(x)
         if cfg.layer_types[self.layer] == "conv":
-            a, kept = Lfm2ShortConv(cfg, name="conv")(h, cache_view,
-                                                      self.layer)
+            with device_scope("short_conv"):
+                a, kept = Lfm2ShortConv(cfg, name="conv")(h, cache_view,
+                                                          self.layer)
+                x = x + a
         else:
-            a, kept = Lfm2Attention(cfg, name="self_attn")(
-                h, positions, cache_view, self.layer)
-        x = x + a
+            with device_scope("attention"):
+                a, kept = Lfm2Attention(cfg, name="self_attn")(
+                    h, positions, cache_view, self.layer)
+                x = x + a
         h = RMSNorm(cfg.norm_eps, name="ffn_norm")(x)
         if self.layer < cfg.num_dense_layers:
-            return x + GatedMLP(cfg.intermediate_size,
-                                cfg.initializer_range,
-                                name="feed_forward")(h), kept
+            h = GatedMLP(cfg.intermediate_size, cfg.initializer_range,
+                         name="feed_forward")(h)
+            with device_scope("mlp"):
+                return x + h, kept
         y, sizes = RoutedExperts(cfg.experts_spec(), name="moe")(h, live)
         if cache_view is not None and "routed" in kept.cache:
             kept = kept.count("routed", self.layer - cfg.num_dense_layers,
                               sizes)
-        return x + y, kept
+        with device_scope("moe_experts"):
+            return x + y, kept
 
 
 class Lfm2MoeLMHeadModel(nn.Module):
@@ -300,11 +304,12 @@ class Lfm2MoeLMHeadModel(nn.Module):
         cfg = self.cfg
         embed = self.param("embed_tokens", _init(cfg),
                            (cfg.vocab_size, cfg.hidden_size))
-        x = jnp.take(embed, input_ids, axis=0)
-        if positions is None:
-            positions = jnp.broadcast_to(
-                jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None],
-                input_ids.shape)
+        with device_scope("embed"):
+            x = jnp.take(embed, input_ids, axis=0)
+            if positions is None:
+                positions = jnp.broadcast_to(
+                    jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None],
+                    input_ids.shape)
         view = cache_views
         for i in range(cfg.num_hidden_layers):
             # ``layer_<i>``: a weight maker that stacks the leaves of
@@ -312,9 +317,10 @@ class Lfm2MoeLMHeadModel(nn.Module):
             # 64 experts in one array, 6 GiB of random bits at once
             x, view = Lfm2MoeBlock(cfg, i, name=f"layer_{i}")(
                 x, positions, view)
-        x = RMSNorm(cfg.norm_eps, name="embedding_norm")(x)
-        logits = jnp.einsum("bsh,vh->bsv", x, embed,
-                            preferred_element_type=jnp.float32)
+        x = RMSNorm(cfg.norm_eps, block="head", name="embedding_norm")(x)
+        with device_scope("head"):
+            logits = jnp.einsum("bsh,vh->bsv", x, embed,
+                                preferred_element_type=jnp.float32)
         if return_kv:
             return logits, view
         return logits

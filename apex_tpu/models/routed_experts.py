@@ -35,18 +35,27 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from apex_tpu.observability.scopes import device_scope
 from apex_tpu.ops.grouped_matmul import grouped_matmul
 
 
 class RMSNorm(nn.Module):
+    """``block``: the device scope its operations fall under, ``norm``
+    but for the final norm, which is the head's (the scope is entered
+    inside the module, so a norm that flax names ``norm`` still reads
+    as the head)."""
+
     eps: float
+    block: str = "norm"
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        xf = x.astype(jnp.float32)
-        y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + self.eps)
-        return (y * scale.astype(jnp.float32)).astype(x.dtype)
+        with device_scope(self.block):
+            xf = x.astype(jnp.float32)
+            y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
+                               + self.eps)
+            return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def rotary_angles(positions, dim: int, theta: float):
@@ -74,10 +83,12 @@ def apply_rotary(x, cos, sin, interleave: bool):
 
 
 class GatedMLP(nn.Module):
-    """``W_down(silu(W_gate x) * (W_up x))``."""
+    """``W_down(silu(W_gate x) * (W_up x))``, under the device scope
+    ``block``: ``mlp``, or ``moe_shared`` for the shared experts."""
 
     width: int
     init_range: float
+    block: str = "mlp"
 
     @nn.compact
     def __call__(self, x):
@@ -86,7 +97,8 @@ class GatedMLP(nn.Module):
         gate = self.param("gate_proj", init, (h, self.width))
         up = self.param("up_proj", init, (h, self.width))
         down = self.param("down_proj", init, (self.width, h))
-        return (nn.silu(x @ gate) * (x @ up)) @ down
+        with device_scope(self.block):
+            return (nn.silu(x @ gate) * (x @ up)) @ down
 
 
 def route(scores, bias, k: int, scaling: float, normalise: bool,
@@ -149,9 +161,9 @@ class RoutedExperts(nn.Module):
         w_gate = self.param("experts_gate_proj", init, (held, h, f))
         w_up = self.param("experts_up_proj", init, (held, h, f))
         w_down = self.param("experts_down_proj", init, (held, f, h))
-        xt = x.reshape(b * s, h)
 
-        with jax.named_scope("moe_router"):
+        with device_scope("moe_router"):
+            xt = x.reshape(b * s, h)
             scores = jax.nn.sigmoid(jnp.dot(
                 xt.astype(jnp.float32), router.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST))
@@ -161,7 +173,7 @@ class RoutedExperts(nn.Module):
             # bound how often a precision picks another expert
             self.sow("intermediates", "chosen", chosen.reshape(b, s, k))
 
-        with jax.named_scope("moe_experts"):
+        with device_scope("moe_experts"):
             mine = (chosen >= first) & (chosen < first + held)
             if live is not None:
                 mine = mine & live.reshape(b * s, 1)
@@ -182,9 +194,10 @@ class RoutedExperts(nn.Module):
                 out * jnp.where(mine, weights, 0.0)[..., None], axis=1)
 
         if sp.shared_width:
-            with jax.named_scope("moe_shared"):
+            with device_scope("moe_shared"):
                 routed = routed + GatedMLP(
-                    sp.shared_width, sp.init_range,
+                    sp.shared_width, sp.init_range, block="moe_shared",
                     name="shared_experts")(xt).astype(jnp.float32)
-        y = routed.astype(x.dtype)
-        return y.reshape(b, s, h), sizes
+        with device_scope("moe_experts"):
+            y = routed.astype(x.dtype)
+            return y.reshape(b, s, h), sizes

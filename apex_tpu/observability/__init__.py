@@ -44,6 +44,10 @@ Two pieces, both process-wide and dependency-free:
   per-compiled-program call/wall/compile tallies behind the pinned
   ``stats()["programs"]`` table and the
   ``serving_program_*`` registry counters.
+- :mod:`observability.scopes` — :data:`DEVICE_SCOPES`, the names of
+  the blocks of the device programs, and :func:`device_scope`, the
+  ``jax.named_scope`` that admits only them: each XLA operation's
+  ``tf_op`` in a profile names its block.
 
 What is instrumented out of the box: the serving step loop (``step``
 over retire / apply / plan / chunk-prefill / draft / inputs / launch /
@@ -97,6 +101,7 @@ from apex_tpu.observability.watchdog import (
     HangWatchdog,
     NullWatchdog,
 )
+from apex_tpu.observability.scopes import DEVICE_SCOPES, device_scope
 from apex_tpu.observability.slo import SLOPolicy, SLOTargets, SLOTracker
 from apex_tpu.observability.tracing import (
     NULL_TRACER,
@@ -110,6 +115,7 @@ from apex_tpu.observability.tracing import (
 
 __all__ = [
     "Counter",
+    "DEVICE_SCOPES",
     "FlightRecorder",
     "Gauge",
     "HangWatchdog",
@@ -139,6 +145,7 @@ __all__ = [
     "SLOTracker",
     "SpanTracer",
     "TRACE_ENV",
+    "device_scope",
     "dump_journeys",
     "enable_tracing",
     "escape_label_value",

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.observability.scopes import device_scope
 from apex_tpu.ops.flatten import (FlatSpec, flatten, flatten_grouped,
                                   flatten_like, unflatten)
 from apex_tpu.ops.pallas_utils import (DEFAULT_ROWS, LANES, SUBLANES, on_tpu,
@@ -421,21 +422,23 @@ class FusedAdam:
         true, updates are zero and the state is unchanged — the
         skip-step select runs inside the fused kernel (zero extra HBM
         traffic) instead of over materialized trees."""
-        if params is None:
-            raise ValueError("FusedAdam.update requires params")
-        if self.layout == "tree":
-            p2, new_state = self._step_tree(params, grads, state, scale,
-                                            grad_norm, skip=skip)
-            updates = jax.tree_util.tree_map(
-                lambda n, p: (n - p.astype(n.dtype)).astype(p.dtype),
-                p2, params)
+        with device_scope("optimizer"):
+            if params is None:
+                raise ValueError("FusedAdam.update requires params")
+            if self.layout == "tree":
+                p2, new_state = self._step_tree(params, grads, state, scale,
+                                                grad_norm, skip=skip)
+                updates = jax.tree_util.tree_map(
+                    lambda n, p: (n - p.astype(n.dtype)).astype(p.dtype),
+                    p2, params)
+                return updates, new_state
+            new_flat, new_state, old_flat = self._step_flat(
+                params, grads, state, scale, grad_norm, skip=skip)
+            # match param leaf dtypes (masters are fp32; O3 runs half params)
+            dtypes = [p.dtype for p in jax.tree_util.tree_leaves(params)]
+            updates = unflatten(new_flat - old_flat,
+                                _with_dtypes(state.spec, dtypes))
             return updates, new_state
-        new_flat, new_state, old_flat = self._step_flat(
-            params, grads, state, scale, grad_norm, skip=skip)
-        # match param leaf dtypes (masters are fp32; O3 runs half params)
-        updates = unflatten(new_flat - old_flat, _with_dtypes(
-            state.spec, [p.dtype for p in jax.tree_util.tree_leaves(params)]))
-        return updates, new_state
 
     # -- apex-style step --------------------------------------------------
     def step(self, params: Pytree, grads: Pytree, state: FusedAdamState,
@@ -451,20 +454,21 @@ class FusedAdam:
         ``skip`` (bool scalar or None): amp's overflow->skip-step,
         selected INSIDE the fused kernel — see :func:`_adam_math`.
         """
-        if self.layout == "tree":
-            new_params, new_state = self._step_tree(
+        with device_scope("optimizer"):
+            if self.layout == "tree":
+                new_params, new_state = self._step_tree(
+                    params, grads, state, scale, grad_norm, skip=skip)
+                if output_params_dtype is not None:
+                    new_params = jax.tree_util.tree_map(
+                        lambda x: x.astype(output_params_dtype), new_params)
+                return new_params, new_state
+            new_flat, new_state, _ = self._step_flat(
                 params, grads, state, scale, grad_norm, skip=skip)
+            spec = state.spec
             if output_params_dtype is not None:
-                new_params = jax.tree_util.tree_map(
-                    lambda x: x.astype(output_params_dtype), new_params)
-            return new_params, new_state
-        new_flat, new_state, _ = self._step_flat(params, grads, state, scale,
-                                                 grad_norm, skip=skip)
-        spec = state.spec
-        if output_params_dtype is not None:
-            spec = _with_dtypes(spec,
-                                [output_params_dtype] * len(spec.dtypes))
-        return unflatten(new_flat, spec), new_state
+                spec = _with_dtypes(spec,
+                                    [output_params_dtype] * len(spec.dtypes))
+            return unflatten(new_flat, spec), new_state
 
     # -- core -------------------------------------------------------------
     def _step_group(self, p, m, v, g, hp, step, scale, grad_norm,

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from apex_tpu.observability.scopes import device_scope
 from apex_tpu.parallel.collectives import all_gather_g, pmean_g, psum_g
 from apex_tpu.parallel.mesh import ProcessGroup
 
@@ -171,44 +172,45 @@ class DistributedDataParallel:
         preserving exact apex semantics ("every rank ends with the
         world-averaged gradient") in both conventions.
         """
-        pg = self.process_group
-        if pg.axis_index_groups is not None:
-            n = len(pg.axis_index_groups[0])
-        else:
-            n = lax.psum(1, pg.axis_name)
-        n_world = lax.psum(1, pg.axis_name)
-
-        # vma tracking is only meaningful when shard_map's varying-axis
-        # checking is on; under check_rep/check_vma=False EVERY value has
-        # an empty vma set and "not in vma" would wrongly skip the psum.
-        # Probe with axis_index, which is varying by construction.
-        probe = lax.axis_index(pg.axis_name)
-        vma_tracked = pg.axis_name in jax.typeof(probe).vma
-
-        def one(g):
-            orig_dtype = g.dtype
-            if self.allreduce_always_fp32:
-                g = g.astype(jnp.float32)
-            already_summed = (vma_tracked
-                              and pg.axis_name not in jax.typeof(g).vma)
-            if already_summed:
-                # autodiff's implicit psum ran over the FULL axis, so the
-                # average divides by the world size — a sub-group mean is
-                # not recoverable from a world sum (grouped semantics need
-                # varying-typed grads, i.e. params passed through in_specs)
-                if self.gradient_average:
-                    g = g / n_world
+        with device_scope("grad_exchange"):
+            pg = self.process_group
+            if pg.axis_index_groups is not None:
+                n = len(pg.axis_index_groups[0])
             else:
-                if self.gradient_predivide_factor != 1.0:
-                    g = g / self.gradient_predivide_factor
-                g = psum_g(g, pg.axis_name, pg.axis_index_groups)
-                if self.gradient_average:
-                    g = g * (self.gradient_predivide_factor / n)
-            if self.allreduce_always_fp32:
-                g = g.astype(orig_dtype)
-            return g
+                n = lax.psum(1, pg.axis_name)
+            n_world = lax.psum(1, pg.axis_name)
 
-        return jax.tree_util.tree_map(one, grads)
+            # vma tracking is only meaningful when shard_map's varying-axis
+            # checking is on; under check_rep/check_vma=False EVERY value has
+            # an empty vma set and "not in vma" would wrongly skip the psum.
+            # Probe with axis_index, which is varying by construction.
+            probe = lax.axis_index(pg.axis_name)
+            vma_tracked = pg.axis_name in jax.typeof(probe).vma
+
+            def one(g):
+                orig_dtype = g.dtype
+                if self.allreduce_always_fp32:
+                    g = g.astype(jnp.float32)
+                already_summed = (vma_tracked
+                                  and pg.axis_name not in jax.typeof(g).vma)
+                if already_summed:
+                    # autodiff's implicit psum ran over the FULL axis, so the
+                    # average divides by the world size — a sub-group mean is
+                    # not recoverable from a world sum (grouped semantics need
+                    # varying-typed grads, i.e. params passed through in_specs)
+                    if self.gradient_average:
+                        g = g / n_world
+                else:
+                    if self.gradient_predivide_factor != 1.0:
+                        g = g / self.gradient_predivide_factor
+                    g = psum_g(g, pg.axis_name, pg.axis_index_groups)
+                    if self.gradient_average:
+                        g = g * (self.gradient_predivide_factor / n)
+                if self.allreduce_always_fp32:
+                    g = g.astype(orig_dtype)
+                return g
+
+            return jax.tree_util.tree_map(one, grads)
 
     def broadcast_params(self, params: Pytree, src: int = 0) -> Pytree:
         return broadcast_params(params, self.process_group, src=src)

@@ -94,7 +94,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.models.family import layer_states, layer_windows
-from apex_tpu.observability import NULL_PROGRAM_ACCOUNTING, NULL_TRACER
+from apex_tpu.observability import (NULL_PROGRAM_ACCOUNTING, NULL_TRACER,
+                                    device_scope)
 from apex_tpu.ops.decode_attention import paged_attention_fits
 from apex_tpu.ops.pallas_utils import LANES, on_tpu, pallas_auto_gate
 from apex_tpu.ops.sampling import finite_rows, greedy_argmax, sample_tokens
@@ -479,8 +480,10 @@ class DecodeEngine:
         launch of the whole batch (None)."""
         if self.layers is None:
             ring = None
+        with device_scope("kv_write"):
+            start = start.astype(jnp.int32)
         return CacheView(
-            cache, tables, start.astype(jnp.int32), slots,
+            cache, tables, start, slots,
             block_size=self.block_size, row=self.row,
             table=self.attention_paths[program] == "table",
             ring=ring, layers=self.layers)
@@ -517,9 +520,10 @@ class DecodeEngine:
         such layers alone is given it)."""
         logits, cache = self._fed_rows("chunk_prefill", params, cache,
                                        ids, start, length, table, slot)
-        last = jnp.take_along_axis(
-            logits, (length[:, None, None] - 1).astype(jnp.int32),
-            axis=1)[:, 0]                                  # (1, V)
+        with device_scope("head"):
+            last = jnp.take_along_axis(
+                logits, (length[:, None, None] - 1).astype(jnp.int32),
+                axis=1)[:, 0]                              # (1, V)
         return cache, last
 
     def _verify_impl(self, params, cache, ids, start, length, tables):
@@ -548,13 +552,17 @@ class DecodeEngine:
         valid.  Every layer attends through the launch's view of the
         pool and writes its K/V there (invalid columns sink into the
         garbage block).  Returns (logits (B, K, V), cache)."""
-        off = jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :]
-        pos = start[:, None].astype(jnp.int32) + off       # (B, K)
-        slots = jnp.where(off < length[:, None],
-                          slot_index(tables, pos, self.block_size), 0)
-        # padded columns can run past the embedding table; clamp (their
-        # logits are ignored and their K/V writes garbage-sunk)
-        pos_emb = jnp.minimum(pos, self.cfg.max_position_embeddings - 1)
+        with device_scope("kv_write"):
+            off = jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :]
+            pos = start[:, None].astype(jnp.int32) + off   # (B, K)
+            slots = jnp.where(off < length[:, None],
+                              slot_index(tables, pos, self.block_size), 0)
+        with device_scope("embed"):
+            # padded columns can run past the embedding table; clamp
+            # (their logits are ignored and their K/V writes
+            # garbage-sunk)
+            pos_emb = jnp.minimum(pos,
+                                  self.cfg.max_position_embeddings - 1)
         logits, view = self.model.apply(
             {"params": params}, ids, positions=pos_emb,
             deterministic=True,
@@ -593,14 +601,17 @@ class DecodeEngine:
         its position (== cached context length); tables (B,
         blocks_per_seq).  Returns (cache, logits (B, V))."""
         slots = slot_index(tables, positions, self.block_size)
+        with device_scope("embed"):
+            ids = tokens[:, None]
+            pos = positions[:, None].astype(jnp.int32)
         logits, view = self.model.apply(
-            {"params": params}, tokens[:, None],
-            positions=positions[:, None].astype(jnp.int32),
-            deterministic=True,
+            {"params": params}, ids, positions=pos, deterministic=True,
             cache_views=self._view("decode", cache, tables, positions,
                                    slots[:, None]),
             return_kv=True)
-        return view.cache, logits[:, 0]               # (B, V)
+        with device_scope("head"):
+            logits = logits[:, 0]                     # (B, V)
+        return view.cache, logits
 
     # -- fused on-device-sampling bodies ----------------------------------
     # Each composes its logits twin with greedy argmax + the finite-row
@@ -615,10 +626,11 @@ class DecodeEngine:
         cross-shard reduction (documented lowest-global-id tie rule),
         so the vocab-sharded logits are never all-gathered just to be
         argmaxed."""
-        if self.mesh is not None:
-            return vocab_parallel_sample(logits, self.mesh,
-                                         self.tp_axis)
-        return greedy_argmax(logits), finite_rows(logits)
+        with device_scope("sample"):
+            if self.mesh is not None:
+                return vocab_parallel_sample(logits, self.mesh,
+                                             self.tp_axis)
+            return greedy_argmax(logits), finite_rows(logits)
 
     def _chunk_sampled_impl(self, params, cache, ids, start, length,
                             table, slot=None):
@@ -659,19 +671,22 @@ class DecodeEngine:
             return jnp.broadcast_to(x.reshape(x.shape + (1,) * extra),
                                     b)
 
-        args = (bc(temp), bc(tk), bc(tp_), bc(seed))
-        if self.mesh is not None:
-            return vocab_parallel_sample_tokens(
-                logits, *args, counters, self.mesh, self.tp_axis)
-        return sample_tokens(logits, *args, counters)
+        with device_scope("sample"):
+            args = (bc(temp), bc(tk), bc(tp_), bc(seed))
+            if self.mesh is not None:
+                return vocab_parallel_sample_tokens(
+                    logits, *args, counters, self.mesh, self.tp_axis)
+            return sample_tokens(logits, *args, counters)
 
     def _chunk_stoch_impl(self, params, cache, ids, start, length,
                           table, temp, tk, tp_, seed, slot=None):
         cache, last = self._chunk_impl(params, cache, ids, start,
                                        length, table, slot)
         # final chunk: start + length == the full context length
-        ids_out, fin = self._sample_stoch(last, start + length, temp,
-                                          tk, tp_, seed)
+        with device_scope("sample"):
+            counters = start + length
+        ids_out, fin = self._sample_stoch(last, counters, temp, tk, tp_,
+                                          seed)
         return cache, ids_out, fin                             # (1,)
 
     def _decode_stoch_impl(self, params, cache, tokens, positions,
@@ -680,8 +695,10 @@ class DecodeEngine:
                                           positions, tables)
         # the input token sits at `positions`; the drawn token is the
         # next sequence index
-        ids_out, fin = self._sample_stoch(logits, positions + 1, temp,
-                                          tk, tp_, seed)
+        with device_scope("sample"):
+            counters = positions + 1
+        ids_out, fin = self._sample_stoch(logits, counters, temp, tk,
+                                          tp_, seed)
         return cache, ids_out, fin                             # (B,)
 
     def _verify_stoch_impl(self, params, cache, ids, start, length,
@@ -695,8 +712,9 @@ class DecodeEngine:
         # ops.sample_tokens — rejection sampling's exact accept/
         # residual probabilities, with a draft-independent stream)
         kw = ids.shape[1]
-        counters = (start[:, None].astype(jnp.int32) + 1
-                    + jnp.arange(kw, dtype=jnp.int32)[None, :])
+        with device_scope("sample"):
+            counters = (start[:, None].astype(jnp.int32) + 1
+                        + jnp.arange(kw, dtype=jnp.int32)[None, :])
         ids_out, fin = self._sample_stoch(logits, counters, temp, tk,
                                           tp_, seed)
         return cache, ids_out, fin                             # (B, K)

@@ -142,6 +142,7 @@ import numpy as np
 from jax import lax
 
 from apex_tpu.models.family import CacheRow
+from apex_tpu.observability.scopes import device_scope
 # the quantization numeric contract lives with the kernels that widen
 # it back (ops); re-exported here because the cache is what stores it
 from apex_tpu.ops.kv_quant import (  # noqa: F401  (re-export)
@@ -379,15 +380,16 @@ def slot_index(block_tables, positions, block_size: int):
     (B, max_blocks): ``table[pos // bs] * bs + pos % bs``.
     Unallocated table entries are 0, so out-of-range logical positions
     land in the garbage block."""
-    blk = positions // block_size
-    off = positions % block_size
-    squeeze = blk.ndim == block_tables.ndim - 1
-    if squeeze:
-        blk = blk[..., None]
-    phys = jnp.take_along_axis(block_tables, blk, axis=-1)
-    if squeeze:
-        phys = phys[..., 0]
-    return phys * block_size + off
+    with device_scope("kv_write"):
+        blk = positions // block_size
+        off = positions % block_size
+        squeeze = blk.ndim == block_tables.ndim - 1
+        if squeeze:
+            blk = blk[..., None]
+        phys = jnp.take_along_axis(block_tables, blk, axis=-1)
+        if squeeze:
+            phys = phys[..., 0]
+        return phys * block_size + off
 
 
 def write_layer(cache, layer, kv, slots):
@@ -406,23 +408,24 @@ def write_layer(cache, layer, kv, slots):
     ``[layer, slots]`` compiles to an update of the donated buffer with
     no temporary, where one over every layer at once (``[:, slots]``)
     makes XLA:TPU transpose the whole leaf there and back."""
-    flat = slots.reshape(-1)
-    out = dict(cache)
-    if isinstance(kv, tuple):
-        k, v = kv
-        if "k_scale" in cache:
-            (k, ks), (v, vs) = k, v
-            out["k_scale"] = cache["k_scale"].at[layer, flat].set(
-                ks.reshape(-1, ks.shape[-1]))
-            out["v_scale"] = cache["v_scale"].at[layer, flat].set(
-                vs.reshape(-1, vs.shape[-1]))
-        rows = pack_rows(k, v)
-    else:
-        rows = jnp.pad(kv, [(0, 0)] * (kv.ndim - 1) + [
-            (0, cache["kv"].shape[-1] - kv.shape[-1])])
-    out["kv"] = cache["kv"].at[layer, flat].set(
-        rows.astype(cache["kv"].dtype).reshape(-1, rows.shape[-1]))
-    return out
+    with device_scope("kv_write"):
+        flat = slots.reshape(-1)
+        out = dict(cache)
+        if isinstance(kv, tuple):
+            k, v = kv
+            if "k_scale" in cache:
+                (k, ks), (v, vs) = k, v
+                out["k_scale"] = cache["k_scale"].at[layer, flat].set(
+                    ks.reshape(-1, ks.shape[-1]))
+                out["v_scale"] = cache["v_scale"].at[layer, flat].set(
+                    vs.reshape(-1, vs.shape[-1]))
+            rows = pack_rows(k, v)
+        else:
+            rows = jnp.pad(kv, [(0, 0)] * (kv.ndim - 1) + [
+                (0, cache["kv"].shape[-1] - kv.shape[-1])])
+        out["kv"] = cache["kv"].at[layer, flat].set(
+            rows.astype(cache["kv"].dtype).reshape(-1, rows.shape[-1]))
+        return out
 
 
 def _per_layer(kvs, layer):
@@ -526,16 +529,18 @@ def ring_tables(ring, ring_blocks: int):
     """The block ids of each row's ring, (B, ring_blocks): ring ``n``
     owns the blocks ``1 + n * ring_blocks ..`` of the window leaf
     (block 0 is its garbage sink)."""
-    return (1 + ring.astype(jnp.int32)[:, None] * ring_blocks
-            + jnp.arange(ring_blocks, dtype=jnp.int32)[None, :])
+    with device_scope("kv_write"):
+        return (1 + ring.astype(jnp.int32)[:, None] * ring_blocks
+                + jnp.arange(ring_blocks, dtype=jnp.int32)[None, :])
 
 
 def ring_slots(ring, positions, live, rows: int, block_size: int):
     """Flat slots in the window leaf of ``positions`` (B, S) of rings
     ``ring`` (B,): position ``p`` at ring row ``p % rows``; rows that
     are no tokens (``live`` false) at the garbage block."""
-    base = block_size + ring.astype(jnp.int32)[:, None] * rows
-    return jnp.where(live, base + positions % rows, 0)
+    with device_scope("kv_write"):
+        base = block_size + ring.astype(jnp.int32)[:, None] * rows
+        return jnp.where(live, base + positions % rows, 0)
 
 
 def pool_leaves(cache):
@@ -607,10 +612,11 @@ def write_blocks(cache, block_ids, leaves, block_size: int):
                                           block_size, 1),
             block_ids[i] * block_size, 1)
 
-    return {**cache, **{name: lax.fori_loop(
-        0, block_ids.shape[0],
-        functools.partial(put, rows=leaves[name].astype(arr.dtype)), arr)
-        for name, arr in pool_leaves(cache).items()}}
+    with device_scope("kv_write"):
+        return {**cache, **{name: lax.fori_loop(
+            0, block_ids.shape[0],
+            functools.partial(put, rows=leaves[name].astype(arr.dtype)),
+            arr) for name, arr in pool_leaves(cache).items()}}
 
 
 def copy_blocks_across(dst_cache, src_cache, src, dst, block_size: int):
@@ -738,7 +744,8 @@ class CacheView:
     def live(self):
         """(B, S) which fed rows are tokens: the rows of idle slots and
         padding were pointed at the garbage block."""
-        return self.slots >= self.block_size
+        with device_scope("kv_write"):
+            return self.slots >= self.block_size
 
     @property
     def rings(self):
@@ -746,7 +753,8 @@ class CacheView:
         use."""
         if self.ring is not None:
             return self.ring
-        return jnp.arange(self.slots.shape[0], dtype=jnp.int32)
+        with device_scope("kv_write"):
+            return jnp.arange(self.slots.shape[0], dtype=jnp.int32)
 
     def convolve(self, layer: int, bcx, taps):
         """A state layer's short convolution (``ops.short_conv``):
@@ -770,8 +778,9 @@ class CacheView:
         (``cfg.serving_counters()``): accumulated on the device, read
         only when somebody asks (``stats()``)."""
         cache = dict(self.cache)
-        cache[name] = cache[name].at[index].add(
-            amounts.astype(cache[name].dtype))
+        with device_scope("kv_write"):
+            cache[name] = cache[name].at[index].add(
+                amounts.astype(cache[name].dtype))
         return dataclasses.replace(self, cache=cache)
 
     def attend(self, layer: int, q, kv, scale=None):
