@@ -45,6 +45,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -350,8 +351,8 @@ def cached_attention(q, k, v, *, kv_bias: Optional[jax.Array] = None,
 
 # -- attention in place: the pool read through the block table ---------------
 
-# pages one grid step streams: 8 pages of 16 slots are 128 keys, one
-# full lane row of scores and one native MXU tile of K/V per head
+# pages one window of the loop holds: 8 pages of 16 slots are 128 keys,
+# one full lane row of scores and one native MXU tile of K/V per head
 _PAGES = 8
 
 
@@ -371,14 +372,20 @@ def _first_key(start, first_row, reach):
     return jnp.maximum(start + first_row - (reach - 1), 0)
 
 
-def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
+def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, pool_ref,
+                  o_ref, buf_ref, sems, half_ref, acc_ref, m_ref, l_ref, *,
                   scale, block_size, rows, groups, shared, value, pages,
-                  reach=None):
-    """One (slot, row tile, ``pages``-page window) step of the
-    streaming softmax over the pool itself.  ``rest`` is the window's
-    page blocks (``(block_size, groups * width)`` each: one row a
-    token, ``groups`` groups of ``width`` values side by side), the
-    output block and the ``(acc, m, l)`` scratch.
+                  table, reach=None):
+    """One (slot, row tile) step of the streaming softmax over the pool
+    itself: a loop over the ``pages``-page windows the tile's rows can
+    see, and no other.  ``pool_ref`` is the pool leaf, left in HBM; the
+    kernel copies each window's pages into one half of ``buf_ref``
+    (``(2, pages * block_size, groups * width)``: one row a token,
+    ``groups`` groups of ``width`` values side by side) while the other
+    half is computed, a DMA semaphore a page of each half.  The grid
+    runs in order, and a step's last window fetches the next step's
+    first, so only the launch's first fetch is waited on with nothing
+    to compute; ``half_ref`` says which half that window is in.
 
     A query row is as wide as a group, with zeros over the lanes that
     are not key, so ``q . group`` is ``q . K`` and no lane is sliced.
@@ -389,46 +396,91 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
     lane tiles for a latent row.  Where a group's key is whole lane
     tiles itself (``head_dim`` a multiple of 128) the queries are as
     wide as the key alone and ``value`` is the group's upper half: both
-    slices are aligned, and the products run over half the lanes.  ``shared`` query heads read one
-    group; their rows lie side by side in the tile (row ``m`` is query
-    row ``m // shared``), so a sequence's heads meet a page as one
-    matrix.
+    slices are aligned, and the products run over half the lanes.
+    ``shared`` query heads read one group; their rows lie side by side
+    in the tile (row ``m`` is query row ``m // shared``), so a
+    sequence's heads meet a page as one matrix.
 
     ``reach``: a row sees the ``reach`` keys that end with its own and
-    no earlier one (a window layer).  The grid's last dimension then
-    counts windows from the one that holds the tile's first visible
-    key, so the pages before it are neither fetched nor visited."""
-    del layer_ref, tables_ref          # the index maps read them
-    page_refs = rest[:pages]
-    o_ref, acc_ref, m_ref, l_ref = rest[pages:]
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    no earlier one (a window layer), and ``table`` is a ring: the page
+    of positions ``n * block_size ..`` is entry ``n % table``.  The
+    loop then starts at the window that holds the tile's first visible
+    key."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    tiles = pl.num_programs(1)
     span = pages * block_size           # keys in one window
     tile, q_width = q_ref.shape[1], q_ref.shape[2]
-    width = page_refs[0].shape[1] // groups
-
+    width = buf_ref.shape[2] // groups
     query_row = functools.partial(_query_row, shared=shared)
-    start = starts_ref[b]
-    # the tile's last live row: beyond it the tile holds padding
-    last = start + query_row(
-        i * tile + jnp.minimum(tile, rows * shared - i * tile) - 1)
 
-    # the window of keys this step holds
-    w = j if reach is None else j + _first_key(
-        start, query_row(i * tile), reach) // span
+    def bounds(b, i):
+        """The first key and the last row of slot ``b``'s row tile
+        ``i``: beyond its last live row a tile holds padding, and a row
+        sees every key at or before its own position (the rows were
+        written before this call)."""
+        start = starts_ref[b]
+        first = 0 if reach is None else _first_key(
+            start, query_row(i * tile), reach)
+        return first, start + query_row(
+            i * tile + jnp.minimum(tile, rows * shared - i * tile) - 1)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def fetch(b, i, w, half):
+        """The copies of window ``w`` of slot ``b``'s row tile ``i``
+        into ``half``: every page slot filled, those past the tile's
+        pages with the nearest of them (a probability of 0 meets a
+        value there, never uninitialised memory)."""
+        first, last = bounds(b, i)
+        copies = []
+        for k in range(pages):
+            blk = jnp.clip(w * pages + k, first // block_size,
+                           last // block_size)
+            blk = blk % table if reach is not None else jnp.minimum(
+                blk, table - 1)
+            row0 = tables_ref[b * table + blk] * block_size
+            copies.append(pltpu.make_async_copy(
+                pool_ref.at[layer_ref[0], pl.ds(row0, block_size)],
+                buf_ref.at[half, pl.ds(k * block_size, block_size)],
+                sems.at[half, k]))
+        return copies
 
-    # a row sees every key at or before its own position (the rows were
-    # written before this call); windows past the tile's last row hold
-    # nothing any of its rows may see
-    @pl.when(w * span <= last)
-    def _window():
+    def begin(copies):
+        for copy in copies:
+            copy.start()
+
+    first, last = bounds(b, i)
+    w0, w1 = first // span, last // span
+    # the step after this one, whose first window the last window of
+    # this one fetches
+    b_next = jnp.where(i + 1 < tiles, b, b + 1)
+    i_next = jnp.where(i + 1 < tiles, i + 1, 0)
+
+    @pl.when((b == 0) & (i == 0))
+    def _prologue():
+        half_ref[0] = 0
+        begin(fetch(b, i, w0, 0))
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    half0 = half_ref[0]
+
+    def window(w, carry):
+        half = (half0 + w - w0) % 2
+
+        @pl.when(w < w1)
+        def _ahead():
+            begin(fetch(b, i, w + 1, 1 - half))
+
+        @pl.when((w == w1) & (b_next < pl.num_programs(0)))
+        def _next_step():
+            begin(fetch(b_next, i_next,
+                        bounds(b_next, i_next)[0] // span, 1 - half))
+            half_ref[0] = 1 - half
+
+        for copy in fetch(b, i, w, half):
+            copy.wait()
         key = w * span + lax.broadcasted_iota(jnp.int32, (tile, span), 1)
-        row = start + query_row(
+        row = starts_ref[b] + query_row(
             i * tile + lax.broadcasted_iota(jnp.int32, (tile, span), 0))
         seen = key <= row
         if reach is not None:
@@ -436,7 +488,7 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
 
         def group(g, carry):
             lanes = pl.ds(pl.multiple_of(g * width, width), width)
-            kv = jnp.concatenate([r[:, lanes] for r in page_refs], axis=0)
+            kv = buf_ref[half, :, lanes]
             s = lax.dot_general(q_ref[g], kv if q_width == width
                                 else kv[:, :q_width],
                                 (((1,), (1,)), ((), ())),
@@ -461,12 +513,11 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
         # slots live at GPT-2 XL, 391 rolled up; my chip run, PR 25)
         # and the host traces a twenty-fifth of it
         lax.fori_loop(0, groups, group, 0, unroll=True)
+        return carry
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _writeout():
-        # key 0 is at or before every row, so no row is fully masked
-        o_ref[...] = (acc_ref[...] / l_ref[...][:, :, :1]).astype(
-            o_ref.dtype)
+    lax.fori_loop(w0, w1 + 1, window, 0)
+    # every row sees its own key, so no row is fully masked
+    o_ref[...] = (acc_ref[...] / l_ref[...][:, :, :1]).astype(o_ref.dtype)
 
 
 # query rows one grid step holds: more rows a step reuse each K/V tile
@@ -474,18 +525,18 @@ def _paged_kernel(layer_ref, tables_ref, starts_ref, q_ref, *rest,
 _ROW_TILE = 128
 # ... where ``heads_per_group`` query heads read one key-value head's
 # ``K | V`` pair (or a layer attends a window): 64 positions of 8 heads
-# a tile and 256 keys a step.  Every group's accumulator is in VMEM at
+# a tile and 256 keys a window.  Every group's accumulator is in VMEM at
 # once (8 groups of 512 rows of 128 float32 lanes are 2 MiB)
 _GROUP_ROW_TILE = 512
 _GROUP_PAGES = 16
 # ... and where heads share a row: 32 positions of 32 heads a tile and
-# 512 keys a step.  The accumulator (row tile x value, float32) is
-# rescaled once a step whatever the step's keys, so more keys a step
-# cost less of it.  A chunk of 256 at 8,192 cached rows, one layer at
-# the long-document cell's shapes: 2.94 ms at 8 pages and a tile of 512,
-# 1.81 at 32 pages, 1.37 at 32 pages and a tile of 1,024 (58% of the
-# MXU's peak on the absorbed product); 8 slots decoding at 12,000: 0.97,
-# 0.71, 0.71 (my chip run, PR 27).
+# 512 keys a window.  The accumulator (row tile x value, float32) is
+# rescaled once a window whatever the window's keys, so more keys a
+# window cost less of it.  A chunk of 256 at 8,192 cached rows, one
+# layer at the long-document cell's shapes: 2.94 ms at 8 pages and a
+# tile of 512, 1.81 at 32 pages, 1.37 at 32 pages and a tile of 1,024
+# (58% of the MXU's peak on the absorbed product); 8 slots decoding at
+# 12,000: 0.97, 0.71, 0.71 (one TPU v5e).
 _SHARED_ROW_TILE = 1024
 _SHARED_PAGES = 32
 
@@ -494,12 +545,13 @@ _SHARED_PAGES = 32
     "block_size", "rows", "shared", "value", "scale", "name", "interpret",
     "window", "tile", "reach"))
 def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
-                  shared, value, scale, name, interpret, window=_PAGES,
-                  tile=None, reach=None):
+                  shared, value, scale, name, interpret, window, tile,
+                  reach=None):
     """q4: (B, G, Mp, W) queries, one row of a group's width a tile
     row, zero over the lanes that are not key, Mp a whole number of row
     tiles (``shared`` heads' rows of one position side by side); pages:
-    the pool leaf (L, num_slots, G * W); layer (1,), tables
+    the pool leaf (L, num_slots, G * W), left in HBM for the kernel to
+    copy its windows from; layer (1,), tables
     (B * blocks_per_seq,), starts (B,) int32 are prefetched scalars.
     With ``reach`` the table is a ring: the page of positions
     ``n * block_size ..`` is entry ``n % blocks_per_seq``."""
@@ -507,43 +559,16 @@ def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
     nb = tables.shape[0] // b
     width = pages.shape[2]
     window = min(window, nb)
-    tile = min(mp, tile or _ROW_TILE)
     vw = value[1] - value[0]
-
-    def page_spec(k):
-        def index(bi, ii, ji, layer_ref, tables_ref, starts_ref):
-            # the window's k-th page, held at the tile's last live
-            # page beyond it: a block index that does not change is
-            # not fetched again
-            last = (starts_ref[bi] + _query_row(jnp.minimum(
-                (ii + 1) * tile, rows * shared) - 1, shared)
-                    ) // block_size
-            if reach is None:
-                blk = jnp.minimum(jnp.minimum(ji * window + k, last),
-                                  nb - 1)
-            else:
-                # ... and at the first visible key's page before it
-                first = _first_key(starts_ref[bi],
-                                   _query_row(ii * tile, shared), reach)
-                blk = jnp.clip(
-                    (first // (window * block_size) + ji) * window + k,
-                    first // block_size, last) % nb
-            return layer_ref[0], tables_ref[bi * nb + blk], 0
-        return pl.BlockSpec((None, block_size, width), index)
 
     def rows_spec(lanes):
         return pl.BlockSpec((None, g, tile, lanes),
-                            lambda bi, ii, ji, *_: (bi, 0, ii, 0))
+                            lambda bi, ii, *_: (bi, 0, ii, 0))
 
     kernel = functools.partial(_paged_kernel, scale=scale,
                                block_size=block_size, rows=rows, groups=g,
                                shared=shared, value=value, pages=window,
-                               reach=reach)
-    # windows a tile's rows can reach: all of the table's, or those
-    # that ``reach`` keys before the first row up to the last row touch
-    steps = _cdiv(nb, window) if reach is None else (
-        reach + _cdiv(tile, shared) + window * block_size - 3
-    ) // (window * block_size) + 1
+                               table=nb, reach=reach)
     itemsize = jnp.dtype(q4.dtype).itemsize
     vmem = (g * tile * (vw + 2 * LANES) * 4         # acc, m, l
             + 2 * g * tile * (gw + vw) * itemsize   # q and out, twice
@@ -552,23 +577,67 @@ def _paged_pallas(layer, tables, starts, q4, pages, *, block_size, rows,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, mp // tile, steps),
-            in_specs=[rows_spec(gw)] + [page_spec(k)
-                                        for k in range(window)],
+            grid=(b, mp // tile),
+            in_specs=[rows_spec(gw), pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=rows_spec(vw),
-            scratch_shapes=[pltpu.VMEM((g, tile, vw), jnp.float32),
-                            pltpu.VMEM((g, tile, LANES), jnp.float32),
-                            pltpu.VMEM((g, tile, LANES), jnp.float32)]),
+            scratch_shapes=[
+                pltpu.VMEM((2, window * block_size, width), pages.dtype),
+                pltpu.SemaphoreType.DMA((2, window)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((g, tile, vw), jnp.float32),
+                pltpu.VMEM((g, tile, LANES), jnp.float32),
+                pltpu.VMEM((g, tile, LANES), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, g, mp, vw), q4.dtype,
                                        vma=union_vma(q4, pages)),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # in order: each step fetches the next one's first window
+            dimension_semantics=("arbitrary", "arbitrary"),
             # the default scoped limit is 16 MiB; a chunk's row tiles
             # need about that, so ask for what is used and half again
             vmem_limit_bytes=max(16 * 2 ** 20, vmem * 3 // 2)),
         interpret=interpret,
         name=name,
-    )(layer, tables, starts, q4, *([pages] * window))
+    )(layer, tables, starts, q4, pages)
+
+
+def _loop_shape(rows: int, heads: int, heads_per_group: int, latent: bool,
+                windowed: bool):
+    """The page loop's shape for ``rows`` query rows of ``heads`` heads
+    a sequence: ``(shared, padded, tile, pages)``, the heads whose rows
+    lie side by side in a tile, the rows a sequence padded to whole row
+    tiles (or whole sublane pairs, under one tile), the row tile and the
+    pages a window."""
+    if latent:
+        shared, unit, tile, pages = (heads, 2 * _QROWS, _SHARED_ROW_TILE,
+                                     _SHARED_PAGES)
+    elif heads_per_group > 1 or windowed:
+        shared, unit, tile, pages = (heads_per_group, 2 * _QROWS,
+                                     _GROUP_ROW_TILE, _GROUP_PAGES)
+    else:
+        shared, unit, tile, pages = 1, _QROWS, _ROW_TILE, _PAGES
+    m = rows * shared
+    padded = _cdiv(m, unit) * unit
+    if padded > tile:
+        padded = _cdiv(m, tile) * tile
+    return shared, padded, min(padded, tile), pages
+
+
+def page_windows(starts, rows: int, table: int, *, block_size: int,
+                 heads: int, heads_per_group: int = 1,
+                 latent: bool = False):
+    """What one launch of :func:`paged_attention` over a block table
+    (no ``window``) does, counted on the host from what the launch is
+    given: ``(windows the loop visits, windows a grid over the whole
+    table would run)``, each summed over the sequences and their row
+    tiles.  ``starts`` (B,) each sequence's first row's position,
+    ``rows`` its rows, ``table`` the entries of its block table."""
+    shared, padded, tile, pages = _loop_shape(rows, heads, heads_per_group,
+                                              latent, False)
+    pages = min(pages, table)
+    ends = np.minimum(np.arange(tile, padded + 1, tile), rows * shared)
+    last = np.asarray(starts, np.int64)[:, None] + (ends - 1) // shared
+    return (int(np.sum(last // (pages * block_size) + 1)),
+            int(last.size) * _cdiv(table, pages))
 
 
 def paged_attention_fits(head_dim: int, block_size: int, dtype) -> bool:
@@ -632,9 +701,10 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
         are read.
       interpret: Pallas interpret mode (defaults to not-on-TPU).
 
-    Only the pages up to each sequence's last row are streamed; nothing
-    of ``max_context`` size is built.  fp32 scores, softmax state and
-    accumulation; the probabilities meet V in the pool's dtype, as the
+    The grid runs over sequences and row tiles; a loop in the kernel
+    visits only the windows of pages a tile's rows can see, whatever the
+    table's length, and nothing of ``max_context`` size is built.  fp32
+    scores, softmax state and accumulation; the probabilities meet V in the pool's dtype, as the
     jnp oracle's do.  Returns (B, R, H, D) in q.dtype, or
     (B, R, H, latent_value).  The Pallas call is named
     ``_decode_kernel`` for one row, ``_verify_kernel`` for up to a
@@ -661,18 +731,15 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
                 f"paged_attention cannot tile a latent row of {d}, "
                 f"block_size={block_size}, dtype={pages.dtype}")
         # the heads of one position side by side: row m is (m // H)
+        _, mp, tile, window_pages = _loop_shape(r, h, 1, True, False)
         m = r * h
-        mp = _cdiv(m, 2 * _QROWS) * 2 * _QROWS
-        if mp > _SHARED_ROW_TILE:
-            mp = _cdiv(m, _SHARED_ROW_TILE) * _SHARED_ROW_TILE
         q4 = jnp.pad(q.astype(pages.dtype).reshape(b, 1, m, d),
                      ((0, 0), (0, 0), (0, mp - m), (0, 0)))
         out = _paged_pallas(
             *scalars, q4, pages, block_size=int(block_size), rows=int(r),
             shared=int(h), value=(0, int(latent_value)),
             scale=float(scale), name=_kernel_name(r, "_latent"),
-            interpret=bool(interpret), window=_SHARED_PAGES,
-            tile=_SHARED_ROW_TILE)
+            interpret=bool(interpret), window=window_pages, tile=tile)
         return out[:, 0, :m].reshape(b, r, h, latent_value).astype(q.dtype)
     g = h // heads_per_group
     if pages.ndim != 3 or pages.shape[2] != g * 2 * d or h % heads_per_group:
@@ -686,6 +753,8 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
             f"block_size={block_size}, dtype={pages.dtype}")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    _, mp, tile, window_pages = _loop_shape(
+        r, h, heads_per_group, False, window is not None)
     if heads_per_group > 1 or window is not None:
         ring = block_tables.shape[1] * block_size
         if window is not None and ring < window + r:
@@ -696,9 +765,6 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
         # g is (position m // heads_per_group, head g * heads_per_group
         # + m % heads_per_group)
         m = r * heads_per_group
-        mp = _cdiv(m, 2 * _QROWS) * 2 * _QROWS
-        if mp > _GROUP_ROW_TILE:
-            mp = _cdiv(m, _GROUP_ROW_TILE) * _GROUP_ROW_TILE
         # a key of whole lane tiles is sliced from its group for nothing:
         # the queries are then as wide as the key and the value is the
         # group's upper half; a narrower one meets the whole group
@@ -711,20 +777,17 @@ def paged_attention(q, pages, layer, block_tables, starts, *,
             shared=int(heads_per_group),
             value=(d, 2 * d) if split else (0, 2 * d), scale=float(scale),
             name=_kernel_name(r, "" if window is None else "_window"),
-            interpret=bool(interpret), window=_GROUP_PAGES,
-            tile=_GROUP_ROW_TILE,
+            interpret=bool(interpret), window=window_pages, tile=tile,
             reach=None if window is None else int(window))
         out = out[:, :, :m, -d:].reshape(b, g, r, heads_per_group, d)
         return jnp.moveaxis(out, 1, 2).reshape(b, r, h, d).astype(q.dtype)
-    rp = _cdiv(r, _QROWS) * _QROWS
-    if rp > _ROW_TILE:
-        rp = _cdiv(r, _ROW_TILE) * _ROW_TILE
     q4 = jnp.pad(jnp.swapaxes(q, 1, 2).astype(pages.dtype),
-                 ((0, 0), (0, 0), (0, rp - r), (0, d)))
+                 ((0, 0), (0, 0), (0, mp - r), (0, d)))
     out = _paged_pallas(
         *scalars, q4, pages, block_size=int(block_size), rows=int(r),
         shared=1, value=(0, 2 * d), scale=float(scale),
-        name=_kernel_name(r), interpret=bool(interpret))
+        name=_kernel_name(r), interpret=bool(interpret),
+        window=window_pages, tile=tile)
     return jnp.swapaxes(out[:, :, :r, d:], 1, 2).astype(q.dtype)
 
 

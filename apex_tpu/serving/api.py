@@ -143,6 +143,7 @@ from apex_tpu.observability import (
     resolve_journeys,
     write_postmortem,
 )
+from apex_tpu.ops.decode_attention import page_windows
 from apex_tpu.ops.sampling import SamplingParams, sample_tokens_host
 from apex_tpu.resilience.breaker import CircuitBreaker
 from apex_tpu.serving.engine import DecodeEngine
@@ -1311,7 +1312,9 @@ class InferenceServer:
             # predating the stochastic twins keep working
             skw = {"sampling": samp} if samp is not None else {}
         with tr.span("chunk_prefill", uid=req.uid, tokens=len(tokens),
-                     start=start, **_rid(req)):
+                     start=start, **_rid(req),
+                     **self._kv_windows("chunk_prefill", [start],
+                                        self.prefill_chunk)):
             # whose rings a model's window and state layers use
             ring = {"slot": req.slot} if engine.layers is not None else {}
             try:
@@ -1478,6 +1481,25 @@ class InferenceServer:
         return {k: c(k) - m
                 for k, m in zip(self._OFFLOAD_EVENTS, marks)}
 
+    def _kv_windows(self, program, starts, rows) -> dict:
+        """A launch's ``kv_windows`` and ``kv_windows_table``, only
+        while the tracer records and where ``program`` attends through
+        the block table: the windows the page loop of the layers that
+        keep every token visits, summed over slots and row tiles, and
+        those a grid over the whole table would have run
+        (``ops.decode_attention.page_windows``)."""
+        engine = self.engine
+        if not self.tracer.enabled or getattr(
+                engine, "attention_paths", {}).get(program) != "table":
+            return {}
+        row = engine.row
+        visited, table = page_windows(
+            starts, rows, engine.blocks_per_seq,
+            block_size=engine.block_size, heads=row.heads,
+            heads_per_group=row.heads_per_group,
+            latent=row.kind == "latent")
+        return {"kv_windows": visited, "kv_windows_table": table}
+
     def _decode_inputs(self, running):
         """The decode launch arrays — (tokens, positions, tables),
         inactive slots zeroed — shared by the synchronous and
@@ -1547,7 +1569,8 @@ class InferenceServer:
             kw = {"sampling": samp} if samp is not None else {}
         try:
             with tr.span("launch", program="decode",
-                         batch=len(running)):
+                         batch=len(running),
+                         **self._kv_windows("decode", positions, 1)):
                 ids, fin = engine.decode_sampled(tokens, positions,
                                                  tables, **kw)
                 self._compile_verify_beside(kw)
@@ -1764,7 +1787,9 @@ class InferenceServer:
         try:
             with tr.span("launch", program="verify",
                          batch=len(running),
-                         drafted=sum(len(v) for v in drafts.values())):
+                         drafted=sum(len(v) for v in drafts.values()),
+                         **self._kv_windows("verify", positions,
+                                            self.spec_tokens + 1)):
                 ids, fin = engine.verify_sampled(tokens, lengths,
                                                  positions, tables,
                                                  **kw)

@@ -58,9 +58,10 @@ How a program attends is fixed when the engine is built
   whole sublane tiles).
   Decode, verify and chunk prefill write a layer's rows, then
   ``ops.decode_attention.paged_attention`` takes the pool itself with
-  the block tables and lengths as prefetched scalars and streams only
-  the pages up to each slot's last row: no ``(B, max_context)`` copy of
-  keys and values, no bias row, no concatenation, no pad.  (The chunk
+  the block tables and lengths as prefetched scalars and visits only
+  the windows of pages up to each slot's last row: no
+  ``(B, max_context)`` copy of keys and values, no bias row, no
+  concatenation, no pad.  (The chunk
   program took the kernel by measurement: 13.0 ms a launch at GPT-2 XL
   against 20.0 ms gathering its one request's context; my chip run,
   PR 25.)

@@ -6,7 +6,7 @@ this sweeps the knobs that move single-chip throughput and prints one JSON
 line per point, so block sizes / batch sizes can be chosen from data
 rather than defaults.
 
-Usage: ``python tools/perf_sweep.py [--quick | --sampler]``
+Usage: ``python tools/perf_sweep.py [--quick | --sampler | --paged]``
 """
 
 import argparse
@@ -210,12 +210,110 @@ def sweep_sampler(shapes=SAMPLER_SHAPES, iters=20, sample_tokens=None):
     return rows
 
 
+# the page loop at the serving cells' shapes (PERF.md section 4): slots,
+# block-table entries, query heads, key-value heads, head size and the
+# mean context of the cell's mix
+PAGED_SHAPES = {
+    "kexaone": dict(slots=16, table=2048, heads=64, kv_heads=8, head_dim=128,
+                    mean=4000),
+    "lfm2": dict(slots=64, table=384, heads=32, kv_heads=8, head_dim=64,
+                 mean=1800),
+    "gpt2xl": dict(slots=8, table=64, heads=25, kv_heads=25, head_dim=64,
+                   mean=200),
+}
+# a decode launch's row, a verify launch's five, a prefill chunk's 256
+PAGED_ROWS = (1, 5, 256)
+
+
+def sweep_paged(shapes=PAGED_SHAPES, rows_list=PAGED_ROWS, iters=10,
+                block_size=16):
+    """``ops.decode_attention.paged_attention`` ALONE, jitted, at each
+    cell's shape for decode, verify and chunk rows (a chunk launch is one
+    slot's), every slot's context (its rows included) at one page, at
+    the mix's mean and at the whole table.  Each context below the
+    whole table is timed twice: through a table of the server's length
+    and through one cut to the context, so that the difference is what
+    the windows past a slot's last row cost.  One JSON line a point: the
+    milliseconds a launch takes on the DEVICE's clock, the windows the
+    contexts hold and the table has, and the context's bytes over the
+    time; then one line a pair with the microseconds an empty window
+    costs (``empty_window_us``: a window of a slot's table past its
+    context, over all the slot's row tiles).  On the CPU a point carries an error and no time.
+    Returns the rows."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from apex_tpu.ops import decode_attention as da
+
+    rows_out = []
+    for cell, sh in shapes.items():
+        hpg = sh["heads"] // sh["kv_heads"]
+        d, nb, slots = sh["head_dim"], sh["table"], sh["slots"]
+        width = sh["kv_heads"] * 2 * d
+        # every slot's whole table in pages of its own, behind block 0
+        pool = jax.random.normal(
+            jax.random.PRNGKey(0), (1, (slots * nb + 1) * block_size, width),
+            jnp.bfloat16)
+        pages = da._GROUP_PAGES if hpg > 1 else da._PAGES
+        for r in rows_list:
+            b = 1 if r > da._QROWS else slots
+            q = jax.random.normal(jax.random.PRNGKey(r),
+                                  (b, r, sh["heads"], d), jnp.bfloat16)
+            launch = jax.jit(functools.partial(
+                da.paged_attention, block_size=block_size,
+                heads_per_group=hpg, interpret=False))
+            name = da._kernel_name(r)
+            full = nb * block_size
+            for ctx in (block_size, sh["mean"], full):
+                ctx = min(max(ctx, r), full)
+                used = -(-ctx // block_size)
+                tables = np.zeros((b, nb), np.int32)
+                tables[:, :used] = (1 + np.arange(b)[:, None] * nb
+                                    + np.arange(used)[None])
+                starts = jnp.full((b,), ctx - r, jnp.int32)
+                ms = {}
+                for cut in ((nb, used) if used < nb else (nb,)):
+                    row = {"sweep": "paged", "cell": cell, "kernel": name,
+                           "slots": b, "rows": r, "context": ctx,
+                           "table": cut,
+                           "windows": b * -(-used // min(pages, cut)),
+                           "table_windows": b * -(-cut // min(pages, cut))}
+                    try:
+                        ms[cut] = _kernel_ms(
+                            launch, (q, pool, jnp.int32(0),
+                                     jnp.asarray(tables[:, :cut]), starts),
+                            iters, name)
+                        row["ms"] = round(ms[cut], 4)
+                        row["context_gb_per_s"] = round(
+                            b * ctx * width * 2 / (ms[cut] * 1e-3) / 1e9, 1)
+                    except Exception as e:
+                        row["error"] = f"{type(e).__name__}: {e}"[:200]
+                    rows_out.append(row)
+                    print(json.dumps(row), flush=True)
+                if len(ms) == 2:
+                    empty = b * (-(-nb // pages) - -(-used // pages))
+                    row = {"sweep": "paged_empty", "cell": cell,
+                           "kernel": name, "rows": r, "context": ctx,
+                           "empty_windows": empty,
+                           "empty_window_us": round(
+                               (ms[nb] - ms[used]) * 1e3 / empty, 4)}
+                    rows_out.append(row)
+                    print(json.dumps(row), flush=True)
+    return rows_out
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="fewer points / iterations")
     ap.add_argument("--sampler", action="store_true",
                     help="only the serving sampler at the cells' shapes")
+    ap.add_argument("--paged", action="store_true",
+                    help="only the paged-attention page loop at the serving "
+                         "cells' shapes")
     args = ap.parse_args()
 
     from apex_tpu.ops.pallas_utils import require_tpu
@@ -229,6 +327,9 @@ def main():
 
     if args.sampler:
         sweep_sampler()
+        return
+    if args.paged:
+        sweep_paged()
         return
     iters = 5 if args.quick else 20
     sweep_resnet([128] if args.quick else [64, 128, 256], iters)
